@@ -18,7 +18,6 @@ import ctypes
 import os
 import re
 import struct
-import subprocess
 import threading
 from opengemini_tpu.utils import lockdep
 
@@ -30,74 +29,46 @@ _LIB = None
 _TRIED = False
 
 
-def _lib_path() -> str:
-    return os.path.abspath(os.path.join(
-        os.path.dirname(__file__), "..", "..", "native",
-        "libogtseriesindex.so"))
-
-
 def load():
-    """The loaded library or None. Never raises."""
+    """The series-index library or None (native.open_library builds a
+    missing one and rebuilds a stale one; the reason it did not load is
+    in native.report())."""
     global _LIB, _TRIED
-    if _TRIED:
-        return _LIB
-    _TRIED = True
-    path = _lib_path()
-    if not os.path.exists(path):
-        _build()
-    if not os.path.exists(path):
-        return None
-    _LIB = _load_at(path)
-    if _LIB is None:
-        # a stale .so from before a symbol was added: rebuild once and
-        # retry — refusing to open existing mergeset dirs over a fixable
-        # build is much worse than one make invocation
-        _build()
-        _LIB = _load_at(path)
+    if not _TRIED:
+        _TRIED = True
+        from opengemini_tpu import native
+
+        _LIB = native.open_library("seriesindex", _bind)
     return _LIB
 
 
-def _load_at(path: str):
-    try:
-        lib = ctypes.CDLL(path)
-        u64 = ctypes.c_uint64
-        p = ctypes.c_void_p
-        cp = ctypes.c_char_p
-        u64p = ctypes.POINTER(u64)
-        for name, res, args in [
-            ("msi_open", p, [cp]),
-            ("msi_close", None, [p]),
-            ("msi_free", None, [p]),
-            ("msi_insert", u64, [p, cp, u64, u64]),
-            ("msi_insert_keys", u64, [p, cp, u64, u64, u64p]),
-            ("msi_lookup", u64, [p, cp, u64]),
-            ("msi_has_live", ctypes.c_int, [p, cp, u64]),
-            ("msi_series_ids", p, [p, cp, u64, u64p]),
-            ("msi_match_eq", p, [p, cp, u64, cp, u64, cp, u64, u64p]),
-            ("msi_enum_field", p, [p, ctypes.c_char, cp, u64,
-                                   ctypes.c_uint32, u64p, u64p]),
-            ("msi_key_of", p, [p, u64, u64p]),
-            ("msi_keys_of", p, [p, u64p, u64, u64p]),
-            ("msi_remove_sids", None, [p, u64p, u64]),
-            ("msi_flush", None, [p]),
-            ("msi_compact", None, [p]),
-            ("msi_stats", None, [p, u64p, u64p, u64p, u64p]),
-        ]:
-            fn = getattr(lib, name)
-            fn.restype = res
-            fn.argtypes = args
-        return lib
-    except (OSError, AttributeError):
-        return None
-
-
-def _build() -> None:
-    d = os.path.dirname(_lib_path())
-    try:
-        subprocess.run(["make", "-C", d, "libogtseriesindex.so"],
-                       check=True, capture_output=True)
-    except (OSError, subprocess.CalledProcessError):
-        pass
+def _bind(lib) -> None:
+    u64 = ctypes.c_uint64
+    p = ctypes.c_void_p
+    cp = ctypes.c_char_p
+    u64p = ctypes.POINTER(u64)
+    for name, res, args in [
+        ("msi_open", p, [cp]),
+        ("msi_close", None, [p]),
+        ("msi_free", None, [p]),
+        ("msi_insert", u64, [p, cp, u64, u64]),
+        ("msi_insert_keys", u64, [p, cp, u64, u64, u64p]),
+        ("msi_lookup", u64, [p, cp, u64]),
+        ("msi_has_live", ctypes.c_int, [p, cp, u64]),
+        ("msi_series_ids", p, [p, cp, u64, u64p]),
+        ("msi_match_eq", p, [p, cp, u64, cp, u64, cp, u64, u64p]),
+        ("msi_enum_field", p, [p, ctypes.c_char, cp, u64,
+                               ctypes.c_uint32, u64p, u64p]),
+        ("msi_key_of", p, [p, u64, u64p]),
+        ("msi_keys_of", p, [p, u64p, u64, u64p]),
+        ("msi_remove_sids", None, [p, u64p, u64]),
+        ("msi_flush", None, [p]),
+        ("msi_compact", None, [p]),
+        ("msi_stats", None, [p, u64p, u64p, u64p, u64p]),
+    ]:
+        fn = getattr(lib, name)
+        fn.restype = res
+        fn.argtypes = args
 
 
 def _field(b: bytes) -> bytes:

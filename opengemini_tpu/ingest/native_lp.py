@@ -18,8 +18,6 @@ ingest/line_protocol.py, which remains the semantic reference.
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
 
 import numpy as np
 
@@ -57,46 +55,26 @@ class _LpBatch(ctypes.Structure):
     ]
 
 
-def _lib_path() -> str:
-    return os.path.abspath(os.path.join(
-        os.path.dirname(__file__), "..", "..", "native",
-        "libogtlineproto.so"))
-
-
-def _build() -> None:
-    src_dir = os.path.dirname(_lib_path())
-    try:
-        subprocess.run(
-            ["make", "-C", src_dir, "libogtlineproto.so"],
-            capture_output=True, timeout=120, check=False,
-        )
-    except (OSError, subprocess.TimeoutExpired):
-        pass
+def _bind(lib) -> None:
+    lib.ogt_lp_parse.restype = ctypes.POINTER(_LpBatch)
+    lib.ogt_lp_parse.argtypes = [
+        ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_int64, ctypes.c_int64,
+    ]
+    lib.ogt_lp_free.restype = None
+    lib.ogt_lp_free.argtypes = [ctypes.POINTER(_LpBatch)]
 
 
 def load():
-    """The loaded library or None. Never raises."""
+    """The line-protocol parser library or None (native.open_library
+    builds a missing one; the reason it did not load is in
+    native.report())."""
     global _LIB, _TRIED
-    if _TRIED:
-        return _LIB
-    _TRIED = True
-    path = _lib_path()
-    if not os.path.exists(path):
-        _build()
-    if not os.path.exists(path):
-        return None
-    try:
-        lib = ctypes.CDLL(path)
-        lib.ogt_lp_parse.restype = ctypes.POINTER(_LpBatch)
-        lib.ogt_lp_parse.argtypes = [
-            ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,
-            ctypes.c_int64, ctypes.c_int64,
-        ]
-        lib.ogt_lp_free.restype = None
-        lib.ogt_lp_free.argtypes = [ctypes.POINTER(_LpBatch)]
-        _LIB = lib
-    except (OSError, AttributeError):
-        _LIB = None
+    if not _TRIED:
+        _TRIED = True
+        from opengemini_tpu import native
+
+        _LIB = native.open_library("lineproto", _bind)
     return _LIB
 
 

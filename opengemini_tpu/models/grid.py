@@ -4,8 +4,8 @@ fast path (ops/segment.py grid_window_agg_t).
 TSBS-shaped data — every series sampled on a constant stride — lets
 windowed aggregation skip segment machinery entirely: place samples into
 a dense (series_run, samples_per_window, num_windows) grid and every
-per-window statistic is one sublane-axis reduce (measured 132-290 G
-rows/s on v5e-1 vs 62-79 G for the bucketed layout; bench.py config #1).
+per-window statistic is one sublane-axis reduce (its speed against the
+bucketed layout: not measured on the present code).
 The reference reaches its regular fast path through pre-aggregation
 metadata + the interval cursor (engine/immutable/pre_aggregation.go:40,
 engine/aggregate_cursor.go:343); here regularity is detected per scan and
@@ -886,13 +886,10 @@ def _grid_jit(shape: tuple, dtype: str, kind: str):
     devobs.note_compile("grid_" + kind, (shape, dtype))
 
     if kind == "basic":
-        # deliberately XLA, not the Pallas grid kernel: the recorded v5e
-        # measurements (ops/pallas_segment.py module docstring) show XLA's
-        # own fusion WINNING for the pure grid reductions (~28-55 vs
-        # ~22-48 G rows/s) — only the selector lex-scans benefit from
-        # Pallas. Measurement beats ideology; it also keeps GSPMD row
-        # sharding working under a device mesh (pallas_call does not
-        # auto-partition).
+        # XLA, not the Pallas grid kernel (ops/pallas_segment.py): the
+        # plain reduce is what GSPMD can row-shard under a device mesh
+        # (pallas_call does not auto-partition).  Which of the two is
+        # faster on one chip: not measured on the present code.
 
         @jax.jit
         def basic(v, m):

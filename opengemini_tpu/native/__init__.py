@@ -1,10 +1,15 @@
-"""ctypes bindings for the C++ codec library (native/codecs.cpp).
+"""ctypes bindings for the C++ libraries under native/, and the one
+place that builds and opens them.
 
 The reference uses cgo for its native pieces (textindex, lz4, rocksdb);
 pybind11 isn't in this image, so the bridge is a plain C ABI + ctypes
-(SURVEY.md environment notes). Missing/unbuilt library degrades
-gracefully: encoders fall back to the pure-Python/zlib paths, and the
-pure-Python gorilla/varint decoders below keep every file readable.
+(SURVEY.md environment notes).  `.gitignore` excludes the built `.so`
+files, so a clean checkout has none: `open_library` runs the library's
+make target when the file is missing.  A library that still cannot be
+built or opened leaves its callers on their pure-Python paths (every
+file stays readable), and the reason is kept for `report()` — the
+server prints it at start-up (chip_smoke.py fails on it) and in SHOW
+DIAGNOSTICS, instead of running slow in silence.
 """
 
 from __future__ import annotations
@@ -15,54 +20,110 @@ import subprocess
 
 import numpy as np
 
+NATIVE_DIR = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "..", "native"))
+LIBRARIES = ("codecs", "textindex", "seriesindex", "lineproto")
+
+# library -> "" once loaded, else why it did not load
+_status: dict[str, str] = {}
+
+
+def lib_path(name: str) -> str:
+    return os.path.join(NATIVE_DIR, f"libogt{name}.so")
+
+
+def _make(name: str, force: bool = False) -> str:
+    """Run one library's make target; "" on success, else what failed."""
+    cmd = ["make", "-C", NATIVE_DIR, f"libogt{name}.so"]
+    if force:
+        cmd.insert(1, "-B")
+    try:
+        r = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return f"make: {type(e).__name__}: {e}"
+    if r.returncode != 0:
+        tail = (r.stderr or r.stdout).strip().splitlines()[-3:]
+        return f"make exited {r.returncode}: " + " | ".join(tail)
+    return ""
+
+
+def open_library(name: str, bind):
+    """The loaded library, or None with the reason recorded.
+
+    Builds `native/libogt<name>.so` from its source first when the file
+    is missing, opens it, and lets ``bind(lib)`` declare the signatures.
+    A library that lacks a symbol `bind` asks for is a stale build from
+    before the symbol existed: it is rebuilt once and opened again."""
+    path = lib_path(name)
+    why = "" if os.path.exists(path) else _make(name)
+    for rebuilt in (False, True):
+        if why:
+            break
+        try:
+            lib = ctypes.CDLL(path)
+            bind(lib)
+        except OSError as e:
+            why = f"dlopen {path}: {e}"
+        except AttributeError as e:
+            why = f"{path}: {e}"
+            if not rebuilt:
+                why = _make(name, force=True)
+        else:
+            _status[name] = ""
+            return lib
+    _status[name] = why
+    return None
+
+
+def load_all() -> dict[str, str]:
+    """Open all four libraries (building any that is missing) and return
+    {library: "" if loaded else why not}."""
+    from opengemini_tpu.index import mergeset
+    from opengemini_tpu.ingest import native_lp
+    from opengemini_tpu.native import textindex
+
+    load()
+    textindex._load()
+    mergeset.load()
+    native_lp.load()
+    return {name: _status[name] for name in LIBRARIES}
+
+
+def report() -> str:
+    """load_all() as one line: `codecs=loaded textindex=NOT LOADED (why)`."""
+    return " ".join(
+        f"{name}={'loaded' if not why else 'NOT LOADED (' + why + ')'}"
+        for name, why in load_all().items())
+
+
 _LIB = None
 _TRIED = False
 
 
-def _lib_path() -> str:
-    return os.path.join(os.path.dirname(__file__), "..", "..", "native", "libogtcodecs.so")
+def _bind(lib) -> None:
+    sig = [ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64]
+    for name in ("ogt_gorilla_encode", "ogt_gorilla_decode",
+                 "ogt_varint_delta_encode", "ogt_varint_delta_decode"):
+        fn = getattr(lib, name)
+        fn.restype = ctypes.c_int64
+        fn.argtypes = sig
 
 
 def load():
-    """The loaded library or None. Never raises."""
+    """The codec library or None (see open_library)."""
     global _LIB, _TRIED
-    if _TRIED:
-        return _LIB
-    _TRIED = True
-    path = os.path.abspath(_lib_path())
-    if not os.path.exists(path):
-        return None
-    try:
-        lib = ctypes.CDLL(path)
-        for name, restype, argtypes in [
-            ("ogt_gorilla_encode", ctypes.c_int64,
-             [ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64]),
-            ("ogt_gorilla_decode", ctypes.c_int64,
-             [ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64]),
-            ("ogt_varint_delta_encode", ctypes.c_int64,
-             [ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64]),
-            ("ogt_varint_delta_decode", ctypes.c_int64,
-             [ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64]),
-        ]:
-            fn = getattr(lib, name)
-            fn.restype = restype
-            fn.argtypes = argtypes
-        _LIB = lib
-    except OSError:
-        _LIB = None
+    if not _TRIED:
+        _TRIED = True
+        _LIB = open_library("codecs", _bind)
     return _LIB
 
 
 def build() -> bool:
-    """Compile the library with g++ (used by native.build / tests)."""
-    d = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "..", "native"))
-    try:
-        subprocess.run(["make", "-C", d], check=True, capture_output=True)
-    except (subprocess.CalledProcessError, OSError):
+    """Rebuild the codec library from source and reload it (tests)."""
+    global _TRIED
+    if _make("codecs", force=True):
         return False
-    global _TRIED, _LIB
     _TRIED = False
-    _LIB = None
     return load() is not None
 
 

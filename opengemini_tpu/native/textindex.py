@@ -9,7 +9,6 @@ shard-persistent text indexes layer on top of this in the logstore round.
 from __future__ import annotations
 
 import ctypes
-import os
 
 import numpy as np
 
@@ -17,33 +16,28 @@ _LIB = None
 _TRIED = False
 
 
+def _bind(lib) -> None:
+    lib.ogt_text_index_new.restype = ctypes.c_void_p
+    lib.ogt_text_index_free.argtypes = [ctypes.c_void_p]
+    lib.ogt_text_index_add.argtypes = [
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_char_p, ctypes.c_int64
+    ]
+    lib.ogt_text_index_search.restype = ctypes.c_int64
+    lib.ogt_text_index_search.argtypes = [
+        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int64,
+        ctypes.c_void_p, ctypes.c_int64,
+    ]
+    lib.ogt_text_index_tokens.restype = ctypes.c_int64
+    lib.ogt_text_index_tokens.argtypes = [ctypes.c_void_p]
+
+
 def _load():
     global _LIB, _TRIED
-    if _TRIED:
-        return _LIB
-    _TRIED = True
-    path = os.path.abspath(
-        os.path.join(os.path.dirname(__file__), "..", "..", "native", "libogttextindex.so")
-    )
-    if not os.path.exists(path):
-        return None
-    try:
-        lib = ctypes.CDLL(path)
-        lib.ogt_text_index_new.restype = ctypes.c_void_p
-        lib.ogt_text_index_free.argtypes = [ctypes.c_void_p]
-        lib.ogt_text_index_add.argtypes = [
-            ctypes.c_void_p, ctypes.c_int64, ctypes.c_char_p, ctypes.c_int64
-        ]
-        lib.ogt_text_index_search.restype = ctypes.c_int64
-        lib.ogt_text_index_search.argtypes = [
-            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int64,
-            ctypes.c_void_p, ctypes.c_int64,
-        ]
-        lib.ogt_text_index_tokens.restype = ctypes.c_int64
-        lib.ogt_text_index_tokens.argtypes = [ctypes.c_void_p]
-        _LIB = lib
-    except OSError:
-        _LIB = None
+    if not _TRIED:
+        _TRIED = True
+        from opengemini_tpu import native
+
+        _LIB = native.open_library("textindex", _bind)
     return _LIB
 
 

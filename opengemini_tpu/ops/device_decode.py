@@ -24,8 +24,8 @@ Decodable block kinds (encoding.DeviceBlock):
   gorilla  XOR-compressed float64: a host structural scan walks the
            control bits once per block (cached) and emits per-value
            (bitpos, mbits, shift) aux vectors; the device unpacks the
-           payload to bits (Pallas unpack_bits where probed, jnp
-           shift/mask fallback), gathers each value's meaningful-bit
+           payload to bits (jnp shift/mask), gathers each value's
+           meaningful-bit
            window, and reconstructs with a parallel XOR prefix scan —
            bit-identical to the host decoder including NaN/±0.0
   varint   delta+zigzag LEB128 int64: fully data-parallel — terminator
@@ -41,13 +41,9 @@ host decode — EncodedColumn.values decodes lazily and the existing path
 runs unchanged.  `OGT_DEVICE_DECODE=0` disables this module entirely
 (bit-identical host path); `OGT_DEVICE_DECODE_CODECS` restricts the
 device family to a comma list of the kinds above (default: all); x64 is
-required for bit-identity (int64 cumsum, f64 bitcast), so non-x64
-backends answer inactive and fall back silently.
-
-The widen and bit-unpack steps route through Pallas kernels
-(ops/pallas_segment.widen_packed / unpack_bits) where the backend
-supports Pallas (devobs.backend_capabilities probe + the use_pallas
-routing); the jnp bitcast/shift paths serve everywhere else.
+required for bit-identity (int64 cumsum, f64 bitcast), so a process
+without x64 — a server, today (ROADMAP S2) — answers inactive and
+decodes on the host.
 
 Mesh sharding: under a configured device mesh, build_mesh_grid_plan
 splits one grid plan into per-output-row-shard sub-plans (series runs
@@ -114,35 +110,20 @@ def codecs_enabled() -> frozenset:
     return frozenset(t.strip().lower() for t in raw.split(",") if t.strip())
 
 
-@functools.lru_cache(maxsize=1)
-def _backend_ok() -> bool:
-    """One-time probe: a live jax backend."""
-    try:
-        import jax
-
-        jax.devices()
-        return True
-    except Exception:  # noqa: BLE001 — no backend = host decode
-        return False
-
-
 def _x64_on() -> bool:
     """Read the x64 flag FRESH every time — it is runtime-togglable,
     and a stale cached True would run the int64 cumsum / f64 bitcast in
     32-bit and silently diverge from the host path."""
-    try:
-        import jax
+    import jax
 
-        return bool(jax.config.jax_enable_x64)
-    except Exception:  # noqa: BLE001
-        return False
+    return bool(jax.config.jax_enable_x64)
 
 
 def active() -> bool:
-    """Device decode usable in this process (knob + x64 + backend).
-    x64 is what makes the int64 cumsum and f64 bitcast bit-identical to
-    the host decoders."""
-    return enabled() and _x64_on() and _backend_ok()
+    """Device decode usable in this process (knob + x64).  x64 is what
+    makes the int64 cumsum and f64 bitcast bit-identical to the host
+    decoders."""
+    return enabled() and _x64_on()
 
 
 @functools.lru_cache(maxsize=1024)
@@ -1007,15 +988,10 @@ def _view_gather(vals_full, viewruns, n_view: int):
 def _widen(raw, width: int, cnt: int):
     """(cnt*width,) LE bytes -> (cnt,) int64, matching the host
     frombuffer(...).astype(int64) exactly (zero-extend below 8 bytes,
-    bit-reinterpretation at 8).  Width-1/2 blocks route through the
-    Pallas widen kernel where the backend supports it."""
+    bit-reinterpretation at 8)."""
     import jax
     import jax.numpy as jnp
 
-    if width in (1, 2) and _pallas_widen_ok():
-        from opengemini_tpu.ops import pallas_segment as ps
-
-        return ps.widen_packed(raw, width, cnt).astype(jnp.int64)
     if width == 1:
         return raw.astype(jnp.int64)
     if width == 8:
@@ -1028,22 +1004,11 @@ def _widen(raw, width: int, cnt: int):
         raw.reshape(cnt, width), dt).astype(jnp.int64)
 
 
-def _pallas_widen_ok() -> bool:
-    from opengemini_tpu.ops import pallas_segment as ps
-
-    return ps.use_pallas() and devobs.pallas_supported()[0]
-
-
 def _unpack_bits(raw, nbytes: int):
-    """(nbytes,) uint8 -> (nbytes*8,) int32 bits, MSB-first per byte —
-    Pallas unpack_bits where the probe allows, jnp shift/mask fallback
-    elsewhere (both match np.unpackbits exactly)."""
+    """(nbytes,) uint8 -> (nbytes*8,) int32 bits, MSB-first per byte
+    (matches np.unpackbits exactly)."""
     import jax.numpy as jnp
 
-    if _pallas_widen_ok():
-        from opengemini_tpu.ops import pallas_segment as ps
-
-        return ps.unpack_bits(raw, nbytes)
     shifts = jnp.arange(7, -1, -1, dtype=jnp.uint8)
     return ((raw[:, None] >> shifts) & jnp.uint8(1)).astype(
         jnp.int32).reshape(nbytes * 8)

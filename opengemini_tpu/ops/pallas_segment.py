@@ -18,22 +18,19 @@ hand-written per combination, here it is one kernel per *shape family*:
   - ``grid_window_agg_t``      — (S, SPW, W) regular-grid window layout
                                  (ops/segment.grid_window_agg_t)
 
-Measured on v5e-1 (full-output consumption so XLA cannot dead-code-
-eliminate rows; interleaved best-of-4): the fused SELECTOR kernel beats
-the XLA lex-scan chain ~1.5x (3.5-4.9 vs 2.2-2.4 G rows/s at (131072,
-256)) because one tile residency feeds all four lexicographic scans, so
-models/ragged routes selectors here on TPU. For the pure reductions
-(basic/grid) XLA's own fusion wins (~28-55 vs ~22-48 G rows/s) — those
-kernels are retained, tested, and directly callable as the explicit-
-fusion alternate, but the routing keeps XLA for them: measurement beats
-ideology.
+Routing (models/ragged.py): on a TPU the SELECTOR kernel serves unsharded
+buckets — one tile residency feeds all four lexicographic scans — and
+the pure reductions (basic/grid) stay with XLA's own fusion, which also
+keeps GSPMD row sharding working under a device mesh (pallas_call does
+not auto-partition).  The basic and grid kernels are retained, tested
+and directly callable as the explicit-fusion alternates.  Speed of
+either side: not measured on the present code.
 
-Semantics match the XLA kernels exactly (same empty-segment identities:
-count 0, sum 0, min +inf, max -inf, ssd 0) — ``tests/test_pallas.py``
-asserts equality against them, and the routing layer (``use_pallas``)
-only engages on a real TPU backend, falling back to the XLA path
-elsewhere, so CPU-forced test runs and the virtual multichip dryrun are
-unaffected.
+All three are compiled by Mosaic and compared with their XLA twins on
+the chip by tools/pallas_chip_check.py; tests/test_pallas.py asserts
+the same equality in interpret mode on the CPU (same empty-segment
+identities: count 0, sum 0, min +inf, max -inf, ssd 0).  Interpret mode
+is for the CPU only: on a TPU a kernel Mosaic refuses raises.
 
 Mask convention: callers pass bool masks; ``_as_i8`` widens to int8 at
 the call boundary (TPU VMEM has no packed bool tiling) and kernels
@@ -57,25 +54,20 @@ _BIG_I32 = 2**31 - 1
 
 @functools.lru_cache(maxsize=1)
 def use_pallas() -> bool:
-    """True when the Pallas kernels should serve the hot path: a real TPU
+    """True when the Pallas kernels should serve the hot path: a TPU
     backend and not explicitly disabled. OGTPU_PALLAS=1 forces them on
-    (interpret mode off-TPU is far slower than XLA — test-only), =0 off."""
+    (interpret mode on the CPU is far slower than XLA — test-only), =0
+    off."""
     flag = os.environ.get("OGTPU_PALLAS")
     if flag is not None:
         return flag.strip().lower() not in ("0", "false", "off", "no", "")
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:
-    """Interpret mode whenever the default backend is not a TPU — keeps the
-    kernels runnable (tests, forced-on CPU) without Mosaic."""
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
-        return True
+    """Interpret mode on the CPU only (tests, forced-on CPU runs); on an
+    accelerator the kernels compile or raise."""
+    return jax.default_backend() == "cpu"
 
 
 def _as_i8(mask) -> jax.Array:
@@ -329,72 +321,3 @@ def grid_window_agg_t(values_t, mask_t):
     """Pallas variant of ops/segment.grid_window_agg_t: same (S, SPW, W)
     windows-on-lanes layout, all five stats from one VMEM residency."""
     return _grid_call(jnp.asarray(values_t), _as_i8(mask_t), interpret=_interpret())
-
-
-# -- packed-delta widen (device decode, ops/device_decode.py) ----------------
-
-
-def _widen_kernel(b_ref, out_ref):
-    """(cnt, width) LE bytes -> (cnt, 1) int32 little-endian combine.
-    int32 is exact for the width-1/2 blocks routed here; the explicit
-    astype keeps x64 interpret mode off int64 (the int32-ref rule)."""
-    b = b_ref[...]
-    acc = b[:, 0].astype(jnp.int32)
-    for j in range(1, b.shape[1]):
-        acc = acc + (b[:, j].astype(jnp.int32) << (8 * j))
-    out_ref[...] = acc[:, None].astype(jnp.int32)
-
-
-@functools.partial(jax.jit, static_argnames=("width", "cnt", "interpret"))
-def _widen_call(raw, *, width: int, cnt: int, interpret: bool):
-    from jax.experimental import pallas as pl
-
-    out = pl.pallas_call(
-        _widen_kernel,
-        out_shape=jax.ShapeDtypeStruct((cnt, 1), jnp.int32),
-        interpret=interpret,
-    )(raw.reshape(cnt, width))
-    return out[:, 0]
-
-
-def widen_packed(raw, width: int, cnt: int):
-    """Widen `cnt` packed little-endian `width`-byte unsigned values to
-    int32 — the byte-combine step of the device-side FOR-delta decode
-    (ops/device_decode.py), as an explicit VMEM tile pass.  Callers
-    guarantee width in (1, 2) so int32 is exact."""
-    return _widen_call(jnp.asarray(raw), width=width, cnt=cnt,
-                       interpret=_interpret())
-
-
-# -- bit unpack (gorilla device decode, ops/device_decode.py) ----------------
-
-
-def _unpack_bits_kernel(b_ref, out_ref):
-    """(nbytes,) uint8 -> (nbytes, 8) int32 bits, MSB-first within each
-    byte (np.unpackbits order — the gorilla stream's bit order).  int32
-    out keeps x64 interpret mode off int64 (the int32-ref rule)."""
-    b = b_ref[...].astype(jnp.int32)
-    shifts = jnp.arange(7, -1, -1, dtype=jnp.int32)
-    out_ref[...] = ((b[:, None] >> shifts) & 1).astype(jnp.int32)
-
-
-@functools.partial(jax.jit, static_argnames=("nbytes", "interpret"))
-def _unpack_bits_call(raw, *, nbytes: int, interpret: bool):
-    from jax.experimental import pallas as pl
-
-    out = pl.pallas_call(
-        _unpack_bits_kernel,
-        out_shape=jax.ShapeDtypeStruct((nbytes, 8), jnp.int32),
-        interpret=interpret,
-    )(raw)
-    return out.reshape(nbytes * 8)
-
-
-def unpack_bits(raw, nbytes: int):
-    """Unpack `nbytes` payload bytes into a flat (nbytes*8,) int32 bit
-    vector, MSB-first per byte — the bit-addressing substrate of the
-    device-side gorilla decode (templated on the same probed pallas
-    routing as widen_packed; ops/device_decode.py carries the jnp
-    shift/mask fallback where the probe fails)."""
-    return _unpack_bits_call(jnp.asarray(raw), nbytes=nbytes,
-                             interpret=_interpret())

@@ -477,6 +477,20 @@ def instant_values(times, values, counts, eval_times, lookback_s: float = 300.0)
 _MS_PER_S = 1000
 
 
+def _value_form(kernel: str, **opts) -> str:
+    """The form in which a tiled kernel reads its values on a device
+    that computes in float32 (TiledPrepared._narrowed makes them).  One
+    table for the kernels, which ask for the form, and for ShardedTiled,
+    which ships it."""
+    if kernel == "rate":
+        return "mono" if opts["is_counter"] else "rel"
+    if kernel == "instant_rate":
+        return "mono" if opts["per_second"] else "rel"
+    if kernel == "over_time" and opts["func"] in ("last", "min", "max"):
+        return "abs"
+    return "rel"
+
+
 class TilePlan:
     """Time-tile grid for one range query: all window edges on the
     anchor + i*g_ms lattice.  Built host-side by plan_tiles (None when the
@@ -623,8 +637,6 @@ class TiledPrepared:
         # first/last sample index per window: prefix lookups at edge tiles
         first_idx = tile_cum[:, plan.a_idx]
         last_idx = tile_cum[:, plan.b_idx] - 1
-        self.first_idx = first_idx.astype(np.int64)
-        self.last_idx = last_idx.astype(np.int64)
         n_samp = last_idx - first_idx + 1
         self.has1 = n_samp >= 1
         self.has2 = n_samp >= 2
@@ -663,7 +675,11 @@ class TiledPrepared:
             [prev_valid[:, :, None], own_valid], axis=2)
         gidx_local = np.clip(gidx_local, 0, lim[:, :, None])
         self.gidx = (np.arange(S, dtype=np.int64)[:, None, None] * N
-                     + gidx_local).astype(np.int64)
+                     + gidx_local)
+        if S * N <= np.iinfo(np.int32).max:
+            # what a device without x64 indexes in: narrowed here, once
+            # and checked, not wherever jax would wrap it silently
+            self.gidx = self.gidx.astype(np.int32)
         # row-LOCAL gather columns (gidx minus its row offset): the mesh
         # path gathers per series row so GSPMD can shard the series axis
         # without collectives; None until shard_tiled derives it
@@ -703,15 +719,68 @@ class TiledPrepared:
             self.values = values
         return self.values
 
-    def _values_for(self, xp):
+    def _ftype(self, xp) -> np.dtype:
+        """The float dtype the kernels compute in: the prepared dtype on
+        the host, what x64 allows on the device (float32 without it) —
+        named here so that no astype/zeros asks jax for a float64 it
+        would truncate with a warning."""
+        if xp is np:
+            return self.dtype
+        import jax
+
+        return np.dtype(jax.dtypes.canonicalize_dtype(self.dtype))
+
+    def _narrowed(self, form: str) -> np.ndarray:
+        """The (S, N) value matrix in the float32 a device without x64
+        computes in, narrowed HERE, after the float64 arithmetic that
+        float32 cannot do.  jax would narrow on the way in, silently,
+        and a counter near 1e9 keeps its value only to 64: a kernel that
+        differences neighbours would difference rounding error.
+
+          abs   the values themselves, each one rounding away from its
+                float64: for kernels that select a value;
+          rel   relative to the series' first sample — exact in float64,
+                small in float32: for kernels that difference, compare
+                or centre values (good while the series stays within
+                2^24 of its resolution from that sample);
+          mono  rel plus the cumulative counter-reset corrections: the
+                monotone counter Prometheus defines.  rate()/irate()
+                difference it directly, so no reset is left for float32
+                to cancel against a 1e9 correction."""
+        raw = self._host_values()
+        if form == "abs":
+            return raw.astype(self._ftype(jnp))
+        out = raw - raw[:, :1]
+        if form == "mono" and self.N > 1:
+            prev = raw[:, :-1]
+            pair = (np.arange(1, self.N)[None, :]
+                    < np.asarray(self.counts)[:, None])
+            out[:, 1:] += np.cumsum(
+                np.where((raw[:, 1:] < prev) & pair, prev, 0.0), axis=1)
+        return out.astype(self._ftype(jnp))
+
+    def _narrows(self, xp, values) -> bool:
+        """True where the kernels read _narrowed values: on a device
+        whose float is narrower than the prepared dtype (a server: x64
+        off).  Never on the host, under x64, or for values the caller
+        supplies in the dtype it chose."""
+        return (xp is not np and values is None
+                and self._ftype(xp) != self.dtype)
+
+    def _values_for(self, xp, form: str = "abs"):
         """The prepared value matrix in xp's array type (one cached device
-        copy for the traced path, so gathers run on device).  A
-        still-encoded column decodes ON the device for the traced path —
-        the H2D carries the raw block payloads instead of the padded f64
-        matrix."""
+        copy per form for the traced path, so gathers run on device; a
+        device that does not narrow has the one exact copy for every
+        form).  A still-encoded column decodes ON the device for the
+        traced path — the H2D carries the raw block payloads instead of
+        the padded f64 matrix."""
         if xp is np:
             return self._host_values()
-        dev = getattr(self, "_dev_values", None)
+        narrow = self._narrows(xp, None)
+        if not narrow:
+            form = "abs"
+        cache = self.__dict__.setdefault("_dev_values", {})
+        dev = cache.get(form)
         if dev is None:
             import time as _time
 
@@ -726,9 +795,9 @@ class TiledPrepared:
                     devobs.LEDGER.register(
                         "prom_dev_values", int(dev.nbytes),
                         label="tiled-values-decoded", anchor=self)
-                    self._dev_values = dev
+                    cache[form] = dev
                     return dev
-            mat = self._host_values()
+            mat = self._narrowed(form) if narrow else self._host_values()
             t0 = _time.perf_counter_ns()
             dev = xp.asarray(mat)
             devobs.note_transfer(
@@ -737,11 +806,32 @@ class TiledPrepared:
             devobs.LEDGER.register(
                 "prom_dev_values", int(mat.nbytes),
                 label="tiled-values", anchor=self)
-            self._dev_values = dev
+            cache[form] = dev
         return dev
 
-    def _vals(self, xp, values, value_shift):
-        v = self._values_for(xp) if values is None else values
+    def _narrowed_level(self, which: str) -> np.ndarray:
+        """A level the narrowed forms drop, float32, for the kernels
+        that need one back: "first" (S, K), each window's first sample
+        itself — rate()'s zero-point clamp divides it by the increase,
+        and mono values cannot give it back; "base" (S, 1), what rel
+        values are relative to — a sum, a mean and the regression's
+        intercept return a level."""
+        raw = self._host_values()
+        out = (np.take_along_axis(raw, self.safe_f, axis=1)
+               if which == "first" else raw[:, :1])
+        return out.astype(self._ftype(jnp))
+
+    def _level(self, xp, which: str):
+        """_narrowed_level on the device (one cached copy).  Only where
+        _narrows."""
+        cache = self.__dict__.setdefault("_dev_levels", {})
+        dev = cache.get(which)
+        if dev is None:
+            dev = cache[which] = xp.asarray(self._narrowed_level(which))
+        return dev
+
+    def _vals(self, xp, values, value_shift, form: str = "rel"):
+        v = self._values_for(xp, form) if values is None else values
         vg = self._gather_tiles(xp, v)
         v_first = xp.take_along_axis(v, self.safe_f, axis=1)
         v_last = xp.take_along_axis(v, self.safe_l, axis=1)
@@ -758,6 +848,10 @@ class TiledPrepared:
         the mesh path shard the series axis with zero collectives."""
         if self.gidx_col is not None:
             return xp.take_along_axis(mat[:, None, :], self.gidx_col, axis=2)
+        if xp is not np and self.gidx.dtype != np.int32:
+            raise ValueError(
+                f"flat gather over {self.S}x{self.N} samples overflows the "
+                "device's int32 index")
         return mat.reshape(-1)[self.gidx]
 
     def _window_sums(self, xp, tile_vals):
@@ -777,9 +871,19 @@ class TiledPrepared:
         tile-prefix counter-reset corrections + first/last gathers,
         prom extrapolatedRate semantics (identical formulas to
         extrapolated_rate above)."""
-        v, vg, v_first, v_last = self._vals(xp, values, value_shift)
+        # a device that narrows reads counters with their resets already
+        # folded in on the host, in float64 (_narrowed "mono"): the
+        # increase is one small difference and nothing is left to correct
+        folded = is_counter and self._narrows(xp, values)
+        v = (self._values_for(xp, _value_form("rate", is_counter=is_counter))
+             if values is None else values)
+        v_first = self._gather1(xp, v, self.safe_f, value_shift)
+        v_last = self._gather1(xp, v, self.safe_l, value_shift)
         delta = v_last - v_first
-        if is_counter:
+        if is_counter and not folded:
+            vg = self._gather_tiles(xp, v)
+            if value_shift is not None:
+                vg = vg + value_shift
             drop = xp.where((vg[:, :, 1:] < vg[:, :, :-1]) & self.pairmask,
                             vg[:, :, :-1], xp.zeros((), vg.dtype))
             corr = self._window_sums(xp, drop.sum(axis=2))
@@ -800,6 +904,8 @@ class TiledPrepared:
         d2s = xp.where(d2s > thr, avg_int / 2, d2s)
         d2e = xp.where(d2e > thr, avg_int / 2, d2e)
         if is_counter:
+            if folded:
+                v_first = self._level(xp, "first")
             dz = xp.where((delta > 0) & (v_first >= 0),
                           sampled * (v_first / xp.maximum(delta, 1e-30)),
                           xp.asarray(np.inf, dtype=sampled.dtype)
@@ -813,13 +919,18 @@ class TiledPrepared:
     def instant_rate(self, xp=np, values=None, value_shift=None, *,
                      per_second: bool):
         """irate/idelta: last two samples per window, prefix-resolved."""
-        v = self._values_for(xp) if values is None else values
+        v = (self._values_for(
+                 xp, _value_form("instant_rate", per_second=per_second))
+             if values is None else values)
         v_last = self._gather1(xp, v, self.safe_l, value_shift)
         v_prev = self._gather1(xp, v, self.safe_lm1, value_shift)
         valid = self.has2
         dv = v_last - v_prev
         if per_second:
-            dv = xp.where(dv < 0, v_last, dv)  # counter reset
+            # a counter reset; where the device narrows, the host has
+            # folded it in already (see rate())
+            if not self._narrows(xp, values):
+                dv = xp.where(dv < 0, v_last, dv)
             dt = xp.maximum(self.t_last - self.t_lm1, 1e-9)
             return dv / dt, valid
         return dv, valid
@@ -831,27 +942,35 @@ class TiledPrepared:
         the fixed-length sliding-extreme over tile partials — no dense
         (S, chunk, N) membership tensor anywhere."""
         has = self.has1
-        wcnt = xp.where(has, self.n_samp, xp.zeros((), self.n_samp.dtype))
+        ft = self._ftype(xp)
+        wcnt = xp.where(has, self.n_samp, xp.zeros((), ft))
         if func == "count":
             return wcnt, has
         if func == "present":
-            one = np.ones((), self.dtype) if xp is np else jnp.ones((), self.dtype)
-            return xp.where(has, one, 0), has
+            return xp.where(has, xp.ones((), ft), 0), has
+        form = _value_form("over_time", func=func)
         if func == "last":
-            v = self._values_for(xp) if values is None else values
+            v = self._values_for(xp, form) if values is None else values
             return self._gather1(xp, v, self.safe_l, value_shift), has
-        v, vg, _vf, _vl = self._vals(xp, values, value_shift)
+        v, vg, _vf, _vl = self._vals(xp, values, value_shift, form)
         if func in ("sum", "avg"):
             vz = xp.where(self.ownmask, vg[:, :, 1:], xp.zeros((), vg.dtype))
             wsum = self._window_sums(xp, vz.sum(axis=2))
-            if func == "sum":
-                return xp.where(has, wsum, xp.zeros((), wsum.dtype)), has
-            return xp.where(has, wsum, xp.zeros((), wsum.dtype)) / xp.maximum(wcnt, 1), has
+            wsum = xp.where(has, wsum, xp.zeros((), wsum.dtype))
+            if func == "avg":
+                wsum = wsum / xp.maximum(wcnt, 1)
+            if self._narrows(xp, values):
+                # the float32 prefix sums ran over small rel values; the
+                # level goes back in as often as the answer holds it
+                times = wcnt if func == "sum" else xp.where(
+                    has, xp.ones((), ft), 0)
+                wsum = wsum + self._level(xp, "base") * times
+            return wsum, has
         if func in ("stddev", "stdvar"):
             # center on the per-series mean first (see over_time above: raw
             # v^2 prefixes cancel catastrophically for large magnitudes)
             valid_cols = xp.arange(self.N)[None, :] < self.counts[:, None]
-            series_n = xp.maximum(self.counts, 1).astype(self.dtype)[:, None]
+            series_n = xp.maximum(self.counts, 1).astype(ft)[:, None]
             vz_raw = xp.where(valid_cols, v, xp.zeros((), v.dtype))
             center = vz_raw.sum(axis=1, keepdims=True) / series_n
             vc = xp.where(self.ownmask, vg[:, :, 1:] - center[:, :, None],
@@ -867,9 +986,9 @@ class TiledPrepared:
             from opengemini_tpu.ops import segment as seg
 
             want_min = func == "min"
-            fill = self.dtype.type(np.inf if want_min else -np.inf)
+            fill = ft.type(np.inf if want_min else -np.inf)
             if self.pmax == 0:  # no samples in any covered tile
-                tile_ext = xp.full((self.S, self.C), fill, dtype=self.dtype)
+                tile_ext = xp.full((self.S, self.C), fill, dtype=ft)
             elif want_min:
                 tile_ext = xp.where(self.ownmask, vg[:, :, 1:], fill).min(axis=2)
             else:
@@ -888,13 +1007,14 @@ class TiledPrepared:
             ind = (cur != prev) & self.pairmask
         else:
             ind = (cur < prev) & self.pairmask
-        wind = self._window_sums(xp, ind.astype(self.dtype).sum(axis=2))
+        ft = self._ftype(xp)
+        wind = self._window_sums(xp, ind.astype(ft).sum(axis=2))
         v_fm1 = self._gather1(xp, v, self.safe_fm1, value_shift)
         if kind == "changes":
             bnd = (v_first != v_fm1) & self.fmask
         else:
             bnd = (v_first < v_fm1) & self.fmask
-        out = wind - bnd.astype(self.dtype)
+        out = wind - bnd.astype(ft)
         valid = self.has1
         return xp.where(valid, out, xp.zeros((), out.dtype)), valid
 
@@ -903,7 +1023,8 @@ class TiledPrepared:
         end (prom linearRegression), from tile partials of {v, t, t^2, tv}
         — the O(S*chunk*N) dense pass becomes four prefix lookups."""
         v, vg, _vf, _vl = self._vals(xp, values, value_shift)
-        tg = self._gather_tiles(xp, self.times)[:, :, 1:].astype(self.dtype)
+        tg = self._gather_tiles(xp, self.times)[:, :, 1:].astype(
+            self._ftype(xp))
         z = xp.zeros((), vg.dtype)
         vz = xp.where(self.ownmask, vg[:, :, 1:], z)
         tz = xp.where(self.ownmask, tg, z)
@@ -922,6 +1043,9 @@ class TiledPrepared:
         slope = cov / xp.where(var == 0, 1.0, var)
         slope = xp.where(var == 0, 0.0, slope)
         intercept = sv / denom_n - slope * (st / denom_n)
+        if self._narrows(xp, values):
+            # the slope of rel values is the slope; the level is not
+            intercept = intercept + self._level(xp, "base")
         has2 = self.has2 & (self.t_last > self.t_first)
         return slope, intercept, has2
 
@@ -954,7 +1078,7 @@ class TiledPrepared:
 
 # per-series tensors (leading axis S — sharded over every mesh axis)
 _TILED_SHARD_ATTRS = (
-    "values", "counts", "times", "ownmask", "pairmask", "fmask",
+    "counts", "times", "ownmask", "pairmask", "fmask",
     "has1", "has2", "n_samp", "safe_f", "safe_l", "safe_fm1", "safe_lm1",
     "t_first", "t_last", "t_lm1",
 )
@@ -968,8 +1092,14 @@ class _TiledShardView(TiledPrepared):
     attributes are traced (sharded) arrays, statics are Python scalars.
     The kernel methods run unmodified against it."""
 
-    def __init__(self):  # attrs are assigned by the trace, not prepared
-        pass
+    def __init__(self, arrays):  # attrs are assigned by the trace, not prepared
+        self.__dict__.update(arrays)
+
+    def _values_for(self, xp, form: str = "abs"):
+        return self.values  # ShardedTiled shipped the form this kernel reads
+
+    def _level(self, xp, which: str):
+        return getattr(self, "level_" + which)
 
 
 class _PlanView:
@@ -998,14 +1128,12 @@ def _sharded_tiled_jit(kernel: str, opts: tuple, meta: tuple):
     kwargs = dict(opts)
 
     def fn(arrays):
-        view = _TiledShardView()
-        view.__dict__.update(arrays)
+        view = _TiledShardView(arrays)
         view.gidx = None  # force the row-local gather form
         view.S, view.N, view.K = s_pad, n_cols, k_win
         view.C, view.pmax = c_cov, pmax
         view.dtype = np.dtype(dtype_str)
         view.plan = _PlanView(win_tiles, window_s)
-        view._dev_values = arrays["values"]
         return getattr(TiledPrepared, kernel)(view, jnp, **kwargs)
 
     return jax.jit(fn)
@@ -1032,10 +1160,15 @@ class ShardedTiled:
         # row-local covered-tile gather: flat gidx minus its row offset
         rows = (np.arange(prep.S, dtype=np.int64) * prep.N)[:, None, None]
         gidx_col = (prep.gidx - rows).astype(np.int32)
-        series = {name: (prep._host_values() if name == "values"
-                         else getattr(prep, name))
-                  for name in _TILED_SHARD_ATTRS}
+        series = {name: getattr(prep, name) for name in _TILED_SHARD_ATTRS}
         series["gidx_col"] = gidx_col
+        # the value matrix follows per kernel, in the form it reads
+        # (_values_in); a device that narrows gets the two levels as well
+        self.narrow = jax.dtypes.canonicalize_dtype(prep.dtype) != prep.dtype
+        if self.narrow:
+            for which in ("first", "base"):
+                series["level_" + which] = prep._narrowed_level(which)
+        self._values: dict = {}
         sharded = dist.shard_leading_axis(mesh, *series.values(),
                                           xfer_site="prom-shard")
         self.arrays = dict(zip(series.keys(), sharded))
@@ -1057,10 +1190,32 @@ class ShardedTiled:
             mesh_epoch=_prt.mesh_epoch(), label="sharded-tiled",
             anchor=self)
 
+    def _values_in(self, form: str):
+        """The sharded (S_pad, N) value matrix in one form: a transfer per
+        form a query's kernels read, not per kernel."""
+        from opengemini_tpu.parallel import distributed as dist
+        from opengemini_tpu.parallel import runtime as _prt
+        from opengemini_tpu.utils import devobs
+
+        dev = self._values.get(form)
+        if dev is None:
+            host = (self.prep._narrowed(form) if self.narrow
+                    else self.prep._host_values())
+            (dev,) = dist.shard_leading_axis(self.mesh, host,
+                                             xfer_site="prom-shard")
+            devobs.LEDGER.register(
+                "prom_sharded", int(dev.nbytes),
+                mesh_epoch=_prt.mesh_epoch(), label="sharded-tiled-values",
+                anchor=self)
+            self._values[form] = dev
+        return dev
+
     def _run(self, kernel: str, **opts):
         from opengemini_tpu.query import offload
         from opengemini_tpu.utils import devobs
 
+        form = _value_form(kernel, **opts) if self.narrow else "abs"
+        arrays = dict(self.arrays, values=self._values_in(form))
         opts_t = tuple(sorted(opts.items()))
         devobs.note_use("prom_" + kernel, (opts_t, self._meta))
         offload.register_builder(
@@ -1069,7 +1224,7 @@ class ShardedTiled:
                 _sharded_tiled_jit(k, o, m))
         fn = _sharded_tiled_jit(kernel, opts_t, self._meta)
         t0 = devobs.t0()
-        out = fn(self.arrays)
+        out = fn(arrays)
         if t0:
             devobs.note_exec(t0)
         return out

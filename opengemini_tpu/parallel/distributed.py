@@ -28,36 +28,6 @@ from opengemini_tpu.ops import segment as seg
 _BIG_I32 = 2**31 - 1
 
 
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """Version-compat shard_map: the `jax.shard_map` alias (with its
-    `check_vma` kwarg) only exists on newer jax; older releases ship it
-    as `jax.experimental.shard_map.shard_map` with the equivalent kwarg
-    named `check_rep`.  Replication checking stays OFF either way — the
-    collectives here produce replicated outputs by construction and the
-    checker rejects the one-hot winner combines."""
-    impl = getattr(jax, "shard_map", None)
-    if impl is not None:
-        try:
-            return impl(fn, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_vma=False)
-        except TypeError:  # alias exists but still takes check_rep
-            return impl(fn, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _impl
-
-    return _impl(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                 check_rep=False)
-
-
-def _axis_size(ax: str):
-    """jax.lax.axis_size is newer than the oldest supported jax; psum of
-    a per-device 1 is the portable spelling of the same number."""
-    impl = getattr(jax.lax, "axis_size", None)
-    if impl is not None:
-        return impl(ax)
-    return jax.lax.psum(1, ax)
-
-
 def make_mesh(n_devices: int | None = None, axes: tuple[str, ...] = ("shard",),
               shape: tuple[int, ...] | None = None) -> Mesh:
     devs = jax.devices()
@@ -135,7 +105,7 @@ def _merge_time_extreme(value, hi, lo, axes, earliest: bool):
     # one actual row's value — never an average of tied rows)
     rank = jnp.zeros((), jnp.int32)
     for ax in axes:
-        rank = rank * _axis_size(ax) + jax.lax.axis_index(ax)
+        rank = rank * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     rank_masked = jnp.where(cand, rank, _BIG_I32)
     rank_best = rank_masked
     for ax in axes:
@@ -175,7 +145,11 @@ def build_dist_agg(mesh: Mesh, num_segments: int):
             "min": mn, "max": mx, "first": fv, "last": lv,
         }
 
-    sharded = _shard_map(step, mesh, (row_spec,) * 5, P())
+    # replication checking off: the collectives produce replicated
+    # outputs by construction and the checker rejects the one-hot
+    # winner combines
+    sharded = jax.shard_map(step, mesh=mesh, in_specs=(row_spec,) * 5,
+                            out_specs=P(), check_vma=False)
     return jax.jit(sharded)
 
 
@@ -208,7 +182,7 @@ def _winner(keys, valid, axes):
         cand = cand & (masked == best)
     rank = jnp.zeros((), jnp.int32)
     for ax in axes:
-        rank = rank * _axis_size(ax) + jax.lax.axis_index(ax)
+        rank = rank * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     rank_masked = jnp.where(cand, rank, _BIG_I32)
     rank_best = _reduce(rank_masked, axes, jax.lax.pmin)
     return cand & (rank == rank_best)
@@ -286,7 +260,8 @@ def build_batch_agg(mesh: Mesh, num_segments: int,
             out[name + "_sel"] = _pick(gsel, w, axes)
         return out
 
-    sharded = _shard_map(step, mesh, (P(axes),) * 6, P())
+    sharded = jax.shard_map(step, mesh=mesh, in_specs=(P(axes),) * 6,
+                            out_specs=P(), check_vma=False)
     return jax.jit(sharded)
 
 
@@ -345,6 +320,10 @@ def shard_leading_axis(mesh: Mesh, *arrays, xfer_site: str = "mesh-shard"):
         out.append(jax.device_put(a, leading_axis_sharding(mesh, a.ndim)))
         nbytes += int(a.nbytes)
     _STATS.incr("device", "mesh_dense_batches")
+    # how many devices the newest batch really landed on: a mesh that
+    # put every shard on the first chip would read 1 here
+    _STATS.set("device", "mesh_shard_devices",
+               len({s.device.id for s in out[0].addressable_shards}))
     # every byte here is a host->device transfer a warm mesh query should
     # NOT repeat (the colcache device tier retains the sharded buffers);
     # the multichip bench asserts this counter is flat across warm runs

@@ -106,12 +106,9 @@ def _want_encoded() -> bool:
 
 @functools.lru_cache(maxsize=1)
 def _backend_is_cpu() -> bool:
-    try:
-        import jax
+    import jax
 
-        return jax.default_backend() == "cpu"
-    except Exception:  # noqa: BLE001 — no backend = host kernels
-        return True
+    return jax.default_backend() == "cpu"
 
 
 def _host_kernels() -> bool:

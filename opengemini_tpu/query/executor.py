@@ -86,8 +86,8 @@ def pick_batch(schema, agg_names, field: str, dtype, grid_ctx=None):
     (W, every_ns)), dense-capable aggregates try the regular-grid
     windows-on-lanes batch first (models/grid.py — the fastest layout,
     with built-in fallback when the scanned data is not constant-stride);
-    otherwise they use the ragged->dense bucketed batch (~100x over
-    scatter on TPU, models/ragged.py); rank-based ones
+    otherwise they use the ragged->dense bucketed batch
+    (models/ragged.py); rank-based ones
     (percentile/median/count_distinct) keep the lexsort AggBatch. Shared
     by the local aggregate path and the data-node partial computation
     (query/partials.py) so both sides pick identical numerics."""
@@ -108,8 +108,8 @@ def pick_batch(schema, agg_names, field: str, dtype, grid_ctx=None):
     # aggregates to AggBatch — the grid and bucketed layouts themselves go
     # multi-chip by sharding their independent row axes (zero-collective
     # GSPMD partitioning, distributed.shard_leading_axis), so multi-chip
-    # keeps the 62-160+ G rows/s dense kernels instead of the scatter
-    # family. AggBatch's shard_map path still serves its own cases.
+    # keeps the dense kernels instead of the scatter family. AggBatch's
+    # shard_map path still serves its own cases.
     if (
         grid_ctx is not None
         and not os.environ.get("OGTPU_DISABLE_GRID")  # A/B knob (bench.py)

@@ -493,12 +493,28 @@ class ShowDdlMixin:
 
             from opengemini_tpu import __version__
 
+            import importlib.metadata as _md
+
+            from opengemini_tpu import native as _native
+
+            try:
+                libtpu = _md.version("libtpu")
+            except _md.PackageNotFoundError:
+                libtpu = "not installed"
+            devs = _jax.devices()
             rows = [
                 ["version", __version__],
                 ["python", _sys.version.split()[0]],
                 ["jax", _jax.__version__],
+                ["jaxlib", _md.version("jaxlib")],
+                ["libtpu", libtpu],
                 ["backend", _jax.default_backend()],
-                ["devices", str(len(_jax.devices()))],
+                ["device_kind", devs[0].device_kind],
+                ["devices", str(len(devs))],
+                ["x64", str(bool(_jax.config.jax_enable_x64)).lower()],
+                ["compile_cache_dir",
+                 _jax.config.jax_compilation_cache_dir or ""],
+                ["native_libraries", _native.report()],
                 ["platform", platform.platform()],
                 ["data_dir", self.engine.root],
             ]

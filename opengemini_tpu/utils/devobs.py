@@ -60,10 +60,11 @@ armed or not):
 
   capability probes    backend_capabilities() answers what this jax
       backend can actually run — today: Pallas support (probed by
-      executing a tiny real kernel from ops/pallas_segment.py).  The
-      tier-1 pallas suite skips-with-reason on backends where the probe
-      fails instead of reporting 12 undiagnosable failures, and fails
-      for real where it succeeds.
+      executing a tiny self-contained kernel).  On the CPU the tier-1
+      pallas suite skips-with-reason where the probe fails instead of
+      reporting 12 undiagnosable failures, and fails for real where it
+      succeeds.  On a TPU a failing probe raises: no route may read it
+      as "unsupported" and quietly take another path.
 
 An on-demand `jax.profiler` capture (start_profile / /debug/ctrl
 op=profile&seconds=N) rounds out the ops surface — single-capture
@@ -134,25 +135,46 @@ def set_enabled(on: bool) -> None:
 
 
 def _ensure_listener() -> None:
-    """Register the jax.monitoring compile-duration listener once (at
-    first arming — registration itself is idempotent-guarded here)."""
+    """Register the jax.monitoring listeners once (utils/backend.init at
+    start-up, or first arming — idempotent-guarded here)."""
     global _listener_registered
     if _listener_registered:
         return
     _listener_registered = True
-    try:
-        import jax.monitoring as _mon
+    import jax.monitoring as _mon
 
-        _mon.register_event_duration_secs_listener(_on_jax_duration)
-    except Exception:  # noqa: BLE001 — observability must not raise
-        pass
+    _mon.register_event_duration_secs_listener(_on_jax_duration)
+    _mon.register_event_listener(_on_jax_event)
+
+
+watch_compiles = _ensure_listener
 
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
+# what XLA itself built or loaded, counted armed or not: the lowering-
+# site counters above see only the sites that call note_compile(), and
+# cannot tell a compile from a persistent-cache load
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache":
+        "persistent_cache_requests_total",
+    "/jax/compilation_cache/cache_hits": "persistent_cache_hits_total",
+}
+
+
+def _on_jax_event(event: str, **_kw) -> None:
+    name = _CACHE_EVENTS.get(event)
+    if name is not None:
+        _STATS.incr("device", name)
+
 
 def _on_jax_duration(event: str, duration_s: float, **_kw) -> None:
-    if not _ON or event != _COMPILE_EVENT:
+    if event != _COMPILE_EVENT:
+        return
+    # one per executable XLA produced for this process, compiled or
+    # read back from the persistent cache
+    _STATS.incr("device", "xla_programs_total")
+    if not _ON:
         return
     global _compile_wall_ns
     ns = int(duration_s * 1e9)
@@ -573,8 +595,8 @@ def backend_capabilities(probe: bool = True) -> dict:
     `pallas`: executes a tiny SELF-CONTAINED pallas_call (interpret mode
     off-TPU, Mosaic on TPU) exercising the same backend capability the
     product kernels need — an int-typed masked reduce stored into an
-    int32 out ref (exactly what breaks in interpret mode under x64 on
-    some jax versions).  Deliberately NOT one of the product kernels:
+    int32 out ref (what interpret mode rejects under x64 without the
+    explicit cast).  Deliberately NOT one of the product kernels:
     a regression in ops/pallas_segment.py must fail its tests, not
     convert them into skips.
 
@@ -588,15 +610,10 @@ def backend_capabilities(probe: bool = True) -> dict:
         return {"probed": False, "pallas": {
             "supported": None,
             "reason": "unprobed (pallas_supported() runs the probe)"}}
-    caps: dict = {"probed": True}
-    try:
-        import jax
+    import jax
 
-        caps["backend"] = jax.default_backend()
-        caps["device_count"] = len(jax.devices())
-    except Exception as e:  # noqa: BLE001 — a dead backend is an answer
-        caps["backend"] = None
-        caps["error"] = f"{type(e).__name__}: {e}"
+    caps: dict = {"probed": True, "backend": jax.default_backend(),
+                  "device_count": len(jax.devices())}
     ok, why = _probe_pallas()
     caps["pallas"] = {"supported": ok, "reason": why}
     with _caps_lock:
@@ -605,34 +622,42 @@ def backend_capabilities(probe: bool = True) -> dict:
 
 
 def _probe_pallas() -> tuple[bool, str]:
+    """(True, "") when the probe kernel ran.  On the CPU a failure is an
+    answer — interpret mode cannot run here, tests skip with the reason.
+    On a TPU it is an error and raises: Mosaic refusing a kernel must
+    never turn into "unsupported" and a quiet jnp route."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    def kern(m_ref, cnt_ref):
+        # the product kernels' idiom: a masked integer reduce with an
+        # EXPLICIT int32 result stored into an int32 ref.  The
+        # explicit cast is load-bearing — x64 interpret mode widens
+        # bare integer reduces to int64, which int32 refs reject —
+        # so the kernels in ops/pallas_segment.py cast the same way,
+        # and the probe passes wherever they can actually run.
+        cnt_ref[...] = ((m_ref[...] != 0)
+                        .sum(axis=1, keepdims=True)
+                        .astype(jnp.int32))
+
+    on_cpu = jax.default_backend() == "cpu"
+    # one native int8 tile, so Mosaic's (32, 128) tiling accepts it
+    m = _np.ones((32, 128), _np.int8)
     try:
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental import pallas as pl
-
-        def kern(m_ref, cnt_ref):
-            # the product kernels' idiom: a masked integer reduce with an
-            # EXPLICIT int32 result stored into an int32 ref.  The
-            # explicit cast is load-bearing — x64 interpret mode widens
-            # bare integer reduces to int64, which int32 refs reject —
-            # so the kernels in ops/pallas_segment.py cast the same way,
-            # and the probe passes wherever they can actually run.
-            cnt_ref[...] = ((m_ref[...] != 0)
-                            .sum(axis=1, keepdims=True)
-                            .astype(jnp.int32))
-
-        m = _np.ones((8, 8), _np.int8)
         out = pl.pallas_call(
             kern,
-            out_shape=jax.ShapeDtypeStruct((8, 1), jnp.int32),
-            interpret=jax.default_backend() != "tpu",
+            out_shape=jax.ShapeDtypeStruct((32, 1), jnp.int32),
+            interpret=on_cpu,
         )(m)
-        if int(_np.asarray(out)[0, 0]) != 8:
-            return False, "pallas probe kernel computed a wrong count"
-        return True, ""
-    except Exception as e:  # noqa: BLE001 — any failure = unsupported
+    except Exception as e:  # noqa: BLE001 — on the CPU, any failure = skip
+        if not on_cpu:
+            raise
         return False, (f"pallas probe failed on this backend: "
                        f"{type(e).__name__}: {e}")
+    if int(_np.asarray(out)[0, 0]) != 128:
+        raise RuntimeError("pallas probe kernel computed a wrong count")
+    return True, ""
 
 
 def pallas_supported() -> tuple[bool, str]:
@@ -705,22 +730,11 @@ def device_table() -> list[dict]:
     """One row per jax device, with per-device memory stats where the
     backend reports them (TPU/GPU; CPU answers null) — the cross-check
     against the ledger's own residency accounting."""
-    try:
-        import jax
+    import jax
 
-        devs = jax.devices()
-    except Exception as e:  # noqa: BLE001
-        return [{"error": f"{type(e).__name__}: {e}"}]
-    out = []
-    for d in devs:
-        row = {"id": d.id, "platform": d.platform,
-               "device_kind": getattr(d, "device_kind", "")}
-        try:
-            row["memory_stats"] = d.memory_stats()
-        except Exception:  # noqa: BLE001 — optional per backend
-            row["memory_stats"] = None
-        out.append(row)
-    return out
+    return [{"id": d.id, "platform": d.platform,
+             "device_kind": d.device_kind,
+             "memory_stats": d.memory_stats()} for d in jax.devices()]
 
 
 def debug_doc() -> dict:

@@ -126,31 +126,6 @@ def test_decode_to_device_bit_identical(profile_on, rng):
         enc.decode_floats(fb[0]))
 
 
-def test_pallas_widen_matches_jnp(profile_on, monkeypatch, rng):
-    """Force the Pallas widen kernel (interpret mode) and compare
-    against the default jnp bitcast path."""
-    from opengemini_tpu.ops import pallas_segment as ps
-    from opengemini_tpu.utils import devobs
-
-    ok, why = devobs.pallas_supported()
-    if not ok:
-        pytest.skip(why)
-    v = np.cumsum(rng.integers(0, 60_000, 400)).astype(np.int64)
-    blocks = [enc.encode_ints(v)]
-    want = np.asarray(dd.decode_to_device(blocks))
-    monkeypatch.setenv("OGTPU_PALLAS", "1")
-    ps.use_pallas.cache_clear()
-    dd._decode_program.cache_clear()
-    try:
-        got = np.asarray(dd.decode_to_device(blocks))
-    finally:
-        monkeypatch.delenv("OGTPU_PALLAS")
-        ps.use_pallas.cache_clear()
-        dd._decode_program.cache_clear()
-    np.testing.assert_array_equal(got, want)
-    np.testing.assert_array_equal(want, v)
-
-
 # -- EncodedColumn view algebra ----------------------------------------------
 
 

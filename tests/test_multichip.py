@@ -74,7 +74,7 @@ class TestShardedTiledProm:
     def test_kernels_match_host(self, rng, mesh, n_series):
         prep = _prep(rng, n_series)
         sh = prep.sharded(mesh)
-        assert len(sh.arrays["values"].addressable_shards) == mesh.size
+        assert len(sh.arrays["times"].addressable_shards) == mesh.size
         cases = [
             ("rate", lambda p, xp: p.rate(xp, is_counter=True, is_rate=True),
              lambda s: s.rate(is_counter=True, is_rate=True), 0.0),
@@ -563,7 +563,7 @@ plan = promops.plan_tiles(ends - 120.0, ends, int(t_all.min()),
                           int(t_all.max()), 1 << 20)
 prep = promops.prepare_tiled(plan, t_all, v_all, lens, dtype=np.float64)
 sh = prep.sharded(mesh)
-assert len(sh.arrays["values"].addressable_shards) == 6
+assert len(sh.arrays["times"].addressable_shards) == 6
 h, hk = prep.rate(np, is_counter=True, is_rate=True)
 m, mk = sh.rate(is_counter=True, is_rate=True)
 m = np.asarray(m)[:S, :prep.k_real]

@@ -5,12 +5,12 @@ match models/ragged._stats_jit and ops/segment.grid_window_agg_t exactly,
 including empty-segment identities and lexicographic tie-breaks.
 
 Kernel-executing tests gate on the devobs backend-capability probe
-(utils/devobs.py backend_capabilities): on backends/configs where
-Pallas cannot execute at all — e.g. interpret mode under x64 on jax
-versions whose lowering widens int ops against int32 refs — they SKIP
-with the probe's reason instead of failing 12 times with the same
-undiagnosable traceback; where the probe passes they run (and fail) for
-real.  The routing test runs everywhere: it never executes a kernel."""
+(utils/devobs.py backend_capabilities): where interpret mode cannot
+execute at all on this CPU they SKIP with the probe's reason instead of
+failing 12 times with the same undiagnosable traceback; where the probe
+passes they run (and fail) for real.  The routing test runs everywhere:
+it never executes a kernel.  What Mosaic accepts on the chip is checked
+by tools/pallas_chip_check.py, not here."""
 
 import os
 

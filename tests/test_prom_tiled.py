@@ -269,6 +269,96 @@ class TestTiledVsReference:
                 rtol=1e-6, atol=1e-8)
 
 
+class TestDeviceWithoutX64:
+    """A server runs with x64 off: the device computes in float32, where
+    a counter near 1e9 keeps its value only to 64.  The device paths
+    narrow on the host, after the float64 arithmetic float32 cannot do
+    (ops/prom.py TiledPrepared._narrowed), and every kernel must agree
+    with the float64 host path to float32 scale.
+
+    One series in three restarts (one twice, one to exactly 0).  What
+    Prometheus defines over a counter with resets — rate, increase,
+    irate — and what selects a sample — min, max, last — must be right
+    in EVERY window, the ones at and after a reset included.  The other
+    kernels read values relative to the series' first sample and are
+    held to float32 scale only where the series stays near it: the
+    series without a reset (the rest is ROADMAP S2's contract)."""
+
+    RESET_PROOF = ("rate", "increase", "irate", "min", "max", "last")
+
+    @pytest.fixture
+    def case(self):
+        rng = np.random.default_rng(5)
+        S, n = 19, 160
+        t = BASE_MS + np.arange(n, dtype=np.int64) * 15_000
+        start = rng.integers(10**8, 10**9, size=S).astype(np.float64)
+        inc = rng.integers(0, 100, size=(S, n)).astype(np.float64)
+        v = start[:, None] + np.cumsum(inc, axis=1)
+        resets = np.zeros(S, bool)
+        for s in range(0, S, 3):
+            for at in rng.integers(n // 4, 3 * n // 4, size=2 if s == 3 else 1):
+                v[s, at:] = np.cumsum(inc[s, at:]) - (inc[s, at] if s == 6
+                                                      else 0.0)
+            resets[s] = True
+        assert (np.diff(v[resets], axis=1) < 0).any(axis=1).all()
+        assert (v[6] == 0).any()
+        lens = np.full(S, n, np.int64)
+        ends = BASE + 300.0 + np.arange(30) * 60.0
+        prep = make_prep(np.tile(t, S), v.reshape(-1), lens,
+                         ends - 300.0, ends)
+        return prep, resets
+
+    def _kernels(self, run):
+        out = {"rate": run("rate", is_counter=True, is_rate=True),
+               "increase": run("rate", is_counter=True, is_rate=False),
+               "delta": run("rate", is_counter=False, is_rate=False),
+               "irate": run("instant_rate", per_second=True),
+               "idelta": run("instant_rate", per_second=False),
+               "changes": run("changes_resets", kind="changes"),
+               "resets": run("changes_resets", kind="resets"),
+               "linreg": run("linear_regression")}
+        for func in ("sum", "avg", "min", "max", "last", "stddev"):
+            out[func] = run("over_time", func=func)
+        return out
+
+    def _assert_close(self, got, want, prep, resets):
+        for name, w in want.items():
+            rows = (slice(None) if name in self.RESET_PROOF
+                    else np.flatnonzero(~resets))
+            for g_arr, w_arr in zip(got[name][:-1], w[:-1]):
+                g_arr = np.asarray(g_arr)[:prep.S, :prep.k_real][rows]
+                w_arr = np.asarray(w_arr)[:, :prep.k_real][rows]
+                assert g_arr.dtype == np.float32, name
+                # the regression and the variance sum products in
+                # float32 and then cancel them: digits fewer than a sum
+                cancels = name in ("linreg", "stddev")
+                np.testing.assert_allclose(
+                    g_arr, w_arr, rtol=2e-3 if cancels else 2e-5,
+                    atol=1e-6, err_msg=name)
+
+    def test_single_device_kernels_match_host_f64(self, case):
+        import jax
+        import jax.numpy as jnp
+
+        prep, resets = case
+        want = self._kernels(lambda k, **o: getattr(prep, k)(np, **o))
+        with jax.enable_x64(False):
+            got = self._kernels(lambda k, **o: getattr(prep, k)(jnp, **o))
+        self._assert_close(got, want, prep, resets)
+
+    def test_mesh_kernels_match_host_f64(self, case):
+        import jax
+
+        from opengemini_tpu.parallel import distributed as dist
+
+        prep, resets = case
+        want = self._kernels(lambda k, **o: getattr(prep, k)(np, **o))
+        with jax.enable_x64(False):
+            sh = prep.sharded(dist.make_mesh(4, ("shard",)))
+            got = self._kernels(lambda k, **o: getattr(sh, k)(**o))
+        self._assert_close(got, want, prep, resets)
+
+
 class TestBoundaries:
     """Left-open/right-closed edges, empty and 1-sample windows."""
 

@@ -193,7 +193,6 @@ sherlock-interval-s = 3600
         env = dict(os.environ)
         env.update({
             "JAX_PLATFORMS": "cpu",
-            "OGTPU_SKIP_BACKEND_PROBE": "1",
             "OGT_WAL_GROUP_COMMIT_US": "0",
             # the RPC hardening under test: short probes, one transient
             # retry, a live circuit breaker
