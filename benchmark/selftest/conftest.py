@@ -1,0 +1,11 @@
+"""Self-tests of the yardstick: `python3 -m pytest benchmark/selftest -q`
+(on the CPU; none of them reads a device number)."""
+
+import os
+import sys
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
+for p in (BENCH, ROOT):
+    if p not in sys.path:
+        sys.path.insert(0, p)
