@@ -33,7 +33,7 @@ import time
 import numpy as np
 
 from opengemini_tpu.models import ragged, templates
-from opengemini_tpu.utils import devobs
+from opengemini_tpu.utils import devobs, tracing
 from opengemini_tpu.utils.stats import GLOBAL as STATS
 
 # aggregates the grid path serves; others never get routed here
@@ -176,7 +176,8 @@ class GridBatch:
         """Returns the grid state dict, or None (delegate to bucketed)."""
         if self._state is not None or self._fallback is not None:
             return self._state
-        state = self._try_grid(num_segments)
+        with tracing.span("layout_build", rows=self.n):
+            state = self._try_grid(num_segments)
         if state is None:
             STATS.incr("executor", "grid_fallbacks")
             self._ensure_fallback()
@@ -314,12 +315,19 @@ class GridBatch:
             self._ensure_fallback()
             return self._fallback.run(spec, num_segments, params,
                                       want_sel=want_sel)
-        G = num_segments // self.W
         raw = self._raw_stats(
             need_ssd=(name == "stddev"),
             need_selectors=name in ("first", "last") or (
                 want_sel and name in ("min", "max")),
         )
+        with tracing.span("host_combine"):
+            return self._combine(st, raw, name, num_segments, want_sel)
+
+    def _combine(self, st, raw, name: str, num_segments: int,
+                 want_sel: bool):
+        """The host half of run(): per-row device stats reduced to the
+        (group, window) segments."""
+        G = num_segments // self.W
         order, starts = st["row_order"], st["gid_starts"]
         gids, W = st["gids_present"], self.W
 
@@ -657,14 +665,11 @@ class GridBatch:
             STATS.incr("executor", "grid_decode_fused")
             return stats
         vt, mt, imat = self._device_arrays(with_imat=(kind == "selectors"))
-        t0 = devobs.t0()
         tw = time.perf_counter()
-        if kind == "selectors":
-            out = _grid_jit(vt.shape, str(vt.dtype), kind)(vt, mt, imat)
-        else:
-            out = _grid_jit(vt.shape, str(vt.dtype), kind)(vt, mt)
-        if t0:
-            devobs.note_exec(t0)
+        out = devobs.launch(
+            _grid_jit(vt.shape, str(vt.dtype), kind),
+            (vt, mt, imat) if kind == "selectors" else (vt, mt),
+            program="grid_" + kind, xfer_site="grid-launch")
         if st.get("arrays") is not None or st.get("host_route_s") is not None:
             # host-route planner sample, one per kernel group: the first
             # launch carries the decode+scatter wall (freeze), every
@@ -730,8 +735,8 @@ class GridBatch:
                 self._raw["ssd"] = devobs.fetch_np(got)[:S, : self.W]
             else:
                 self._raw.update(
-                    {k: devobs.fetch_np(v)[:S, : self.W]
-                     for k, v in got.items()})
+                    {k: a[:S, : self.W]
+                     for k, a in devobs.fetch_dict(got).items()})
 
         if "count" not in self._raw:
             settle("basic")

@@ -28,7 +28,7 @@ import functools
 import numpy as np
 
 from opengemini_tpu.models import templates
-from opengemini_tpu.utils import devobs
+from opengemini_tpu.utils import devobs, tracing
 
 _REL_LO_BITS = 30
 _REL_LO_MASK = (1 << _REL_LO_BITS) - 1
@@ -135,11 +135,15 @@ class BucketedBatch:
     # -- freeze: ragged -> dense buckets --------------------------------
 
     def _freeze(self, num_segments: int):
-        if self._frozen is not None:
-            return self._frozen
-        if self.n == 0:
-            self._frozen = []
-            return self._frozen
+        if self._frozen is None:
+            if self.n == 0:
+                self._frozen = []
+            else:
+                with tracing.span("layout_build", rows=self.n):
+                    self._frozen = self._build_buckets(num_segments)
+        return self._frozen
+
+    def _build_buckets(self, num_segments: int) -> list:
         vals = np.concatenate(self._vals)
         rel = np.concatenate(self._rel)
         seg = np.concatenate(self._seg)
@@ -209,7 +213,6 @@ class BucketedBatch:
             b.sub_base = sub_base
             b.n_sub = n_sub
             b.rel = rel  # for host combine of split selectors
-        self._frozen = buckets
         return buckets
 
     # -- execution -------------------------------------------------------
@@ -298,20 +301,16 @@ class _Bucket:
         # buckets keep the fused Pallas kernel on TPU
         sel_kind = "selectors_xla" if arrays is not self.arrays else "selectors"
         if "count" not in self._raw:
-            t0 = devobs.t0()
-            got = _stats_jit("basic")(*arrays)
-            if t0:
-                devobs.note_exec(t0)  # dispatch; fetch attributes below
-            self._raw.update({k: devobs.fetch_np(v)[: self.g]
-                              for k, v in got.items()})
+            self._launch("basic", arrays)
         if need_selectors and "sel_first" not in self._raw:
-            t0 = devobs.t0()
-            got = _stats_jit(sel_kind)(*arrays)
-            if t0:
-                devobs.note_exec(t0)
-            self._raw.update({k: devobs.fetch_np(v)[: self.g]
-                              for k, v in got.items()})
+            self._launch(sel_kind, arrays)
         return self._raw
+
+    def _launch(self, kind: str, arrays) -> None:
+        got = devobs.launch(_stats_jit(kind), arrays,
+                            program="bucket_" + kind, xfer_site="bucket-launch")
+        self._raw.update({k: a[: self.g]
+                          for k, a in devobs.fetch_dict(got).items()})
 
     def combined(self, need_selectors: bool) -> dict:
         """Per-segment stats: raw sub-row stats + host k-way combine."""
@@ -320,6 +319,10 @@ class _Bucket:
         ):
             return self._combined
         raw = self._raw_stats(need_selectors)
+        with tracing.span("host_combine"):
+            return self._combine(raw, need_selectors)
+
+    def _combine(self, raw: dict, need_selectors: bool) -> dict:
         if (self.n_sub == 1).all():
             self._combined = dict(raw)
             cnt = raw["count"].astype(np.int64)

@@ -165,7 +165,9 @@ class AggBatch:
         if got is None:
             seg_pad = winmod.pad_to(max(num_segments, 1), 256)
             arrays = self._concat_padded()
-            counts, _ = _jitted(_count_fn, seg_pad, ())(*arrays)
+            counts, _ = devobs.launch(
+                _jitted(_count_fn, seg_pad, ()), arrays,
+                program="agg_count", xfer_site="agg-launch")
             got = devobs.fetch_np(counts)[:num_segments]
             self._counts_cache[num_segments] = got
         return got
@@ -190,12 +192,8 @@ class AggBatch:
         arrays = self._concat_padded()
         fn = _jitted(spec.fn, seg_pad, tuple(params))
         _STATS.incr("device", "kernel_launches")
-        t0 = devobs.t0()
-        out, sel = fn(*arrays)
-        if t0:
-            # dispatch only — the blocking fetch below attributes to
-            # device_transfer (fetch_np), never double-counted here
-            devobs.note_exec(t0)
+        out, sel = devobs.launch(fn, arrays, program="agg_" + spec.name,
+                                 xfer_site="agg-launch")
         out_np = devobs.fetch_np(out)[:num_segments]
         sel_np = (devobs.fetch_np(sel)[:num_segments]
                   if sel is not None else None)
@@ -219,11 +217,9 @@ class AggBatch:
             sharded = dist.shard_rows(
                 mesh, values, rel_hi, rel_lo, seg_ids, mask, gidx
             )
-            t0 = devobs.t0()
-            got = fn(*sharded)
-            if t0:
-                devobs.note_exec(t0)  # dispatch; fetch attributes below
-            outs = {k: devobs.fetch_np(v) for k, v in got.items()}
+            got = devobs.launch(fn, sharded, program="agg_mesh",
+                                xfer_site="agg-launch")
+            outs = devobs.fetch_dict(got)
             self._mesh_outs[cache_key] = outs
         out = outs[spec.name][:num_segments]
         sel = outs.get(spec.name + "_sel")

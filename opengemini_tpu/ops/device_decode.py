@@ -526,11 +526,8 @@ def run_grid_plan(plan: GridPlan):
     offload.register_builder("grid_decode_fused", pw_geo,
                              lambda g=geom: _grid_program(g))
     fn = _grid_program(plan.geom)
-    t = devobs.t0()
-    stats, vt, mt, flat = fn(*dev)
-    if t:
-        devobs.note_exec(t)
-    return stats, vt, mt, flat
+    return devobs.launch(fn, dev, program="grid_decode_fused",
+                         xfer_site=_XFER_SITE)
 
 
 class MeshGridPlan:
@@ -772,12 +769,11 @@ def run_mesh_grid_plan(mplan: MeshGridPlan):
     devobs.note_transfer("h2d", _XFER_SITE, nbytes,
                          (time.perf_counter_ns() - t0) / 1e9, mesh=True)
     outs = []
-    t = devobs.t0()
     for plan, ins in zip(mplan.shards, shard_in):
         _note_decode_stats(plan.geom[0], plan.n)
-        outs.append(_grid_program(plan.geom)(*ins))
-    if t:
-        devobs.note_exec(t)
+        outs.append(devobs.launch(_grid_program(plan.geom), ins,
+                                  program="grid_decode_fused",
+                                  xfer_site=_XFER_SITE))
     ax = tuple(mesh.axis_names)
 
     def assemble(pieces):
@@ -929,11 +925,8 @@ def decode_rows_matrix(enc, shape, dtype):
                              lambda a=pw: _rows_program(*a))
     fn = _rows_program(sig, n_view, (S, N), np.dtype(dtype).str,
                        None if viewruns is None else len(viewruns))
-    t = devobs.t0()
-    out = fn(*dev)
-    if t:
-        devobs.note_exec(t)
-    return out
+    return devobs.launch(fn, dev, program="prom_decode_rows",
+                         xfer_site=_XFER_SITE)
 
 
 @functools.lru_cache(maxsize=256)

@@ -1223,11 +1223,8 @@ class ShardedTiled:
             lambda k=kernel, o=opts_t, m=self._meta:
                 _sharded_tiled_jit(k, o, m))
         fn = _sharded_tiled_jit(kernel, opts_t, self._meta)
-        t0 = devobs.t0()
-        out = fn(arrays)
-        if t0:
-            devobs.note_exec(t0)
-        return out
+        return devobs.launch(fn, (arrays,), program="prom_" + kernel,
+                             xfer_site="prom-launch")
 
     def rate(self, *, is_counter: bool, is_rate: bool):
         return self._run("rate", is_counter=is_counter, is_rate=is_rate)

@@ -141,19 +141,6 @@ def _mesh_for_tiled():
     return prt.get_mesh()
 
 
-@contextmanager
-def _stage(name: str):
-    """Per-stage attribution: /debug/vars query_stages + the per-query
-    stage map in /debug/queries and the slow-query log."""
-    t0 = _time.perf_counter_ns()
-    try:
-        yield
-    finally:
-        ns = _time.perf_counter_ns() - t0
-        tracing.record_stage(name, ns)
-        TRACKER.add_stage_ns(TRACKER.current_qid(), name, ns)
-
-
 def _anchor(pattern: str) -> str:
     return "^(?:" + pattern + ")$"
 
@@ -274,41 +261,46 @@ class PromEngine:
         if n_steps > 11_000:
             raise PromError("too many steps (max 11000)")
         steps = start_s + np.arange(n_steps) * step_s
-        expr = pp.parse(text)
+        with tracing.span("prom_parse"):
+            expr = pp.parse(text)
         with self._tracked(text, db):
             frame = self._eval(expr, steps, db)
-        result = []
-        for i, labels in enumerate(frame.labels):
-            pts = [
-                [float(steps[k]), _fmt(frame.values[i, k])]
-                for k in range(n_steps)
-                if frame.valid[i, k]
-            ]
-            if pts:
-                result.append({"metric": labels, "values": pts})
-        result.sort(key=lambda r: sorted(r["metric"].items()))
+        with tracing.span("prom_render") as sp:
+            result = []
+            for i, labels in enumerate(frame.labels):
+                pts = [
+                    [float(steps[k]), _fmt(frame.values[i, k])]
+                    for k in range(n_steps)
+                    if frame.valid[i, k]
+                ]
+                if pts:
+                    result.append({"metric": labels, "values": pts})
+            result.sort(key=lambda r: sorted(r["metric"].items()))
+            sp.add_field("series", len(result))
         return {"resultType": "matrix", "result": result}
 
     def query_instant(self, text: str, time_s: float, db: str) -> dict:
         self._check_readable()
         steps = np.array([time_s])
-        expr = pp.parse(text)
+        with tracing.span("prom_parse"):
+            expr = pp.parse(text)
         with self._tracked(text, db):
             frame = self._eval(expr, steps, db)
         if frame.is_scalar:
             return {"resultType": "scalar", "result": [time_s, _fmt(frame.values[0, 0])]}
-        result = []
-        for i, labels in enumerate(frame.labels):
-            if frame.valid[i, 0]:
-                result.append(
-                    {"metric": labels, "value": [float(time_s), _fmt(frame.values[i, 0])]}
-                )
-        # top-level sort()/sort_desc()/sort_by_label() own the output
-        # order; everything else gets the stable by-labels order
-        if not (isinstance(expr, pp.FunctionCall)
-                and expr.name in ("sort", "sort_desc", "sort_by_label",
-                                  "sort_by_label_desc")):
-            result.sort(key=lambda r: sorted(r["metric"].items()))
+        with tracing.span("prom_render"):
+            result = []
+            for i, labels in enumerate(frame.labels):
+                if frame.valid[i, 0]:
+                    result.append(
+                        {"metric": labels, "value": [float(time_s), _fmt(frame.values[i, 0])]}
+                    )
+            # top-level sort()/sort_desc()/sort_by_label() own the output
+            # order; everything else gets the stable by-labels order
+            if not (isinstance(expr, pp.FunctionCall)
+                    and expr.name in ("sort", "sort_desc", "sort_by_label",
+                                      "sort_by_label_desc")):
+                result.sort(key=lambda r: sorted(r["metric"].items()))
         return {"resultType": "vector", "result": result}
 
     def series_labels(self, vs: "pp.VectorSelector", db: str) -> list[dict]:
@@ -364,6 +356,13 @@ class PromEngine:
         previously invisible to both."""
         t0 = _time.perf_counter_ns()
         qid = TRACKER.register(text, db)
+        trace = tracing.active_trace()
+        if trace is not None:
+            # the request's tree (OGT_TRACE=1; its root is the HTTP
+            # front end's): /debug/trace?qid= finds it under this qid
+            trace.qid = qid
+            trace.add_field("query", text)
+            TRACKER.set_trace(qid, trace)
         try:
             yield
         finally:
@@ -371,7 +370,7 @@ class PromEngine:
             from opengemini_tpu.utils.slowlog import GLOBAL as SLOWLOG
 
             if SLOWLOG.enabled():
-                SLOWLOG.note(qid, text, db, dur_ns / 1e6,
+                SLOWLOG.note(qid, text, db, dur_ns / 1e6, trace=trace,
                              stages=TRACKER.stages_of(qid),
                              extra={"kind": "promql"})
             TRACKER.unregister(qid)
@@ -527,17 +526,17 @@ class PromEngine:
         eval_times = steps - vs.offset_s
         t_max_ns = int(eval_times[-1] * 1e9) + 1
         t_min_ns = int((eval_times[0] - window_s) * 1e9)
-        with _stage("prom_collect"):
+        with tracing.span("prom_collect"):
             labels, t_ms_all, v_all, lens = self._collect_series(
                 vs, t_min_ns, t_max_ns, db)
         k = len(steps)
         if not labels:
             return Frame([], np.zeros((0, k)), np.zeros((0, k), bool))
-        with _stage("prom_prepare"):
+        with tracing.span("prom_prepare"):
             times, values, counts, base_ms = promops.prepare_matrix_runs(
                 t_ms_all, v_all, lens, dtype=np.float64)
         rel = eval_times - base_ms / 1000.0
-        with _stage("prom_kernel"):
+        with tracing.span("prom_kernel"):
             vals, valid = promops.instant_values(times, values, counts, rel,
                                                  window_s)
         return Frame(labels, np.asarray(vals), np.asarray(valid))
@@ -852,7 +851,7 @@ class PromEngine:
             eval_times = steps - vs.offset_s
             t_max_ns = int(eval_times[-1] * 1e9) + 1
             t_min_ns = int((eval_times[0] - w) * 1e9)
-            with _stage("prom_collect"):
+            with tracing.span("prom_collect"):
                 got = self._collect_series(
                     vs, t_min_ns, t_max_ns, db,
                     want_encoded=_want_encoded())
@@ -909,9 +908,9 @@ class PromEngine:
         # included), the mesh kernels compute in the device dtype —
         # f32 when jax x64 is off — while the host-numpy path is
         # true f64 (README "Multi-chip execution").
-        with _stage("prom_prepare"):
+        with tracing.span("prom_prepare"):
             sharded = prep.sharded(mesh)
-        with _stage("prom_kernel"):
+        with tracing.span("prom_kernel"):
             if kind == "rate":
                 out, valid = sharded.rate(
                     is_counter=spec["is_counter"],
@@ -936,33 +935,41 @@ class PromEngine:
 
     def _run_tiled_kernel(self, spec, kind, prep, host: bool):
         """Single-device tiled kernels: host numpy or jax.numpy per the
-        planner's route."""
+        planner's route.  The device route is eager jax.numpy — a chain
+        of small programs with no name of its own — so its dispatch is
+        one `device_launch` span named `prom_eager`; the wait for the
+        device is the fetch's."""
         STATS.incr("prom", "tiled_kernels")
-        xp = np
-        if not host:
-            import jax.numpy as xp  # noqa: F811 — device path
-        with _stage("prom_kernel"):
-            if kind == "rate":
-                out, valid = prep.rate(
-                    xp, is_counter=spec["is_counter"],
-                    is_rate=spec["is_rate"])
-            elif kind == "instant_rate":
-                out, valid = prep.instant_rate(
-                    xp, per_second=spec["per_second"])
-            elif kind == "changes_resets":
-                out, valid = prep.changes_resets(xp, kind=spec["which"])
-            elif kind == "deriv":
-                out, _icept, valid = prep.linear_regression(xp)
-            elif kind == "predict":
-                slope, icept, valid = prep.linear_regression(xp)
-                out = icept + slope * spec["dur"]
+        with tracing.span("prom_kernel"):
+            if host:
+                out, valid = self._tiled_dispatch(spec, kind, prep, np)
             else:
-                out, valid = prep.over_time(xp, func=spec["func"])
+                import jax.numpy as jnp
+
+                with tracing.span("device_launch", program="prom_eager"):
+                    out, valid = self._tiled_dispatch(spec, kind, prep, jnp)
         kr = prep.k_real
         from opengemini_tpu.utils import devobs
 
         return (devobs.fetch_np(out)[:, :kr],
                 devobs.fetch_np(valid)[:, :kr])
+
+    @staticmethod
+    def _tiled_dispatch(spec, kind, prep, xp):
+        if kind == "rate":
+            return prep.rate(xp, is_counter=spec["is_counter"],
+                             is_rate=spec["is_rate"])
+        if kind == "instant_rate":
+            return prep.instant_rate(xp, per_second=spec["per_second"])
+        if kind == "changes_resets":
+            return prep.changes_resets(xp, kind=spec["which"])
+        if kind == "deriv":
+            out, _icept, valid = prep.linear_regression(xp)
+            return out, valid
+        if kind == "predict":
+            slope, icept, valid = prep.linear_regression(xp)
+            return icept + slope * spec["dur"], valid
+        return prep.over_time(xp, func=spec["func"])
 
     def _run_range_kernel(self, spec, t_ms_all, v_all, lens, eval_times,
                           w, enc=None):
@@ -970,7 +977,7 @@ class PromEngine:
         the window grid fits the ms tile lattice, dense kernels otherwise.
         Returns host numpy (out, valid)."""
         kind = spec["kind"]
-        with _stage("prom_prepare"):
+        with tracing.span("prom_prepare"):
             prep = self._tiled_prep(spec, t_ms_all, v_all, lens,
                                     eval_times, w, enc=enc)
         if prep is None and v_all is None:
@@ -1012,12 +1019,12 @@ class PromEngine:
             return out, valid
         # dense fallback (searchsorted window bounds)
         STATS.incr("prom", "dense_kernels")
-        with _stage("prom_prepare"):
+        with tracing.span("prom_prepare"):
             times, values, counts, base_ms = promops.prepare_matrix_runs(
                 t_ms_all, v_all, lens, dtype=np.float64)
         ends = eval_times - base_ms / 1000.0
         starts = ends - w
-        with _stage("prom_kernel"):
+        with tracing.span("prom_kernel"):
             if kind == "rate":
                 out, valid = promops.extrapolated_rate(
                     times, values, counts, starts, ends, w,

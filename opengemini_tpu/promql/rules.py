@@ -56,7 +56,6 @@ import json
 import math
 import os
 import time as _time
-from contextlib import contextmanager
 
 import numpy as np
 
@@ -424,17 +423,6 @@ class RuleGroup:
         return g
 
 
-@contextmanager
-def _stage(name: str):
-    t0 = _time.perf_counter_ns()
-    try:
-        yield
-    finally:
-        ns = _time.perf_counter_ns() - t0
-        tracing.record_stage(name, ns)
-        TRACKER.add_stage_ns(TRACKER.current_qid(), name, ns)
-
-
 def _overlaps(inflight, lo_ms: int, hi_ms: int) -> bool:
     return any(a < hi_ms and lo_ms < b for a, b in inflight)
 
@@ -737,7 +725,7 @@ class RuleManager:
         # alert transitions/fire counts only land in the final save — so
         # the re-run cannot double-count, and recording write-back is
         # last-write-wins idempotent.
-        with _stage("rules_mark"):
+        with tracing.span("rules_mark"):
             g.claimed_ns = te_ns
             g.save(g.snapshot())
         _fp("rules-mark-before-eval")
@@ -752,7 +740,7 @@ class RuleManager:
         claimed: list[tuple[_SelState, set[int]]] = []
         lagged = False
         try:
-            with _stage("rules_fold"):
+            with tracing.span("rules_fold"):
                 for sel in g._sels.values():
                     wt = g.max_window_tiles_of(sel)
                     if wt == 0:
@@ -793,7 +781,7 @@ class RuleManager:
         # of threshold rules over one selector cost ONE merge per tick.
         results: dict[str, dict] = {}
         memo: dict = {}
-        with _stage("rules_merge"):
+        with tracing.span("rules_merge"):
             for r in g.rules:
                 if r.compiled.tiled:
                     results[r.name] = self._eval_tiled(g, r, e_tile,
@@ -805,7 +793,7 @@ class RuleManager:
 
         # -- verify: the from-scratch leg must agree bit-for-bit
         if verify_enabled():
-            with _stage("rules_verify"):
+            with tracing.span("rules_verify"):
                 if lagged or inflight:
                     # a mid-apply write makes the two legs read
                     # different storage states: not a counterexample
@@ -815,7 +803,7 @@ class RuleManager:
                     STATS.incr("rules", "verify_ticks")
 
         # -- effects: recording write-back + alert transitions
-        with _stage("rules_write"):
+        with tracing.span("rules_write"):
             points = []
             vf = self.prom.value_field
             for r in g.rules:
@@ -831,7 +819,7 @@ class RuleManager:
             if points:
                 self.engine.write_rows(g.db, points)
                 STATS.incr("rules", "series_written", len(points))
-        with _stage("rules_alerts"):
+        with tracing.span("rules_alerts"):
             for r in g.rules:
                 if r.kind == "alerting":
                     self._advance_alerts(g, r, results[r.name], te_ns)

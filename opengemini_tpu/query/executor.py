@@ -442,7 +442,8 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
         if now_ns is None:
             now_ns = _time.time_ns()
         try:
-            stmts = parse(text)
+            with tracing.span("sql_parse"):
+                stmts = parse(text)
         except ValueError as e:
             return {"results": [{"statement_id": 0, "error": f"error parsing query: {e}"}]}
         STATS.incr("executor", "queries")
@@ -458,33 +459,36 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
         t0 = _time.perf_counter_ns()
         token = GOVERNOR.admit()
         qid = None
-        trace = None
+        # per-query span tree (OGT_TRACE=1): the HTTP front end's, whose
+        # root is the whole request; a caller without one (the Flight
+        # path) gets a tree of its own here.  Active thread-locally, so
+        # deep callees — cluster RPC fan-out, the partials path — attach
+        # spans and wire ctx without a parameter threaded through every
+        # signature
+        trace = tracing.active_trace()
+        own = None
+        if trace is None and tracing.trace_enabled():
+            trace = own = tracing.Trace("query")
         try:
             qid = TRACKER.register(text, db)
-            if token.waited_ns:
-                # attribute the admission wait like any other query stage
-                # (shows in /debug/queries stages and /debug/vars
-                # query_stages — the trace-span channel)
-                TRACKER.add_stage_ns(qid, "admission_wait", token.waited_ns)
-                tracing.record_stage("admission_wait", token.waited_ns)
-            if tracing.trace_enabled():
-                # per-query span tree (OGT_TRACE=1): activated thread-
-                # locally so deep callees — cluster RPC fan-out, the
-                # partials path — attach spans and wire ctx without a
-                # parameter threaded through every signature
-                trace = tracing.Trace("query")
-                trace.root.add_field("statement", _redact(text))
-                trace.root.add_field("database", db)
-                TRACKER.set_trace(qid, trace)
-                with tracing.activate(trace):
-                    return self._execute_statements(
-                        stmts, db, now_ns, read_only, user)
-            return self._execute_statements(stmts, db, now_ns, read_only, user)
+            with tracing.activate(own) if own is not None \
+                    else contextlib.nullcontext():
+                if token.waited_ns:
+                    # the admission wait is a stage like any other
+                    # (/debug/queries stages, /debug/vars query_stages)
+                    tracing.record_stage("admission_wait", token.waited_ns)
+                if trace is not None:
+                    trace.qid = qid
+                    trace.root.add_field("statement", _redact(text))
+                    trace.root.add_field("database", db)
+                    TRACKER.set_trace(qid, trace)
+                return self._execute_statements(
+                    stmts, db, now_ns, read_only, user)
         finally:
             dur_ns = _time.perf_counter_ns() - t0
-            if trace is not None:
-                trace.finish()
-                tracing.note_finished(qid, trace, {"database": db})
+            if own is not None:
+                own.finish()
+                tracing.note_finished(qid, own, {"database": db})
             from opengemini_tpu.utils.slowlog import GLOBAL as SLOWLOG
 
             if SLOWLOG.enabled():
@@ -1409,11 +1413,7 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
                 schema, cache_plan, tmin, tmax)
         if rollup_plan is not None:
             with trace.span("rollup") as sp:
-                t0_rollup = _time.perf_counter_ns()
                 rollup_plan.fetch()
-                TRACKER.add_stage_ns(
-                    TRACKER.current_qid(), "rollup",
-                    _time.perf_counter_ns() - t0_rollup)
                 sp.add_field("windows_spliced", len(rollup_plan.serve))
                 sp.add_field("rollup_rows", rollup_plan.rows_read)
             if rollup_plan.serve:

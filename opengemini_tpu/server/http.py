@@ -14,6 +14,7 @@ single-node surface.
 
 from __future__ import annotations
 
+import contextlib
 import gzip
 import json
 import re
@@ -171,6 +172,7 @@ class HttpService:
         self._thread: threading.Thread | None = None
 
     def start(self) -> None:
+        tracing.watch_gc()  # runtime/gc_* from the first request on
         self._thread = threading.Thread(target=self.httpd.serve_forever, daemon=True)
         self._thread.start()
 
@@ -211,6 +213,16 @@ def _null_nonfinite(obj):
     if isinstance(obj, (list, tuple)):
         return [_null_nonfinite(v) for v in obj]
     return obj
+
+
+@contextlib.contextmanager
+def _admitted():
+    """An admission slot for one PromQL read, its wait a stage of the
+    request like /query's (executor.execute records its own)."""
+    with GOVERNOR.admitted() as token:
+        if token.waited_ns:
+            tracing.record_stage("admission_wait", token.waited_ns)
+        yield
 
 
 def _make_handler(svc: HttpService):
@@ -320,21 +332,27 @@ def _make_handler(svc: HttpService):
                     # unread remainder makes keep-alive unusable)
                     self.close_connection = True
                 self._body_cache = b""
-            self.send_response(code)
-            if self.close_connection:
-                self.send_header("Connection", "close")
-            if payload:
-                self.send_header("Content-Type", ctype)
-            self.send_header("Content-Length", str(len(payload)))
-            self.send_header("X-Influxdb-Version", "1.8.0-" + __version__)
-            extra = getattr(self, "_extra_headers", None)
-            if extra:
-                for k, v in extra.items():
-                    self.send_header(k, v)
-                self._extra_headers = None
-            self.end_headers()
-            if payload:
-                self.wfile.write(payload)
+            trace = tracing.active_trace()
+            if trace is not None:
+                trace.root.add_field("status", code)
+                trace.root.add_field("bytes_out", len(payload))
+            with tracing.span("send", status=code, bytes=len(payload)):
+                self.send_response(code)
+                if self.close_connection:
+                    self.send_header("Connection", "close")
+                if payload:
+                    self.send_header("Content-Type", ctype)
+                self.send_header("Content-Length", str(len(payload)))
+                self.send_header("X-Influxdb-Version",
+                                 "1.8.0-" + __version__)
+                extra = getattr(self, "_extra_headers", None)
+                if extra:
+                    for k, v in extra.items():
+                        self.send_header(k, v)
+                    self._extra_headers = None
+                self.end_headers()
+                if payload:
+                    self.wfile.write(payload)
 
         def _send_err(self, status: int, exc: BaseException,
                       extra: dict | None = None):
@@ -355,16 +373,21 @@ def _make_handler(svc: HttpService):
                        headers: dict | None = None):
             self._extra_headers = headers
             indent = 4 if pretty else None
-            try:
-                # strict JSON: a stray non-finite float anywhere in a
-                # result must not serialize as a bare NaN/Infinity literal
-                # (unparseable by standard clients). allow_nan=False makes
-                # the common all-finite case zero-cost; only offending
-                # payloads pay for the sanitize walk.
-                data = json.dumps(obj, indent=indent, allow_nan=False) + "\n"
-            except ValueError:
-                data = json.dumps(_null_nonfinite(obj), indent=indent) + "\n"
-            self._send(code, data.encode("utf-8"))
+            with tracing.span("serialize"):
+                try:
+                    # strict JSON: a stray non-finite float anywhere in a
+                    # result must not serialize as a bare NaN/Infinity
+                    # literal (unparseable by standard clients).
+                    # allow_nan=False makes the common all-finite case
+                    # zero-cost; only offending payloads pay for the
+                    # sanitize walk.
+                    data = json.dumps(obj, indent=indent,
+                                      allow_nan=False) + "\n"
+                except ValueError:
+                    data = json.dumps(_null_nonfinite(obj),
+                                      indent=indent) + "\n"
+                payload = data.encode("utf-8")
+            self._send(code, payload)
 
         def _authenticate(self, params: dict):
             """Basic auth header or u/p params (influx 1.x). Returns the
@@ -1450,7 +1473,8 @@ def _make_handler(svc: HttpService):
                         return
                     try:
                         started = _devobs.start_profile(
-                            seconds, logdir=params.get("dir") or None)
+                            seconds, logdir=params.get("dir") or None,
+                            python=params.get("python") in ("1", "true"))
                     except RuntimeError as e:
                         # capture already active (or backend refused):
                         # 409 so retry loops back off instead of
@@ -1555,6 +1579,10 @@ def _make_handler(svc: HttpService):
             self._send_json(200, {"status": "ok", "mod": mod, "switchon": on})
 
         def _handle_query(self, params: dict, read_only: bool = False):
+            with tracing.request("query"):
+                self._query(params, read_only)
+
+        def _query(self, params: dict, read_only: bool):
             user = self._authenticate(params)
             if user is False:
                 return
@@ -1581,14 +1609,16 @@ def _make_handler(svc: HttpService):
                 return
             epoch = params.get("epoch")
             pretty = params.get("pretty") in ("true", "1")
-            result = format_result(result, epoch)
+            with tracing.span("format"):
+                result = format_result(result, epoch)
             if params.get("chunked") in ("true", "1"):
                 try:
                     chunk_size = max(1, int(params.get("chunk_size", 10_000)))
                 except ValueError:
                     self._send_json(400, {"error": "bad chunk_size"})
                     return
-                self._send_chunked(result, chunk_size)
+                with tracing.span("send", chunked=True):
+                    self._send_chunked(result, chunk_size)
                 return
             self._send_json(200, result, pretty)
 
@@ -1629,6 +1659,13 @@ def _make_handler(svc: HttpService):
 
         def _handle_prom(self, path: str, params: dict):
             """Prometheus HTTP API v1 (reference: handler_prom.go)."""
+            if path in ("/api/v1/query_range", "/api/v1/query"):
+                with tracing.request("prom"):
+                    self._prom(path, params)
+            else:
+                self._prom(path, params)
+
+        def _prom(self, path: str, params: dict):
             user = self._authenticate(params)
             if user is False:
                 return
@@ -1642,7 +1679,7 @@ def _make_handler(svc: HttpService):
                     # PromQL reads scan like any interactive query and must
                     # take an admission slot — otherwise this surface is an
                     # ungoverned side door around the /query sheds
-                    with GOVERNOR.admitted():
+                    with _admitted():
                         data = svc.prom.query_range(
                             params.get("query", ""),
                             _prom_time(params.get("start")),
@@ -1652,7 +1689,7 @@ def _make_handler(svc: HttpService):
                         )
                 elif path == "/api/v1/query":
                     t = params.get("time")
-                    with GOVERNOR.admitted():
+                    with _admitted():
                         data = svc.prom.query_instant(
                             params.get("query", ""),
                             _prom_time(t) if t else time_now_s(),
@@ -2097,27 +2134,14 @@ def _make_handler(svc: HttpService):
             precision = params.get("precision", "ns")
             if precision == "n":
                 precision = "ns"
-            # coordinator-side write trace (OGT_TRACE=1): routed-write
-            # RPC fan-out under it carries wire ctx, replica ack spans
-            # graft back, and the stitched tree lands in the
-            # /debug/trace ring (no qid — writes are not tracked
-            # queries; addressable by trace_id)
-            wtrace = None
-            if tracing.trace_enabled() and not internal:
-                wtrace = tracing.Trace("write")
-                wtrace.root.add_field("database", db)
-            try:
-                if wtrace is not None:
-                    with tracing.activate(wtrace):
-                        self._write_dispatch(params, db, rp, precision,
-                                             internal)
-                else:
-                    self._write_dispatch(params, db, rp, precision,
-                                         internal)
-            finally:
-                if wtrace is not None:
-                    wtrace.finish()
-                    tracing.note_finished(None, wtrace, {"database": db})
+            # the write's root span; under OGT_TRACE=1 the coordinator-
+            # side tree: routed-write RPC fan-out under it carries wire
+            # ctx, replica ack spans graft back, and the stitched tree
+            # lands in the /debug/trace ring (no qid — writes are not
+            # tracked queries; addressable by trace_id).  A peer-
+            # forwarded write's spans belong to the coordinator's tree
+            with tracing.request("write", tree=not internal, database=db):
+                self._write_dispatch(params, db, rp, precision, internal)
 
         def _write_dispatch(self, params: dict, db: str, rp,
                             precision: str, internal: bool) -> None:
@@ -2127,7 +2151,9 @@ def _make_handler(svc: HttpService):
                     self._routed_write(router, db, rp, precision,
                                        consistency=params.get("consistency"))
                     return
-                svc.engine.write_lines(db, self._body(), precision=precision, rp=rp)
+                with tracing.span("read_body"):
+                    body = self._body()
+                svc.engine.write_lines(db, body, precision=precision, rp=rp)
             except DatabaseNotFound as e:
                 self._send_err(404, e)
                 return
@@ -2158,7 +2184,10 @@ def _make_handler(svc: HttpService):
             from opengemini_tpu.parallel.cluster import RemoteScanError
 
             try:
-                points = parse_lines(self._body(), precision, _time.time_ns())
+                with tracing.span("read_body"):
+                    body = self._body()
+                with tracing.span("lp_parse", bytes=len(body)):
+                    points = parse_lines(body, precision, _time.time_ns())
                 router.routed_write(db, rp, points,
                                     consistency=consistency)
             except RemoteScanError as e:

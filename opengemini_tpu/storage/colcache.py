@@ -77,9 +77,8 @@ from opengemini_tpu.utils import lockdep
 import time
 from collections import OrderedDict
 
-from opengemini_tpu.utils import devobs
+from opengemini_tpu.utils import devobs, tracing
 from opengemini_tpu.utils.governor import _env_int
-from opengemini_tpu.utils.querytracker import GLOBAL as _TRACKER
 from opengemini_tpu.utils.stats import GLOBAL as _STATS
 
 _DEFAULT_MB = 256
@@ -534,7 +533,7 @@ class ColumnCache:
     @staticmethod
     def _note_time(dt_ns: int) -> None:
         _STATS.incr("colcache", "time_ns", dt_ns)
-        _TRACKER.add_stage_ns(_TRACKER.current_qid(), "colcache", dt_ns)
+        tracing.record_stage("colcache", dt_ns)
 
 
 # process-wide cache (the reference's readcache singleton)

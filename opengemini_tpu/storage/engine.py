@@ -9,11 +9,12 @@ meta plane lives in opengemini_tpu/meta and layers on top).
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import shutil
 import threading
-from opengemini_tpu.utils import lockdep
+from opengemini_tpu.utils import lockdep, tracing
 import time as _time
 
 from opengemini_tpu.ingest import line_protocol as lp
@@ -1145,31 +1146,36 @@ class Engine:
             elif n is not None:
                 return n
             else:
-                batch = native_lp.parse_columnar(raw, precision, now_ns)
+                with tracing.span("lp_parse", bytes=len(raw)):
+                    batch = native_lp.parse_columnar(raw, precision, now_ns)
         if batch is not None:
             if len(batch) == 0:
                 return 0
             STATS.incr("write", "points", len(batch))
-            rtok = None
-            if self.rollup_mgr is not None:
-                # PRE-apply: a late write's dirty mark is durable before
-                # the rows are (storage/rollup.py watermark contract);
-                # write_done releases the in-flight fold floor
-                rtok = self.rollup_mgr.note_write_columnar(db, rp, batch)
-            utok = None
-            if self.rules_hook is not None:
-                utok = self.rules_hook.note_write_columnar(db, rp, batch)
+            rtok = utok = None
+            with tracing.span("write_hooks"):
+                if self.rollup_mgr is not None:
+                    # PRE-apply: a late write's dirty mark is durable
+                    # before the rows are (storage/rollup.py watermark
+                    # contract); write_done releases the in-flight fold
+                    # floor
+                    rtok = self.rollup_mgr.note_write_columnar(
+                        db, rp, batch)
+                if self.rules_hook is not None:
+                    utok = self.rules_hook.note_write_columnar(
+                        db, rp, batch)
             try:
                 tickets: list = []
                 touched: list = []
-                with self._lock:
+                with self._write_lock():
                     n = self._write_columnar_locked(
                         db, rp, batch, raw, precision, now_ns, tickets,
                         touched)
                 self._commit_wal_tickets(tickets)
                 self._flush_over_threshold(touched)
                 if self._write_observers:
-                    self._notify_write(db, rp, batch.to_points())
+                    with tracing.span("write_observers"):
+                        self._notify_write(db, rp, batch.to_points())
                 return n
             finally:
                 if rtok is not None:
@@ -1177,20 +1183,21 @@ class Engine:
                 if utok is not None:
                     self.rules_hook.write_done(utok)
 
-        points = lp.parse_lines(lines, precision, now_ns,
-                                expand_tag_arrays=self.tag_arrays)
+        with tracing.span("lp_parse", bytes=len(raw)):
+            points = lp.parse_lines(lines, precision, now_ns,
+                                    expand_tag_arrays=self.tag_arrays)
         if not points:
             return 0
         STATS.incr("write", "points", len(points))
-        rtok = None
-        if self.rollup_mgr is not None:
-            rtok = self.rollup_mgr.note_write_points(db, rp, points)
-        utok = None
-        if self.rules_hook is not None:
-            utok = self.rules_hook.note_write_points(db, rp, points)
+        rtok = utok = None
+        with tracing.span("write_hooks"):
+            if self.rollup_mgr is not None:
+                rtok = self.rollup_mgr.note_write_points(db, rp, points)
+            if self.rules_hook is not None:
+                utok = self.rules_hook.note_write_points(db, rp, points)
         try:
             tickets: list = []
-            with self._lock:
+            with self._write_lock():
                 # group points by target shard (time routing)
                 by_shard: dict[int, list] = {}
                 shards: dict[int, Shard] = {}
@@ -1207,7 +1214,8 @@ class Engine:
                     tickets.append((shards[key], t))
             self._commit_wal_tickets(tickets)  # fsyncs coalesce off-lock
             self._flush_over_threshold(shards.values())
-            self._notify_write(db, rp, points)
+            with tracing.span("write_observers"):
+                self._notify_write(db, rp, points)
             return n
         finally:
             if rtok is not None:
@@ -1233,9 +1241,6 @@ class Engine:
             return None
         if native_lp.load() is None:
             return None
-        segs = _split_lp_segments(raw, _INGEST_WORKERS)
-        if len(segs) < 2:
-            return None
         errs: list = []
 
         def parse_one(idx_seg):
@@ -1245,7 +1250,12 @@ class Engine:
             except ParseError as e:
                 errs.append((idx, e))
                 return None
-        parsed = list(pool.map(parse_one, enumerate(segs)))
+        with tracing.span("lp_parse", bytes=len(raw)) as sp:
+            segs = _split_lp_segments(raw, _INGEST_WORKERS)
+            if len(segs) < 2:
+                return None
+            sp.add_field("segments", len(segs))
+            parsed = list(pool.map(parse_one, enumerate(segs)))
         if errs:
             # report the FIRST bad line of the body, not whichever worker
             # thread finished first
@@ -1257,39 +1267,41 @@ class Engine:
         # cross-segment field-type check BEFORE applying anything: the
         # single-batch path rejects an internally-conflicting body with
         # nothing persisted; segments must not differ
-        body_types: dict[tuple[str, str], object] = {}
-        for batch in parsed:
-            for mst_id, name, ftype, _values, valid in batch.cols:
-                if not valid.any():
-                    continue
-                key = (batch.measurements[mst_id], name)
-                have = body_types.get(key)
-                if have is None:
-                    body_types[key] = ftype
-                elif have != ftype:
-                    raise FieldTypeConflict(name, have, ftype)
+        with tracing.span("type_check"):
+            body_types: dict[tuple[str, str], object] = {}
+            for batch in parsed:
+                for mst_id, name, ftype, _values, valid in batch.cols:
+                    if not valid.any():
+                        continue
+                    key = (batch.measurements[mst_id], name)
+                    have = body_types.get(key)
+                    if have is None:
+                        body_types[key] = ftype
+                    elif have != ftype:
+                        raise FieldTypeConflict(name, have, ftype)
         total = 0
         rtoks = []
         utoks = []
         try:
-            if self.rollup_mgr is not None:
-                # inside the try: a note hook failing for batch k must
-                # still release batches <k's in-flight floors via the
-                # finally, or the watermark stalls forever
-                for batch in parsed:
-                    if len(batch):
-                        t = self.rollup_mgr.note_write_columnar(
-                            db, rp, batch)
-                        if t is not None:
-                            rtoks.append(t)
-            if self.rules_hook is not None:
-                for batch in parsed:
-                    if len(batch):
-                        t = self.rules_hook.note_write_columnar(
-                            db, rp, batch)
-                        if t is not None:
-                            utoks.append(t)
-            with self._lock:
+            with tracing.span("write_hooks"):
+                if self.rollup_mgr is not None:
+                    # inside the try: a note hook failing for batch k
+                    # must still release batches <k's in-flight floors
+                    # via the finally, or the watermark stalls forever
+                    for batch in parsed:
+                        if len(batch):
+                            t = self.rollup_mgr.note_write_columnar(
+                                db, rp, batch)
+                            if t is not None:
+                                rtoks.append(t)
+                if self.rules_hook is not None:
+                    for batch in parsed:
+                        if len(batch):
+                            t = self.rules_hook.note_write_columnar(
+                                db, rp, batch)
+                            if t is not None:
+                                utoks.append(t)
+            with self._write_lock():
                 # ONE lock acquisition for the whole body, with every
                 # segment pre-validated against the LIVE shard schemas
                 # before the first applies: the old per-segment lock
@@ -1298,13 +1310,15 @@ class Engine:
                 # never produce.  Routing runs ONCE per segment and is
                 # reused for the apply.
                 routed = []
-                for seg, batch in zip(segs, parsed):
-                    if len(batch) == 0:
-                        continue
-                    route = list(self._route_columnar_locked(db, rp, batch))
-                    for shard, rows in route:
-                        shard._check_columnar_types(batch, rows)
-                    routed.append((seg, batch, route))
+                with tracing.span("index_route"):
+                    for seg, batch in zip(segs, parsed):
+                        if len(batch) == 0:
+                            continue
+                        route = list(
+                            self._route_columnar_locked(db, rp, batch))
+                        for shard, rows in route:
+                            shard._check_columnar_types(batch, rows)
+                        routed.append((seg, batch, route))
                 tickets: list = []
                 touched: list = []
                 for seg, batch, route in routed:
@@ -1320,18 +1334,32 @@ class Engine:
             self._flush_over_threshold(touched)
             if self._write_observers and total:
                 # observers see the body ONCE, post-commit, like
-                # write_lines
-                pts: list = []
-                for batch in parsed:
-                    if len(batch):
-                        pts.extend(batch.to_points())
-                self._notify_write(db, rp, pts)
+                # write_lines.  The span covers building the points they
+                # are handed, asked for or not
+                with tracing.span("write_observers"):
+                    pts: list = []
+                    for batch in parsed:
+                        if len(batch):
+                            pts.extend(batch.to_points())
+                    self._notify_write(db, rp, pts)
             return total
         finally:
             for t in rtoks:
                 self.rollup_mgr.write_done(t)
             for t in utoks:
                 self.rules_hook.write_done(t)
+
+    @contextlib.contextmanager
+    def _write_lock(self):
+        """The engine lock for a write's apply, with the wait for it a
+        stage of its own: writers queue here behind each other and
+        behind everything else that takes the engine lock."""
+        with tracing.span("write_lock_wait"):
+            self._lock.acquire()
+        try:
+            yield
+        finally:
+            self._lock.release()
 
     def _route_columnar_locked(self, db: str, rp: str, batch):
         """Yield (shard, rows) for a ColumnarBatch — ONE routing
@@ -1372,8 +1400,9 @@ class Engine:
         # the group-commit fsync — a kill here must never lose a row that
         # a caller was told about (the ack happens after this returns)
         _fp("engine-before-wal-commit")
-        for shard, ticket in tickets:
-            shard.wal.commit(ticket)
+        with tracing.span("wal_commit"):
+            for shard, ticket in tickets:
+                shard.wal.commit(ticket)
 
     def _flush_over_threshold(self, shards) -> None:
         """Threshold flushes AFTER the engine lock drops: the off-lock
@@ -1387,8 +1416,10 @@ class Engine:
         flush benignly (drop discarded the data on purpose) — re-raise
         only if the shard is still registered."""
         _fp("engine-before-threshold-flush")  # engine lock released
-        self._flush_tolerating_drop(
-            shards, lambda sh: sh.flush_if_over(self.flush_threshold_bytes))
+        with tracing.span("flush_inline"):
+            self._flush_tolerating_drop(
+                shards,
+                lambda sh: sh.flush_if_over(self.flush_threshold_bytes))
 
     def _flush_tolerating_drop(self, shards, flush_fn) -> None:
         """Flush each distinct shard OFF the engine lock, swallowing a
@@ -1417,7 +1448,9 @@ class Engine:
         and written shards to `touched` for the caller to finish
         (commit + threshold flush) off-lock."""
         n = 0
-        for shard, rows in self._route_columnar_locked(db, rp, batch):
+        with tracing.span("index_route"):
+            route = list(self._route_columnar_locked(db, rp, batch))
+        for shard, rows in route:
             got, t = shard.write_columnar(
                 batch, rows, raw, precision, now_ns, defer_commit=True)
             n += got
@@ -1686,7 +1719,8 @@ class Engine:
                     tickets.append((shards[key], t))
             self._commit_wal_tickets(tickets)  # fsyncs coalesce off-lock
             self._flush_over_threshold(shards.values())
-            self._notify_write(db, rp, points)
+            with tracing.span("write_observers"):
+                self._notify_write(db, rp, points)
             return n
         finally:
             if rtok is not None:

@@ -44,6 +44,7 @@ from opengemini_tpu.utils import lockdep
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 
+from opengemini_tpu.utils import tracing
 from opengemini_tpu.utils.governor import InflightGauge
 from opengemini_tpu.utils.querytracker import GLOBAL as _TRACKER
 
@@ -136,15 +137,18 @@ def map_ordered(jobs, est_bytes=None, inflight_bytes: int | None = None):
         if len(est) != len(jobs):
             raise ValueError("est_bytes length must match jobs")
     qid = _TRACKER.current_qid()
+    handed = tracing.handoff()
 
     def run(job):
         # worker-side cancellation: a killed query stops paying for
         # decodes whose results would be discarded anyway. Binding the
         # qid also attributes worker-side cache fills (colcache stage
         # time) to the owning query; the binding dies with the next task.
+        # A traced query's worker spans parent under the dispatching span.
         _TRACKER.bind(qid)
         _TRACKER.raise_if_killed(qid)
-        return job()
+        with tracing.adopt(handed):
+            return job()
 
     pending: deque = deque()
     inflight = 0
@@ -196,6 +200,7 @@ def prefetch_ordered(thunks, depth: int = 2):
     q: "queue.Queue" = queue.Queue(maxsize=max(1, depth))
     stop = threading.Event()
     qid = _TRACKER.current_qid()
+    handed = tracing.handoff()
 
     def put(item) -> bool:
         while not stop.is_set():
@@ -209,11 +214,14 @@ def prefetch_ordered(thunks, depth: int = 2):
     def produce():
         _TRACKER.bind(qid)  # kill checks inside thunks fire here too
         try:
-            for t in thunks:
-                if stop.is_set() or _TRACKER.is_killed(qid):
-                    break
-                if not put(("ok", t())):
-                    return
+            # a traced query's spans from this thread (a bulk read's
+            # `decode`) parent under the span that started the scan
+            with tracing.adopt(handed):
+                for t in thunks:
+                    if stop.is_set() or _TRACKER.is_killed(qid):
+                        break
+                    if not put(("ok", t())):
+                        return
         except BaseException as e:  # noqa: BLE001 — relayed to consumer
             put(("err", e))
             return

@@ -11,7 +11,7 @@ from __future__ import annotations
 import os
 import itertools
 import threading
-from opengemini_tpu.utils import lockdep
+from opengemini_tpu.utils import lockdep, tracing
 
 import numpy as np
 
@@ -658,8 +658,10 @@ class Shard:
         write_points). Type conflicts raise BEFORE the WAL append."""
         with self._lock:
             self._check_columnar_types(batch, rows)
-            ticket = self.wal.append_lines(raw_lines, precision, now_ns)
-            n = self._apply_columnar(batch, rows=rows)
+            with tracing.span("wal_append", bytes=len(raw_lines)):
+                ticket = self.wal.append_lines(raw_lines, precision, now_ns)
+            with tracing.span("memtable_apply"):
+                n = self._apply_columnar(batch, rows=rows)
         if defer_commit:
             return n, ticket
         self.wal.commit(ticket)  # see write_points: group-commit wait
@@ -1657,11 +1659,15 @@ class Shard:
                 miss_at.append(len(slots))
                 slots.append(None)
                 ests.append(scanpool.est_chunk_bytes(c, n_fields))
-        try:
-            for i, part in zip(miss_at, scanpool.map_ordered(jobs, ests)):
-                slots[i] = part
-        except CorruptFile as e:
-            self.note_corrupt(e)  # see read_series
+        if jobs:
+            # the column cache missed: decode, one span per bulk read
+            with tracing.span("decode", chunks=len(jobs)):
+                try:
+                    for i, part in zip(
+                            miss_at, scanpool.map_ordered(jobs, ests)):
+                        slots[i] = part
+                except CorruptFile as e:
+                    self.note_corrupt(e)  # see read_series
         parts.extend(p for p in slots if p is not None)
         for m in mems:  # frozen snapshots oldest first, live memtable last
             for sid_arr, mem_rec in m.bulk_parts(measurement, sids):

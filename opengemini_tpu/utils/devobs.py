@@ -25,7 +25,8 @@ armed or not):
       disarmed, replacing the old bare `device/compile_cache_misses`).
       Armed additionally: backend compile WALL TIME via the
       jax.monitoring duration events, attributed to the kernel label
-      and to the running query's `device_compile` stage.
+      and to the running query's `device_compile` stage
+      (tracing.record_stage).
 
       The tripwire: mark_warm() (bench warm loops, or the ctrl op)
       snapshots "everything is compiled now"; ANY lowering-site miss
@@ -41,11 +42,14 @@ armed or not):
       is the single chokepoint for h2d / d2h / reshard byte accounting
       (it owns the `device/{h2d,d2h,reshard}_bytes` counters the ad-hoc
       sites used to bump inline).  Armed additionally: per-site
-      `ogt_device_{h2d,d2h,reshard}_{bytes,seconds}` histograms and
-      `device_transfer` stage attribution.  fetch_np() wraps the
-      device->host materialization (np.asarray of a jax array) so
-      result fetches are labeled `result-fetch` — disarmed it is one
-      isinstance check over a plain np.asarray.
+      `ogt_device_{h2d,d2h,reshard}_{bytes,seconds}` histograms.
+      launch() is the one way to call a compiled program: a
+      `device_launch` span (utils/tracing.py; always on) around the
+      call, and the host arrays among its arguments — the implicit H2D
+      of a single-chip launch — counted as h2d bytes.  fetch_np() and
+      fetch_dict() wrap the device->host materialization (np.asarray of
+      a jax array: the device wait plus the D2H) in a `device_fetch`
+      span and count its bytes.
 
   device-memory ledger every RETAINED device buffer registers (owner,
       nbytes, mesh-epoch): the colcache device tier, grid `mesh_arrays`
@@ -68,7 +72,10 @@ armed or not):
 
 An on-demand `jax.profiler` capture (start_profile / /debug/ctrl
 op=profile&seconds=N) rounds out the ops surface — single-capture
-guarded, writing a TensorBoard-loadable trace directory.
+guarded, writing a TensorBoard-loadable trace directory.  It runs
+without the python tracer: the host events in it are the runtime's own
+and the program's spans (`ogt:<stage>`, utils/tracing.py), on the same
+clock as the device's operations.  `python=1` asks for frames as well.
 
 Knobs (README "Device observability"): OGT_DEVOBS (1 = armed),
 OGT_DEVOBS_RING (recent-compile ring bound, default 256).
@@ -193,18 +200,9 @@ def _on_jax_duration(event: str, duration_s: float, **_kw) -> None:
     from opengemini_tpu.utils.stats import observe_ns
 
     observe_ns("device_compile_seconds", ns, kernel=kernel)
-    _note_stage("device_compile", ns)
-
-
-def _note_stage(name: str, ns: int) -> None:
-    """Attribute device time to the running query (tracker stages ->
-    /debug/queries + slow-log stages_ms) and the cumulative stage stats
-    (query_stages + the query_stage_seconds histogram)."""
     from opengemini_tpu.utils import tracing
-    from opengemini_tpu.utils.querytracker import GLOBAL as _TRACKER
 
-    tracing.record_stage(name, ns)
-    _TRACKER.add_stage_ns(_TRACKER.current_qid(), name, ns)
+    tracing.record_stage("device_compile", ns)
 
 
 # per-(family, site) histogram cache: note_transfer is on the armed hot
@@ -387,8 +385,7 @@ def note_transfer(direction: str, site: str, nbytes: int,
                   mesh: bool = False) -> None:
     """The single chokepoint for device transfer accounting.  Always
     owns the `device/{h2d,d2h,reshard}_bytes` counters; armed it adds
-    the per-site byte/latency histograms and attributes the wall to the
-    running query's `device_transfer` stage.  ``mesh=True`` marks a
+    the per-site byte/latency histograms.  ``mesh=True`` marks a
     transfer made under a configured device mesh (a `mesh="on"` label on
     the site's histograms — the sharded-decode H2D is distinguishable
     from the single-device one at the same site)."""
@@ -401,46 +398,64 @@ def note_transfer(direction: str, site: str, nbytes: int,
     _hist("device_" + direction + "_bytes", site, "bytes",
           mesh).observe_ns(nbytes)
     if seconds is not None:
-        ns = int(seconds * 1e9)
         _hist("device_" + direction + "_seconds", site, "seconds",
-              mesh).observe_ns(ns)
-        _note_stage("device_transfer", ns)
+              mesh).observe_ns(int(seconds * 1e9))
 
 
-def fetch_np(x, site: str = "result-fetch"):
-    """np.asarray with d2h accounting: device arrays count bytes (and,
-    armed, fetch wall time); host arrays pass straight through."""
+def _fetch(x, site: str):
+    """np.asarray of one array; a device array's bytes (and, armed, its
+    fetch wall) are counted as d2h."""
     import jax
 
     if not isinstance(x, jax.Array):
         return _np.asarray(x)
-    if not _ON:
-        a = _np.asarray(x)
-        note_transfer("d2h", site, a.nbytes)
-        return a
-    t0 = time.perf_counter_ns()
+    t0 = time.perf_counter_ns() if _ON else 0
     a = _np.asarray(x)
     note_transfer("d2h", site, a.nbytes,
-                  (time.perf_counter_ns() - t0) / 1e9)
+                  (time.perf_counter_ns() - t0) / 1e9 if _ON else None)
     return a
 
 
-def t0() -> int:
-    """perf_counter_ns when armed, 0 disarmed — the one-branch guard
-    for exec-time attribution at kernel dispatch sites:
+def fetch_np(x, site: str = "result-fetch"):
+    """np.asarray with d2h accounting: a device array is fetched inside
+    a `device_fetch` span (the wait for the device, then the copy) and
+    its bytes counted; host arrays pass straight through."""
+    import jax
 
-        t = devobs.t0()
-        out = fn(*arrays)
-        if t:
-            devobs.note_exec(t)
-    """
-    return time.perf_counter_ns() if _ON else 0
+    if not isinstance(x, jax.Array):
+        return _np.asarray(x)
+    from opengemini_tpu.utils import tracing
+
+    with tracing.span("device_fetch") as sp:
+        a = _fetch(x, site)
+        sp.add_field("bytes", a.nbytes)
+    return a
 
 
-def note_exec(t0_ns: int) -> None:
-    """Attribute device-exec wall (dispatch + any blocking wait) since
-    ``t0_ns`` to the running query's `device_exec` stage."""
-    _note_stage("device_exec", time.perf_counter_ns() - t0_ns)
+def fetch_dict(outs: dict, site: str = "result-fetch") -> dict:
+    """fetch_np over the values of one launch's result dict, as ONE
+    `device_fetch` span."""
+    from opengemini_tpu.utils import tracing
+
+    with tracing.span("device_fetch") as sp:
+        got = {k: _fetch(v, site) for k, v in outs.items()}
+        sp.add_field("bytes", sum(a.nbytes for a in got.values()))
+    return got
+
+
+def launch(fn, args, program: str, xfer_site: str):
+    """Call the compiled program `fn(*args)` inside a `device_launch`
+    span: the dispatch (jax returns before the device is done; the wait
+    is the fetch's) with the implicit H2D of whatever host arrays are
+    among `args`, whose bytes count as h2d at `xfer_site`."""
+    from opengemini_tpu.utils import tracing
+
+    h2d = sum(a.nbytes for a in args if isinstance(a, _np.ndarray))
+    with tracing.span("device_launch", program=program, h2d_bytes=h2d):
+        out = fn(*args)
+    if h2d:
+        note_transfer("h2d", xfer_site, h2d)
+    return out
 
 
 def span_snapshot() -> dict:
@@ -673,12 +688,18 @@ _profile = {"active": False, "dir": None, "started_uptime_s": None,
             "seconds": None, "last": None}
 
 
-def start_profile(seconds: float, logdir: str | None = None) -> dict:
+def start_profile(seconds: float, logdir: str | None = None,
+                  python: bool = False) -> dict:
     """Start a single-capture-guarded jax.profiler trace for
     ``seconds`` (clamped to [0.05, 120]); a background thread stops it.
     Raises RuntimeError while a capture is already active.  Returns the
     status dict (dir included) immediately — the trace directory is
-    TensorBoard / XProf loadable once `active` goes false."""
+    TensorBoard / XProf loadable once `active` goes false.
+
+    The python tracer is off unless ``python``: it stretches a request
+    3-5x and takes tens of seconds to stop, and the program's own spans
+    (tracing.span -> `ogt:<stage>` annotations, on while `active`) say
+    what the host was doing."""
     import tempfile
 
     seconds = min(max(float(seconds), 0.05), 120.0)
@@ -693,8 +714,10 @@ def start_profile(seconds: float, logdir: str | None = None) -> dict:
                             time.perf_counter() - _started_pc, 3))
     import jax
 
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 1 if python else 0
     try:
-        jax.profiler.start_trace(logdir)
+        jax.profiler.start_trace(logdir, profiler_options=options)
     except Exception as e:  # noqa: BLE001 — surface, don't wedge the guard
         with _profile_lock:
             _profile.update(active=False,
@@ -705,11 +728,14 @@ def start_profile(seconds: float, logdir: str | None = None) -> dict:
     def _stop():
         time.sleep(seconds)
         doc = {"dir": logdir, "seconds": seconds, "ok": True}
+        t_stop = time.perf_counter()
         try:
             jax.profiler.stop_trace()
         except Exception as e:  # noqa: BLE001
             doc = {"dir": logdir, "seconds": seconds, "ok": False,
                    "error": f"{type(e).__name__}: {e}"}
+        # what stopping and writing the capture cost, for the operator
+        doc["stop_s"] = round(time.perf_counter() - t_stop, 3)
         with _profile_lock:
             _profile.update(active=False, last=doc)
 

@@ -38,6 +38,15 @@ class Statistics:
         with self._lock:
             self._counters[module][name] += delta
 
+    def add(self, module: str, items) -> None:
+        """Several counters of one module under one lock acquisition:
+        `items` is an iterable of (name, delta).  The span primitive
+        (utils/tracing.py) closes every stage through here."""
+        with self._lock:
+            sect = self._counters[module]
+            for name, delta in items:
+                sect[name] += delta
+
     def set(self, module: str, name: str, value: int) -> None:
         with self._lock:
             self._counters[module][name] = value
@@ -237,18 +246,29 @@ _HIST_LOCK = lockdep.Lock()
 _HISTOGRAMS: dict[tuple, Histogram] = {}
 
 
+def histogram_key(name: str, **labels) -> tuple:
+    """The registry key of (name, labels), for call sites that look one
+    histogram up per event and want to build the key once."""
+    return (name, tuple(sorted(labels.items())))
+
+
 def histogram(name: str, unit: str = "seconds", **labels) -> Histogram:
     """Get-or-create the process-wide histogram for (name, labels).
     Call sites with fixed labels should cache the returned object —
     observe_ns() itself is the hot path, not this lookup.  ``unit`` is
     fixed at first creation (a family never changes units)."""
-    key = (name, tuple(sorted(labels.items())))
+    return histogram_at(histogram_key(name, **labels), unit)
+
+
+def histogram_at(key: tuple, unit: str = "seconds") -> Histogram:
+    """histogram() by a key histogram_key() built earlier: one dict
+    lookup, and still correct after reset_histograms()."""
     h = _HISTOGRAMS.get(key)
     if h is None:
         with _HIST_LOCK:
             h = _HISTOGRAMS.get(key)
             if h is None:
-                h = Histogram(name, key[1], unit=unit)
+                h = Histogram(key[0], key[1], unit=unit)
                 _HISTOGRAMS[key] = h
     return h
 

@@ -385,8 +385,8 @@ class TestQueryStages:
             recs = slowlog.GLOBAL.snapshot()["records"]
             assert recs
             stages = recs[-1]["stages_ms"]
-            assert "device_exec" in stages, stages
-            assert "device_transfer" in stages, stages
+            assert "device_launch" in stages, stages
+            assert "device_fetch" in stages, stages
         finally:
             slowlog.GLOBAL.configure(slow_ms=prev_slow)
             slowlog.GLOBAL.clear()

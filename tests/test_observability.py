@@ -71,6 +71,20 @@ def _post(port, path, body=b"", **params):
         return e.code, e.read()
 
 
+def _recent(name: str) -> list[dict]:
+    """Retained traces whose root span is `name`.  A request's root
+    closes after its response is sent, so the client can be back here a
+    moment before the trace is retained: wait for it."""
+    import time
+
+    deadline = time.monotonic() + 5.0
+    while True:
+        docs = [d for d in tracing.recent_traces() if d["name"] == name]
+        if docs or time.monotonic() > deadline:
+            return docs
+        time.sleep(0.01)
+
+
 # -- histograms --------------------------------------------------------------
 
 
@@ -397,8 +411,7 @@ class TestClusterTraceStitching:
             assert total == 40
 
             # one stitched tree at the coordinator
-            docs = [d for d in tracing.recent_traces()
-                    if d["name"] == "query"]
+            docs = _recent("http_query")
             assert docs, "no query trace retained"
             doc = tracing.get_trace(qid=docs[0]["qid"])
             root = doc["trace"]["root"]
@@ -427,8 +440,7 @@ class TestClusterTraceStitching:
 
             # routed-write stitching: the write trace carries the
             # replica's internal_write/apply subtree
-            wdocs = [d for d in tracing.recent_traces()
-                     if d["name"] == "write"]
+            wdocs = _recent("http_write")
             assert wdocs
             wdoc = tracing.get_trace(trace_id=wdocs[0]["trace_id"])
             wspans = _spans_by_name(wdoc["trace"]["root"])
@@ -475,8 +487,7 @@ class TestClusterTraceStitching:
                 assert total == 40  # exact despite the failover
             finally:
                 netfault.clear_all()
-            docs = [d for d in tracing.recent_traces()
-                    if d["name"] == "query"]
+            docs = _recent("http_query")
             assert docs
             doc = tracing.get_trace(qid=docs[0]["qid"])
             spans = _spans_by_name(doc["trace"]["root"])
@@ -527,7 +538,7 @@ class TestSlowLog:
         assert rec["duration_ms"] >= 0
         # tracing was armed: the record embeds the span tree
         assert rec["trace"] is not None
-        assert rec["trace"]["root"]["name"] == "query"
+        assert rec["trace"]["root"]["name"] == "http_query"
         # disable via ctrl: capture stops
         _post(port, "/debug/ctrl", mod="obs", slow_ms="off", trace="0")
         before = json.loads(_get(port, "/debug/slow")[1])["captured"]

@@ -1,0 +1,441 @@
+"""The span primitive (utils/tracing.py) and its three sinks: counters
+with self time, the request's tree under OGT_TRACE=1 on the three served
+paths, and `ogt:` annotations in a profiler capture; the garbage-
+collection hook; H2D bytes counted at a single-device launch."""
+
+import gc
+import glob
+import json
+import os
+import threading
+import time
+import urllib.parse
+import urllib.request
+
+import numpy as np
+import pytest
+
+from opengemini_tpu.models import ragged
+from opengemini_tpu.ops import aggregates as aggmod
+from opengemini_tpu.parallel import runtime as prt
+from opengemini_tpu.server.http import HttpService
+from opengemini_tpu.storage import colcache, scanpool
+from opengemini_tpu.storage.engine import Engine
+from opengemini_tpu.utils import devobs, tracing
+from opengemini_tpu.utils.stats import GLOBAL as STATS
+
+NS = 10**9
+BASE = 1_700_000_000
+DAY = 86_400
+
+QUERY_SPANS = {"sql_parse", "select: cpu", "map_shards", "scan", "decode",
+               "colcache", "device_compute", "layout_build",
+               "device_launch", "device_fetch", "host_combine", "inc_cache",
+               "render", "format", "serialize", "send"}
+PROM_SPANS = {"prom_parse", "prom_collect", "decode", "prom_prepare",
+              "prom_kernel", "device_launch", "device_fetch", "prom_render",
+              "serialize", "send"}
+WRITE_SPANS = {"read_body", "lp_parse", "type_check", "write_hooks",
+               "write_lock_wait", "index_route", "memtable_apply",
+               "wal_append", "wal_commit", "flush_inline", "write_observers",
+               "send"}
+
+
+@pytest.fixture(autouse=True)
+def _state():
+    prev = tracing.trace_enabled()
+    tracing.set_trace_enabled(False)
+    tracing.clear_recent()
+    yield
+    tracing.set_trace_enabled(prev)
+    tracing.clear_recent()
+
+
+def _counters(group: str) -> dict:
+    return STATS.counters(group)
+
+
+def _delta(group: str, before: dict) -> dict:
+    return {k: v - before.get(k, 0) for k, v in _counters(group).items()
+            if v != before.get(k, 0)}
+
+
+# -- sink 1: counters ---------------------------------------------------------
+
+
+def test_self_time_is_elapsed_minus_children():
+    q0, h0 = _counters("query_stages"), _counters("http")
+    with tracing.request("query"):
+        with tracing.span("t_outer"):
+            with tracing.span("t_inner"):
+                time.sleep(0.02)
+            tracing.record_stage("t_measured", 3_000_000)
+            time.sleep(0.01)
+    d = _delta("query_stages", q0)
+    assert d["t_outer_count"] == d["t_inner_count"] == 1
+    assert d["t_inner_self_ns"] == d["t_inner_ns"] >= 20_000_000
+    assert d["t_measured_ns"] == d["t_measured_self_ns"] == 3_000_000
+    # exact: the frame's accumulator holds what the children recorded
+    assert d["t_outer_self_ns"] == \
+        d["t_outer_ns"] - d["t_inner_ns"] - d["t_measured_ns"]
+    assert d["t_outer_self_ns"] >= 7_000_000    # its own 10 ms, less the 3
+    h = _delta("http", h0)
+    assert h["query_count"] == 1
+    assert h["query_self_ns"] == h["query_ns"] - d["t_outer_ns"]
+    assert h["query_offcpu_ns"] == h["query_ns"] - h["query_cpu_ns"]
+    assert h["query_offcpu_ns"] >= 30_000_000      # it slept
+
+
+@pytest.mark.parametrize("route, group", [("query", "query_stages"),
+                                          ("prom", "query_stages"),
+                                          ("write", "write_stages")])
+def test_a_root_names_its_childrens_group(route, group):
+    before = _counters(group)
+    with tracing.request(route):
+        with tracing.span("t_grouped"):
+            pass
+    assert _delta(group, before)["t_grouped_count"] == 1
+
+
+def test_a_span_on_another_thread_leaves_the_parents_self_time_alone():
+    before = _counters("query_stages")
+
+    def worker():
+        with tracing.span("t_worker"):
+            time.sleep(0.02)
+
+    with tracing.span("t_parent"):
+        th = threading.Thread(target=worker)
+        th.start()
+        th.join(timeout=10)
+        assert not th.is_alive()
+    d = _delta("query_stages", before)
+    assert d["t_parent_self_ns"] == d["t_parent_ns"] >= d["t_worker_ns"]
+
+
+def test_the_stage_lands_on_the_bound_query():
+    from opengemini_tpu.utils.querytracker import GLOBAL as TRACKER
+
+    qid = TRACKER.register("t", "db")
+    try:
+        with tracing.span("t_tracked"):
+            pass
+        tracing.record_stage("t_noted", 5)
+        stages = TRACKER.stages_of(qid)
+    finally:
+        TRACKER.unregister(qid)
+    assert stages["t_tracked"] > 0 and stages["t_noted"] == 5
+
+
+# -- sink 2: the request's tree ----------------------------------------------
+
+
+def _http(port, method, path, body=None, **params):
+    url = f"http://127.0.0.1:{port}{path}?" + urllib.parse.urlencode(params)
+    req = urllib.request.Request(url, data=body, method=method)
+    with urllib.request.urlopen(req, timeout=60) as r:
+        return r.status, r.read()
+
+
+def _trace_of(port, root_name: str) -> dict:
+    """The newest retained tree with that root, from /debug/trace.  A
+    root closes after its response is sent: wait for it."""
+    deadline = time.monotonic() + 10.0
+    while time.monotonic() < deadline:
+        recent = json.loads(_http(port, "GET", "/debug/trace")[1])["recent"]
+        hit = [d for d in recent if d["name"] == root_name]
+        if hit:
+            doc = json.loads(_http(port, "GET", "/debug/trace",
+                                   trace_id=hit[0]["trace_id"])[1])
+            assert doc["trace"]["trace_id"] == hit[0]["trace_id"]
+            return doc
+        time.sleep(0.01)
+    raise AssertionError(f"no {root_name} tree was retained")
+
+
+def _walk(node: dict, parent=None):
+    yield node, parent
+    for child in node["children"]:
+        yield from _walk(child, node)
+
+
+def _check_tree(doc: dict, root_name: str, vocabulary: set) -> dict:
+    """Root, vocabulary, parent links; returns {name: [(span, parent)]}."""
+    root = doc["trace"]["root"]
+    assert root["name"] == root_name and root["elapsed_ns"] > 0
+    by_name: dict = {}
+    ids = set()
+    for span, parent in _walk(root):
+        assert span["span_id"] not in ids
+        ids.add(span["span_id"])
+        if parent is not None:
+            assert span["parent_id"] == parent["span_id"]
+            assert span["name"] in vocabulary, span["name"]
+            assert span["start_ns"] >= root["start_ns"]
+        by_name.setdefault(span["name"], []).append((span, parent))
+    return by_name
+
+
+@pytest.fixture
+def server(tmp_path, monkeypatch):
+    # the scan pool on, however few cores the test machine has
+    monkeypatch.setattr(scanpool, "WORKERS", 4)
+    engine = Engine(str(tmp_path / "data"))
+    engine.create_database("db")
+    engine.create_database("prom")
+    svc = HttpService(engine, "127.0.0.1", 0)
+    svc.start()
+    yield svc
+    svc.stop()
+    engine.close()
+
+
+def test_a_query_leaves_a_tree(server):
+    port = server.port
+    # two shards (a week apart) of 70 series each, on disk: two bulk
+    # reads, the second on the scan pool's prefetch thread
+    for t0 in (BASE, BASE + 8 * DAY):
+        lines = "\n".join(
+            f"cpu,host=h{h} v={h + k} {(t0 + k * 600) * NS}"
+            for h in range(70) for k in range(6))
+        assert _http(port, "POST", "/write", lines.encode(),
+                     db="db")[0] == 204
+    _http(port, "POST", "/debug/ctrl", mod="flush")
+    colcache.GLOBAL.clear()
+    tracing.set_trace_enabled(True)
+    status, body = _http(
+        port, "GET", "/query", db="db",
+        q=f"SELECT mean(v) FROM cpu WHERE time >= {BASE * NS} AND "
+          f"time < {(BASE + 9 * DAY) * NS} GROUP BY time(1d), host")
+    assert status == 200 and "error" not in json.loads(body)["results"][0]
+    doc = _trace_of(port, "http_query")
+    spans = _check_tree(doc, "http_query", QUERY_SPANS)
+    assert doc["qid"] is not None       # the executor's query id
+    for name in ("sql_parse", "select: cpu", "format", "serialize", "send"):
+        [(_, parent)] = spans[name]
+        assert parent["name"] == "http_query"
+    for name in ("map_shards", "scan", "device_compute", "render"):
+        assert all(p["name"] == "select: cpu" for _, p in spans[name])
+    for name in ("layout_build", "device_launch", "device_fetch",
+                 "host_combine"):
+        assert all(p["name"] == "device_compute" for _, p in spans[name])
+    # the bulk reads missed the cache and decoded, one span each, from
+    # the prefetch thread, under the scan that dispatched them
+    assert len(spans["decode"]) == 2
+    assert all(p["name"] == "scan" for _, p in spans["decode"])
+    launch = dict(map(tuple, spans["device_launch"][0][0]["fields"]))
+    assert launch["program"] and launch["h2d_bytes"] > 0
+    fields = dict(map(tuple, doc["trace"]["root"]["fields"]))
+    assert fields["status"] == 200 and fields["bytes_out"] == len(body)
+
+
+def test_a_promql_query_leaves_a_tree(server):
+    port = server.port
+    lines = "\n".join(
+        f"http_requests_total,job=j{j} value={k * (j + 1)} "
+        f"{(BASE + k * 15) * NS}" for j in range(5) for k in range(200))
+    assert _http(port, "POST", "/write", lines.encode(), db="prom")[0] == 204
+    tracing.set_trace_enabled(True)
+    status, body = _http(port, "GET", "/api/v1/query_range",
+                         query="rate(http_requests_total[5m])",
+                         start=BASE + 600, end=BASE + 2400, step=60)
+    assert status == 200 and json.loads(body)["status"] == "success"
+    doc = _trace_of(port, "http_prom")
+    spans = _check_tree(doc, "http_prom", PROM_SPANS)
+    assert doc["qid"] is not None
+    for name in ("prom_parse", "prom_collect", "prom_prepare", "prom_kernel",
+                 "prom_render", "serialize", "send"):
+        assert [p["name"] for _, p in spans[name]] == ["http_prom"], name
+
+
+def test_a_write_leaves_a_tree(server):
+    port = server.port
+    tracing.set_trace_enabled(True)
+    before = _counters("write_stages")
+    observed = []
+    server.engine.add_write_observer(
+        lambda db, rp, points: observed.append(len(points)))
+    lines = "\n".join(f"m,host=h{i % 9} v={i} {(BASE + i) * NS}"
+                      for i in range(500))
+    assert _http(port, "POST", "/write", lines.encode(), db="db")[0] == 204
+    doc = _trace_of(port, "http_write")
+    spans = _check_tree(doc, "http_write", WRITE_SPANS)
+    for name in ("read_body", "lp_parse", "write_hooks", "write_lock_wait",
+                 "index_route", "wal_append", "memtable_apply", "wal_commit",
+                 "flush_inline", "write_observers", "send"):
+        assert [p["name"] for _, p in spans[name]] == ["http_write"], name
+    assert observed == [500]
+    d = _delta("write_stages", before)
+    assert d["lp_parse_count"] == d["memtable_apply_count"] == 1
+
+
+def test_a_large_body_parses_in_segments(server, monkeypatch):
+    from opengemini_tpu.storage import engine as engmod
+
+    monkeypatch.setattr(engmod, "_INGEST_SEGMENT_BYTES", 4096)
+    if engmod._ingest_pool() is None:
+        pytest.skip("no ingest pool on this machine")
+    tracing.set_trace_enabled(True)
+    lines = "\n".join(f"m,host=h{i % 50} v={i} {(BASE + i) * NS}"
+                      for i in range(2000))
+    assert _http(server.port, "POST", "/write", lines.encode(),
+                 db="db")[0] == 204
+    spans = _check_tree(_trace_of(server.port, "http_write"), "http_write",
+                        WRITE_SPANS)
+    [(parse, _)] = spans["lp_parse"]
+    assert dict(map(tuple, parse["fields"]))["segments"] >= 2
+    assert len(spans["type_check"]) == 1
+    assert len(spans["memtable_apply"]) == len(spans["wal_append"]) >= 2
+
+
+def test_no_tree_without_the_knob(server):
+    _http(server.port, "POST", "/write", f"m v=1 {BASE * NS}".encode(),
+          db="db")
+    _http(server.port, "GET", "/query", db="db", q="SELECT count(v) FROM m")
+    time.sleep(0.05)
+    assert tracing.recent_traces() == []
+
+
+# -- sink 3: the profiler capture --------------------------------------------
+
+
+def _capture_events(logdir: str) -> dict:
+    """{line name: [(event name, start, end, stats)]} of the host plane."""
+    from jax.profiler import ProfileData
+
+    [path] = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                       recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for n, line in enumerate(plane.lines):
+            out[f"{n}:{line.name}"] = [
+                (e.name, e.start_ns, e.start_ns + e.duration_ns,
+                 dict(e.stats) if e.name.startswith("ogt:") else None)
+                for e in line.events]
+    return out
+
+
+def _wait_capture() -> dict:
+    deadline = time.monotonic() + 60.0
+    while devobs.profile_status()["active"]:
+        assert time.monotonic() < deadline, "the capture never stopped"
+        time.sleep(0.02)
+    last = devobs.profile_status()["last"]
+    assert last["ok"], last
+    return last
+
+
+def _traced_work():
+    def worker():
+        with tracing.span("t_pool_stage"):
+            time.sleep(0.01)
+
+    with tracing.request("query"):
+        with tracing.span("t_stage", rows=3):
+            with tracing.span("t_leaf"):
+                time.sleep(0.01)
+            th = threading.Thread(target=worker)
+            th.start()
+            th.join(timeout=10)
+
+
+@pytest.mark.parametrize("python", [False, True])
+def test_a_capture_holds_the_spans_on_its_own_clock(tmp_path, python):
+    logdir = str(tmp_path / "capture")
+    devobs.start_profile(1.0, logdir=logdir, python=python)
+    assert devobs.profile_status()["active"]
+    _traced_work()
+    last = _wait_capture()
+    assert last["stop_s"] < 30
+    lines = _capture_events(logdir)
+    by = {name: (start, end, line, stats) for line, evs in lines.items()
+          for name, start, end, stats in evs if name.startswith("ogt:")}
+    assert set(by) == {"ogt:http_query", "ogt:t_stage", "ogt:t_leaf",
+                       "ogt:t_pool_stage"}
+    root, stage, leaf = by["ogt:http_query"], by["ogt:t_stage"], \
+        by["ogt:t_leaf"]
+    # nested as the spans were, on one thread's line
+    assert root[2] == stage[2] == leaf[2]
+    assert root[0] <= stage[0] <= leaf[0] < leaf[1] <= stage[1] <= root[1]
+    pool = by["ogt:t_pool_stage"]
+    assert pool[2] != root[2]                      # its own thread's line
+    assert stage[0] <= pool[0] < pool[1] <= stage[1]
+    # the span's fields ride the annotation
+    assert stage[3] == {"rows": 3}
+    frames = [name for evs in lines.values() for name, _, _, _ in evs
+              if name.startswith("$")]
+    assert bool(frames) == python, frames[:5]
+    # no capture, no annotation object
+    with tracing.span("t_after") as sp:
+        assert sp._ann is None
+
+
+# -- the garbage-collection hook ---------------------------------------------
+
+
+def test_the_gc_hook_counts_a_full_collection():
+    tracing.watch_gc()
+    tracing.watch_gc()                              # idempotent
+    assert gc.callbacks.count(tracing._on_gc) == 1
+    before = STATS.snapshot()["runtime"]
+    gc.collect(2)
+    after = STATS.snapshot()["runtime"]
+    assert after["gc_gen2_collections"] == before["gc_gen2_collections"] + 1
+    assert after["gc_collections"] >= before["gc_collections"] + 1
+    assert after["gc_gen2_pause_ns"] > before["gc_gen2_pause_ns"]
+    assert after["gc_pause_ns"] - before["gc_pause_ns"] >= \
+        after["gc_gen2_pause_ns"] - before["gc_gen2_pause_ns"]
+    gc.collect(0)
+    last = STATS.snapshot()["runtime"]
+    assert last["gc_gen2_collections"] == after["gc_gen2_collections"]
+    assert last["gc_collections"] > after["gc_collections"]
+
+
+def test_a_full_collection_inside_a_traced_request_is_a_span():
+    tracing.watch_gc()
+    tracing.set_trace_enabled(True)
+    with tracing.request("query") as root:
+        with tracing.span("t_stage"):
+            gc.collect(2)
+    [stage] = root.trace.root.children
+    [pause] = [c for c in stage.children if c.name == "gc"]
+    assert 0 < pause.elapsed_ns <= stage.elapsed_ns
+
+
+# -- H2D bytes at a single-device launch --------------------------------------
+
+
+def _bucketed(rows: int = 600) -> ragged.BucketedBatch:
+    rng = np.random.default_rng(7)
+    b = ragged.BucketedBatch(np.float64)
+    seg = np.sort(rng.integers(0, 40, rows))
+    b.add(rng.normal(size=rows), np.arange(rows, dtype=np.int64), seg,
+          np.ones(rows, bool), np.arange(rows, dtype=np.int64))
+    return b
+
+
+def test_h2d_bytes_count_the_host_arrays_of_a_launch():
+    import jax
+
+    assert prt.get_mesh() is None
+    batch = _bucketed()
+    buckets = batch._freeze(40)
+    want = sum(a.nbytes for b in buckets for a in b.arrays)
+    h0 = _counters("device").get("h2d_bytes_total", 0)
+    q0 = _counters("query_stages")
+    out, _sel, counts = batch.run(aggmod.get("mean"), 40)
+    assert counts.sum() == 600
+    assert _counters("device")["h2d_bytes_total"] - h0 == want
+    d = _delta("query_stages", q0)
+    assert d["device_launch_count"] == d["device_fetch_count"] == \
+        d["host_combine_count"] == len(buckets)
+    # the same launch over arrays that already live on the device
+    again = _bucketed()
+    for b in again._freeze(40):
+        b.arrays = tuple(jax.device_put(a) for a in b.arrays)
+    h1 = _counters("device")["h2d_bytes_total"]
+    out2, _sel, _counts = again.run(aggmod.get("mean"), 40)
+    assert _counters("device")["h2d_bytes_total"] == h1
+    np.testing.assert_allclose(out2, out)
