@@ -108,9 +108,11 @@ class ColumnarBatch:
         return self.series_mst[self.series_ref]
 
     def to_points(self) -> list:
-        """Rebuild (measurement, tags, t_ns, fields) tuples — the slow-path
-        shape write observers (streams, subscriptions) consume. Only called
-        when observers are registered."""
+        """Rebuild (measurement, tags, t_ns, {field: (type, value)}) tuples,
+        one per row in row order — the shape write observers consume.
+        Slow (a dict and boxed values a row): the engine hands observers a
+        WrittenPoints view and calls this only when one of them reads it,
+        i.e. when the written database has a stream or a subscription."""
         from opengemini_tpu.index.inverted import parse_series_key
 
         tag_cache = [None] * len(self.series_keys)

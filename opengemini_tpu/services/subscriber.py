@@ -59,8 +59,10 @@ class SubscriberManager:
         subs = getattr(d, "subscriptions", None) if d else None
         if not subs:
             return
+        # the view is read here, on the writer's thread: the queue holds
+        # plain tuples, never the write's columnar arrays
         try:
-            self._q.put_nowait((db, rp, points))
+            self._q.put_nowait((db, rp, list(points)))
         except queue.Full:
             logger.warning("subscription queue full; dropping batch for %s", db)
 

@@ -9,6 +9,7 @@ meta plane lives in opengemini_tpu/meta and layers on top).
 
 from __future__ import annotations
 
+import collections.abc
 import contextlib
 import json
 import os
@@ -75,6 +76,48 @@ def _split_lp_segments(raw: bytes, n: int) -> list[bytes]:
     if start < len(raw):
         segs.append(raw[start:])
     return segs
+
+
+class WrittenPoints(collections.abc.Sequence):
+    """What a write observer is handed: the rows of one committed write
+    as a read-only sequence of (measurement, tags, t_ns, {field: (type,
+    value)}) tuples, in body order.
+
+    A natively parsed write stays columnar: `len()` answers from the
+    batches, and the first read (iteration, indexing) builds the tuples
+    once with ColumnarBatch.to_points and keeps them, so every later
+    reader of this write shares the one build.  A write that already
+    holds its points (the Python parser, write_rows) is wrapped as it is.
+    Read it on the notifying thread; an observer that defers its work
+    takes `list(points)` first."""
+
+    __slots__ = ("_batches", "_points", "_n")
+
+    def __init__(self, batches=(), points: list | None = None):
+        self._batches = batches
+        self._points = points
+        self._n = (len(points) if points is not None
+                   else sum(len(b) for b in batches))
+
+    def __len__(self) -> int:
+        return self._n
+
+    def _built(self) -> list:
+        points = self._points
+        if points is None:
+            points = []
+            for batch in self._batches:
+                points.extend(batch.to_points())
+            STATS.incr("write", "observer_rows_built", len(points))
+            self._points = points
+            self._batches = ()
+        return points
+
+    def __iter__(self):
+        return iter(self._built())
+
+    def __getitem__(self, i):
+        return self._built()[i]
 
 
 def _check_namespace_name(name: str, what: str) -> None:
@@ -1173,9 +1216,7 @@ class Engine:
                         touched)
                 self._commit_wal_tickets(tickets)
                 self._flush_over_threshold(touched)
-                if self._write_observers:
-                    with tracing.span("write_observers"):
-                        self._notify_write(db, rp, batch.to_points())
+                self._notify_write(db, rp, WrittenPoints((batch,)))
                 return n
             finally:
                 if rtok is not None:
@@ -1214,8 +1255,7 @@ class Engine:
                     tickets.append((shards[key], t))
             self._commit_wal_tickets(tickets)  # fsyncs coalesce off-lock
             self._flush_over_threshold(shards.values())
-            with tracing.span("write_observers"):
-                self._notify_write(db, rp, points)
+            self._notify_write(db, rp, WrittenPoints(points=points))
             return n
         finally:
             if rtok is not None:
@@ -1332,16 +1372,10 @@ class Engine:
                         touched.append(shard)
             self._commit_wal_tickets(tickets)  # fsyncs coalesce off-lock
             self._flush_over_threshold(touched)
-            if self._write_observers and total:
+            if total:
                 # observers see the body ONCE, post-commit, like
-                # write_lines.  The span covers building the points they
-                # are handed, asked for or not
-                with tracing.span("write_observers"):
-                    pts: list = []
-                    for batch in parsed:
-                        if len(batch):
-                            pts.extend(batch.to_points())
-                    self._notify_write(db, rp, pts)
+                # write_lines: one view over every segment, in order
+                self._notify_write(db, rp, WrittenPoints(parsed))
             return total
         finally:
             for t in rtoks:
@@ -1590,21 +1624,31 @@ class Engine:
                 self._save_meta()
 
     def add_write_observer(self, fn) -> None:
-        """fn(db, rp, points) called after every successful write — the
-        stream engine's ingest hook (reference: stream-aware PointsWriter,
-        coordinator/points_writer.go stream rows)."""
+        """fn(db, rp, points) is called once after every committed write,
+        on the writer's thread — the stream engine's ingest hook
+        (reference: stream-aware PointsWriter, coordinator/
+        points_writer.go stream rows).  `points` is a WrittenPoints view:
+        `len()` is free, and the point tuples are built only when an
+        observer reads them, once for all observers of that write.  An
+        observer with nothing to do for `db` returns before reading."""
         self._write_observers.append(fn)
 
-    def _notify_write(self, db: str, rp: str | None, points: list) -> None:
-        for fn in self._write_observers:
-            try:
-                fn(db, rp, points)
-            except Exception:  # noqa: BLE001 — observers never break ingest
-                import logging
+    def _notify_write(self, db: str, rp: str | None,
+                      points: WrittenPoints) -> None:
+        """The span covers the calls and whatever build they cause."""
+        if not self._write_observers:
+            return
+        with tracing.span("write_observers"):
+            STATS.incr("write", "observer_rows_offered", len(points))
+            for fn in self._write_observers:
+                try:
+                    fn(db, rp, points)
+                except Exception:  # noqa: BLE001 — observers never break ingest
+                    import logging
 
-                logging.getLogger("opengemini_tpu.engine").exception(
-                    "write observer failed"
-                )
+                    logging.getLogger("opengemini_tpu.engine").exception(
+                        "write observer failed"
+                    )
 
     def add_downsample_policy(self, db: str, rp: str, policy: "DownsamplePolicy") -> None:
         with self._lock:
@@ -1719,8 +1763,7 @@ class Engine:
                     tickets.append((shards[key], t))
             self._commit_wal_tickets(tickets)  # fsyncs coalesce off-lock
             self._flush_over_threshold(shards.values())
-            with tracing.span("write_observers"):
-                self._notify_write(db, rp, points)
+            self._notify_write(db, rp, WrittenPoints(points=points))
             return n
         finally:
             if rtok is not None:
