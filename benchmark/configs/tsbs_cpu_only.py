@@ -5,7 +5,7 @@ statements the traffic files ask.  Imports nothing of the program.
 Data generator copied from chip_smoke.py (`tsbs_hosts`, `tsbs_values`,
 `_hundredths_table`, PR 21): TSBS's clamped random walk at two decimals, so
 that the decimal text, the stored float64 and the oracle's k/100 are one
-number."""
+number.  `Walk` makes it in blocks (PR 26), value for value the original."""
 
 from __future__ import annotations
 
@@ -43,18 +43,30 @@ def hosts_keys(rng: np.random.Generator, n: int) -> list[bytes]:
     return out
 
 
+class Walk:
+    """TSBS's clamped random walk, a block of ticks at a time: uniform start,
+    unit-normal steps, clamped to [0, 10000] hundredths.  It carries its
+    state (`cur` and the generator), so any split into blocks gives the
+    values, and draws from the generator, that one block gives."""
+
+    def __init__(self, rng: np.random.Generator, hosts: int):
+        self.rng = rng
+        self.cur = rng.integers(0, 10001, size=(hosts, len(FIELDS)))
+
+    def take(self, ticks: int) -> np.ndarray:
+        """The next `ticks` ticks: (ticks, hosts, 10) int32."""
+        out = np.empty((ticks,) + self.cur.shape, np.int32)
+        for t in range(ticks):
+            out[t] = self.cur
+            self.cur = np.clip(self.cur + np.rint(
+                self.rng.standard_normal(self.cur.shape) * 100
+            ).astype(np.int64), 0, 10000)
+        return out
+
+
 def walk(rng: np.random.Generator, ticks: int, hosts: int) -> np.ndarray:
-    """(ticks, hosts, 10) int32 hundredths in [0, 10000]: uniform start,
-    unit-normal steps, clamped."""
-    nf = len(FIELDS)
-    out = np.empty((ticks, hosts, nf), np.int32)
-    cur = rng.integers(0, 10001, size=(hosts, nf))
-    for t in range(ticks):
-        out[t] = cur
-        cur = np.clip(cur + np.rint(
-            rng.standard_normal((hosts, nf)) * 100).astype(np.int64),
-            0, 10000)
-    return out
+    """(ticks, hosts, 10) int32 hundredths in [0, 10000]."""
+    return Walk(rng, hosts).take(ticks)
 
 
 def hundredths_table() -> np.ndarray:
@@ -68,17 +80,26 @@ def hundredths_table() -> np.ndarray:
 
 
 class Reference:
-    def __init__(self, cfg: dict, seed: int):
+    """`stored`: the deployment's `span_s` of data, held whole (what the read
+    cells load, and the oracle's values).  Not stored: TSBS's loader, a
+    stream with no end whose rows are made as they are sent and kept
+    nowhere; what checks a write is a count and the durability ledger."""
+
+    def __init__(self, cfg: dict, seed: int, stored: bool = True):
         self.cfg = cfg
+        self.seed = seed
         self.field_names = FIELDS
         self.db = cfg["db"]
         self.hosts = int(cfg["hosts"])
         self.start_s = int(cfg["start_s"])
         self.interval_s = int(cfg["interval_s"])
-        self.ticks = int(cfg["span_s"]) // self.interval_s
+        self.ticks = int(cfg["span_s"]) // self.interval_s if stored else 0
         rng = np.random.default_rng(seed)
-        self.hundredths = walk(rng, self.ticks, self.hosts)
-        self.values = self.hundredths / 100.0     # (ticks, hosts, fields)
+        if stored:
+            self.hundredths = walk(rng, self.ticks, self.hosts)
+            self.values = self.hundredths / 100.0     # (ticks, hosts, fields)
+        else:
+            self.hundredths = self.values = None
         self.keys = hosts_keys(rng, self.hosts)
         self.rows = self.ticks * self.hosts
         self.count_q = f"SELECT count({FIELDS[0]}) FROM {MEASUREMENT}"
@@ -108,21 +129,44 @@ class Reference:
                 yield (tpl.fill(self._table[h.reshape(-1, len(FIELDS))], ts),
                        tpl.lines)
 
-    def stream_requests(self, batch_rows: int, max_rows: int):
+    def stream_walk(self) -> Walk:
+        """The walk a stream with no end sends, from its first tick: a
+        generator of its own, so that every call begins the same stream."""
+        return Walk(np.random.default_rng([self.seed, 1]), self.hosts)
+
+    def _tick_blocks(self, ticks: int):
+        if self.hundredths is not None:
+            yield self.hundredths
+            return
+        w = self.stream_walk()
+        while True:
+            yield w.take(ticks)
+
+    def stream_requests(self, batch_rows: int):
         """TSBS's loader: rows in time order, host-major within a tick, in
-        batches of `batch_rows`."""
-        flat = self.hundredths.reshape(-1, len(FIELDS))
+        batches of `batch_rows`.  Of a stored reference, its rows and then
+        no more; else without end, the walk made a batch's ticks at a time."""
+        nf = len(FIELDS)
         templates: dict[tuple[int, int], LineTemplate] = {}
-        for lo in range(0, min(self.rows, max_rows), batch_rows):
-            hi = min(lo + batch_rows, self.rows)
-            key = (lo % self.hosts, hi - lo)
+
+        def batch(lo: int, rows: np.ndarray):
+            key = (lo % self.hosts, len(rows))
             if key not in templates:
                 templates[key] = LineTemplate(
-                    [self.keys[r % self.hosts] for r in range(lo, hi)],
-                    FIELDS, WIDTH)
-            rows = np.arange(lo, hi)
-            yield (templates[key].fill(self._table[flat[lo:hi]],
-                                       self._ts(rows // self.hosts)), hi - lo)
+                    [self.keys[r % self.hosts]
+                     for r in range(lo, lo + len(rows))], FIELDS, WIDTH)
+            at = np.arange(lo, lo + len(rows)) // self.hosts
+            return templates[key].fill(self._table[rows], self._ts(at)), \
+                len(rows)
+
+        lo, have = 0, np.empty((0, nf), np.int32)
+        for block in self._tick_blocks(-(-batch_rows // self.hosts)):
+            have = np.concatenate((have, block.reshape(-1, nf)))
+            while len(have) >= batch_rows:
+                yield batch(lo, have[:batch_rows])
+                lo, have = lo + batch_rows, have[batch_rows:]
+        if len(have):
+            yield batch(lo, have)
 
     # -- the oracle ---------------------------------------------------------
 

@@ -51,11 +51,15 @@ def _free_port() -> int:
 
 
 class Client:
-    """One keep-alive connection; a thread of the generator owns one."""
+    """One keep-alive connection; a thread of the generator owns one.
+    `meanwhile`, where set, is called once a request has been sent and
+    before its answer is read: the caller's work for the time the server
+    is busy (an `lp_stream` makes its next batch there)."""
 
     def __init__(self, port: int, timeout: float = 600.0):
         self.port, self.timeout = port, timeout
         self.conn: http.client.HTTPConnection | None = None
+        self.meanwhile = None
 
     def request(self, method: str, path: str,
                 body: bytes | None = None) -> tuple[int, bytes]:
@@ -67,6 +71,8 @@ class Client:
                     "127.0.0.1", self.port, timeout=self.timeout)
             try:
                 self.conn.request(method, path, body=body)
+                if self.meanwhile is not None:
+                    self.meanwhile()
                 r = self.conn.getresponse()
                 return r.status, r.read()
             except (http.client.HTTPException, OSError) as e:

@@ -22,8 +22,8 @@ T_PROCESS = time.monotonic()      # set-up counts from here
 
 import argparse                   # noqa: E402
 import json                       # noqa: E402
-import math                       # noqa: E402
 import os                         # noqa: E402
+import resource                   # noqa: E402
 import shutil                     # noqa: E402
 import subprocess                 # noqa: E402
 import sys                        # noqa: E402
@@ -54,6 +54,13 @@ def load_json(*parts: str) -> dict:
 
 def with_dry(doc: dict, dry: bool) -> dict:
     return {**doc, **doc.get("dry_run", {})} if dry else doc
+
+
+def onto_one_core() -> None:
+    """This thread, and those it starts from here on, onto the machine's
+    last core: the generator leaves the others to the server."""
+    if len(os.sched_getaffinity(0)) > 2:
+        os.sched_setaffinity(0, {max(os.sched_getaffinity(0))})
 
 
 class Checks:
@@ -97,7 +104,9 @@ class Cell:
                     if cell["name"] in m.get("workloads", [cell["name"]])]
         self.layer = [m for m in bench["per_layer"]
                       if cell["name"] in m.get("workloads", [cell["name"]])]
-        # a metric file that does not fit fails before anything starts
+        # a traffic or metric file that does not fit fails before anything
+        # starts
+        traffic.check(self.traffic, self.cfg)
         self.readers = {m["name"]: metrics.load(m["name"], m)
                         for m in self.layer}
         for m in self.e2e:
@@ -115,14 +124,10 @@ class Cell:
     def reference(self):
         mod = load_module(os.path.join(HERE, "configs", self.cfg["reference"]),
                           "reference")
-        cfg = dict(self.cfg)
         if self.traffic["kind"] == "lp_stream":
-            # the stream runs on past the stored hour for as long as the
-            # window may need rows
-            rows = self.traffic["max_rows_per_s"] * (self.args.seconds + 30)
-            cfg["span_s"] = (math.ceil(rows / cfg["hosts"]) + 1) \
-                * cfg["interval_s"]
-        return mod.Reference(cfg, self.args.seed)
+            # TSBS's loader: rows made as they are sent, none stored
+            return mod.Reference(self.cfg, self.args.seed, stored=False)
+        return mod.Reference(self.cfg, self.args.seed)
 
     def start(self) -> dict:
         shutil.rmtree(WORK, ignore_errors=True)
@@ -183,8 +188,12 @@ class Cell:
             now = [counter(v, p) for p in watch]
             still = still + 1 if now == last and now[1] == 0 else 0
             last = now
+        files = [os.path.join(d, f) for d, _, fs in os.walk(WORK) for f in fs
+                 if f.endswith(".tsf")]
         log(f"flushed and quiet after {time.monotonic() - t0:.1f}s: "
-            + ", ".join(f"{p}={int(x)}" for p, x in zip(watch, last)))
+            + ", ".join(f"{p}={int(x)}" for p, x in zip(watch, last))
+            + f"; {len(files)} data file(s), "
+            f"{sum(map(os.path.getsize, files)) >> 20} MB")
 
     def warm_up(self, plan, ref) -> list:
         """Touch every column the window can touch, then repeat the cell's
@@ -202,7 +211,7 @@ class Cell:
 
         if self.traffic["kind"] == "lp_stream":
             srv.query("CREATE DATABASE warm")
-            for req in plan.requests[:w["batches"]]:
+            for req in plan.warm_touch:
                 status, _ = srv.call("POST", "/write", req.body, db="warm")
                 if status != 204:
                     raise BenchFailure(f"warm-up /write -> HTTP {status}")
@@ -340,8 +349,8 @@ class Cell:
         more = float(self.traffic["trace"]["send_s"]) if a.trace else 0.0
         plan = traffic.build(self.traffic, ref, a.seed, a.seconds + more)
         warm = self.warm_up(plan, ref)
-        if len(os.sched_getaffinity(0)) > 2:
-            os.sched_setaffinity(0, {max(os.sched_getaffinity(0))})
+        traffic.join_walk(plan, len(warm) - len(plan.warm_touch))
+        onto_one_core()
         return device, ref, plan, warm
 
     def run(self) -> dict:
@@ -353,8 +362,10 @@ class Cell:
         vars0, dev0 = self.snapshot()
         setup_s = time.monotonic() - T_PROCESS
         log(f"set-up took {setup_s:.1f}s; the window of {a.seconds}s begins "
-            f"({len(plan.requests)} requests made from the seed, loop "
-            f"{plan.loop})")
+            + ("(a stream made from the seed as it is sent, "
+               if plan.stream else
+               f"({len(plan.requests)} requests made from the seed, ")
+            + f"loop {plan.loop})")
         traffic.run(plan, srv.port, a.seconds)
         vars1, dev1 = self.snapshot(plan)
         window_s = plan.t_end - plan.t_start
@@ -363,10 +374,20 @@ class Cell:
             f"{vars1['client']['completed']} answered in shape; latency ms "
             "p10/p50/p90/max " + "/".join(
                 f"{metrics.percentile(took, q):.0f}" for q in (10, 50, 90, 100)))
+        if writes:
+            served = 1e-9 * (counter(vars1, "http/write_ns")
+                             - counter(vars0, "http/write_ns"))
+            log(f"the client's share of a batch: ({window_s:.3f}s - "
+                f"{served:.3f}s inside the server's http_write) / "
+                f"{len(plan.results)} = "
+                f"{1e3 * (window_s - served) / max(1, len(plan.results)):.2f}"
+                " ms; peak resident memory of this process "
+                f"{resource.getrusage(resource.RUSAGE_SELF).ru_maxrss >> 10}"
+                " MB")
         if plan.exhausted:
             log("the request list ran out before the window did: the rate "
                 "is over the time the requests took (raise the traffic "
-                "file's cap)")
+                "file's max_qps)")
         if a.trace:
             self.capture(plan, ref)
         elif writes:
@@ -466,7 +487,7 @@ class Cell:
                 / float(work["launches_per_request"])
         reqs = [self.phase.requests[r.index] for r in ok] \
             or self.phase.requests[:1]
-        per = len(reqs)
+        per = max(1, len(reqs))
         return {"requests": n, "needs": work.get("needs"),
                 "points": n * sum(q.units for q in reqs) / per,
                 "groups": n * sum(q.stmt.get("groups", 0) for q in reqs) / per}

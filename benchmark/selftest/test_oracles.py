@@ -73,7 +73,7 @@ def test_fast_load_carries_the_loader_rows():
     mod, cfg = reference("tsbs-devops-cpu-4000", span_s=300)
     ref = mod.Reference(cfg, 11)
     fast = list(ref.load_requests())
-    slow = list(ref.stream_requests(60, 10**9))
+    slow = list(ref.stream_requests(60))
     assert sum(n for _, n in fast) == sum(n for _, n in slow) == ref.rows
     assert _rows(fast) == _rows(slow)
     # series-major in the fast shape: a host's lines are consecutive
