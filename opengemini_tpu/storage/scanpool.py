@@ -40,6 +40,7 @@ from __future__ import annotations
 import contextlib
 import os
 import threading
+import time
 from opengemini_tpu.utils import lockdep
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
@@ -47,6 +48,7 @@ from concurrent.futures import ThreadPoolExecutor
 from opengemini_tpu.utils import tracing
 from opengemini_tpu.utils.governor import InflightGauge
 from opengemini_tpu.utils.querytracker import GLOBAL as _TRACKER
+from opengemini_tpu.utils.stats import GLOBAL as _STATS
 
 
 def _auto_workers() -> int:
@@ -110,6 +112,17 @@ def pool() -> ThreadPoolExecutor | None:
     return _pool
 
 
+def _timed(job):
+    """Run one decode job and add its wall time to `scanpool/busy_ns`:
+    summed over the workers (the caller, in the serial fallback), over a
+    dispatcher's `decode` time it reads as the decode's parallelism."""
+    t0 = time.perf_counter_ns()
+    try:
+        return job()
+    finally:
+        _STATS.incr("scanpool", "busy_ns", time.perf_counter_ns() - t0)
+
+
 def map_ordered(jobs, est_bytes=None, inflight_bytes: int | None = None):
     """Run `jobs` (argless callables) on the pool; yield results in
     SUBMISSION order regardless of completion order.  `est_bytes[i]` is
@@ -125,7 +138,7 @@ def map_ordered(jobs, est_bytes=None, inflight_bytes: int | None = None):
     if p is None or len(jobs) < MIN_POOL_JOBS:
         for job in jobs:
             _TRACKER.check()
-            yield job()
+            yield _timed(job)
         return
     budget = inflight_bytes if inflight_bytes is not None else INFLIGHT_BYTES
     if est_bytes is None:
@@ -148,7 +161,7 @@ def map_ordered(jobs, est_bytes=None, inflight_bytes: int | None = None):
         _TRACKER.bind(qid)
         _TRACKER.raise_if_killed(qid)
         with tracing.adopt(handed):
-            return job()
+            return _timed(job)
 
     pending: deque = deque()
     inflight = 0
@@ -167,7 +180,10 @@ def map_ordered(jobs, est_bytes=None, inflight_bytes: int | None = None):
                 i += 1
             fut, nb = pending.popleft()
             try:
-                out = fut.result()
+                # the dispatcher's own stage: what is left of its `decode`
+                # once the workers' jobs run beside it
+                with tracing.span("pool_wait"):
+                    out = fut.result()
             finally:
                 inflight -= nb
                 _note_inflight(-nb)

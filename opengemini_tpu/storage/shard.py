@@ -1634,6 +1634,7 @@ class Shard:
         ests = []
         slots: list = []
         miss_at = []
+        rows_decoded = 0
         for r in files:
             for c in r.chunks(measurement, None, tmin, tmax):
                 if c.packed:
@@ -1659,6 +1660,7 @@ class Shard:
                 miss_at.append(len(slots))
                 slots.append(None)
                 ests.append(scanpool.est_chunk_bytes(c, n_fields))
+                rows_decoded += c.rows
         if jobs:
             # the column cache missed: decode, one span per bulk read
             with tracing.span("decode", chunks=len(jobs)):
@@ -1678,7 +1680,15 @@ class Shard:
                          if k in fields},
                     )
                 parts.append((sid_arr, mem_rec))
-        return _merge_bulk_parts(parts, lo_t, hi_t)
+        if not jobs:    # every chunk came from the cache: nothing is added
+            return _merge_bulk_parts(parts, lo_t, hi_t)
+        # a read that decoded says what became of it: a chunk decodes
+        # whole, the merge keeps the rows inside [tmin, tmax)
+        with tracing.span("scan_merge", parts=len(parts)):
+            sid_arr, rec = _merge_bulk_parts(parts, lo_t, hi_t)
+        _STATS.add("scan", (("rows_decoded", rows_decoded),
+                            ("rows_kept", len(rec))))
+        return sid_arr, rec
 
     def content_digest(self) -> dict:
         """Per-measurement logical content digest: {mst: [rows, hash64]}.

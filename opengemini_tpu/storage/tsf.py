@@ -26,6 +26,7 @@ multi-series blocks (colstore layout, see add_packed_chunk).
 
 from __future__ import annotations
 
+import functools
 import itertools
 import json
 import os
@@ -40,6 +41,7 @@ import numpy as np
 
 from opengemini_tpu.record import Column, EncodedColumn, FieldType, Record
 from opengemini_tpu.storage import colcache, diskfault, encodepool, encoding
+from opengemini_tpu.utils import tracing
 from opengemini_tpu.utils.bloom import BloomFilter
 from opengemini_tpu.utils.stats import GLOBAL as _STATS
 
@@ -48,6 +50,7 @@ MAGIC2 = b"OGTSF02\n"  # revision 2: per-block crc32 seals (written)
 END_MAGIC = b"OGTSFEND"
 _TRAILER = struct.Struct("<QII")
 _BLOCK_CRC = struct.Struct("<I")
+_SIDS = "\x00sids"     # a packed chunk's sid column, among its field names
 
 
 HIST_BINS = 32
@@ -497,9 +500,6 @@ class TSFReader:
                 self.path, f"block crc mismatch at offset {loc[0]}")
         return payload
 
-    def read_times(self, chunk: ChunkMeta) -> np.ndarray:
-        return encoding.decode_ints(self._read(chunk.time_loc))
-
     # decoded-column caching (reference: lib/readcache — hot chunks
     # decode once, not per query). Safe because TSF files are immutable
     # and no read path mutates decoded arrays in place. Two regimes:
@@ -531,29 +531,29 @@ class TSFReader:
         # is a cheap binary search over the cached arrays)
         return (self.owner_ns, self.gen, id(chunk), chunk.sid, name)
 
-    def _cached_col(self, chunk: ChunkMeta, name, decode):
-        """Decode-once lookup for one column of one chunk: `name` is the
-        field name, None for the time column, "\\x00sids" for a packed
-        chunk's sid column."""
+    def _cache_get(self, chunk: ChunkMeta, name):
+        """Counted decode-once lookup of one column of one chunk, in
+        whichever cache serves (see above): `name` is the field name,
+        None for the time column, _SIDS for a packed chunk's sid column."""
         cc = colcache.GLOBAL
         if cc.enabled():
-            key = self._colcache_key(chunk, name)
-            got = cc.get(key)
-            if got is not None:
-                return got
-            val = decode()
-            cc.put(key, val)
-            return val
+            return cc.get(self._colcache_key(chunk, name))
         key = (id(chunk), name)
         with self._cache_lock:
             got = self._col_cache.get(key)
             if got is not None:
                 self._col_cache.move_to_end(key)
-                return got
-        val = decode()
+            return got
+
+    def _cache_put(self, chunk: ChunkMeta, name, val) -> None:
+        cc = colcache.GLOBAL
+        if cc.enabled():
+            cc.put(self._colcache_key(chunk, name), val)
+            return
         nb = self._val_nbytes(val)
         if nb > self._CACHE_BYTES:
-            return val  # a single oversized column never enters the cache
+            return  # a single oversized column never enters the cache
+        key = (id(chunk), name)
         with self._cache_lock:
             if key not in self._col_cache:
                 self._col_cache[key] = val
@@ -562,7 +562,68 @@ class TSFReader:
             while self._cache_bytes > self._CACHE_BYTES and self._col_cache:
                 _k, old = self._col_cache.popitem(last=False)
                 self._cache_bytes -= self._val_nbytes(old)
-        return val
+
+    def _load_columns(self, chunk: ChunkMeta, wanted, cache: bool) -> dict:
+        """{name: decoded column} for `wanted`, a list of (name, block
+        locs, codec) in output order; a loc of None reads as b"".  What
+        the cache holds comes from it.  The rest is the miss path, in
+        three stages that each take all the chunk's missing columns, so
+        that a chunk costs three spans and two counter updates however
+        many columns it has: `block_read` (pread + CRC), `codec`,
+        `colcache_fill` (the puts and the evictions they cause).  On a
+        scan-pool thread these spans have no parent frame: they sum, over
+        the workers, to CPU time beside the dispatcher's `decode`."""
+        out = {name: (self._cache_get(chunk, name) if cache else None)
+               for name, _locs, _codec in wanted}
+        todo = [w for w in wanted if out[w[0]] is None]
+        if not todo:
+            return out
+        with tracing.span("block_read"):
+            bufs = [[self._read(loc) if loc else b"" for loc in locs]
+                    for _name, locs, _codec in todo]
+        read = [loc[1] for _name, locs, _codec in todo for loc in locs if loc]
+        with tracing.span("codec"):
+            for (name, _locs, codec), blocks in zip(todo, bufs):
+                out[name] = codec(*blocks)
+        if cache:
+            with tracing.span("colcache_fill"):
+                for name, _locs, _codec in todo:
+                    self._cache_put(chunk, name, out[name])
+        _STATS.add("tsf", (("read_bytes", sum(read)),
+                           ("blocks_read", len(read))))
+        _STATS.incr("scan", "decoded_bytes",
+                    sum(self._val_nbytes(out[w[0]]) for w in todo))
+        return out
+
+    @staticmethod
+    def _decode_field(ftype: FieldType, encoded_ok: bool,
+                      vbuf: bytes, mbuf: bytes):
+        if encoded_ok and ftype in (FieldType.FLOAT, FieldType.INT):
+            db = encoding.device_block(vbuf)
+            if db is not None:
+                return EncodedColumn(
+                    ftype, [vbuf], encoding.decode_mask(mbuf, db.n),
+                    encoding.decode_value_blocks)
+        return encoding.decode_column(ftype, vbuf, mbuf)
+
+    def _chunk_columns(
+        self, measurement: str, chunk: ChunkMeta, fields: list[str] | None,
+        cache: bool, encoded_ok: bool, with_sids: bool,
+    ) -> tuple[np.ndarray | None, Record]:
+        """(sid column or None, record) of one chunk: one `_load_columns`
+        for the times, the sids where asked and every field."""
+        schema = self.schema(measurement)
+        wanted = [(None, (chunk.time_loc,), encoding.decode_ints)]
+        if with_sids:
+            wanted.append((_SIDS, (chunk.sid_loc,), encoding.decode_ints))
+        for name in (fields if fields is not None else list(chunk.cols)):
+            loc = chunk.cols.get(name)
+            if loc is not None:
+                wanted.append((name, (loc["v"], loc["m"]), functools.partial(
+                    self._decode_field, schema[name], encoded_ok)))
+        cols = self._load_columns(chunk, wanted, cache)
+        times = cols.pop(None)
+        return cols.pop(_SIDS, None), Record(times, cols)
 
     def read_chunk(
         self, measurement: str, chunk: ChunkMeta,
@@ -576,37 +637,8 @@ class TSFReader:
         but the payload decode is deferred to the accelerator (or to the
         column's lazy host fallback).  Times and masks always decode on
         the host (they drive window/run planning)."""
-        schema = self.schema(measurement)
-
-        def times_decode():
-            return self.read_times(chunk)
-
-        times = (self._cached_col(chunk, None, times_decode)
-                 if cache else times_decode())
-        cols = {}
-        names = fields if fields is not None else list(chunk.cols)
-        for name in names:
-            loc = chunk.cols.get(name)
-            if loc is None:
-                continue
-
-            def decode(loc=loc, name=name):
-                vbuf = self._read(loc["v"])
-                mbuf = self._read(loc["m"]) if loc["m"] else b""
-                ftype = schema[name]
-                if encoded_ok and ftype in (FieldType.FLOAT,
-                                            FieldType.INT):
-                    db = encoding.device_block(vbuf)
-                    if db is not None:
-                        return EncodedColumn(
-                            ftype, [vbuf],
-                            encoding.decode_mask(mbuf, db.n),
-                            encoding.decode_value_blocks)
-                return encoding.decode_column(ftype, vbuf, mbuf)
-
-            cols[name] = (self._cached_col(chunk, name, decode)
-                          if cache else decode())
-        return Record(times, cols)
+        return self._chunk_columns(measurement, chunk, fields, cache,
+                                   encoded_ok, with_sids=False)[1]
 
     def _chunk_from_cache(self, chunk: ChunkMeta,
                           fields: list[str] | None) -> Record | None:
@@ -646,11 +678,9 @@ class TSFReader:
 
     def read_packed_sids(self, chunk: ChunkMeta, cache: bool = True) -> np.ndarray:
         """The sid column of a packed chunk (non-decreasing int64)."""
-        def decode():
-            return encoding.decode_ints(self._read(chunk.sid_loc))
-
-        return (self._cached_col(chunk, "\x00sids", decode)
-                if cache else decode())
+        return self._load_columns(
+            chunk, [(_SIDS, (chunk.sid_loc,), encoding.decode_ints)],
+            cache)[_SIDS]
 
     @staticmethod
     def _sid_row_range(chunk: ChunkMeta, sids: np.ndarray,
@@ -722,7 +752,7 @@ class TSFReader:
         cc = colcache.GLOBAL
         if not cc.enabled():
             return None
-        sids = cc.peek(self._colcache_key(chunk, "\x00sids"))
+        sids = cc.peek(self._colcache_key(chunk, _SIDS))
         if sids is None:
             return None
         lo, hi = self._sid_row_range(chunk, sids, sid)
@@ -748,9 +778,8 @@ class TSFReader:
         numeric value decode exactly like read_chunk — a sid filter that
         actually drops rows slices the columns, which host-decodes the
         lazy ones (bit-identical fallback)."""
-        sids = self.read_packed_sids(chunk, cache)
-        rec = self.read_chunk(measurement, chunk, fields, cache,
-                              encoded_ok=encoded_ok)
+        sids, rec = self._chunk_columns(measurement, chunk, fields, cache,
+                                        encoded_ok, with_sids=True)
         return self._packed_bulk_filter(sids, rec, sid_filter)
 
     @staticmethod
@@ -803,7 +832,7 @@ class TSFReader:
         cc = colcache.GLOBAL
         if not cc.enabled():
             return None
-        sids = cc.peek(self._colcache_key(chunk, "\x00sids"))
+        sids = cc.peek(self._colcache_key(chunk, _SIDS))
         if sids is None:
             return None
         rec = self._chunk_from_cache(chunk, fields)

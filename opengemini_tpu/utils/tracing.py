@@ -429,8 +429,10 @@ def _annotate(name: str, fields: dict):
 
 class span:
     """`with span("scan", rows=n) as sp:` — one stage of a request, at
-    stage granularity only: never inside a per-series, per-chunk or
-    per-row loop.  `sp.add_field` adds to the tree's span (a no-op
+    stage granularity only: never inside a per-series or per-row loop,
+    and per chunk only on the column cache's miss path, where a chunk
+    is read, decoded and cached in three stages (storage/tsf.py
+    `_load_columns`).  `sp.add_field` adds to the tree's span (a no-op
     without a tree); fields given here also ride the annotation."""
 
     __slots__ = ("name", "group", "_fields", "_t0", "_child_ns", "_prev",

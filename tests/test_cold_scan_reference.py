@@ -1,0 +1,287 @@
+"""The deployment whose data does not fit the column cache
+(`benchmark/configs/tsbs-devops-cpu-4000-6h.json`, cell
+`tsbs_fleet_groupby_cold`), small, on the CPU, through the served /query
+path: 512 hosts, six "hours" of 600 s, the cell's own statements from the
+benchmark's generator, data and expected answers from the plain reference
+`benchmark/configs/tsbs_cpu_only.py` on a seed.
+
+What the cell is defined around is held here: in the series-major layout
+(what compaction leaves) every chunk spans the whole range, so a statement
+over one hour decodes all six and a cache smaller than one statement's
+decode never hits; the same rows loaded in time order decode under twice
+what they keep; and the answer is the reference's, bit for bit the same,
+whatever the cache does.  The miss path's spans and counters (PR 27) are
+read as the benchmark's metric files read them."""
+
+import json
+import os
+import sys
+import urllib.parse
+import urllib.request
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+from harness import load_module, traffic  # noqa: E402
+from harness.metrics import counter  # noqa: E402
+from harness.oracle import TOL  # noqa: E402
+
+from opengemini_tpu.server.http import HttpService  # noqa: E402
+from opengemini_tpu.storage import colcache, scanpool  # noqa: E402
+from opengemini_tpu.storage.engine import Engine  # noqa: E402
+from opengemini_tpu.utils.stats import GLOBAL as STATS  # noqa: E402
+
+HOSTS, HOUR, HOURS, TICKS_AN_HOUR = 512, 600, 6, 60
+FILES = HOSTS // 64             # of the series-major load, a chunk each
+SEED = 27
+# One statement decodes 512 hosts x 360 rows x (5 fields x 9 B + times +
+# sids) = 11 MB in 8 chunks of 7 columns.  A 1 MB cache holds five such
+# columns, less than one chunk: with two workers going through the chunks
+# in order, what a statement leaves behind (of its last chunks) is long
+# evicted when the next one comes to look for it, as in the cell, where
+# 256 MB hold the last 35 of 70 chunks.  64 MB hold everything.
+REGIMES = {"off": 0, "evicting": 1, "roomy": 64}
+MISS_SPANS = ("decode", "pool_wait", "block_read", "codec", "colcache_fill",
+              "scan_merge")
+
+
+def _json(*parts):
+    with open(os.path.join(BENCH, *parts), encoding="utf-8") as f:
+        return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def ref():
+    cfg = _json("configs", "tsbs-devops-cpu-4000-6h.json")
+    cfg.update(hosts=HOSTS, span_s=HOUR * HOURS,
+               load_block={"series": 64, "ticks": HOURS * TICKS_AN_HOUR})
+    mod = load_module(os.path.join(BENCH, "configs", cfg["reference"]),
+                      "reference")
+    return mod.Reference(cfg, SEED)
+
+
+@pytest.fixture(scope="module")
+def statements(ref):
+    """Two rounds of the six hours, as the cell sends them: the touches,
+    then seed-drawn fields, the n-th statement at hour n mod 6."""
+    mix = _json("traffic", "fleet_groupby_cold.json")
+    mix.update(range_s=HOUR, every_s=50)        # 12 windows a statement
+    plan = traffic.build(mix, ref, SEED, 1.0)
+    assert plan.cycle == HOURS
+    sent = (plan.warm_touch + plan.warm_repeat)[:2 * HOURS]
+    assert [(q.stmt["t0"] - ref.start_s) // HOUR for q in sent] \
+        == list(range(HOURS)) * 2
+    return sent
+
+
+class Served:
+    """One server over one store; `layout` is the order of the load."""
+
+    def __init__(self, path, ref, layout: str):
+        self.engine = Engine(str(path))
+        self.engine.create_database(ref.db)
+        self.svc = HttpService(self.engine, "127.0.0.1", 0)
+        self.svc.start()
+        bodies = (ref.load_requests() if layout == "series_major" else
+                  ref.stream_requests(HOSTS * TICKS_AN_HOUR))
+        for body, _rows in bodies:     # a file a request, as a flush leaves
+            assert self.http("POST", "/write", body, db=ref.db)[0] == 204
+            self.http("POST", "/debug/ctrl", mod="flush")
+
+    def http(self, method, path, body=None, **params):
+        url = f"http://127.0.0.1:{self.svc.port}{path}"
+        if params:
+            url += "?" + urllib.parse.urlencode(params)
+        req = urllib.request.Request(url, data=body, method=method)
+        with urllib.request.urlopen(req, timeout=120) as r:
+            return r.status, r.read()
+
+    def ask(self, req) -> bytes:
+        status, body = self.http(req.method, req.path, req.body)
+        assert status == 200
+        return body
+
+    def vars(self) -> dict:
+        return json.loads(self.http("GET", "/debug/vars")[1])
+
+    def close(self):
+        self.svc.stop()
+        self.engine.close()
+
+
+@pytest.fixture(scope="module")
+def stores(tmp_path_factory, ref):
+    """The same rows twice: series-major (8 files of 64 hosts, every packed
+    chunk all six hours long) and in time order (6 files, an hour each)."""
+    with pytest.MonkeyPatch.context() as mp:
+        # a scan pool of two workers, whatever the machine's cores and
+        # whatever pool an earlier test of this process left behind
+        mp.setattr(scanpool, "WORKERS", 2)
+        mp.setattr(scanpool, "_pool", None)
+        before = colcache.GLOBAL.config()
+        made = {layout: Served(tmp_path_factory.mktemp(layout), ref, layout)
+                for layout in ("series_major", "time_ordered")}
+        yield made
+        for s in made.values():
+            s.close()
+        colcache.GLOBAL.configure(**before)
+        if scanpool._pool is not None:
+            scanpool._pool.shutdown(wait=True)
+
+
+def run(srv: Served, statements, budget_mb: int):
+    """Every statement once, from an empty cache of `budget_mb`: the
+    bodies, and /debug/vars before the first and after each.  A server of
+    the cell is never asked a statement twice; one here is, a regime
+    later, and must scan again: its result cache is emptied too."""
+    colcache.GLOBAL.configure(budget_mb=budget_mb)
+    colcache.GLOBAL.clear()
+    srv.svc.executor._inc_cache.clear()
+    seen = [srv.vars()]
+    bodies = []
+    for req in statements:
+        bodies.append(srv.ask(req))
+        seen.append(srv.vars())
+    return bodies, seen
+
+
+@pytest.fixture(scope="module")
+def runs(stores, statements):
+    out = {name: run(stores["series_major"], statements, mb)
+           for name, mb in REGIMES.items()}
+    out["time_ordered"] = run(stores["time_ordered"], statements,
+                              REGIMES["evicting"])
+    return out
+
+
+def delta(seen, path: str, lo: int = 0, hi: int = -1) -> float:
+    return counter(seen[hi], path) - counter(seen[lo], path)
+
+
+# -- the answers --------------------------------------------------------------
+
+
+@pytest.mark.parametrize("which", [*REGIMES, "time_ordered"])
+def test_every_answer_is_the_references(ref, statements, runs, which):
+    """Window times and group sets exact (`parse` raises otherwise), means
+    within the configuration's limit, over both rounds of the hours."""
+    bodies, _ = runs[which]
+    for req, body in zip(statements, bodies):
+        got = ref.parse(req.stmt, json.loads(body))
+        assert got.shape == (12, HOSTS, 5)
+        (value, limit), = ref.numbers(req.stmt, got).values()
+        assert limit == TOL["mean"] == 2e-5
+        assert value <= limit, req.stmt["q"]
+
+
+def test_answers_do_not_depend_on_the_cache_or_the_layout(runs):
+    want = runs["off"][0]
+    for which in ("evicting", "roomy", "time_ordered"):
+        assert runs[which][0] == want, which        # bytes of the response
+
+
+# -- the regime the cell is defined around ------------------------------------
+
+
+def test_an_evicting_cache_never_hits_and_decodes_six_hours_to_keep_one(
+        runs, statements):
+    _, seen = runs["evicting"]
+    assert delta(seen, "colcache/hits") == 0
+    assert delta(seen, "colcache/misses") > 0
+    assert delta(seen, "colcache/evictions") > 0
+    for n in range(len(statements)):            # of every statement alone
+        kept = delta(seen, "scan/rows_kept", n, n + 1)
+        assert kept == HOSTS * TICKS_AN_HOUR
+        assert delta(seen, "scan/rows_decoded", n, n + 1) == HOURS * kept
+        assert delta(seen, "colcache/hits", n, n + 1) == 0
+
+
+def test_the_same_rows_in_time_order_decode_under_twice_what_they_keep(runs):
+    _, seen = runs["time_ordered"]
+    kept = delta(seen, "scan/rows_kept")
+    assert kept == 2 * HOURS * HOSTS * TICKS_AN_HOUR
+    assert 1.0 <= delta(seen, "scan/rows_decoded") / kept < 2.0
+
+
+def test_a_roomy_cache_hits_once_it_is_full_and_the_hit_path_adds_nothing(
+        runs):
+    """Every chunk spans all six hours, so the two touches (fields 0-4,
+    then 5-9) decode the whole store and every later statement hits; a
+    read that only hits opens no span of the miss path and moves none of
+    its counters."""
+    _, seen = runs["roomy"]
+    assert delta(seen, "query_stages/decode_count", 0, 2) == 2
+    assert delta(seen, "colcache/hits", 2) > 0
+    assert delta(seen, "colcache/misses", 2) == 0
+    assert delta(seen, "colcache/evictions") == 0
+    for path in [f"query_stages/{s}_count" for s in MISS_SPANS] + [
+            "scan/rows_decoded", "scan/rows_kept", "scan/decoded_bytes",
+            "tsf/read_bytes", "tsf/blocks_read", "scanpool/busy_ns"]:
+        assert delta(seen, path, 2) == 0, path
+
+
+# -- the miss path's spans and counters ---------------------------------------
+
+
+def test_the_miss_path_reports_its_stages_and_its_bytes(
+        stores, runs, statements):
+    _, seen = runs["evicting"]
+    n = len(statements)
+    chunks = [(r, c) for sh in stores["series_major"].engine.all_shards()
+              for r in sh._files for c in r.chunks("cpu")]
+    assert len(chunks) == FILES and all(c.packed for _r, c in chunks)
+    for name in ("decode", "scan_merge"):
+        assert delta(seen, f"query_stages/{name}_count") == n
+        assert delta(seen, f"query_stages/{name}_ns") > 0
+    for name in ("pool_wait", "block_read", "codec", "colcache_fill"):
+        assert delta(seen, f"query_stages/{name}_count") == n * len(chunks)
+        assert delta(seen, f"query_stages/{name}_ns") > 0
+    assert delta(seen, "scanpool/busy_ns") > 0
+    # of one statement: the blocks of times, sids and its five fields in
+    # every chunk, seals included; and what the codecs made of them
+    for k, req in enumerate(statements):
+        locs = [loc for _r, c in chunks
+                for loc in [c.time_loc, c.sid_loc]
+                + [c.cols[f][part] for f in req.stmt["fields"]
+                   for part in "vm"] if loc]
+        assert delta(seen, "tsf/blocks_read", k, k + 1) == len(locs)
+        assert delta(seen, "tsf/read_bytes", k, k + 1) \
+            == sum(loc[1] for loc in locs)
+        rows = sum(c.rows for _r, c in chunks)
+        assert delta(seen, "scan/decoded_bytes", k, k + 1) \
+            == rows * (5 * 9 + 8 + 8)
+
+
+@pytest.mark.parametrize("pool", [True, False])
+def test_decode_self_time_is_never_negative(stores, statements, pool,
+                                            monkeypatch):
+    """On the pool's threads the stages have no parent frame, so they sum
+    beside `decode` (CPU time) and take nothing from its self time; run
+    inline (one worker) they are its children on one thread.  Either way
+    what `decode` does not spend in a child stage is its self time >= 0,
+    and `scan` contains `decode` and `scan_merge`."""
+    srv = stores["series_major"]
+    if not pool:
+        monkeypatch.setattr(scanpool, "WORKERS", 1)
+    colcache.GLOBAL.configure(budget_mb=REGIMES["evicting"])
+    colcache.GLOBAL.clear()
+    srv.svc.executor._inc_cache.clear()
+    for req in statements[:HOURS]:
+        before = STATS.counters("query_stages")
+        srv.ask(req)
+        after = STATS.counters("query_stages")
+        d = {k: after[k] - before.get(k, 0) for k in after}
+        assert d["decode_count"] == 1
+        assert d["pool_wait_count"] == (FILES if pool else 0)
+        stages = d["block_read_ns"] + d["codec_ns"] + d["colcache_fill_ns"]
+        if pool:    # exact: the frame holds what its children recorded
+            assert d["decode_self_ns"] == d["decode_ns"] - d["pool_wait_ns"]
+        else:       # and the counted lookups that missed (`colcache`)
+            assert d["decode_self_ns"] <= d["decode_ns"] - stages
+        assert 0 <= d["decode_self_ns"] <= d["decode_ns"]
+        assert d["decode_ns"] + d["scan_merge_ns"] <= d["scan_ns"]
+        assert d["scan_self_ns"] >= 0 and d["colcache_fill_self_ns"] >= 0

@@ -28,11 +28,13 @@ NS = 10**9
 BASE = 1_700_000_000
 DAY = 86_400
 
-QUERY_SPANS = {"sql_parse", "select: cpu", "map_shards", "scan", "decode",
+MISS_SPANS = {"decode", "pool_wait", "block_read", "codec", "colcache_fill",
+              "scan_merge"}
+QUERY_SPANS = {"sql_parse", "select: cpu", "map_shards", "scan", *MISS_SPANS,
                "colcache", "device_compute", "layout_build",
                "device_launch", "device_fetch", "host_combine", "inc_cache",
                "render", "format", "serialize", "send"}
-PROM_SPANS = {"prom_parse", "prom_collect", "decode", "prom_prepare",
+PROM_SPANS = {"prom_parse", "prom_collect", *MISS_SPANS, "prom_prepare",
               "prom_kernel", "device_launch", "device_fetch", "prom_render",
               "serialize", "send"}
 WRITE_SPANS = {"read_body", "lp_parse", "type_check", "write_hooks",
@@ -223,6 +225,10 @@ def test_a_query_leaves_a_tree(server):
     # the prefetch thread, under the scan that dispatched them
     assert len(spans["decode"]) == 2
     assert all(p["name"] == "scan" for _, p in spans["decode"])
+    # each decoded its one chunk in three stages, and then merged
+    for name in ("block_read", "codec", "colcache_fill"):
+        assert [p["name"] for _, p in spans[name]] == ["decode"] * 2, name
+    assert [p["name"] for _, p in spans["scan_merge"]] == ["scan"] * 2
     launch = dict(map(tuple, spans["device_launch"][0][0]["fields"]))
     assert launch["program"] and launch["h2d_bytes"] > 0
     fields = dict(map(tuple, doc["trace"]["root"]["fields"]))
