@@ -15,7 +15,7 @@ in it with no flag of this script:
 This process stays off JAX (numpy and the standard library only): a chip
 belongs to one process, and that process is the server.  It
 
-  1. builds the four native libraries from native/*.cpp (never from a
+  1. builds the five native libraries from native/*.cpp (never from a
      .so that happens to lie on disk) and opens each;
   2. starts ONE server through its CLI entry point
      (`python -m opengemini_tpu.server.app -config <generated toml>`),
@@ -70,7 +70,7 @@ import urllib.request
 import numpy as np
 
 REPO = os.path.dirname(os.path.abspath(__file__))
-NATIVE_LIBS = ("codecs", "textindex", "seriesindex", "lineproto")
+NATIVE_LIBS = ("codecs", "textindex", "seriesindex", "lineproto", "render")
 
 # -- sizes --------------------------------------------------------------------
 # Widths (hosts, tags, fields, series) are the sources' and are never cut;

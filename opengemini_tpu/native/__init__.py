@@ -3,9 +3,12 @@ place that builds and opens them.
 
 The reference uses cgo for its native pieces (textindex, lz4, rocksdb);
 pybind11 isn't in this image, so the bridge is a plain C ABI + ctypes
-(SURVEY.md environment notes).  `.gitignore` excludes the built `.so`
-files, so a clean checkout has none: `open_library` runs the library's
-make target when the file is missing.  A library that still cannot be
+(SURVEY.md environment notes).  Five libraries: codecs (bound here),
+textindex (native/textindex.py), seriesindex (index/mergeset.py),
+lineproto (ingest/native_lp.py), render (promql/render.py).
+`.gitignore` excludes the built `.so` files, so a clean checkout has
+none: `open_library` runs the library's make target when the file is
+missing.  A library that still cannot be
 built or opened leaves its callers on their pure-Python paths (every
 file stays readable), and the reason is kept for `report()` — the
 server prints it at start-up (chip_smoke.py fails on it) and in SHOW
@@ -22,7 +25,7 @@ import numpy as np
 
 NATIVE_DIR = os.path.abspath(
     os.path.join(os.path.dirname(__file__), "..", "..", "native"))
-LIBRARIES = ("codecs", "textindex", "seriesindex", "lineproto")
+LIBRARIES = ("codecs", "textindex", "seriesindex", "lineproto", "render")
 
 # library -> "" once loaded, else why it did not load
 _status: dict[str, str] = {}
@@ -76,16 +79,18 @@ def open_library(name: str, bind):
 
 
 def load_all() -> dict[str, str]:
-    """Open all four libraries (building any that is missing) and return
+    """Open all five libraries (building any that is missing) and return
     {library: "" if loaded else why not}."""
     from opengemini_tpu.index import mergeset
     from opengemini_tpu.ingest import native_lp
     from opengemini_tpu.native import textindex
+    from opengemini_tpu.promql import render
 
     load()
     textindex._load()
     mergeset.load()
     native_lp.load()
+    render.load()
     return {name: _status[name] for name in LIBRARIES}
 
 

@@ -23,6 +23,7 @@ import numpy as np
 
 from opengemini_tpu.ops import prom as promops
 from opengemini_tpu.promql import parser as pp
+from opengemini_tpu.promql import render
 from opengemini_tpu.utils import tracing
 from opengemini_tpu.utils.governor import _env_int
 from opengemini_tpu.utils.querytracker import GLOBAL as TRACKER
@@ -250,6 +251,16 @@ class PromEngine:
 
     def query_range(self, text: str, start_s: float, end_s: float, step_s: float,
                     db: str) -> dict:
+        """The matrix answer as a tree, for in-process callers."""
+        return self._range(text, start_s, end_s, step_s, db, render.matrix_dict)
+
+    def query_range_json(self, text: str, start_s: float, end_s: float,
+                         step_s: float, db: str) -> bytes:
+        """The same answer as `json.dumps(query_range(...))` would write it,
+        rendered in bulk (promql/render.py): what /api/v1/query_range sends."""
+        return self._range(text, start_s, end_s, step_s, db, render.matrix_json)
+
+    def _range(self, text, start_s, end_s, step_s, db, render_matrix):
         self._check_readable()
         if step_s <= 0:
             raise PromError("step must be positive")
@@ -265,19 +276,8 @@ class PromEngine:
             expr = pp.parse(text)
         with self._tracked(text, db):
             frame = self._eval(expr, steps, db)
-        with tracing.span("prom_render") as sp:
-            result = []
-            for i, labels in enumerate(frame.labels):
-                pts = [
-                    [float(steps[k]), _fmt(frame.values[i, k])]
-                    for k in range(n_steps)
-                    if frame.valid[i, k]
-                ]
-                if pts:
-                    result.append({"metric": labels, "values": pts})
-            result.sort(key=lambda r: sorted(r["metric"].items()))
-            sp.add_field("series", len(result))
-        return {"resultType": "matrix", "result": result}
+        with tracing.span("prom_render", series=len(frame.labels)):
+            return render_matrix(frame, steps)
 
     def query_instant(self, text: str, time_s: float, db: str) -> dict:
         self._check_readable()
@@ -1792,9 +1792,4 @@ _CLOCK_FNS = {
 }
 
 
-def _fmt(v: float) -> str:
-    if math.isnan(v):
-        return "NaN"
-    if math.isinf(v):
-        return "+Inf" if v > 0 else "-Inf"
-    return repr(float(v))
+_fmt = render.fmt_value

@@ -1674,13 +1674,16 @@ def _make_handler(svc: HttpService):
                 code = 401 if user is None else 403
                 self._send_json(code, {"status": "error", "error": "read not authorized"})
                 return
+            matrix = None
             try:
                 if path == "/api/v1/query_range":
                     # PromQL reads scan like any interactive query and must
                     # take an admission slot — otherwise this surface is an
-                    # ungoverned side door around the /query sheds
+                    # ungoverned side door around the /query sheds.  The
+                    # matrix comes back as its JSON text, rendered in bulk
+                    # (promql/render.py): json.dumps never sees a point
                     with _admitted():
-                        data = svc.prom.query_range(
+                        matrix = svc.prom.query_range_json(
                             params.get("query", ""),
                             _prom_time(params.get("start")),
                             _prom_time(params.get("end")),
@@ -1733,6 +1736,11 @@ def _make_handler(svc: HttpService):
                 self._send_json(
                     400, {"status": "error", "errorType": "bad_data", "error": str(e)}
                 )
+                return
+            if matrix is not None:
+                with tracing.span("serialize"):
+                    payload = b'{"status": "success", "data": ' + matrix + b"}\n"
+                self._send(200, payload)
                 return
             self._send_json(200, {"status": "success", "data": data})
 

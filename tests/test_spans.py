@@ -254,6 +254,37 @@ def test_a_promql_query_leaves_a_tree(server):
         assert [p["name"] for _, p in spans[name]] == ["http_prom"], name
 
 
+def test_a_range_answer_counts_the_points_it_rendered(server):
+    port = server.port
+    lines = "\n".join(
+        f"http_requests_total,job=j{j} value={k * (j + 1)} "
+        f"{(BASE + k * 15) * NS}" for j in range(5) for k in range(200))
+    assert _http(port, "POST", "/write", lines.encode(), db="prom")[0] == 204
+    assert _http(port, "POST", "/write", f"cpu v=1 {BASE * NS}".encode(),
+                 db="db")[0] == 204
+    before, stages = _counters("prom"), _counters("query_stages")
+    # neither an InfluxQL answer nor an instant vector is a matrix body
+    assert _http(port, "GET", "/query", db="db",
+                 q="SELECT count(v) FROM cpu")[0] == 200
+    assert _http(port, "GET", "/api/v1/query", time=BASE + 900,
+                 query="rate(http_requests_total[5m])")[0] == 200
+    moved = _delta("prom", before)
+    assert "render_points" not in moved and "render_native_points" not in moved
+    status, body = _http(port, "GET", "/api/v1/query_range",
+                         query="rate(http_requests_total[5m])",
+                         start=BASE + 600, end=BASE + 2400, step=60)
+    result = json.loads(body)["data"]["result"]
+    points = sum(len(s["values"]) for s in result)
+    assert status == 200 and len(result) == 5 and points == 5 * 31
+    moved = _delta("prom", before)
+    assert moved["render_points"] == moved["render_native_points"] == points
+    d = _delta("query_stages", stages)
+    # one render, one envelope and one socket write for the matrix (the
+    # two answers before it serialized and sent theirs too)
+    assert d["prom_render_count"] == 2 and d["serialize_count"] == 3
+    assert d["send_count"] == 3
+
+
 def test_a_write_leaves_a_tree(server):
     port = server.port
     tracing.set_trace_enabled(True)
