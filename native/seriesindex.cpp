@@ -557,7 +557,7 @@ uint64_t msi_insert(void *h, const char *blob, uint64_t blob_len,
 // guaranteed escape-free by the caller (keys containing backslashes take
 // the per-key structured path). Parsing mst,k=v,... here removes the
 // per-series Python parse + pack + ctypes round-trip that dominated
-// high-cardinality ingest (BASELINE.md config #5 profile). Returns the
+// high-cardinality ingest (BASELINE.md config #5). Returns the
 // number of keys processed; sids land in out_sids.
 uint64_t msi_insert_keys(void *h, const char *blob, uint64_t blob_len,
                          uint64_t count, uint64_t *out_sids) {

@@ -838,9 +838,7 @@ def _pow2_at_least(n: int, floor: int) -> int:
 def _lane_quantum() -> int:
     """Lane-axis padding quantum: 128 on TPU (the native lane tile —
     anything less re-pads on device), 8 on CPU/GPU backends where a
-    128-wide floor at W=20 meant computing 6.4x the cells for nothing
-    (the measured grid-loses-to-bucketed regression in bench_e2e's
-    cpu-smoke shape)."""
+    128-wide floor at W=20 meant computing 6.4x the cells for nothing)."""
     import jax
 
     return 128 if jax.default_backend() == "tpu" else 8

@@ -13,16 +13,16 @@ Two generations live here:
     (series, step) window answers from cumulative tile prefixes plus two
     boundary refinements — O(1) per window, no searchsorted, no dense
     membership tensors.  One xp-generic code path runs as host numpy,
-    eager jax.numpy, or traced under jit (the bench harness compiles
-    it; the engine's accelerator path is eager today).
+    eager jax.numpy, or traced under jit (the engine's accelerator
+    path is eager today).
 
   * The DENSE kernels (top of the module): padded (num_series,
     max_samples) matrices, vmap'd searchsorted window bounds, chunked
     (S, chunk, N) membership tensors for the non-prefix-able forms.
     They remain as the fallback for window grids the tile lattice cannot
     express (sub-ms edges, over-budget tile counts) and for
-    quantile/mad/holt_winters, and as the in-bench/test reference the
-    tiled engine is equality-gated against.
+    quantile/mad/holt_winters, and as the reference the tests hold the
+    tiled engine equal to.
 
 Semantics follow Prometheus exactly (promql/functions.go extrapolatedRate):
   - counter resets: correction[i] = v[i-1] if v[i] < v[i-1], restricted
@@ -69,8 +69,8 @@ def prepare_matrix(series_samples: list[tuple[np.ndarray, np.ndarray]], dtype=np
 def prepare_matrix_runs(t_ms_all, v_all, lens, dtype=np.float32):
     """prepare_matrix over run-encoded input: one concatenated (times_ms,
     values) pair with per-series lengths, filled by ONE flat scatter — no
-    per-series Python loop (the loop dominated 1M-series instant queries,
-    BASELINE.md config #5)."""
+    per-series Python loop (an instant query of BASELINE.md config #5
+    spans 1M series)."""
     lens = np.asarray(lens, np.int64)
     S = len(lens)
     n_max = max(1, int(lens.max()) if S else 1)
@@ -575,7 +575,7 @@ class TiledPrepared:
     windows in O(1) per window.  `xp` selects numpy (host) or jax.numpy
     (device); `values`/`value_shift` let callers re-run the value-dependent
     part with fresh values against the same prepared time structure (the
-    bench harness and the device jit path)."""
+    device jit path)."""
 
     def __init__(self, plan: TilePlan, t_ms_all, v_all, lens,
                  dtype=np.float64, max_gather_cols: int | None = None,

@@ -243,8 +243,7 @@ def grid_window_agg_t(values_t, mask_t):
     measured on the present code).
     Production wiring: models/grid.py GridBatch assembles scanned chunks
     directly in this layout when the data is stride-regular (pick_batch
-    routes GROUP BY time() aggregates there); bench.py measures the same
-    kernel standalone.
+    routes GROUP BY time() aggregates there).
 
     Returns dict of (num_series, num_windows) arrays.
     """

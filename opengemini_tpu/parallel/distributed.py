@@ -326,7 +326,7 @@ def shard_leading_axis(mesh: Mesh, *arrays, xfer_site: str = "mesh-shard"):
                len({s.device.id for s in out[0].addressable_shards}))
     # every byte here is a host->device transfer a warm mesh query should
     # NOT repeat (the colcache device tier retains the sharded buffers);
-    # the multichip bench asserts this counter is flat across warm runs
+    # tests/test_multichip.py asserts this counter is flat across warm runs
     _STATS.incr("device", "mesh_h2d_bytes", nbytes)
     devobs.note_transfer("h2d", xfer_site, nbytes,
                          (_time.perf_counter_ns() - t0) / 1e9)

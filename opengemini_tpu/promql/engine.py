@@ -1100,9 +1100,9 @@ class PromEngine:
     def _eval_agg_fast(self, node: pp.Aggregation, steps, db):
         """topk/bottomk/count_values over a bare high-cardinality selector
         without materializing input labels: the winners' (or none of the)
-        labels resolve AFTER selection. At 1M series the eager path spends
-        ~85% of its time building label dicts that the result never uses
-        (BASELINE.md config #5). Returns None when inapplicable.
+        labels resolve AFTER selection. At 1M series (BASELINE.md config
+        #5) the eager path builds a label dict per input series that the
+        result never uses. Returns None when inapplicable.
 
         Exact-value ties at the topk/bottomk boundary may admit a
         different (equally-valid) subset than the eager path: this path

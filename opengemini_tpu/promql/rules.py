@@ -27,7 +27,7 @@ services/stream.py and services/continuous.py):
 Correctness contract: every tick's incremental answer is BITWISE
 identical to a from-scratch evaluation (fold every window tile off one
 full scan, merge identically) — ``OGT_RULES_VERIFY=1`` asserts it on
-every tick (bench/loadgen/tests run with it on).  That contract pins the
+every tick (loadgen and the tests run with it on).  That contract pins the
 fold/merge arithmetic to host numpy float64 in a canonical series order;
 the matcher probes still ride the columnar label tier (index/labels.py)
 and the full-rescan fallback leg evaluates through the ordinary
@@ -321,8 +321,6 @@ class RuleGroup:
         self.fires: dict[str, int] = {}
         self.resolves: dict[str, int] = {}
         self.last_tick_ms = 0.0
-        self.last_results: dict[str, dict] = {}  # in-memory, per tick
-        self.last_e_tile: int | None = None
         self._sels: dict[tuple, _SelState] = {}
         # (lo_ms, hi_ms] spans of writes between note_write_* and
         # write_done: tiles overlapping one stay dirty this tick (a fold
@@ -582,7 +580,7 @@ class RuleManager:
     def invalidate(self, db: str, group: str | None = None) -> int:
         """Drop every cached tile of the matching groups so the next
         tick refolds whole windows from storage — the forced from-
-        scratch leg bench/loadgen measure the incremental path against
+        scratch leg loadgen measures the incremental path against
         (and the repair hammer if tile state is ever suspect)."""
         n = 0
         with self._lock:
@@ -788,8 +786,6 @@ class RuleManager:
                                                        memo=memo)
                 else:
                     results[r.name] = self._eval_fallback(g, r, te_ns)
-        g.last_results = results
-        g.last_e_tile = int(e_tile)
 
         # -- verify: the from-scratch leg must agree bit-for-bit
         if verify_enabled():
@@ -971,17 +967,6 @@ class RuleManager:
                       if k != "__name__"}
             out[tuple(sorted(labels.items()))] = float(s["value"][1])
         return out
-
-    def verify_last_tick(self, g: RuleGroup) -> bool:
-        """Re-run the from-scratch leg against the last tick's retained
-        results (bench/loadgen: assert bit-identity on a measured tick
-        without paying the verify rescan INSIDE the timed tick).
-        Raises on mismatch; False when no tick has run yet."""
-        if g.last_e_tile is None:
-            return False
-        with g.m_lock:
-            self._verify(g, g.last_e_tile, g.last_results)
-        return True
 
     def _verify(self, g: RuleGroup, e_tile: int, got: dict) -> None:
         """The from-scratch leg: fold EVERY window tile off one full

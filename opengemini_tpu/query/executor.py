@@ -49,7 +49,7 @@ from opengemini_tpu.utils.querytracker import (GLOBAL as TRACKER,
 from opengemini_tpu.utils.stats import GLOBAL as STATS
 from opengemini_tpu.sql.parser import parse
 
-from opengemini_tpu.query.qhelpers import *  # noqa: F401,F403 — split helpers (VERDICT r3 #7)
+from opengemini_tpu.query.qhelpers import *  # noqa: F401,F403 — split helpers
 from opengemini_tpu.query.qhelpers import (  # noqa: F401
     NS, MAX_SELECT_BUCKETS, QueryError,
 )
@@ -112,7 +112,6 @@ def pick_batch(schema, agg_names, field: str, dtype, grid_ctx=None):
     # shard_map path still serves its own cases.
     if (
         grid_ctx is not None
-        and not os.environ.get("OGTPU_DISABLE_GRID")  # A/B knob (bench.py)
         and schema.get(field) in (FieldType.FLOAT, FieldType.INT)
         and all(n in _grid.GRID_AGGS for n in agg_names)
     ):
@@ -1359,8 +1358,8 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
             group_time is not None
             and W >= 1
             and aggs  # tag-count-only statements have nothing to cache
-            # OGT_RESULT_CACHE=0 opts out (A/B runs — e.g. the offload
-            # bench — must see every execution, not one per panel)
+            # OGT_RESULT_CACHE=0 opts out (A/B runs must see every
+            # execution, not one per panel)
             and os.environ.get("OGT_RESULT_CACHE", "1") not in ("", "0")
             and self.router is None
             and ctx.live is None
@@ -1499,8 +1498,8 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
                     b.device_cache_token = f"{device_token}|{f}"
 
         # at-spec scans: window-aligned time slicing bounds host/device
-        # memory and overlaps decode with device compute (VERDICT r4 #1;
-        # reference analogue: the record-plan batch reader streams chunks,
+        # memory and overlaps decode with device compute (reference
+        # analogue: the record-plan batch reader streams chunks,
         # engine/record_plan.go:75)
         slice_plan = None
         if (
@@ -1725,7 +1724,7 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
                     # the COORDINATOR's tag-key view: peers must evaluate
                     # mixed trees against the same classification — a tag
                     # absent from a peer's local index must still inject
-                    # as an empty-string column (r3 ADVICE #2)
+                    # as an empty-string column
                     "tag_keys": sorted(sc.tag_keys),
                 }
                 peer_docs = self.router.select_partials(req, ctx.live)
@@ -1786,8 +1785,8 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
 
         # batched multi-series path: one bulk decode per shard when
         # many series are scanned (packed colstore chunks decode once
-        # for all their series; kills the per-sid Python loop that
-        # dominated config #5 — BASELINE.md round-2 profile)
+        # for all their series, with no per-sid Python loop: config #5
+        # of BASELINE.md scans 1M series)
         remaining_plan = scan_plan
         if not pre_eligible:
             by_shard: dict[int, tuple] = {}
@@ -1887,7 +1886,7 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
         batch set, then the device kernels for that slice are DISPATCHED
         (not materialized) before the next slice decodes — on a real
         accelerator the device crunches slice k while the host decodes
-        k+1 (the double-buffering VERDICT r4 #1 asked for). Returns
+        k+1 (double-buffering). Returns
         (rows_scanned, [(w0, W_s, {field: batch})])."""
         rows_scanned = 0
         out = []

@@ -103,7 +103,7 @@ def _env_float(name: str, default: float) -> float:
 # host), "1"/"0" force.  Hot-reloadable via /debug/ctrl?mod=offload.
 _PROM_HOST_KERNELS = os.environ.get("OGT_PROM_HOST_KERNELS", "")
 
-# forced route for A/B work (bench legs, forced-all-host vs
+# forced route for A/B work (forced-all-host vs
 # forced-all-device): decide() answers this route whenever it is a
 # candidate, and gate_prior() stands aside for it
 _FORCE = os.environ.get("OGT_OFFLOAD_FORCE", "") or None
@@ -126,10 +126,6 @@ def enabled() -> bool:
 def set_enabled(on: bool) -> None:
     global _ON
     _ON = bool(on)
-
-
-def force_route() -> str | None:
-    return _FORCE
 
 
 def set_force(route: str | None) -> None:

@@ -1,6 +1,6 @@
 """Shared helpers for the query executor family: AST utilities, host
 scalar evaluation, call resolution, fill/render primitives, and the
-QueryError type. Split out of query/executor.py (VERDICT r3 #7) so
+QueryError type. Split out of query/executor.py so
 the executor modules stay review-able; semantics unchanged.
 """
 

@@ -37,7 +37,7 @@ from opengemini_tpu.query.qhelpers import (  # noqa: F401
 
 # chunked inner evaluation: estimated inner scans above the threshold
 # evaluate window-aligned time chunks into the spill engine one at a
-# time, bounding the JSON intermediate (VERDICT r4 #9; reference:
+# time, bounding the JSON intermediate (reference:
 # streaming subquery_transform.go). The cap is the loud guard for
 # non-chunkable shapes (reference analogue: max-select-point).
 SUBQUERY_CHUNK_ROWS = int(os.environ.get(

@@ -97,8 +97,7 @@ def _init_jax_distributed(dev_cfg: dict) -> None:
 def _configure_device_mesh(dev_cfg: dict) -> None:
     """[device] mesh-axes -> a process-wide jax mesh: every dense batch
     (grid / bucketed) and the AggBatch shard_map path then run multi-chip
-    (parallel/runtime.set_mesh; VERDICT r3 #3 — previously no production
-    code path ever built a mesh). The reference's always-on shard fan-out
+    (parallel/runtime.set_mesh). The reference's always-on shard fan-out
     analogue is coordinator/shard_mapper.go:61."""
     from opengemini_tpu.parallel import runtime as prt
 

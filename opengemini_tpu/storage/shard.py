@@ -1591,8 +1591,8 @@ class Shard:
         """Batched multi-series read: (sid_column, record) for every
         requested series, rows grouped by sid and time-sorted within a
         sid, last-write-wins deduped.  Packed chunks decode ONCE for all
-        their series — the per-sid Python loop this replaces was the
-        measured bottleneck at 1M series (BASELINE.md config #5)."""
+        their series, with no per-sid Python loop (BASELINE.md config #5
+        reads 1M series)."""
         sids = np.asarray(sorted(int(s) for s in sids), dtype=np.int64)
         lo_t = tmin if tmin is not None else -(2**63)
         hi_t = tmax if tmax is not None else 2**63 - 1

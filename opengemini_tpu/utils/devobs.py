@@ -28,7 +28,7 @@ armed or not):
       and to the running query's `device_compile` stage
       (tracing.record_stage).
 
-      The tripwire: mark_warm() (bench warm loops, or the ctrl op)
+      The tripwire: mark_warm() (test warm loops, or the ctrl op)
       snapshots "everything is compiled now"; ANY lowering-site miss
       after the mark increments `recompiles_after_warm_total` and flags
       the ring entry — the classic silent 10x regression in jit systems
@@ -306,7 +306,7 @@ def note_use(kernel: str, geometry=()) -> None:
 
 def mark_warm() -> None:
     """Arm the recompile tripwire: everything needed is compiled NOW;
-    any lowering-site miss from here on is a flagged recompile.  Bench
+    any lowering-site miss from here on is a flagged recompile.  Test
     warm loops call this after their compile warmup; operators via
     /debug/ctrl?mod=devobs&op=mark_warm once a service is warm."""
     global _warm_marked, _compiles_since_warm
@@ -460,8 +460,7 @@ def launch(fn, args, program: str, xfer_site: str):
 
 def span_snapshot() -> dict:
     """Cheap counters-only snapshot for per-span delta attribution (the
-    executor's device_compute span fields) and the bench device
-    blocks."""
+    executor's device_compute span fields)."""
     snap = _STATS.counters("device")
     with _lock:
         wall = _compile_wall_ns

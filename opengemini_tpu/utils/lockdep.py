@@ -9,8 +9,7 @@ mechanically, the way Linux lockdep proves lock-class ordering: armed
 via ``OGT_LOCKDEP=1``, every ``lockdep.Lock()``/``RLock()``/
 ``Condition()`` in the tree becomes a tracked wrapper; unset, the names
 are plain CLASS ALIASES for ``threading.Lock``/``RLock``/``Condition``
-— zero per-acquisition work, asserted by tests/test_lockdep.py and
-measured by ``bench.py lockdep_overhead``.
+— zero per-acquisition work, asserted by tests/test_lockdep.py.
 
 What the armed mode proves, per process:
 
@@ -99,7 +98,7 @@ def enabled() -> bool:
 if not _ARMED:
     # Pass-through: plain aliases, NOT shims — the unarmed tree pays
     # zero per-acquisition (and zero per-construction) work.  Asserted
-    # identity (`lockdep.Lock is threading.Lock`) in tests and bench.
+    # identity (`lockdep.Lock is threading.Lock`) in tests.
     Lock = threading.Lock
     RLock = threading.RLock
     Condition = threading.Condition

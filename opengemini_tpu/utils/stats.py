@@ -140,7 +140,7 @@ _BOUNDS_NS = [1 << (_H_LO + i) for i in range(_NBOUNDS)]
 _BOUNDS_S = [b / 1e9 for b in _BOUNDS_NS]
 
 # histogram arming: OGT_TRACE=0 short-circuits every observe() to one
-# global read — the bench's disabled arm.  Unset/1 = armed (a default
+# global read.  Unset/1 = armed (a default
 # /metrics scrape sees live latency data without any knob).
 _OBS_ON = os.environ.get("OGT_TRACE", "") != "0"
 

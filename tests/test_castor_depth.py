@@ -1,4 +1,4 @@
-"""Castor algorithm depth (VERDICT r4 #8): the STL-style sudden-change
+"""Castor algorithm depth: the STL-style sudden-change
 pipeline, fit/detect with persisted seasonal artifacts, and the stream
 entry point. Reference: python/ts-udf/server/fit_detect.py:32
 (FitDetectorUDF) + server/udf/sudden_increase_STL3.py; the

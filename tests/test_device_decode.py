@@ -259,6 +259,7 @@ def test_cold_scan_engages_device_decode(env, monkeypatch, rng):
     silently fall back) and transfer fewer H2D bytes than the host
     path's decoded grid."""
     from opengemini_tpu.storage import colcache
+    from opengemini_tpu.utils import devobs
     from opengemini_tpu.utils.stats import GLOBAL as STATS
 
     e, ex = env
@@ -287,6 +288,15 @@ def test_cold_scan_engages_device_decode(env, monkeypatch, rng):
     assert json.dumps(out_host) == json.dumps(out_dev)
     assert fused >= 1, "fused decode path did not engage"
     assert 0 < bytes_dev < bytes_host, (bytes_dev, bytes_host)
+    # warm repeats reuse every program: the recompile tripwire stays 0
+    devobs.mark_warm()
+    try:
+        for _ in range(3):
+            ex._inc_cache.clear()
+            assert json.dumps(ex.execute(q, db="db")) == json.dumps(out_dev)
+        assert devobs.compiles_since_warm() == 0
+    finally:
+        devobs.clear_warm()
     colcache.GLOBAL.configure(device=False)
 
 

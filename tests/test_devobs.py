@@ -416,6 +416,27 @@ class TestPassThrough:
         finally:
             eng.close()
 
+    def test_warm_repeats_compile_nothing_armed_or_not(self, tmp_path):
+        """Arming and disarming between warm repeats of one statement
+        builds no new device program (the recompile tripwire stays 0)
+        and changes no result."""
+        eng = _mk_engine(tmp_path)
+        try:
+            ex = Executor(eng)
+
+            def run(armed):
+                devobs.set_enabled(armed)
+                ex._inc_cache.clear()  # execute, not a cache lookup
+                return json.dumps(ex.execute(_Q, db="db"), sort_keys=True)
+
+            first = {run(False), run(True)}  # compile warm-up, both ways
+            devobs.mark_warm()
+            warm = {run(armed) for armed in (False, True, False, True)}
+            assert devobs.compiles_since_warm() == 0
+            assert warm == first and len(warm) == 1
+        finally:
+            eng.close()
+
     def test_disarmed_records_nothing(self, tmp_path):
         from opengemini_tpu.utils.stats import histograms_snapshot
 

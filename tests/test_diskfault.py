@@ -413,6 +413,41 @@ class TestScrub:
         assert STATS.counters("scrub")["files_verified_total"] >= 1
         eng.close()
 
+    def test_scrub_beside_queries_changes_no_result(self, tmp_path):
+        """The sweep only reads and verifies: a query answered while the
+        scrub thread ticks continuously is the answer without it, cold
+        (colcache emptied, so the read path shares the files) and warm."""
+        from opengemini_tpu.query.executor import Executor
+        from opengemini_tpu.services.scrub import ScrubService
+        from opengemini_tpu.storage import colcache
+
+        eng = _mk_engine(tmp_path, rows=600, series=8)
+        ex = Executor(eng)
+        q = (f"SELECT mean(v), max(v), count(v) FROM m WHERE time >= "
+             f"{BASE * NS} AND time < {(BASE + 600) * NS} "
+             "GROUP BY time(1m), w")
+
+        def run():
+            ex._inc_cache.clear()
+            return json.dumps(ex.execute(q, db="db"), sort_keys=True)
+
+        quiet = run()
+        s = ScrubService(eng, 0.005, mb_per_tick=4)
+        bytes0 = STATS.counters("scrub").get("bytes_total", 0)
+        s.start()
+        try:
+            beside = []
+            for _ in range(6):
+                colcache.GLOBAL.clear()
+                beside.append(run())  # cold: decodes the scrubbed files
+                beside.append(run())  # warm
+        finally:
+            s.stop()
+        assert set(beside) == {quiet}
+        assert STATS.counters("scrub").get("bytes_total", 0) > bytes0
+        assert eng.quarantine_snapshot()["total"] == 0
+        eng.close()
+
     def test_disabled_by_env_is_inert(self, tmp_path, monkeypatch):
         from opengemini_tpu.services import scrub as scrub_mod
 

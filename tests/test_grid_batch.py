@@ -1,5 +1,5 @@
 """GridBatch (windows-on-lanes fast path): parity with BucketedBatch,
-fallback rules, and executor wiring (VERDICT r3 #1)."""
+fallback rules, and executor wiring."""
 
 import numpy as np
 import pytest

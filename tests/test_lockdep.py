@@ -209,6 +209,46 @@ print("ALIAS-OK")
     assert "ALIAS-OK" in proc.stdout
 
 
+_WORKLOAD = """
+import hashlib, json, os, tempfile
+from opengemini_tpu.query.executor import Executor
+from opengemini_tpu.storage.engine import Engine
+from opengemini_tpu.utils import lockdep
+
+assert lockdep.enabled() == (os.environ.get("OGT_LOCKDEP") == "1")
+NS = 1_000_000_000
+BASE = 1_700_000_000
+with tempfile.TemporaryDirectory() as d:
+    eng = Engine(d, sync_wal=False)
+    eng.create_database("db")
+    eng.write_lines("db", "\\n".join(
+        f"cpu,host=h{s} v={50 + (s + p) % 50} {(BASE + p) * NS}"
+        for p in range(300) for s in range(6)))
+    eng.flush_all()
+    out = Executor(eng).execute(
+        "SELECT mean(v), max(v), count(v) FROM cpu "
+        f"WHERE time >= {BASE * NS} AND time < {(BASE + 300) * NS} "
+        "GROUP BY time(1m), host", db="db", now_ns=(BASE + 300) * NS)
+    lockdep.check()  # armed: ingest, flush and query were violation-free
+    eng.close()
+print("DIGEST " + hashlib.sha256(
+    json.dumps(out, sort_keys=True).encode()).hexdigest())
+"""
+
+
+def test_armed_run_answers_as_unarmed():
+    """Arming only observes: the same ingest + flush + GROUP BY time()
+    in an armed and an unarmed process gives the same result digest,
+    and the armed run witnesses no violation."""
+    digests = []
+    for armed in (False, True):
+        proc = _run(_WORKLOAD, armed=armed)
+        assert proc.returncode == 0, proc.stderr + proc.stdout
+        digests.append([ln for ln in proc.stdout.splitlines()
+                        if ln.startswith("DIGEST ")][-1])
+    assert digests[0] == digests[1]
+
+
 def test_synthetic_inverted_flush_lock_order_is_caught():
     """The acceptance scenario: the REAL shard records
     _flush_lock -> _lock during a flush; a synthetic inverted

@@ -153,7 +153,7 @@ def test_migration_waits_for_down_owner(tmp_path):
 
 
 class TestTwoPhaseMigration:
-    """Pre*/Rollback semantics (r3 VERDICT missing #8; reference
+    """Pre*/Rollback semantics (reference
     engine/engine_ha.go:33-258 + migrate_state_machine.go)."""
 
     def _cluster(self, tmp_path, n=2):

@@ -655,6 +655,7 @@ class TestMeshShardedDecode:
 
         from opengemini_tpu.query.executor import Executor
         from opengemini_tpu.storage import colcache
+        from opengemini_tpu.utils import devobs
 
         e = self._engine(tmp_path, monkeypatch, 70, name="warm")
         ex = Executor(e)
@@ -683,13 +684,22 @@ class TestMeshShardedDecode:
             ex._inc_cache.clear()  # drop result cache, keep device tier
             warm = ex.execute(q, db="db")
             h2, m2, f2 = counters()
+            # the device-tier hit has its own program: a second repeat
+            # builds none
+            devobs.mark_warm()
+            ex._inc_cache.clear()
+            again = ex.execute(q, db="db")
+            recompiles = devobs.compiles_since_warm()
         finally:
+            devobs.clear_warm()
             prt.set_mesh(None)
             colcache.GLOBAL.configure(**prior)
             e.close()
         assert f1 - f0 >= 1, "mesh fused decode did not engage"
         assert m1 - m0 > 0, "mesh-cold H2D not accounted as mesh bytes"
         assert h2 - h1 == 0, "warm mesh repeat must transfer zero bytes"
+        assert recompiles == 0, "warm mesh repeat built a new program"
+        assert again == warm
         assert json.dumps(cold, sort_keys=True) == \
             json.dumps(warm, sort_keys=True)
 

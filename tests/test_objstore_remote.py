@@ -1,5 +1,5 @@
 """Remote (HTTP/S3-subset) object store: client unit tests and fault
-injection on the offload/hydrate paths (VERDICT r4 #5).
+injection on the offload/hydrate paths.
 
 Reference: /root/reference/lib/obs (bucket client) +
 engine/immutable/detached_*.go (remote layout). Faults are injected with

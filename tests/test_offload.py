@@ -458,6 +458,40 @@ class TestBitIdentity:
             eng.close()
             colcache.GLOBAL.clear()
 
+    def test_forced_routes_answer_alike(self, tmp_path, monkeypatch):
+        """OGT_OFFLOAD_FORCE=host|device over encoded (device-profile)
+        columns: the fused device decode and the host scatter give the
+        same bytes as the planner's own choice, and each forced route
+        is the one that ran."""
+        from opengemini_tpu.query.executor import Executor
+        from opengemini_tpu.utils.stats import GLOBAL as STATS
+
+        monkeypatch.setenv("OGT_DEVICE_PROFILE", "1")
+        monkeypatch.setenv("OGT_DEVICE_DECODE", "1")
+        eng = _mk_engine(tmp_path, hosts=70)  # >= 64: the bulk scan
+        try:
+            ex = Executor(eng)
+
+            def fused():
+                return STATS.counters("executor").get("grid_decode_fused", 0)
+
+            def run(force):
+                offload.set_force(force)
+                colcache.GLOBAL.clear()
+                ex._inc_cache.clear()
+                before = fused()
+                out = json.dumps(ex.execute(_Q, db="db"), sort_keys=True)
+                return out, fused() - before
+
+            adaptive, _ = run(None)
+            host, host_fused = run("host")
+            device, device_fused = run("device")
+            assert host == adaptive == device
+            assert host_fused == 0 and device_fused >= 1
+        finally:
+            eng.close()
+            colcache.GLOBAL.clear()
+
 
 # -- ctrl + debug surfaces ----------------------------------------------------
 

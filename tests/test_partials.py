@@ -263,7 +263,7 @@ class TestWireShape:
 
     def test_percentile_ships_multiset_not_raw(self, tmp_path):
         """Rank aggregates push down: wire bytes scale with distinct
-        values per group, not rows (VERDICT r2 #7)."""
+        values per group, not rows."""
         nodes, addrs = _mk_cluster(tmp_path, nids=("nA", "nB"))
         week = 7 * 86400
         lines = []

@@ -1,4 +1,4 @@
-"""Incremental GROUP BY time() result cache (VERDICT r3 #5; reference
+"""Incremental GROUP BY time() result cache (reference
 inc_agg_transform.go + lib/resultcache)."""
 
 import time

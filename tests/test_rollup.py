@@ -537,6 +537,10 @@ class TestServiceAndGovernor:
         declare(e)
         write_series(e, n=120)
         svc = RollupService(e, interval_s=3600)
+        # the governor is process-global: an IO alarm raised by an earlier
+        # file on this worker (tests/test_services.py TestIoDetector) closes
+        # the background gate for 30 s and would shed this tenant
+        GOVERNOR.reset()
         GOVERNOR.configure(budget_mb=64)
         try:
             folded = svc.handle(now_ns=(BASE + 400) * NS)

@@ -1,4 +1,4 @@
-"""Window-aligned sliced scan (VERDICT r4 #1): the at-spec pipeline must
+"""Window-aligned sliced scan: the at-spec pipeline must
 produce byte-identical results to the monolithic scan — every per-window
 aggregate, fill behavior, group-by-tag layout, partial edge windows, and
 irregular (bucketed-layout) data.

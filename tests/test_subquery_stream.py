@@ -202,7 +202,7 @@ class TestReviewRegressions:
 
 
 class TestChunkedSubquery:
-    """Chunked inner evaluation (VERDICT r4 #9): big inner scans
+    """Chunked inner evaluation: big inner scans
     materialize chunk-by-chunk into the spill engine; results must be
     identical to single-shot evaluation."""
 

@@ -242,7 +242,7 @@ class TestPersistedTextIndex:
 
 
 class TestUtf8Grams:
-    """UTF-8/CJK gram tokenization (r3 VERDICT missing #7; reference
+    """UTF-8/CJK gram tokenization (reference
     SimpleGramTokenizer split-table walk, FullTextIndex.cpp:19-40)."""
 
     def test_tokenize_mixed(self):

@@ -1,6 +1,5 @@
-"""TLS for the HTTP listener and every peer transport (VERDICT r3 #8;
-reference: the https options of lib/config applied to httpd and
-inter-node traffic)."""
+"""TLS for the HTTP listener and every peer transport (reference: the
+https options of lib/config applied to httpd and inter-node traffic)."""
 
 import json
 import ssl

@@ -6,12 +6,10 @@ the response, optionally paces to a target QPS, repeats), recording
 per-class latency histograms (p50/p95/p99), shed counts (HTTP 429/503
 from the resource governor, utils/governor.py), and error counts.
 
-Used three ways:
+Used two ways:
   - `tests/test_governor.py` overload soak: writers + queries against a
     tiny `OGT_MEM_BUDGET_MB` — no OOM, no deadlock, every acked write
     durable, shed requests carry Retry-After;
-  - `bench.py overload_shed` metric (32 clients vs a small budget:
-    shed rate, admitted-query p99, peak RSS vs budget);
   - standalone CLI:
       python tools/loadgen.py --host 127.0.0.1 --port 8086 --db load \
           --clients 32 --duration 10 --write-frac 0.6
@@ -62,45 +60,6 @@ def _lat_summary(lat_s: list[float]) -> dict:
         "p99_ms": round(percentile(vals, 99) * 1000, 3),
         "max_ms": round((vals[-1] if vals else 0.0) * 1000, 3),
     }
-
-
-class RssSampler:
-    """Peak-RSS sampler of THIS process while the load runs (the bench
-    embeds the server in-process, so its peak is the server's peak)."""
-
-    def __init__(self, interval_s: float = 0.05):
-        self.interval_s = interval_s
-        self.peak_mb = 0.0
-        self._stop = threading.Event()
-        self._thread: threading.Thread | None = None
-
-    @staticmethod
-    def rss_mb() -> float:
-        try:
-            with open("/proc/self/statm", encoding="ascii") as f:
-                pages = int(f.read().split()[1])
-            import os
-
-            return pages * os.sysconf("SC_PAGE_SIZE") / (1 << 20)
-        except (OSError, ValueError, IndexError):  # pragma: no cover
-            return 0.0
-
-    def start(self) -> "RssSampler":
-        def run():
-            while not self._stop.wait(self.interval_s):
-                self.peak_mb = max(self.peak_mb, self.rss_mb())
-
-        self.peak_mb = self.rss_mb()
-        self._thread = threading.Thread(target=run, daemon=True,
-                                        name="loadgen-rss")
-        self._thread.start()
-        return self
-
-    def stop(self) -> float:
-        self._stop.set()
-        if self._thread is not None:
-            self._thread.join(timeout=2)
-        return self.peak_mb
 
 
 class _AckLog:
@@ -642,7 +601,7 @@ def run_mixed_shapes(host: str, port: int, clients: int = 6,
     derives from `seed`, so two runs against identically-seeded engines
     return bit-identical bodies — `fingerprints` (sha256 per distinct
     query, issued once single-threaded after the fleet) is the equality
-    contract bench.py's offload_planner legs assert on.  Reports
+    contract between runs under different forced routes.  Reports
     per-class (tiny/heavy) p50/p99 and the planner's route/decision
     counter deltas scraped from /debug/device."""
     import hashlib
@@ -698,8 +657,8 @@ def run_mixed_shapes(host: str, port: int, clients: int = 6,
     # heavy scans: a few distinct full-span dashboard panels, each
     # re-issued round-robin.  SAME padded decode geometry across
     # variants (constant width + window count + series set -> one
-    # device compile covers all); the result cache is off in the bench
-    # legs, so every issue re-executes — on the host route that is a
+    # device compile covers all); with OGT_RESULT_CACHE=0 every
+    # issue re-executes — on the host route that is a
     # full decode+scatter per repeat, while the device route's decoded
     # grid stays RESIDENT in the colcache device tier and warm repeats
     # skip the decode entirely.  Residency, not raw decode speed, is

@@ -146,9 +146,8 @@ def _rel(path: str, root: str) -> str:
 
 
 def _iter_py_files(root: str):
-    """Product + tools + bench.py — tests are consumers of these
-    invariants, not subjects (they construct raw locks and fake knobs
-    freely)."""
+    """Product + tools — tests are consumers of these invariants, not
+    subjects (they construct raw locks and fake knobs freely)."""
     roots = [os.path.join(root, "opengemini_tpu"),
              os.path.join(root, "tools")]
     for r in roots:
@@ -157,9 +156,6 @@ def _iter_py_files(root: str):
             for f in sorted(files):
                 if f.endswith(".py"):
                     yield os.path.join(dirpath, f)
-    bench = os.path.join(root, "bench.py")
-    if os.path.exists(bench):
-        yield bench
 
 
 def _suppressed(lines: list[str], lineno: int, rule: str) -> bool:
