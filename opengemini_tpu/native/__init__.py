@@ -5,7 +5,8 @@ The reference uses cgo for its native pieces (textindex, lz4, rocksdb);
 pybind11 isn't in this image, so the bridge is a plain C ABI + ctypes
 (SURVEY.md environment notes).  Five libraries: codecs (bound here),
 textindex (native/textindex.py), seriesindex (index/mergeset.py),
-lineproto (ingest/native_lp.py), render (promql/render.py).
+lineproto (ingest/native_lp.py), render (promql/render.py, which also binds
+the row writer query/render.py calls).
 `.gitignore` excludes the built `.so` files, so a clean checkout has
 none: `open_library` runs the library's make target when the file is
 missing.  A library that still cannot be

@@ -35,6 +35,10 @@ def _bind(lib) -> None:
     lib.ogt_render_matrix.restype = i64
     lib.ogt_render_matrix.argtypes = [ptr, ptr, i64, ptr, i64, ptr, ptr,
                                       ptr, ptr, ptr, i64]
+    # an InfluxQL aggregate's rows (query/render.py)
+    lib.ogt_render_rows.restype = i64
+    lib.ogt_render_rows.argtypes = [i64, ptr, ptr, ptr, ptr, i64, i64, ptr,
+                                    ptr, ptr, i64, ptr, ptr, ptr, i64]
 
 
 def load():

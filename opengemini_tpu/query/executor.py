@@ -32,6 +32,7 @@ from opengemini_tpu.parallel import cluster as pcluster
 from opengemini_tpu.ops import window as winmod
 from opengemini_tpu.query import condition as cond
 from opengemini_tpu.query import functions as fnmod
+from opengemini_tpu.query import render as qrender
 from opengemini_tpu.record import (EncodedColumn, FieldType,
                                    FieldTypeConflict)
 from opengemini_tpu.sql import ast
@@ -433,11 +434,14 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
 
     def execute(
         self, text: str, db: str = "", now_ns: int | None = None,
-        read_only: bool = False, user=None,
+        read_only: bool = False, user=None, frames: bool = False,
     ) -> dict:
         """read_only=True (HTTP GET) rejects mutating statements — influx
         1.x requires POST for anything but SELECT/SHOW. `user` is the
-        authenticated user when auth is enabled (privilege checks)."""
+        authenticated user when auth is enabled (privilege checks).
+        frames=True is a caller that writes the response's bytes itself:
+        an aggregate SELECT's result may then carry `"frames"` (a list of
+        `query.render.Frame`) in place of `"series"`."""
         if now_ns is None:
             now_ns = _time.time_ns()
         try:
@@ -482,7 +486,7 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
                     trace.root.add_field("database", db)
                     TRACKER.set_trace(qid, trace)
                 return self._execute_statements(
-                    stmts, db, now_ns, read_only, user)
+                    stmts, db, now_ns, read_only, user, frames)
         finally:
             dur_ns = _time.perf_counter_ns() - t0
             if own is not None:
@@ -500,7 +504,8 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
             token.release()
 
 
-    def _execute_statements(self, stmts, db, now_ns, read_only, user) -> dict:
+    def _execute_statements(self, stmts, db, now_ns, read_only, user,
+                            frames=False) -> dict:
         results = []
         for i, stmt in enumerate(stmts):
             try:
@@ -525,7 +530,8 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
                     stmt, (ast.SelectStatement, ast.ExplainStatement)
                 ):
                     raise QueryError("reads are disabled (syscontrol)")
-                res = self.execute_statement(stmt, db, now_ns, user=user)
+                res = self.execute_statement(stmt, db, now_ns, user=user,
+                                             frames=frames)
             except (
                 QueryError, cond.ConditionError, KeyError, ValueError,
                 re.error, FieldTypeConflict, WriteError, QueryKilled,
@@ -690,7 +696,10 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
 
 
     def _select(self, stmt: ast.SelectStatement, db: str, now_ns: int,
-                trace=tracing.NOOP) -> dict:
+                trace=tracing.NOOP, frames: bool = False) -> dict:
+        """`frames`: the caller renders `qrender.Frame`s itself, so a
+        statement that is nothing but aggregates over measurements
+        answers {"frames": [...]}; every other caller reads the tree."""
         if trace is tracing.NOOP:
             # adopt the per-query tree the executor activated (OGT_TRACE);
             # EXPLAIN ANALYZE passes its own trace explicitly
@@ -733,25 +742,24 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
             outer = _copy.copy(stmt)
             outer.sources = [ast.SubQuery(inner)]
             return self._select(outer, db, now_ns, trace)
-        all_series = []
+        # a caller that renders frames gets them only for a statement
+        # nothing below post-processes as rows
+        frames = (frames and multi is None and stmt.into is None
+                  and not stmt.soffset and not stmt.slimit)
+        parts = []  # a source's series dicts, or its qrender.Frame
         for src in stmt.sources:
             if isinstance(src, ast.JoinSource):
                 from opengemini_tpu.query import join as joinmod
 
-                all_series.extend(
-                    joinmod.select_join(self, stmt, src, db, now_ns)
-                )
+                parts.append(joinmod.select_join(self, stmt, src, db, now_ns))
                 continue
             if (isinstance(src, ast.Measurement) and stmt.ctes
                     and src.name in stmt.ctes):
-                all_series.extend(
-                    self._select_cte(stmt, src, db, now_ns, trace)
-                )
+                parts.append(self._select_cte(stmt, src, db, now_ns, trace))
                 continue
             if isinstance(src, ast.SubQuery):
-                all_series.extend(
-                    self._select_from_subquery(stmt, src, db, now_ns, trace)
-                )
+                parts.append(
+                    self._select_from_subquery(stmt, src, db, now_ns, trace))
                 continue
             src_db = src.database or db
             if not src_db:
@@ -761,11 +769,16 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
             names = self._resolve_measurements(src, src_db)
             for mst in names:
                 with trace.span(f"select: {mst}"):
-                    all_series.extend(
-                        self._select_measurement(
-                            stmt, src_db, src.rp or None, mst, now_ns, trace
-                        )
-                    )
+                    parts.append(self._select_measurement(
+                        stmt, src_db, src.rp or None, mst, now_ns, trace,
+                        frames))
+        if parts and all(isinstance(p, qrender.Frame) for p in parts):
+            parts = [p for p in parts if p.keys]
+            return {"frames": parts} if parts else {}
+        all_series = []
+        for p in parts:
+            all_series.extend(
+                p.series() if isinstance(p, qrender.Frame) else p)
         if multi == "merge":
             all_series = _merge_multi_source(all_series, stmt)
         # SLIMIT/SOFFSET over series
@@ -1046,7 +1059,10 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
         return schema
 
 
-    def _select_measurement(self, stmt, db, rp, mst, now_ns, trace=tracing.NOOP) -> list[dict]:
+    def _select_measurement(self, stmt, db, rp, mst, now_ns,
+                            trace=tracing.NOOP, frames=False):
+        """The measurement's series dicts; with `frames`, a device
+        aggregate's `qrender.Frame` where it made one."""
         if _has_call_wildcard(stmt):
             stmt = _expand_call_wildcards(
                 stmt, self._measurement_schema(db, rp, mst)
@@ -1072,7 +1088,8 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
             return self._select_raw(stmt, db, rp, mst, now_ns)
         if kind == "device":
             return self._select_agg(
-                stmt, db, rp, mst, now_ns, _collect_calls(stmt.fields), trace
+                stmt, db, rp, mst, now_ns, _collect_calls(stmt.fields), trace,
+                frames,
             )
         return self._select_host(stmt, db, rp, mst, now_ns)
 
@@ -1249,7 +1266,8 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
     # -- aggregate path -----------------------------------------------------
 
 
-    def _select_agg(self, stmt, db, rp, mst, now_ns, calls, trace=tracing.NOOP) -> list[dict]:
+    def _select_agg(self, stmt, db, rp, mst, now_ns, calls,
+                    trace=tracing.NOOP, frames=False):
         from opengemini_tpu.query import partials as pmod
 
         # resolve agg specs + fields (before planning: the set decides
@@ -1276,13 +1294,13 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
         for attempt in range(attempts):
             try:
                 return self._select_agg_run(
-                    stmt, db, rp, mst, now_ns, aggs, pushdown, trace
+                    stmt, db, rp, mst, now_ns, aggs, pushdown, trace, frames
                 )
             except pcluster.PartialsUnavailable:
                 # a live peer cannot serve partials (e.g. rolling
                 # upgrade): the raw column exchange still works
                 return self._select_agg_run(
-                    stmt, db, rp, mst, now_ns, aggs, False, trace
+                    stmt, db, rp, mst, now_ns, aggs, False, trace, frames
                 )
             except pcluster.PartialsRetry as e:
                 # a peer died mid-query: primary ownership shifted, the
@@ -1293,7 +1311,7 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
 
 
     def _select_agg_run(self, stmt, db, rp, mst, now_ns, aggs, pushdown,
-                        trace=tracing.NOOP) -> list[dict]:
+                        trace=tracing.NOOP, frames=False):
         from opengemini_tpu.query import partials as pmod
 
         with trace.span("map_shards") as sp:
@@ -1748,10 +1766,13 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
             with trace.span("inc_cache"):
                 group_keys = cache_plan.merge(agg_results, aggs, group_keys)
         with trace.span("render"):
-            return self._render_agg(
+            answer = self._render_agg(
                 stmt, mst, group_tags, group_keys, aligned, W, agg_results,
-                batches, schema, tmin,
+                batches, schema,
             )
+            if isinstance(answer, qrender.Frame) and not frames:
+                return answer.series()
+            return answer
 
 
     def _scan_monolithic(
@@ -1993,92 +2014,48 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
 
     def _render_agg(
         self, stmt, mst, group_tags, group_keys, aligned, W, agg_results,
-        batches, schema, tmin,
-    ) -> list[dict]:
-        group_time = stmt.group_by_time
-        every = group_time.every_ns if group_time else 0
-
-        columns = ["time"]
-        col_exprs = []
-        used_names: dict[str, int] = {}
-        for f in stmt.fields:
-            e = _strip_expr(f.expr)
-            if isinstance(e, ast.VarRef) and e.name.lower() == "time":
-                continue  # explicit `time` is always column 0
-            name = f.alias or _default_field_name(f.expr)
-            k = used_names.get(name, 0)
-            used_names[name] = k + 1
-            if k:
-                name = f"{name}_{k}"
-            columns.append(name)
-            col_exprs.append(f.expr)
-
+        batches, schema,
+    ):
+        """The reduce's arrays to the answer: a `qrender.Frame` (columns
+        evaluated and filled as arrays; its reader makes the tree or the
+        response's bytes of it), or the list of series dicts where the
+        shape needs the per-row walker."""
+        columns, col_exprs = _output_columns(stmt)
         # selector fast path: a single selector call (bare, or wrapped in
         # scalar math like `max(rx) * 1`), no GROUP BY time -> result time
         # is the selected point's own timestamp (reference
         # TestServer_Query_Aggregates_Math#2)
         single_selector = None
-        if not group_time and len(col_exprs) == 1:
+        if not stmt.group_by_time and len(col_exprs) == 1:
             calls = _calls_in(col_exprs[0])
             if len(calls) == 1:
                 entry = agg_results.get(id(calls[0]))
                 if entry and entry[3].is_selector:
                     single_selector = entry
 
+        cells = len(group_keys) * W * len(col_exprs)
+        frame = None
+        if single_selector is None:
+            # every row's time is its window's: one array a column
+            try:
+                frame = qrender.build_frame(
+                    stmt, mst, columns, col_exprs, group_tags, group_keys,
+                    aligned, W, agg_results, schema)
+            except qrender.NotColumnar:
+                pass
+        STATS.add("query", (("render_cells", cells),
+                            ("render_bulk_cells",
+                             0 if frame is None else cells)))
+        if frame is not None:
+            return frame
         host_times = (
             batches[single_selector[4]].host_times()
             if single_selector is not None and single_selector[5] is None
             else None
         )
-        out_series = []
-        order = sorted(range(len(group_keys)), key=lambda g: group_keys[g])
-        for g in order:
-            key = group_keys[g]
-            rows = []
-            for w in range(W):
-                seg = g * W + w
-                t_out = aligned + w * every if group_time else (aligned if aligned else 0)
-                vals = []
-                any_present = False
-                for expr in col_exprs:
-                    v, present = _eval_output_expr(expr, agg_results, seg, schema)
-                    any_present = any_present or present
-                    vals.append(v)
-                if single_selector is not None:
-                    out, sel, counts, spec, fname, times_abs = single_selector
-                    if counts[seg] > 0:
-                        t_out = (
-                            int(times_abs[seg]) if times_abs is not None
-                            else int(host_times[sel[seg]])
-                        )
-                rows.append((t_out, vals, any_present))
-            if not any(p for _t, _v, p in rows):
-                # zero matching points in the whole range: no series at
-                # all, regardless of fill (TestServer_Query_Fill#2)
-                continue
-            count_idx = tuple(
-                i for i, e in enumerate(col_exprs)
-                if isinstance(_strip_expr(e), ast.Call)
-                and _strip_expr(e).name in ("count", "count_distinct")
-            )
-            rows = _apply_fill(rows, stmt, columns, count_idx)
-            if not stmt.ascending:
-                rows.reverse()
-            if stmt.offset:
-                rows = rows[stmt.offset :]
-            if stmt.limit:
-                rows = rows[: stmt.limit]
-            if not rows:
-                continue
-            series = {
-                "name": mst,
-                "columns": columns,
-                "values": [[t] + v for t, v, _p in rows],
-            }
-            if group_tags:
-                series["tags"] = dict(zip(group_tags, key))
-            out_series.append(series)
-        return out_series
+        return _render_agg_rows(
+            stmt, mst, columns, col_exprs, group_tags, group_keys, aligned,
+            W, agg_results, schema, single_selector, host_times)
 
     # -- percentile_approx (chunk-histogram sketches) ------------------------
 

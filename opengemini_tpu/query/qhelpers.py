@@ -867,6 +867,87 @@ def _default_field_name(e) -> str:
 
 
 
+def _output_columns(stmt):
+    """-> (column names with "time" first, the expressions of the rest)."""
+    columns = ["time"]
+    col_exprs = []
+    used_names: dict[str, int] = {}
+    for f in stmt.fields:
+        e = _strip_expr(f.expr)
+        if isinstance(e, ast.VarRef) and e.name.lower() == "time":
+            continue  # explicit `time` is always column 0
+        name = f.alias or _default_field_name(f.expr)
+        k = used_names.get(name, 0)
+        used_names[name] = k + 1
+        if k:
+            name = f"{name}_{k}"
+        columns.append(name)
+        col_exprs.append(f.expr)
+    return columns, col_exprs
+
+
+
+def _render_agg_rows(stmt, mst, columns, col_exprs, group_tags, group_keys,
+                     aligned, W, agg_results, schema, single_selector=None,
+                     host_times=None) -> list[dict]:
+    """An aggregate answer one row at a time, for what query/render.py
+    does not express as arrays: a row whose time is the selected point's
+    own (`single_selector`, its agg_results entry; `host_times` where the
+    entry carries no absolute times), and arithmetic that needs Python's
+    unbounded integers.  Also the reference the array path is tested
+    against (tests/test_influx_render.py)."""
+    group_time = stmt.group_by_time
+    every = group_time.every_ns if group_time else 0
+    count_idx = tuple(
+        i for i, e in enumerate(col_exprs)
+        if isinstance(_strip_expr(e), ast.Call)
+        and _strip_expr(e).name in ("count", "count_distinct")
+    )
+    out_series = []
+    for g in sorted(range(len(group_keys)), key=lambda g: group_keys[g]):
+        rows = []
+        for w in range(W):
+            seg = g * W + w
+            t_out = aligned + w * every if group_time else (aligned if aligned else 0)
+            vals = []
+            any_present = False
+            for expr in col_exprs:
+                v, present = _eval_output_expr(expr, agg_results, seg, schema)
+                any_present = any_present or present
+                vals.append(v)
+            if single_selector is not None:
+                _out, sel, counts, _spec, _fname, times_abs = single_selector
+                if counts[seg] > 0:
+                    t_out = (
+                        int(times_abs[seg]) if times_abs is not None
+                        else int(host_times[sel[seg]])
+                    )
+            rows.append((t_out, vals, any_present))
+        if not any(p for _t, _v, p in rows):
+            # zero matching points in the whole range: no series at
+            # all, regardless of fill (TestServer_Query_Fill#2)
+            continue
+        rows = _apply_fill(rows, stmt, columns, count_idx)
+        if not stmt.ascending:
+            rows.reverse()
+        if stmt.offset:
+            rows = rows[stmt.offset :]
+        if stmt.limit:
+            rows = rows[: stmt.limit]
+        if not rows:
+            continue
+        series = {
+            "name": mst,
+            "columns": columns,
+            "values": [[t] + v for t, v, _p in rows],
+        }
+        if group_tags:
+            series["tags"] = dict(zip(group_tags, group_keys[g]))
+        out_series.append(series)
+    return out_series
+
+
+
 def _eval_output_expr(expr, agg_results, seg, schema):
     """Evaluate one output column at segment `seg`. Returns (value, present)."""
     expr = _strip_expr(expr)
@@ -1071,7 +1152,8 @@ __all__ = [
     "_resolve_call",
     "_call_field",
     "_default_field_name",
-    "_eval_output_expr",
+    "_output_columns",
+    "_render_agg_rows",
     "_apply_fill",
     "_linear_fill",
     "_pyval",

@@ -233,10 +233,13 @@ class ShowDdlMixin:
     # -- entry --------------------------------------------------------------
 
 
-    def execute_statement(self, stmt, db: str, now_ns: int, user=None) -> dict:
+    def execute_statement(self, stmt, db: str, now_ns: int, user=None,
+                          frames: bool = False) -> dict:
         if isinstance(stmt, ast.SelectStatement):
             STATS.incr("executor", "selects")
-            res = self._select(stmt, db, now_ns)
+            res = self._select(stmt, db, now_ns, frames=frames)
+            if not stmt.ascending and res.get("frames"):
+                res = {"frames": [f.reversed() for f in reversed(res["frames"])]}
             if not stmt.ascending and res.get("series"):
                 # ORDER BY time DESC reverses the SERIES order too
                 # (reference: Null_Aggregate desc cases expect the

@@ -201,6 +201,17 @@ def format_result(result: dict, epoch: str | None) -> dict:
     return result
 
 
+def _dumps(obj, indent: int | None = None) -> str:
+    """Strict JSON: a stray non-finite float anywhere in a result must
+    not serialize as a bare NaN/Infinity literal (unparseable by standard
+    clients).  allow_nan=False makes the common all-finite case zero-cost;
+    only offending payloads pay for the sanitize walk."""
+    try:
+        return json.dumps(obj, indent=indent, allow_nan=False)
+    except ValueError:
+        return json.dumps(_null_nonfinite(obj), indent=indent)
+
+
 def _null_nonfinite(obj):
     """Deep-copy with non-finite floats replaced by None (influx marshals
     null). Only runs when a payload actually contains one."""
@@ -374,19 +385,7 @@ def _make_handler(svc: HttpService):
             self._extra_headers = headers
             indent = 4 if pretty else None
             with tracing.span("serialize"):
-                try:
-                    # strict JSON: a stray non-finite float anywhere in a
-                    # result must not serialize as a bare NaN/Infinity
-                    # literal (unparseable by standard clients).
-                    # allow_nan=False makes the common all-finite case
-                    # zero-cost; only offending payloads pay for the
-                    # sanitize walk.
-                    data = json.dumps(obj, indent=indent,
-                                      allow_nan=False) + "\n"
-                except ValueError:
-                    data = json.dumps(_null_nonfinite(obj),
-                                      indent=indent) + "\n"
-                payload = data.encode("utf-8")
+                payload = (_dumps(obj, indent) + "\n").encode("utf-8")
             self._send(code, payload)
 
         def _authenticate(self, params: dict):
@@ -1592,9 +1591,15 @@ def _make_handler(svc: HttpService):
                 return
             from opengemini_tpu.meta.users import AuthError
 
+            epoch = params.get("epoch")
+            pretty = params.get("pretty") in ("true", "1")
+            chunked = params.get("chunked") in ("true", "1")
             try:
+                # a plain response is written from an aggregate's arrays
+                # (_send_results); chunked and pretty ones read the tree
                 result = svc.executor.execute(
-                    q, db=params.get("db", ""), read_only=read_only, user=user
+                    q, db=params.get("db", ""), read_only=read_only,
+                    user=user, frames=not (chunked or pretty),
                 )
             except AuthError as e:
                 self._send_err(403, e)
@@ -1607,11 +1612,9 @@ def _make_handler(svc: HttpService):
                     503, {"error": str(e)},
                     headers={"Retry-After": str(e.retry_after_s)})
                 return
-            epoch = params.get("epoch")
-            pretty = params.get("pretty") in ("true", "1")
             with tracing.span("format"):
                 result = format_result(result, epoch)
-            if params.get("chunked") in ("true", "1"):
+            if chunked:
                 try:
                     chunk_size = max(1, int(params.get("chunk_size", 10_000)))
                 except ValueError:
@@ -1620,7 +1623,34 @@ def _make_handler(svc: HttpService):
                 with tracing.span("send", chunked=True):
                     self._send_chunked(result, chunk_size)
                 return
-            self._send_json(200, result, pretty)
+            if pretty:
+                self._send_json(200, result, pretty)
+            else:
+                self._send_results(result, epoch)
+
+        def _send_results(self, result: dict, epoch: str | None):
+            """`_send_json(200, result)`, byte for byte, statement by
+            statement: a statement that carries `"frames"` has its
+            `"series"` written from their arrays (query/render.py), the
+            others are dumped."""
+            from opengemini_tpu.query import render as qrender
+
+            div = _EPOCH_DIV.get(epoch, 1) if epoch else None
+            with tracing.span("serialize"):
+                stmts = []
+                for res in result["results"]:
+                    members = []
+                    for key, val in res.items():
+                        if key == "frames":
+                            key, val = "series", b"[%s]" % b", ".join(
+                                qrender.rows_json(f, div) for f in val)
+                        else:
+                            val = _dumps(val).encode("utf-8")
+                        members.append(
+                            b"%s: %s" % (json.dumps(key).encode(), val))
+                    stmts.append(b"{%s}" % b", ".join(members))
+                payload = b'{"results": [' + b", ".join(stmts) + b"]}\n"
+            self._send(200, payload)
 
         def _send_chunked(self, result: dict, chunk_size: int):
             """Influx chunked responses: newline-delimited JSON documents

@@ -546,3 +546,77 @@ class TestErrnoTaxonomy:
         doc = json.loads(body)
         assert doc["errno"] == errno.WRITE_DB_NOT_FOUND
         assert doc["module"] == "write"
+
+
+# -- an aggregate answer written from its arrays (query/render.py) -----------
+
+_FLEET = ("SELECT mean(a), mean(b), max(n), count(up), first(up) FROM cpu "
+          f"WHERE time >= {BASE}s AND time < {BASE + 3600}s "
+          "GROUP BY time(5m), hostname")
+_BODIES = [
+    _FLEET,
+    _FLEET + " fill(none) ORDER BY time DESC LIMIT 5",
+    _FLEET.replace("mean(a)", 'mean(a) / mean(b) AS "r\\"atio"') + " fill(0)",
+    f"SELECT mean(a), sum(n) FROM cpu WHERE time >= {BASE}s GROUP BY dc",
+    _FLEET + "; SHOW MEASUREMENTS; SELECT a FROM cpu LIMIT 2; "
+    "SELECT mean(a) FROM nothing GROUP BY time(1m); SELECT nope(a) FROM cpu",
+    f"SELECT max(a) FROM cpu WHERE time >= {BASE}s GROUP BY hostname",
+]
+
+
+@pytest.fixture
+def fleet(server):
+    lines = "\n".join(
+        f'cpu,hostname=host_{h},dc=d\\ é{h % 3} a={(h * 31 + k) % 17 / 7},'
+        f"b={(h + k) % 5},n={h * 1000 + k}i,up={'tf'[(h + k) % 2]} "
+        f"{(BASE + k * 47) * NS}"
+        for h in range(9) for k in range(70) if not (h == 4 and 20 < k < 50))
+    assert post(server, "/write", lines.encode(), db="db")[0] == 204
+    return server
+
+
+@pytest.mark.parametrize("epoch", [None, "ns", "ms", "s"])
+@pytest.mark.parametrize("q", _BODIES, ids=[
+    "fleet", "none-desc-limit", "ratio-fill0", "no-group-time", "statements",
+    "single-selector"])
+def test_query_body_is_what_dumping_the_tree_writes(fleet, q, epoch):
+    """A plain /query response is assembled from frames; it is byte for
+    byte `json.dumps` of the tree every other reader gets."""
+    from opengemini_tpu.server.http import format_result
+
+    params = {"epoch": epoch} if epoch else {}
+    status, body = get(fleet, "/query", db="db", q=q, **params)
+    assert status == 200
+    tree = fleet.executor.execute(q, db="db", read_only=True)
+    assert body == (json.dumps(format_result(tree, epoch),
+                               allow_nan=False) + "\n").encode()
+    n_series = len(json.loads(body)["results"][0].get("series", []))
+    assert n_series >= 3
+
+
+def test_chunked_and_pretty_answer_from_the_tree(fleet):
+    from opengemini_tpu.server.http import format_result
+    from opengemini_tpu.utils.stats import GLOBAL as STATS
+
+    tree = format_result(fleet.executor.execute(_FLEET, db="db"), "ns")
+    before = STATS.counters("query")
+    status, body = get(fleet, "/query", db="db", q=_FLEET, epoch="ns",
+                       pretty="true")
+    assert status == 200 and body == (json.dumps(tree, indent=4) + "\n").encode()
+    status, body = get(fleet, "/query", db="db", q=_FLEET, epoch="ns",
+                       chunked="true", chunk_size=7)
+    docs = [json.loads(line) for line in body.splitlines()]
+    rows = {}
+    for doc in docs:
+        for s in doc["results"][0]["series"]:
+            rows.setdefault(s["tags"]["hostname"], []).extend(s["values"])
+    assert status == 200 and len(docs) > 9
+    assert rows == {s["tags"]["hostname"]: s["values"]
+                    for s in tree["results"][0]["series"]}
+    now = STATS.counters("query")
+    # both were evaluated as arrays; neither was written from them
+    cells = 5 * sum(len(s["values"]) for s in tree["results"][0]["series"])
+    assert now["render_bulk_cells"] - before["render_bulk_cells"] == \
+        now["render_cells"] - before["render_cells"] == 2 * cells > 0
+    assert now.get("render_native_cells", 0) == \
+        before.get("render_native_cells", 0)

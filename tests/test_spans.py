@@ -483,3 +483,40 @@ def test_h2d_bytes_count_the_host_arrays_of_a_launch():
     out2, _sel, _counts = again.run(aggmod.get("mean"), 40)
     assert _counters("device")["h2d_bytes_total"] == h1
     np.testing.assert_allclose(out2, out)
+
+
+def test_an_aggregate_answer_counts_its_cells_once(server):
+    """query/render_cells, render_bulk_cells, render_native_cells: one
+    update a statement, equal for the fleet statement's shape, and the
+    stages of a response written from arrays keep their names."""
+    from opengemini_tpu.query import render as qrender
+
+    port = server.port
+    lines = "\n".join(
+        f"cpu,hostname=host_{h} " + ",".join(
+            f"usage_{f}={(h * 7 + k + ord(f)) % 11 / 3}" for f in "abcde")
+        + f" {(BASE + k * 10) * NS}" for h in range(25) for k in range(360))
+    assert _http(port, "POST", "/write", lines.encode(), db="db")[0] == 204
+    q = ("SELECT " + ", ".join(f"mean(usage_{f})" for f in "abcde")
+         + f" FROM cpu WHERE time >= {BASE * NS} AND time < "
+         f"{(BASE + 3600) * NS} GROUP BY time(5m), hostname")
+    before, stages = _counters("query"), _counters("query_stages")
+    status, body = _http(port, "GET", "/query", db="db", q=q, epoch="ns")
+    series = json.loads(body)["results"][0]["series"]
+    cells = sum(len(s["values"]) for s in series) * 5
+    assert status == 200 and len(series) == 25 and cells >= 25 * 12 * 5
+    moved = _delta("query", before)
+    assert moved["render_cells"] == moved["render_bulk_cells"] == cells
+    assert moved.get("render_native_cells", 0) == (
+        cells if qrender._native.load() is not None else 0)
+    deadline = time.monotonic() + 10.0
+    while (_delta("query_stages", stages).get("send_count") != 1
+           and time.monotonic() < deadline):
+        time.sleep(0.01)
+    d = _delta("query_stages", stages)
+    for name in ("render", "format", "serialize", "send"):
+        assert d[name + "_count"] == 1 and d[name + "_ns"] > 0, name
+    # a raw select renders no aggregate cell
+    assert _http(port, "GET", "/query", db="db",
+                 q="SELECT usage_a FROM cpu LIMIT 3")[0] == 200
+    assert _delta("query", before) == moved
