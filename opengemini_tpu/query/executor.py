@@ -1809,6 +1809,7 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
         # for all their series, with no per-sid Python loop: config #5
         # of BASELINE.md scans 1M series)
         remaining_plan = scan_plan
+        mem_kw: dict[int, dict] = {}    # read_series' `mem`, by shard
         if not pre_eligible:
             by_shard: dict[int, tuple] = {}
             for sh, sid, gid in scan_plan:
@@ -1819,6 +1820,14 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
                 if len(pairs) < 64 or not hasattr(sh, "read_series_bulk"):
                     remaining_plan.extend(
                         (sh, sid, gid) for sid, gid in pairs)
+                    # rows not yet flushed: taken once a shard for all its
+                    # series of the tail (span `mem_read`), not once a
+                    # series and a scan range; a proxy has no such view
+                    view = sh.mem_view(
+                        mst, [sid for sid, _gid in pairs], read_fields) \
+                        if hasattr(sh, "mem_view") else None
+                    if view is not None:
+                        mem_kw[id(sh)] = {"mem": view}
                     continue
                 sid_list = np.asarray([p[0] for p in pairs], np.int64)
                 gid_list = np.asarray([p[1] for p in pairs], np.int64)
@@ -1871,8 +1880,8 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
                     rows_scanned += got_rows
                     continue
             for rlo, rhi in scan_ranges:
-                rec = sh.read_series(mst, sid, rlo, rhi,
-                                     fields=read_fields)
+                rec = sh.read_series(mst, sid, rlo, rhi, fields=read_fields,
+                                     **mem_kw.get(id(sh), {}))
                 if len(rec) == 0:
                     continue
                 rows_scanned += len(rec)

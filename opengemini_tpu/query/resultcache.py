@@ -62,6 +62,7 @@ class IncrementalCache:
 
     def update(self, fp: str, windows: dict) -> None:
         """Merge freshly-computed windows into the fingerprint's entry."""
+        evicted = 0     # windows trimmed + fingerprints dropped whole
         with self._lock:
             entry = self._store.get(fp)
             if entry is None:
@@ -71,8 +72,12 @@ class IncrementalCache:
             if len(entry) > self.max_windows:
                 for ws in sorted(entry)[: len(entry) - self.max_windows]:
                     del entry[ws]
+                    evicted += 1
             while len(self._store) > self.max_queries:
                 self._store.popitem(last=False)
+                evicted += 1
+        if evicted:
+            STATS.incr("executor", "inc_cache_evictions", evicted)
 
     def clear(self) -> None:
         with self._lock:
@@ -153,18 +158,32 @@ class CachePlan:
         held = cache.lookup(fp)
         self.cached = held
         by_path = {sh.path: sh for sh in shards}
+        # why a window is recomputed: cut by the range, never cached (or
+        # evicted), or touched (a mutation newer than the cached version
+        # overlaps it, the shard set changed, or the mutation log was
+        # truncated past it); asked = reused + cut + absent + touched
+        cut = absent = touched = 0
         stale = []
         for w in range(W):
             got = held.get(self.wstarts[w])
-            if w in self.partial or got is None or not window_fresh(
-                got[0], by_path, self.wstarts[w],
-                self.wstarts[w] + every_ns,
-            ):
-                stale.append(w)
+            if w in self.partial:
+                cut += 1
+            elif got is None:
+                absent += 1
+            elif not window_fresh(got[0], by_path, self.wstarts[w],
+                                  self.wstarts[w] + every_ns):
+                touched += 1
+            else:
+                continue
+            stale.append(w)
         self.stale = set(stale)
-        STATS.incr("executor", "inc_cache_windows_reused", W - len(stale))
-        if not stale:
-            STATS.incr("executor", "inc_cache_full_hits")
+        STATS.add("executor", (
+            ("inc_cache_windows_asked", W),
+            ("inc_cache_windows_reused", W - len(stale)),
+            ("inc_cache_windows_cut", cut),
+            ("inc_cache_windows_absent", absent),
+            ("inc_cache_windows_touched", touched),
+            ("inc_cache_full_hits", int(not stale))))
 
     @property
     def scan_ranges(self):

@@ -230,6 +230,14 @@ class MemTable:
 
     # -- read side ----------------------------------------------------------
 
+    def holds(self, measurement: str) -> bool:
+        """Does a row of the measurement live here?  What a read asks
+        before it opens a span for its in-memory parts: no slab, no
+        builder of that measurement, nothing to read."""
+        return bool(self._slabs.get(measurement)) or any(
+            len(b) and self._sid_mst.get(sid) == measurement
+            for sid, b in self._builders.items())
+
     def sids_for(self, measurement: str) -> set[int]:
         """Live series ids of one measurement — O(series), no record
         builds (hot-path pruning uses this, not series_records)."""
@@ -270,14 +278,19 @@ class MemTable:
                    sids: np.ndarray | None = None) -> list:
         """[(sid_arr, Record)] parts for a bulk read, oldest first (slab
         consolidation first, builder rows after — builders are newer by
-        the freeze rule). `sids` (sorted int64) filters rows."""
+        the freeze rule). `sids` (int64) filters rows."""
         parts = []
         if self._slabs.get(measurement):
             sid_arr, rec = self._consolidate(measurement)
             if sids is not None and len(sid_arr):
-                mask = np.isin(sid_arr, sids)
-                if not mask.all():
-                    idx = np.flatnonzero(mask)
+                # rows are sorted by sid: a wanted sid is one run of them
+                # (a panel's 8 of 4,000 hosts: no pass over every row)
+                want = np.unique(sids)
+                lo = np.searchsorted(sid_arr, want, "left")
+                n = np.searchsorted(sid_arr, want, "right") - lo
+                if int(n.sum()) != len(sid_arr):
+                    idx = np.repeat(lo - (np.cumsum(n) - n), n) \
+                        + np.arange(int(n.sum()))
                     sid_arr = sid_arr[idx]
                     rec = rec.take(idx)
             if len(rec):

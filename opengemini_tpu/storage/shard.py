@@ -8,6 +8,7 @@ Compact :688, commitSnapshot :1008) and the per-shard WAL replay
 
 from __future__ import annotations
 
+import contextlib
 import os
 import itertools
 import threading
@@ -22,7 +23,7 @@ from opengemini_tpu.record import (
     merge_sorted_records, _zeroed as _rec_zeroed,
 )
 from opengemini_tpu.storage import colcache, scanpool
-from opengemini_tpu.storage.memtable import MemTable
+from opengemini_tpu.storage.memtable import MemTable, _series_slice
 from opengemini_tpu.storage.tsf import (
     PACK_MIN_SERIES, PACK_ROWS, CorruptFile, TSFReader, TSFWriter,
 )
@@ -78,8 +79,6 @@ def _sid_entries(rec: Record, uniq, starts, ends):
     table — the flush path's bridge from memtable tables to chunk writes.
     Column slicing + all-invalid drop shares memtable._series_slice so the
     per-series shape (and content_digest) can never diverge by path."""
-    from opengemini_tpu.storage.memtable import _series_slice
-
     for sid, lo, hi in zip(uniq, starts, ends):
         yield int(sid), _series_slice(rec, lo, hi)
 
@@ -1392,6 +1391,42 @@ class Shard:
             return None
         return recs[0] if len(recs) == 1 else merge_sorted_records(recs)
 
+    def _mem_rows(self, mems: list, measurement: str, sids: np.ndarray,
+                  fields: list[str] | None) -> list:
+        """[(sid_arr, Record)] of `sids` (int64) out of `mems`, oldest
+        first, cut to `fields`: the in-memory parts of one shard
+        read.  The caller holds the `mem_read` span; the rows and parts
+        taken, after the sid filter and before any range cut, are counted
+        here, once."""
+        parts = []
+        for m in mems:  # frozen snapshots oldest first, live memtable last
+            for sid_arr, rec in m.bulk_parts(measurement, sids):
+                if fields is not None:
+                    rec = Record(rec.times, {k: v for k, v in
+                                             rec.columns.items()
+                                             if k in fields})
+                parts.append((sid_arr, rec))
+        _STATS.add("scan", (("mem_rows", sum(len(r) for _s, r in parts)),
+                            ("mem_parts", len(parts))))
+        return parts
+
+    def mem_view(self, measurement: str, sids,
+                 fields: list[str] | None = None) -> list | None:
+        """The in-memory rows of `sids`, taken ONCE for a statement's
+        per-series reads of this shard (`read_series(..., mem=view)`): one
+        `mem_read` span and one consolidation lookup a shard, where
+        `record_for` inside `read_series` would be one a series and a scan
+        range.  None where no in-memory part holds a row of the
+        measurement: such a read opens no span and pays nothing.  The view
+        is older than the files `read_series` then lists, so a flush in
+        between leaves its rows in both, never in neither."""
+        mems = [m for m in self._mem_parts() if m.holds(measurement)]
+        if not mems:
+            return None
+        with tracing.span("mem_read", series=len(sids)):
+            return self._mem_rows(mems, measurement,
+                                  np.asarray(sids, np.int64), fields)
+
     def mem_sids_for(self, measurement: str) -> set[int]:
         out: set[int] = set()
         for m in self._mem_parts():
@@ -1504,9 +1539,12 @@ class Shard:
         tmin: int | None = None,
         tmax: int | None = None,
         fields: list[str] | None = None,
+        mem: list | None = None,
     ) -> Record:
         """Merged view of one series: immutable chunks (oldest first) +
-        memtable last, deduped last-wins, then time-sliced. Multi-chunk
+        memtable last, deduped last-wins, then time-sliced.  `mem`: this
+        shard's `mem_view` of the series (and `fields`), in place of a
+        look into every in-memory part here.  Multi-chunk
         decodes fan out across the scan pool (storage/scanpool.py) in
         file order; KILL QUERY still interrupts mid-series — the pool's
         ordered yield re-checks the tracker per chunk exactly like the
@@ -1563,6 +1601,13 @@ class Shard:
             self.note_corrupt(e)
         # frozen flush snapshots (oldest first) then the live memtable:
         # both are newer than every file, live is newest of all
+        if mem is not None:
+            mems = ()
+            for sid_arr, part in mem:       # each sorted by (sid, time)
+                lo = int(np.searchsorted(sid_arr, sid, "left"))
+                hi = int(np.searchsorted(sid_arr, sid, "right"))
+                if lo < hi:
+                    recs.append(_series_slice(part, lo, hi))
         for m in mems:
             mem_rec = m.record_for(sid)
             if mem_rec is None:
@@ -1671,17 +1716,17 @@ class Shard:
                 except CorruptFile as e:
                     self.note_corrupt(e)  # see read_series
         parts.extend(p for p in slots if p is not None)
-        for m in mems:  # frozen snapshots oldest first, live memtable last
-            for sid_arr, mem_rec in m.bulk_parts(measurement, sids):
-                if fields is not None:
-                    mem_rec = Record(
-                        mem_rec.times,
-                        {k: v for k, v in mem_rec.columns.items()
-                         if k in fields},
-                    )
-                parts.append((sid_arr, mem_rec))
-        if not jobs:    # every chunk came from the cache: nothing is added
-            return _merge_bulk_parts(parts, lo_t, hi_t)
+        mems = [m for m in mems if m.holds(measurement)]
+        with contextlib.ExitStack() as stack:
+            if mems:
+                # rows not yet flushed: one span a bulk read, over taking
+                # them and, where every chunk came from the cache (no
+                # `scan_merge`), over merging them with the files' parts
+                stack.enter_context(
+                    tracing.span("mem_read", series=len(sids)))
+                parts.extend(self._mem_rows(mems, measurement, sids, fields))
+            if not jobs:    # every chunk came from the cache
+                return _merge_bulk_parts(parts, lo_t, hi_t)
         # a read that decoded says what became of it: a chunk decodes
         # whole, the merge keeps the rows inside [tmin, tmax)
         with tracing.span("scan_merge", parts=len(parts)):

@@ -31,10 +31,11 @@ DAY = 86_400
 MISS_SPANS = {"decode", "pool_wait", "block_read", "codec", "colcache_fill",
               "scan_merge"}
 QUERY_SPANS = {"sql_parse", "select: cpu", "map_shards", "scan", *MISS_SPANS,
-               "colcache", "device_compute", "layout_build",
+               "mem_read", "colcache", "device_compute", "layout_build",
                "device_launch", "device_fetch", "host_combine", "inc_cache",
                "render", "format", "serialize", "send"}
-PROM_SPANS = {"prom_parse", "prom_collect", *MISS_SPANS, "prom_prepare",
+PROM_SPANS = {"prom_parse", "prom_collect", *MISS_SPANS, "mem_read",
+              "prom_prepare",
               "prom_kernel", "device_launch", "device_fetch", "prom_render",
               "serialize", "send"}
 WRITE_SPANS = {"read_body", "lp_parse", "type_check", "write_hooks",
@@ -237,6 +238,36 @@ def test_a_query_leaves_a_tree(server):
     assert fields["status"] == 200 and fields["bytes_out"] == len(body)
 
 
+@pytest.mark.parametrize("series", [70, 8], ids=["bulk", "per_series"])
+def test_rows_not_yet_flushed_are_read_under_one_span_a_shard(server, series):
+    """Two shards a week apart, one flushed and one not: the read of the
+    second opens `mem_read` under `scan`, once, whether its series are read
+    in bulk (64 and more) or one by one; the flushed shard opens none."""
+    port = server.port
+    for t0 in (BASE, BASE + 8 * DAY):
+        lines = "\n".join(
+            f"cpu,host=h{h} v={h + k} {(t0 + k * 600) * NS}"
+            for h in range(series) for k in range(6))
+        assert _http(port, "POST", "/write", lines.encode(),
+                     db="db")[0] == 204
+        if t0 == BASE:
+            _http(port, "POST", "/debug/ctrl", mod="flush")
+    before = _counters("scan")
+    tracing.set_trace_enabled(True)
+    status, body = _http(
+        port, "GET", "/query", db="db",
+        q=f"SELECT max(v) FROM cpu WHERE time >= {BASE * NS} AND "
+          f"time < {(BASE + 9 * DAY) * NS} GROUP BY time(1d), host")
+    assert status == 200 and "error" not in json.loads(body)["results"][0]
+    spans = _check_tree(_trace_of(port, "http_query"), "http_query",
+                        QUERY_SPANS)
+    [(span, parent)] = spans["mem_read"]
+    assert parent["name"] == "scan"
+    assert dict(map(tuple, span["fields"]))["series"] == series
+    moved = _delta("scan", before)
+    assert (moved["mem_rows"], moved["mem_parts"]) == (series * 6, 1)
+
+
 def test_a_promql_query_leaves_a_tree(server):
     port = server.port
     lines = "\n".join(
@@ -254,6 +285,8 @@ def test_a_promql_query_leaves_a_tree(server):
     for name in ("prom_parse", "prom_collect", "prom_prepare", "prom_kernel",
                  "prom_render", "serialize", "send"):
         assert [p["name"] for _, p in spans[name]] == ["http_prom"], name
+    # nothing was flushed: the collect read the memtable, under one span
+    assert [p["name"] for _, p in spans["mem_read"]] == ["prom_collect"]
 
 
 def test_a_range_answer_counts_the_points_it_rendered(server):
