@@ -12,7 +12,7 @@ engine/aggregate_cursor.go:343); here regularity is detected per scan and
 the grid is assembled directly from the scanned chunks.
 
 GridBatch is SPECULATIVE: add() accumulates raw rows exactly like
-BucketedBatch; the first run() checks regularity (one global stride that
+BucketedBatch; the first launch_items() or run() checks regularity (one global stride that
 divides the window, per-series-run constant spacing, bounded density
 waste) and either assembles the grid or silently delegates to a
 BucketedBatch built from the same rows. Wrong results are impossible —
@@ -32,7 +32,7 @@ import time
 
 import numpy as np
 
-from opengemini_tpu.models import ragged, templates
+from opengemini_tpu.models import launch, ragged, templates
 from opengemini_tpu.utils import devobs, tracing
 from opengemini_tpu.utils.stats import GLOBAL as STATS
 
@@ -91,6 +91,7 @@ class GridBatch:
         self._state = None  # grid state dict after a successful freeze
         self._fallback = None  # BucketedBatch when the grid refuses
         self._raw: dict = {}  # lazy per-(row, window) device stats
+        self._items: dict = {}  # kernel kind -> its launch.Item
         # scan signature for the decoded-column cache's DEVICE tier
         # (storage/colcache.py): when the executor proves the scan
         # deterministic (local shards) it stamps a token here and the
@@ -300,12 +301,31 @@ class GridBatch:
 
     # -- execution -------------------------------------------------------
 
+    def launch_items(self, num_segments: int, agg_names,
+                     want_sel: bool = True) -> list:
+        """The launch.Items these aggregates still need — the grid's
+        kernels, or the bucketed fallback's where the grid refused or an
+        aggregate is not the grid's: the caller dispatches them with
+        those of the statement's other batches (models/launch.py), and
+        run() then only combines."""
+        names = set(agg_names)
+        mine = names & GRID_AGGS \
+            if self._freeze(num_segments) is not None else set()
+        out = launch.pending(self._items, _kinds(mine, want_sel),
+                             self._item) if mine else []
+        if names - mine:
+            self._ensure_fallback()
+            out += self._fallback.launch_items(num_segments, names - mine,
+                                               want_sel)
+        return out
+
     def run(self, spec, num_segments: int, params: tuple = (),
             want_sel: bool = True):
         """want_sel=False skips the selector index machinery for min/max
         (their values come from the basic kernel) — the sliced scan path
         never consults sel (selector timestamps only matter without
-        GROUP BY time())."""
+        GROUP BY time()).  Statistics no launch group brought yet are
+        launched here, as a group of one."""
         st = self._freeze(num_segments)
         if st is None:
             return self._fallback.run(spec, num_segments, params,
@@ -315,13 +335,9 @@ class GridBatch:
             self._ensure_fallback()
             return self._fallback.run(spec, num_segments, params,
                                       want_sel=want_sel)
-        raw = self._raw_stats(
-            need_ssd=(name == "stddev"),
-            need_selectors=name in ("first", "last") or (
-                want_sel and name in ("min", "max")),
-        )
+        launch.settle(self._items, _kinds((name,), want_sel), self._item)
         with tracing.span("host_combine"):
-            return self._combine(st, raw, name, num_segments, want_sel)
+            return self._combine(st, self._raw, name, num_segments, want_sel)
 
     def _combine(self, st, raw, name: str, num_segments: int,
                  want_sel: bool):
@@ -615,15 +631,23 @@ class GridBatch:
                 imat = st["mesh_imat"]
         return vt, mt, imat
 
-    def _launch(self, kind: str):
-        """Dispatch one kernel group; returns unmaterialized device
-        results (JAX dispatch is async — the host is free to keep
-        decoding while the device reduces)."""
+    def _item(self, kind: str):
+        """One kernel over this grid as a launch.Item, to be dispatched
+        with the same kernel over the statement's other grids of this
+        shape (JAX dispatch is async — the host is free to keep decoding
+        while the device reduces)."""
         st = self._state
+        if (st["arrays"] is None and st.get("device_entry") is None
+                and st.get("encoded_plan") is None):
+            raise RuntimeError(
+                f"grid kernel {kind!r} needed after prefetch dropped the "
+                "host arrays")
+        sink = functools.partial(self._take, st["S"])
         plan = st.get("encoded_plan")
         if plan is not None and kind == "basic":
             # fused cold path: compressed bytes -> device -> decode ->
-            # scatter -> basic reduce in ONE jit program; the decoded
+            # scatter -> basic reduce in ONE jit program of this field's
+            # own (its launch is the item's flight already); the decoded
             # grid buffers come back for retention so ssd/selector
             # kernels (and identically-signed future scans through the
             # colcache device tier) reuse them without any transfer
@@ -663,50 +687,47 @@ class GridBatch:
             st["flat_dev"] = flat_d
             st["device_entry"] = ent
             STATS.incr("executor", "grid_decode_fused")
-            return stats
+            return launch.Item("grid_decode_fused", None, (), sink,
+                               flight=launch.Flight(stats, sink))
         vt, mt, imat = self._device_arrays(with_imat=(kind == "selectors"))
-        tw = time.perf_counter()
-        out = devobs.launch(
-            _grid_jit(vt.shape, str(vt.dtype), kind),
-            (vt, mt, imat) if kind == "selectors" else (vt, mt),
-            program="grid_" + kind, xfer_site="grid-launch")
+        item = launch.Item(
+            "grid_" + kind, _KERNELS[kind],
+            (vt, mt, imat) if kind == "selectors" else (vt, mt), sink)
         if st.get("arrays") is not None or st.get("host_route_s") is not None:
-            # host-route planner sample, one per kernel group: the first
-            # launch carries the decode+scatter wall (freeze), every
-            # launch adds its own H2D-and-reduce dispatch — together the
-            # same span the fused device route's single sample covers
+            # host-route planner sample, one a launch: the first launch
+            # carries the decode+scatter wall (freeze) of the grids that
+            # ride in it, every launch adds its own H2D-and-reduce
+            # dispatch, as the mean a grid — the same span the fused
+            # device route's single sample covers for its one grid
             from opengemini_tpu.query import offload
 
-            base = st.pop("host_route_s", None)
-            offload.GLOBAL.observe(
-                "grid_decode", (st["shape"], str(self.dtype)), "host",
-                (base or 0.0) + (time.perf_counter() - tw))
-        return out
+            item.cost_s = st.pop("host_route_s", None) or 0.0
+            item.observe = functools.partial(
+                offload.GLOBAL.observe, "grid_decode",
+                (st["shape"], str(self.dtype)), "host")
+        return item
+
+    def _take(self, S: int, stats: dict) -> None:
+        self._raw.update({k: a[:S, : self.W] for k, a in stats.items()})
 
     supports_want_sel = True
 
     def prefetch(self, num_segments: int, agg_names,
                  want_sel: bool = False) -> None:
         """Sliced-scan overlap hook: freeze the grid and dispatch every
-        kernel this batch's aggregates will need, then drop the host-side
-        row lists and grid arrays — run() materializes the in-flight
-        device results later. No-op when the grid refuses (bucketed
-        fallback keeps its rows) or an agg outside GRID_AGGS is coming."""
+        kernel this batch's aggregates will need (a launch group of this
+        one batch), then drop the host-side row lists and grid arrays —
+        run() lands the in-flight device results later. No-op when the
+        grid refuses (bucketed fallback keeps its rows) or an agg
+        outside GRID_AGGS is coming."""
         names = set(agg_names)
         if not names or not names <= GRID_AGGS:
             return
         st = self._freeze(num_segments)
         if st is None:
             return
-        self._pending = getattr(self, "_pending", {})
-        if "basic" not in self._pending:
-            self._pending["basic"] = self._launch("basic")
-        if "stddev" in names and "ssd" not in self._pending:
-            self._pending["ssd"] = self._launch("ssd")
-        need_sel_kernel = bool(names & {"first", "last"}) or (
-            want_sel and bool(names & {"min", "max"}))
-        if need_sel_kernel and "selectors" not in self._pending:
-            self._pending["selectors"] = self._launch("selectors")
+        launch.dispatch(launch.pending(
+            self._items, _kinds(names, want_sel), self._item))
         # inputs are on device now; free the host copies
         st["arrays"] = None
         st["imat"] = None
@@ -716,35 +737,6 @@ class GridBatch:
         devobs.LEDGER.drop(st.pop("ledger", None))
         self._vals = self._rel = self._seg = self._mask = self._sids = None
         self._bnds = None
-
-    def _raw_stats(self, need_ssd: bool, need_selectors: bool) -> dict:
-        st = self._state
-        S = st["S"]
-        pending = getattr(self, "_pending", {})
-
-        def settle(kind):
-            got = pending.pop(kind, None)
-            if got is None:
-                if (st["arrays"] is None and st.get("device_entry") is None
-                        and st.get("encoded_plan") is None):
-                    raise RuntimeError(
-                        f"grid kernel {kind!r} needed after prefetch "
-                        "dropped the host arrays")
-                got = self._launch(kind)
-            if kind == "ssd":
-                self._raw["ssd"] = devobs.fetch_np(got)[:S, : self.W]
-            else:
-                self._raw.update(
-                    {k: a[:S, : self.W]
-                     for k, a in devobs.fetch_dict(got).items()})
-
-        if "count" not in self._raw:
-            settle("basic")
-        if need_ssd and "ssd" not in self._raw:
-            settle("ssd")
-        if need_selectors and "sel_first" not in self._raw:
-            settle("selectors")
-        return self._raw
 
     def _combine_value_selector(self, st, raw, name, num_segments):
         """Per-segment row index of the selected min/max point. Value ties
@@ -876,64 +868,67 @@ def _pad_rows(n: int, floor: int) -> int:
     return p
 
 
-@functools.lru_cache(maxsize=256)
-def _grid_jit(shape: tuple, dtype: str, kind: str):
-    """Compiled (S_pad, k, W_pad) grid kernels, cached per canonical shape.
-    'basic' = one fused pass for count/sum/mean/min/max; 'ssd' = two-pass
-    squared deviations (the one-pass formula cancels catastrophically);
-    'selectors' = within-row argmin/argmax sample selection for
-    min/max/first/last."""
-    import jax
+def _kinds(agg_names, want_sel: bool) -> list[str]:
+    """The grid kernels these aggregates read: `basic` always (every
+    aggregate needs the counts), `ssd` for stddev, `selectors` per
+    ragged.needs_selectors."""
+    kinds = ["basic"]
+    if "stddev" in agg_names:
+        kinds.append("ssd")
+    if ragged.needs_selectors(agg_names, want_sel):
+        kinds.append("selectors")
+    return kinds
+
+
+# The (S_pad, k, W_pad) grid kernels, as traceable functions a launch
+# group's program runs once a field (models/launch.py; compiled and
+# counted per (fields, shape, dtype) as `grid_<kind>`).  'basic' = one
+# fused pass for count/sum/mean/min/max; 'ssd' = two-pass squared
+# deviations (the one-pass formula cancels catastrophically);
+# 'selectors' = within-row argmin/argmax sample selection for
+# min/max/first/last.
+
+
+def _basic(v, m):
+    # XLA, not the Pallas grid kernel (ops/pallas_segment.py): the plain
+    # reduce is what GSPMD can row-shard under a device mesh (pallas_call
+    # does not auto-partition).  Which of the two is faster on one chip:
+    # not measured on the present code.
+    from opengemini_tpu.ops import segment as seg
+
+    return seg.grid_window_agg_t(v, m)
+
+
+def _ssd(v, m):
     import jax.numpy as jnp
 
-    devobs.note_compile("grid_" + kind, (shape, dtype))
+    zero = jnp.zeros((), v.dtype)
+    vz = jnp.where(m, v, zero)
+    cnt = m.sum(axis=1)
+    mean = vz.sum(axis=1) / jnp.maximum(cnt, 1).astype(v.dtype)
+    dev = jnp.where(m, v - mean[:, None, :], zero)
+    return {"ssd": (dev * dev).sum(axis=1)}
 
-    if kind == "basic":
-        # XLA, not the Pallas grid kernel (ops/pallas_segment.py): the
-        # plain reduce is what GSPMD can row-shard under a device mesh
-        # (pallas_call does not auto-partition).  Which of the two is
-        # faster on one chip: not measured on the present code.
 
-        @jax.jit
-        def basic(v, m):
-            from opengemini_tpu.ops import segment as seg
+def _selectors(v, m, imat):
+    import jax.numpy as jnp
 
-            return seg.grid_window_agg_t(v, m)
+    big = jnp.array(jnp.inf, v.dtype)
+    k = v.shape[1]
+    # argmin/argmax tie -> lowest k index = earliest in-row timestamp
+    r_min = jnp.argmin(jnp.where(m, v, big), axis=1)
+    r_max = jnp.argmin(jnp.where(m, -v, big), axis=1)
+    r_first = jnp.argmax(m, axis=1)
+    r_last = (k - 1) - jnp.argmax(m[:, ::-1, :], axis=1)
 
-        return basic
+    def take(mat, ridx):
+        return jnp.take_along_axis(mat, ridx[:, None, :], axis=1)[:, 0, :]
 
-    if kind == "ssd":
+    return {
+        "sel_min": take(imat, r_min), "sel_max": take(imat, r_max),
+        "sel_first": take(imat, r_first), "sel_last": take(imat, r_last),
+        "first": take(v, r_first), "last": take(v, r_last),
+    }
 
-        @jax.jit
-        def ssd(v, m):
-            zero = jnp.zeros((), v.dtype)
-            vz = jnp.where(m, v, zero)
-            cnt = m.sum(axis=1)
-            mean = vz.sum(axis=1) / jnp.maximum(cnt, 1).astype(v.dtype)
-            dev = jnp.where(m, v - mean[:, None, :], zero)
-            return (dev * dev).sum(axis=1)
 
-        return ssd
-
-    @jax.jit
-    def selectors(v, m, imat):
-        big = jnp.array(jnp.inf, v.dtype)
-        k = v.shape[1]
-        mn = jnp.where(m, v, big).min(axis=1)
-        mx = jnp.where(m, v, -big).max(axis=1)
-        # argmin/argmax tie -> lowest k index = earliest in-row timestamp
-        r_min = jnp.argmin(jnp.where(m, v, big), axis=1)
-        r_max = jnp.argmin(jnp.where(m, -v, big), axis=1)
-        r_first = jnp.argmax(m, axis=1)
-        r_last = (k - 1) - jnp.argmax(m[:, ::-1, :], axis=1)
-
-        def take(mat, ridx):
-            return jnp.take_along_axis(mat, ridx[:, None, :], axis=1)[:, 0, :]
-
-        return {
-            "sel_min": take(imat, r_min), "sel_max": take(imat, r_max),
-            "sel_first": take(imat, r_first), "sel_last": take(imat, r_last),
-            "first": take(v, r_first), "last": take(v, r_last),
-        }
-
-    return selectors
+_KERNELS = {"basic": _basic, "ssd": _ssd, "selectors": _selectors}

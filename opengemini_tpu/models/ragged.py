@@ -27,7 +27,7 @@ import functools
 
 import numpy as np
 
-from opengemini_tpu.models import templates
+from opengemini_tpu.models import launch, templates
 from opengemini_tpu.utils import devobs, tracing
 
 _REL_LO_BITS = 30
@@ -39,6 +39,16 @@ _MIN_G = 8
 # aggregates the dense path supports (others use the scatter/lexsort path)
 DENSE_AGGS = {"sum", "count", "mean", "min", "max", "first", "last",
               "spread", "stddev"}
+
+
+def needs_selectors(agg_names, want_sel: bool) -> bool:
+    """Whether these aggregates read the selector kernel's statistics:
+    first/last always (their VALUES come from it), min/max only where
+    the caller consults sel (never under GROUP BY time())."""
+    names = set(agg_names)
+    return bool(names & {"first", "last"}) or (
+        want_sel and bool(names & {"min", "max"}))
+
 
 # aggregates the host-exact int64 path supports (INT fields: float compute
 # dtype would corrupt values beyond its mantissa — 2^24 in f32 on TPU).
@@ -219,20 +229,29 @@ class BucketedBatch:
 
     supports_want_sel = True
 
+    def launch_items(self, num_segments: int, agg_names,
+                     want_sel: bool = True) -> list:
+        """The launch.Items these aggregates still need, one a bucket and
+        kernel: the caller dispatches them with those of the statement's
+        other batches (models/launch.py), and run() then only combines."""
+        need_sel = needs_selectors(agg_names, want_sel)
+        return [it for b in self._freeze(num_segments)
+                for it in b.launch_items(need_sel)]
+
     def run(self, spec, num_segments: int, params: tuple = (),
             want_sel: bool = True):
         """Same contract as AggBatch.run: (values, sel|None, counts).
         want_sel=False skips the selector lex-scan kernels for min/max
         (their values come from the basic pass) — GROUP BY time() scans
         never consult sel. first/last still need the selector kernel for
-        their VALUES."""
+        their VALUES.  Statistics no launch group brought yet are
+        launched here, as a group of one."""
         buckets = self._freeze(num_segments)
         out = np.zeros(num_segments, dtype=np.float64)
         sel = np.zeros(num_segments, dtype=np.int64)
         counts = np.zeros(num_segments, dtype=np.int64)
         is_selector = spec.name in ("min", "max", "first", "last")
-        need_sel = spec.name in ("first", "last") or (
-            want_sel and spec.name in ("min", "max"))
+        need_sel = needs_selectors((spec.name,), want_sel)
         for b in buckets:
             st = b.combined(need_selectors=need_sel)
             counts[b.segs] = st["count"]
@@ -259,6 +278,7 @@ class _Bucket:
         self.n_sub = None
         self.rel = None
         self._raw: dict = {}
+        self._items: dict = {}  # kernel family -> its launch.Item
         self._combined: dict = {}
         self._mesh_arrays = None
         self._mesh_epoch = None
@@ -289,28 +309,37 @@ class _Bucket:
                 mesh_epoch=epoch, label="bucket", anchor=self)
         return self._mesh_arrays
 
-    def _raw_stats(self, need_selectors: bool) -> dict:
-        """Per-sub-row device stats, computed lazily per group: selector
-        lex scans (4 extra matrix passes) run only for selector queries."""
+    def launch_items(self, need_selectors: bool) -> list:
+        """Items for the kernel families whose statistics are neither
+        here nor in flight: `basic` always, the selector lex scans (4
+        extra matrix passes) only for selector queries.  Each is passed
+        what its kernel reads: `basic` the values and the mask, not the
+        three time and index matrices beside them."""
+        return launch.pending(self._items, _families(need_selectors),
+                              self._item)
+
+    def _item(self, family: str):
         from opengemini_tpu.parallel import runtime as _prt
 
-        mesh = _prt.get_mesh()
-        arrays = self._device_arrays(mesh)
-        # force the XLA selector form only when the inputs really are
-        # mesh-sharded (pallas_call does not auto-partition); unsharded
-        # buckets keep the fused Pallas kernel on TPU
-        sel_kind = "selectors_xla" if arrays is not self.arrays else "selectors"
-        if "count" not in self._raw:
-            self._launch("basic", arrays)
-        if need_selectors and "sel_first" not in self._raw:
-            self._launch(sel_kind, arrays)
-        return self._raw
+        arrays = self._device_arrays(_prt.get_mesh())
+        kind = family
+        if family == "selectors" and arrays is not self.arrays:
+            # force the XLA selector form only when the inputs really are
+            # mesh-sharded (pallas_call does not auto-partition);
+            # unsharded buckets keep the fused Pallas kernel on TPU
+            kind = "selectors_xla"
+        args = (arrays[0], arrays[4]) if family == "basic" else arrays
+        return launch.Item("bucket_" + kind, _stats_fn(kind), args,
+                           self._take)
 
-    def _launch(self, kind: str, arrays) -> None:
-        got = devobs.launch(_stats_jit(kind), arrays,
-                            program="bucket_" + kind, xfer_site="bucket-launch")
-        self._raw.update({k: a[: self.g]
-                          for k, a in devobs.fetch_dict(got).items()})
+    def _take(self, stats: dict) -> None:
+        self._raw.update({k: a[: self.g] for k, a in stats.items()})
+
+    def _raw_stats(self, need_selectors: bool) -> dict:
+        """Per-sub-row device stats (launch.settle: launched here, alone,
+        where no launch group brought them)."""
+        launch.settle(self._items, _families(need_selectors), self._item)
+        return self._raw
 
     def combined(self, need_selectors: bool) -> dict:
         """Per-segment stats: raw sub-row stats + host k-way combine."""
@@ -386,6 +415,10 @@ class _Bucket:
         return out
 
 
+def _families(need_selectors: bool) -> tuple:
+    return ("basic", "selectors") if need_selectors else ("basic",)
+
+
 def _index_of(buckets: list, b) -> int:
     for i, x in enumerate(buckets):
         if x is b:
@@ -400,46 +433,39 @@ def _pow2_at_least(n: int, floor: int) -> int:
     return p
 
 
-_STATS_FNS: dict = {}
 _BIG_I32 = 2**31 - 1
 
 
-def _stats_jit(kind: str):
-    """Compiled per-sub-row stat kernels: 'basic' (one fused pass for
-    count/sum/mean/min/max/ssd) and 'selectors' (the four lexicographic
-    (hi, lo, col) scans for first/last/min/max row selection).
-    'selectors_xla' forces the XLA form — used with a device mesh, where
-    GSPMD partitions the plain XLA kernels over row-sharded inputs but
-    pallas_call does not auto-partition.
+def _stats_fn(kind: str):
+    """The per-sub-row stat kernel of one bucket matrix set, as a
+    traceable function a launch group's program runs once a field
+    (models/launch.py): 'basic' (v, m) — one fused pass for
+    count/sum/mean/min/max/ssd — and 'selectors' (v, hi, lo, idx, m) —
+    the four lexicographic (hi, lo, col) scans for first/last/min/max
+    row selection.  'selectors_xla' forces the XLA form — used with a
+    device mesh, where GSPMD partitions the plain XLA kernels over
+    row-sharded inputs but pallas_call does not auto-partition.
 
     On a TPU backend 'selectors' routes to the fused Pallas tile kernel
     (ops/pallas_segment.py) — one HBM pass feeds every statistic; the
     XLA expressions below serve CPU runs and remain the semantics
-    oracle the Pallas kernels are tested against.  Each kind is counted
-    in the compile inventory when it is first asked for, so
+    oracle the Pallas kernels are tested against.  The compile inventory
+    counts a kind (`bucket_<kind>`) when a program over it is built, so
     /debug/device shows which of them a workload really ran."""
-    fn = _STATS_FNS.get(kind)
-    if fn is not None:
-        return fn
     from opengemini_tpu.ops import pallas_segment
 
     if kind == "selectors" and pallas_segment.use_pallas():
-        fn = pallas_segment.bucket_stats_selectors
-    elif kind == "basic":
-        fn = _xla_stats_fns()[0]
-    elif kind in ("selectors", "selectors_xla"):
-        fn = _xla_stats_fns()[1]
-    else:
-        raise KeyError(kind)  # unknown kinds must raise, not silently alias
-    devobs.note_compile("bucket_" + kind)
-    _STATS_FNS[kind] = fn
-    return fn
+        return pallas_segment.bucket_stats_selectors
+    if kind == "basic":
+        return _xla_stats_fns()[0]
+    if kind in ("selectors", "selectors_xla"):
+        return _xla_stats_fns()[1]
+    raise KeyError(kind)  # unknown kinds must raise, not silently alias
 
 
 @functools.lru_cache(maxsize=1)
 def _xla_stats_fns():
-    """(basic, selectors) as jitted XLA functions."""
-    import jax
+    """(basic, selectors) as traceable XLA functions."""
     import jax.numpy as jnp
 
     def _take(mat, col_sel):
@@ -462,8 +488,7 @@ def _xla_stats_fns():
         c3 = c2 & (lo == lo_ext[:, None])
         return jnp.where(c3, col, big).min(axis=1)
 
-    @jax.jit
-    def basic(v, hi, lo, idx, m):
+    def basic(v, m):
         zero = jnp.zeros((), v.dtype)
         vz = jnp.where(m, v, zero)
         cnt = m.sum(axis=1)
@@ -498,7 +523,6 @@ def _xla_stats_fns():
         c4 = c3 & (v == v_ext[:, None])
         return jnp.where(c4, col, big).min(axis=1)
 
-    @jax.jit
     def selectors(v, hi, lo, idx, m):
         big = jnp.array(jnp.inf, v.dtype)
         mn = jnp.where(m, v, big).min(axis=1)

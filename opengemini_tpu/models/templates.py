@@ -219,7 +219,7 @@ class AggBatch:
             )
             got = devobs.launch(fn, sharded, program="agg_mesh",
                                 xfer_site="agg-launch")
-            outs = devobs.fetch_dict(got)
+            outs = devobs.fetch_tree(got)
             self._mesh_outs[cache_key] = outs
         out = outs[spec.name][:num_segments]
         sel = outs.get(spec.name + "_sel")

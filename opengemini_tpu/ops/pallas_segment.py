@@ -133,9 +133,9 @@ def _bucket_basic_call(v, m_i8, *, interpret: bool):
 
 
 def bucket_stats_basic(v, hi, lo, idx, m):
-    """Drop-in for models/ragged._stats_jit('basic'): fused single-pass
+    """Pallas twin of models/ragged._stats_fn('basic'): fused single-pass
     count/sum/mean/min/max/ssd over (G, W) bucket rows. hi/lo/idx are
-    accepted (same signature) and unused."""
+    accepted (the selectors twin's signature) and unused."""
     return _bucket_basic_call(jnp.asarray(v), _as_i8(m), interpret=_interpret())
 
 
@@ -264,7 +264,7 @@ def _bucket_sel_call(v, hi, lo, idx, m_i8, *, interpret: bool):
 
 
 def bucket_stats_selectors(v, hi, lo, idx, m):
-    """Drop-in for models/ragged._stats_jit('selectors'): fused first/last
+    """Drop-in for models/ragged._stats_fn('selectors'): fused first/last
     values + first/last/min/max row-index selection in one tile pass."""
     return _bucket_sel_call(
         jnp.asarray(v), jnp.asarray(hi), jnp.asarray(lo), jnp.asarray(idx),

@@ -26,7 +26,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from opengemini_tpu.models import ragged, templates
+from opengemini_tpu.models import launch, ragged, templates
 from opengemini_tpu.ops import aggregates as aggmod
 from opengemini_tpu.parallel import cluster as pcluster
 from opengemini_tpu.ops import window as winmod
@@ -1589,8 +1589,20 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
         agg_results = {}  # id(call) -> (values, sel, counts)
         dv_before = devobs.span_snapshot() if devobs.enabled() else None
         with trace.span("device_compute") as sp:
+            if not no_scan and sliced_out is None:
+                # one launch and one fetch a statement, not one a field:
+                # the batches that froze to the same geometry ride in one
+                # program (models/launch.py); run() below only combines.
+                # GROUP BY time() never consults selector timestamps
+                # (the window start renders), hence want_sel
+                launch.run([
+                    it for f, b in batches.items()
+                    if hasattr(b, "launch_items")
+                    for it in b.launch_items(
+                        num_segments, per_field_aggs[f],
+                        want_sel=not group_time)])
             for call, spec, params, field_name in aggs:
-                TRACKER.check()  # kill between device batch dispatches
+                TRACKER.check()  # kill between aggregates
                 if no_scan:
                     # every window served from cache/rollup: no scan, no
                     # device work
@@ -1704,6 +1716,8 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
                 sp.add_field(
                     "layouts", {f: b.layout_name() for f, b in batches.items()}
                 )
+            # counts the statement's aggregates, not its launches (those
+            # are query_stages/device_launch_count, one a launch group)
             STATS.incr("executor", "device_batches", len(aggs))
             if dv_before is not None:
                 # devobs delta attribution (compiles + transfer bytes

@@ -47,9 +47,9 @@ armed or not):
       `device_launch` span (utils/tracing.py; always on) around the
       call, and the host arrays among its arguments — the implicit H2D
       of a single-chip launch — counted as h2d bytes.  fetch_np() and
-      fetch_dict() wrap the device->host materialization (np.asarray of
+      fetch_tree() wrap the device->host materialization (np.asarray of
       a jax array: the device wait plus the D2H) in a `device_fetch`
-      span and count its bytes.
+      span, one a launch, and count its bytes.
 
   device-memory ledger every RETAINED device buffer registers (owner,
       nbytes, mesh-epoch): the colcache device tier, grid `mesh_arrays`
@@ -432,14 +432,18 @@ def fetch_np(x, site: str = "result-fetch"):
     return a
 
 
-def fetch_dict(outs: dict, site: str = "result-fetch") -> dict:
-    """fetch_np over the values of one launch's result dict, as ONE
-    `device_fetch` span."""
+def fetch_tree(outs, site: str = "result-fetch"):
+    """fetch_np over the arrays of ONE launch's result (any pytree: a
+    launch group's packed pair, a result dict), as ONE `device_fetch`
+    span: the wait for the program, then one copy an array."""
+    import jax
+
     from opengemini_tpu.utils import tracing
 
     with tracing.span("device_fetch") as sp:
-        got = {k: _fetch(v, site) for k, v in outs.items()}
-        sp.add_field("bytes", sum(a.nbytes for a in got.values()))
+        got = jax.tree_util.tree_map(lambda x: _fetch(x, site), outs)
+        sp.add_field("bytes", sum(
+            a.nbytes for a in jax.tree_util.tree_leaves(got)))
     return got
 
 
@@ -447,10 +451,15 @@ def launch(fn, args, program: str, xfer_site: str):
     """Call the compiled program `fn(*args)` inside a `device_launch`
     span: the dispatch (jax returns before the device is done; the wait
     is the fetch's) with the implicit H2D of whatever host arrays are
-    among `args`, whose bytes count as h2d at `xfer_site`."""
+    among the leaves of `args` (a launch group passes its fields as one
+    nested tuple), whose bytes count as h2d at `xfer_site`.  A program
+    is passed only what it reads, so the count is what crosses."""
+    import jax
+
     from opengemini_tpu.utils import tracing
 
-    h2d = sum(a.nbytes for a in args if isinstance(a, _np.ndarray))
+    h2d = sum(a.nbytes for a in jax.tree_util.tree_leaves(args)
+              if isinstance(a, _np.ndarray))
     with tracing.span("device_launch", program=program, h2d_bytes=h2d):
         out = fn(*args)
     if h2d:

@@ -101,14 +101,14 @@ class TestCompileAccounting:
         assert devobs.compiles_since_warm() == 0
 
     def test_lowering_sites_feed_inventory(self, tmp_path):
-        from opengemini_tpu.models.grid import _grid_jit
+        from opengemini_tpu.models.launch import _program
 
         eng = _mk_engine(tmp_path, hosts=4, points=40)
         try:
             # the jit program cache is process-global and may be warm
             # from earlier tests: clear it so THIS query's lowering
             # lands in the per-test devobs inventory
-            _grid_jit.cache_clear()
+            _program.cache_clear()
             Executor(eng).execute(_Q, db="db")
             inv = devobs.jit_inventory()
             # the GROUP BY time() grid path lowered at least its basic
@@ -293,13 +293,13 @@ class TestMetricsArmedUnderMesh:
         assert any(k and k.startswith("grid_") for k in kernels)
 
     def test_debug_device_doc(self, server):
-        from opengemini_tpu.models.grid import _grid_jit
+        from opengemini_tpu.models.launch import _program
 
         port = server.port
         # the jit program cache is process-global and may be warm from
         # earlier tests: clear it so THIS query's lowering lands in the
         # per-test devobs inventory
-        _grid_jit.cache_clear()
+        _program.cache_clear()
         _get(port, "/query", db="db", q=_Q)
         status, body = _get(port, "/debug/device")
         assert status == 200

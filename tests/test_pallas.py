@@ -1,7 +1,7 @@
 """Pallas tile kernels (ops/pallas_segment.py) vs the XLA oracle.
 
 Runs in interpret mode on the CPU-forced test backend; the kernels must
-match models/ragged._stats_jit and ops/segment.grid_window_agg_t exactly,
+match models/ragged._stats_fn and ops/segment.grid_window_agg_t exactly,
 including empty-segment identities and lexicographic tie-breaks.
 
 Kernel-executing tests gate on the devobs backend-capability probe
@@ -43,28 +43,26 @@ def _rand_bucket(g, w, seed, empty_rows=True, dtype=np.float32):
 
 
 def _xla_stats(kind):
-    """The jnp oracle regardless of pallas routing."""
+    """The jnp oracle regardless of pallas routing: the per-field kernel
+    a launch group's program runs (models/launch.py), compiled alone."""
+    import jax
+
     from opengemini_tpu.models import ragged
 
-    saved = dict(ragged._STATS_FNS)
-    ragged._STATS_FNS.clear()
     try:
         os.environ["OGTPU_PALLAS"] = "0"
         ps.use_pallas.cache_clear()
-        fn = ragged._stats_jit(kind)
+        return jax.jit(ragged._stats_fn(kind))
     finally:
         os.environ.pop("OGTPU_PALLAS", None)
         ps.use_pallas.cache_clear()
-        ragged._STATS_FNS.clear()
-        ragged._STATS_FNS.update(saved)
-    return fn
 
 
 @pytest.mark.parametrize("g,w", [(8, 16), (32, 64), (64, 256), (16, 1024)])
 @needs_pallas
 def test_bucket_basic_matches_xla(g, w):
     v, hi, lo, idx, m = _rand_bucket(g, w, seed=g + w)
-    want = {k: np.asarray(x) for k, x in _xla_stats("basic")(v, hi, lo, idx, m).items()}
+    want = {k: np.asarray(x) for k, x in _xla_stats("basic")(v, m).items()}
     got = {k: np.asarray(x) for k, x in ps.bucket_stats_basic(v, hi, lo, idx, m).items()}
     assert set(got) == set(want)
     for k in want:
@@ -144,8 +142,6 @@ def test_ragged_batch_end_to_end_with_pallas(monkeypatch):
     def run(force_pallas: bool):
         monkeypatch.setenv("OGTPU_PALLAS", "1" if force_pallas else "0")
         ps.use_pallas.cache_clear()
-        saved = dict(ragged._STATS_FNS)
-        ragged._STATS_FNS.clear()
         try:
             b = ragged.BucketedBatch()
             b.add(vals, rel, seg_ids, mask, rel)
@@ -157,8 +153,6 @@ def test_ragged_batch_end_to_end_with_pallas(monkeypatch):
                              np.asarray(counts))
             return out
         finally:
-            ragged._STATS_FNS.clear()
-            ragged._STATS_FNS.update(saved)
             monkeypatch.delenv("OGTPU_PALLAS")
             ps.use_pallas.cache_clear()
 

@@ -494,12 +494,17 @@ def _bucketed(rows: int = 600) -> ragged.BucketedBatch:
 
 
 def test_h2d_bytes_count_the_host_arrays_of_a_launch():
+    """A launch is passed what its program reads: `basic` the values and
+    the mask of a bucket, not the three time and index matrices beside
+    them, and those bytes are what h2d counts (ROADMAP R-A9.5).  A lone
+    batch is a launch group of one a bucket."""
     import jax
 
     assert prt.get_mesh() is None
     batch = _bucketed()
     buckets = batch._freeze(40)
-    want = sum(a.nbytes for b in buckets for a in b.arrays)
+    want = sum(b.arrays[0].nbytes + b.arrays[4].nbytes for b in buckets)
+    assert want < sum(a.nbytes for b in buckets for a in b.arrays)
     h0 = _counters("device").get("h2d_bytes_total", 0)
     q0 = _counters("query_stages")
     out, _sel, counts = batch.run(aggmod.get("mean"), 40)
@@ -508,6 +513,11 @@ def test_h2d_bytes_count_the_host_arrays_of_a_launch():
     d = _delta("query_stages", q0)
     assert d["device_launch_count"] == d["device_fetch_count"] == \
         d["host_combine_count"] == len(buckets)
+    # a selector reads all five matrices
+    h1 = _counters("device")["h2d_bytes_total"]
+    batch.run(aggmod.get("first"), 40)
+    assert _counters("device")["h2d_bytes_total"] - h1 == \
+        sum(a.nbytes for b in buckets for a in b.arrays)
     # the same launch over arrays that already live on the device
     again = _bucketed()
     for b in again._freeze(40):

@@ -93,11 +93,12 @@ def main() -> int:
                 args = _bucket_inputs(*shape, seed=sum(shape))
                 if name == "bucket_basic":
                     got = ps.bucket_stats_basic(*args)
-                    want = ragged._stats_jit("basic")(*args)
+                    want = jax.jit(ragged._stats_fn("basic"))(
+                        args[0], args[4])
                     exact = ("count",)
                 else:
                     got = ps.bucket_stats_selectors(*args)
-                    want = ragged._stats_jit("selectors_xla")(*args)
+                    want = jax.jit(ragged._stats_fn("selectors_xla"))(*args)
                     exact = ("sel_first", "sel_last", "sel_min", "sel_max")
             else:
                 rng = np.random.default_rng(sum(shape))
