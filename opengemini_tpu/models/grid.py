@@ -32,7 +32,7 @@ import time
 
 import numpy as np
 
-from opengemini_tpu.models import launch, ragged, templates
+from opengemini_tpu.models import launch, layoutplan, ragged, templates
 from opengemini_tpu.utils import devobs, tracing
 from opengemini_tpu.utils.stats import GLOBAL as STATS
 
@@ -76,10 +76,13 @@ class _EncodedVals:
 class GridBatch:
     accepts_boundaries = True  # coalesced adds forward record breaks
 
-    def __init__(self, dtype, W: int, every_ns: int):
+    def __init__(self, dtype, W: int, every_ns: int, plans=None):
         self.dtype = dtype or templates.compute_dtype()
         self.W = int(W)
         self.every_ns = int(every_ns)
+        # the statement's layout plans: the batches fed the same rows
+        # share the grid's plan (or its refusal, and then the buckets')
+        self._plans = plans or layoutplan.Plans()
         self._vals: list[np.ndarray] = []
         self._rel: list[np.ndarray] = []
         self._seg: list[np.ndarray] = []
@@ -126,20 +129,17 @@ class GridBatch:
               boundaries):
         self._vals.append(vals)
         self._rel.append(np.asarray(rel_ns, dtype=np.int64))
-        self._seg.append(np.asarray(seg_ids, dtype=np.int64))
+        # segment and series ids stay as handed (the plan widens and
+        # expands them, once for all the fields): a private copy a field
+        # would hide that the fields share their rows
+        self._seg.append(np.asarray(seg_ids))
         self._mask.append(np.asarray(mask, dtype=np.bool_))
         self._times.append(np.asarray(times_ns, dtype=np.int64))
-        if sids is None:
-            self._sids.append(None)
-        elif np.isscalar(sids):
-            self._sids.append(
-                np.full(len(self._vals[-1]), sids, dtype=np.int64))
-        else:
-            self._sids.append(np.asarray(sids, dtype=np.int64))
+        self._sids.append(
+            sids if sids is None or np.isscalar(sids) else np.asarray(sids))
         self._bnds.append(
-            None if boundaries is None
-            else np.asarray(boundaries, dtype=np.int64))
-        self.n += len(self._vals[-1])
+            None if boundaries is None else np.asarray(boundaries))
+        self.n += len(vals)
 
     def layout_name(self) -> str:
         if self._state is not None:
@@ -167,86 +167,46 @@ class GridBatch:
                     "bucketed fallback requested after prefetch() dropped "
                     "the raw rows — prefetch callers must keep aggs "
                     "within GRID_AGGS")
-            fb = ragged.BucketedBatch(self.dtype)
+            # the same row arrays and the same plans: the fallbacks of a
+            # statement's fields share one bucket plan
+            fb = ragged.BucketedBatch(self.dtype, self._plans)
             for v, r, s, m, t in zip(self._vals, self._rel, self._seg,
                                      self._mask, self._times):
                 fb.add(v, r, s, m, t)
             self._fallback = fb
 
     def _freeze(self, num_segments: int):
-        """Returns the grid state dict, or None (delegate to bucketed)."""
+        """Returns the grid state dict, or None (delegate to bucketed).
+        The plan — or the refusal — is built by the first batch of the
+        statement that was fed these rows and taken by the others; what
+        a batch does for itself is the fill."""
         if self._state is not None or self._fallback is not None:
             return self._state
         with tracing.span("layout_build", rows=self.n):
-            state = self._try_grid(num_segments)
-        if state is None:
-            STATS.incr("executor", "grid_fallbacks")
-            self._ensure_fallback()
-        else:
-            STATS.incr("executor", "grid_batches")
-            self._state = state
+            plan, shared = self._plans.get(
+                ("grid", self.W, self.every_ns, num_segments),
+                self._rel + self._seg + self._sids + self._bnds,
+                lambda: _plan_grid(
+                    self._rel, self._seg, self._sids, self._bnds, self.W,
+                    self.every_ns, num_segments))
+            if plan is None:
+                # the fallback freezes inside this span and count; its
+                # bucket plan is shared wherever the refusal was
+                self._ensure_fallback()
+                if self.n:
+                    self._fallback.build(num_segments)
+            else:
+                self._state = self._fill(plan)
+        STATS.add("executor", (
+            ("grid_fallbacks" if plan is None else "grid_batches", 1),
+            ("layout_plans_shared", int(shared))))
         return self._state
 
-    def _try_grid(self, num_segments: int):
-        W = self.W
-        if self.n == 0 or W < 1 or num_segments % W:
-            return None
-        if any(s is None for s in self._sids):
-            return None  # no series identity: cannot prove no slot clash
-        rel = np.concatenate(self._rel)
-        seg = np.concatenate(self._seg)
-        sid = np.concatenate(self._sids)
-        n = len(rel)
-        # series runs: sid change or chunk boundary (the same series split
-        # across shards/chunks gets separate rows — a run is only required
-        # to be internally constant-stride)
-        boundary = np.zeros(n, dtype=np.bool_)
-        boundary[0] = True
-        boundary[1:] = sid[1:] != sid[:-1]
-        off = 0
-        for v, b in zip(self._vals, self._bnds):
-            if b is not None and len(b):
-                boundary[off + b] = True  # coalesced-add record breaks
-            off += len(v)
-            if off < n:
-                boundary[off] = True
-        d = np.diff(rel)
-        inner = ~boundary[1:]
-        dd = d[inner]
-        if len(dd) and int(dd.min()) <= 0:
-            return None  # duplicate/unsorted times within a run
-        # dt = gcd(all within-run diffs, window) — every within-run diff is
-        # then a positive multiple of dt and every run's times share one
-        # residue class mod dt, so (window, (rel - w*every)//dt) is
-        # injective per run: gaps and per-series phase shifts grid fine,
-        # they just leave masked-off slots. All-singleton runs (one sample
-        # per series) degenerate to k=1.
-        dt = _stride_gcd(dd, self.every_ns) if len(dd) else self.every_ns
-        if dt <= 0 or self.every_ns % dt:
-            return None
-        k = self.every_ns // dt
-        if k > _MAX_K:
-            return None
-        bnd_idx = np.flatnonzero(boundary)
-        S = len(bnd_idx)
-        S_pad = _pad_rows(S, _MIN_S)
-        W_pad = _pad_lanes(W, _MIN_W)
-        mesh = self._mesh_for_rows(S_pad)
-        if mesh is not None and S_pad % mesh.size:
-            # multi-chip: pad the row axis to a mesh multiple up front so
-            # the grid scatters straight into the shardable shape (no
-            # second padding copy at device_put time) and the device-tier
-            # signature shape is stable across cold/warm scans
-            S_pad += mesh.size - S_pad % mesh.size
-        cells = S_pad * k * W_pad  # padded = what actually allocates
-        if cells > _MAX_GRID_CELLS or cells > max(_MAX_EXPANSION * n, 1 << 20):
-            return None
-        w = seg % W
-        r = (rel - w * self.every_ns) // dt
-        if (r < 0).any() or (r >= k).any():
-            return None  # window grid misaligned with the stride grid
-        rid = np.cumsum(boundary) - 1
-        flat = (rid * k + r) * W_pad + w
+    def _fill(self, plan: dict) -> dict:
+        """This field's half of a freeze, as the state dict: its grids
+        from the device tier, as a fused decode-on-device plan, or
+        scattered here through the plan's index."""
+        shape, flat, mesh = plan["shape"], plan["flat"], plan["mesh"]
         # device tier consult: an identically-signed earlier scan already
         # holds the padded grid on device — skip the host scatter AND the
         # H2D transfer (the signature embeds every shard's data_version,
@@ -258,15 +218,14 @@ class GridBatch:
 
             dev_entry = colcache.GLOBAL.device_get(
                 self.device_cache_token,
-                shape=(S_pad, k, W_pad), dtype=str(self.dtype), mesh=mesh)
+                shape=shape, dtype=str(self.dtype), mesh=mesh)
         enc_plan = None
         host_s = None
+        arrays = None
         if dev_entry is None:
-            enc_plan = self._encoded_plan((S_pad, k, W_pad), flat, mesh,
-                                          rel, bnd_idx, dt)
-            if enc_plan is not None:
-                arrays = None
-            else:
+            enc_plan = self._encoded_plan(shape, flat, mesh, plan["rel"],
+                                          plan["run_starts"], plan["dt"])
+            if enc_plan is None:
                 # host route: the decode (through _EncodedVals.__array__)
                 # + scatter wall; each launch adds its own dispatch wall
                 # so the planner's host samples cover the same span the
@@ -274,29 +233,15 @@ class GridBatch:
                 # group's second full-grid transfer, which the device
                 # route avoids by keeping the grid resident
                 t0 = time.perf_counter()
-                arrays = self._scatter_grid((S_pad, k, W_pad), flat)
+                arrays = self._scatter_grid(shape, flat)
                 host_s = time.perf_counter() - t0
-        else:
-            arrays = None
-        run_gid = (seg[bnd_idx] // W).astype(np.int64)
-        order = np.argsort(run_gid, kind="stable")
-        sg = run_gid[order]
-        gb = np.empty(S, dtype=np.bool_)
-        gb[0] = True
-        gb[1:] = sg[1:] != sg[:-1]
-        starts = np.flatnonzero(gb)
         return {
-            "k": k, "S": S, "W_pad": W_pad, "shape": (S_pad, k, W_pad),
+            **plan,
             "arrays": arrays, "device_entry": dev_entry,
             "encoded_plan": enc_plan, "host_route_s": host_s,
             # imat (sample-index grid for the selector kernels) builds
             # lazily from `flat` — count/sum/mean scans never pay for it
-            "imat": None, "flat": flat, "n": n,
-            "rel": rel,
-            "row_order": order,  # grid rows sorted by gid
-            "gid_starts": starts,  # reduceat starts in row_order
-            "gids_present": sg[starts],
-            "rows_per_gid": np.diff(np.append(starts, S)),
+            "imat": None,
         }
 
     # -- execution -------------------------------------------------------
@@ -436,7 +381,7 @@ class GridBatch:
         if route == "host" and not offload.wants_prewarm(
                 "grid_decode", geo):
             return None
-        mask = np.concatenate(self._mask)
+        mask = layoutplan.cat(self._mask)
         if mesh is not None:
             plan = device_decode.build_mesh_grid_plan(
                 views, flat, mask, shape, self.dtype, mesh,
@@ -473,8 +418,8 @@ class GridBatch:
         the rare rebuild branch can never diverge from the hot path."""
         vt = np.zeros(shape, dtype=self.dtype)
         mt = np.zeros(shape, dtype=np.bool_)
-        vt.reshape(-1)[flat] = np.concatenate(self._vals)
-        mt.reshape(-1)[flat] = np.concatenate(self._mask)
+        vt.reshape(-1)[flat] = layoutplan.cat(self._vals)
+        mt.reshape(-1)[flat] = layoutplan.cat(self._mask)
         return vt, mt
 
     def _build_imat_np(self):
@@ -736,7 +681,7 @@ class GridBatch:
         st.pop("mesh_imat", None)
         devobs.LEDGER.drop(st.pop("ledger", None))
         self._vals = self._rel = self._seg = self._mask = self._sids = None
-        self._bnds = None
+        self._bnds = self._plans = None  # the plan is its siblings' to keep
 
     def _combine_value_selector(self, st, raw, name, num_segments):
         """Per-segment row index of the selected min/max point. Value ties
@@ -804,6 +749,90 @@ class GridBatch:
         sel = np.zeros(num_segments, dtype=np.int64)
         sel.reshape(G, self.W)[gids] = np.take_along_axis(sel_sub, pick, axis=0)
         return vals2d, sel
+
+
+def _plan_grid(rel_parts, seg_parts, sid_parts, bnd_parts, W: int,
+               every_ns: int, num_segments: int):
+    """The grid plan of a row set, or None (the grid refuses): the stride
+    analysis, the padded shape, every row's slot (`flat`) and the combine
+    index of the series rows.  Nothing here reads a value or a mask, so
+    the fields of a statement share it (models/layoutplan.py)."""
+    n = sum(len(r) for r in rel_parts)
+    if n == 0 or W < 1 or num_segments % W:
+        return None
+    if any(s is None for s in sid_parts):
+        return None  # no series identity: cannot prove no slot clash
+    rel = layoutplan.cat(rel_parts)
+    seg = layoutplan.cat(seg_parts, np.int64)
+    sid = layoutplan.cat(
+        [np.full(len(r), s, dtype=np.int64) if np.isscalar(s) else s
+         for r, s in zip(rel_parts, sid_parts)], np.int64)
+    # series runs: sid change or chunk boundary (the same series split
+    # across shards/chunks gets separate rows — a run is only required
+    # to be internally constant-stride)
+    boundary = np.zeros(n, dtype=np.bool_)
+    boundary[0] = True
+    boundary[1:] = sid[1:] != sid[:-1]
+    off = 0
+    for r, b in zip(rel_parts, bnd_parts):
+        if b is not None and len(b):
+            boundary[off + b] = True  # coalesced-add record breaks
+        off += len(r)
+        if off < n:
+            boundary[off] = True
+    d = np.diff(rel)
+    inner = ~boundary[1:]
+    dd = d[inner]
+    if len(dd) and int(dd.min()) <= 0:
+        return None  # duplicate/unsorted times within a run
+    # dt = gcd(all within-run diffs, window) — every within-run diff is
+    # then a positive multiple of dt and every run's times share one
+    # residue class mod dt, so (window, (rel - w*every)//dt) is
+    # injective per run: gaps and per-series phase shifts grid fine,
+    # they just leave masked-off slots. All-singleton runs (one sample
+    # per series) degenerate to k=1.
+    dt = _stride_gcd(dd, every_ns) if len(dd) else every_ns
+    if dt <= 0 or every_ns % dt:
+        return None
+    k = every_ns // dt
+    if k > _MAX_K:
+        return None
+    run_starts = np.flatnonzero(boundary)
+    S = len(run_starts)
+    S_pad = _pad_rows(S, _MIN_S)
+    W_pad = _pad_lanes(W, _MIN_W)
+    mesh = GridBatch._mesh_for_rows(S_pad)
+    if mesh is not None and S_pad % mesh.size:
+        # multi-chip: pad the row axis to a mesh multiple up front so
+        # the grid scatters straight into the shardable shape (no
+        # second padding copy at device_put time) and the device-tier
+        # signature shape is stable across cold/warm scans
+        S_pad += mesh.size - S_pad % mesh.size
+    cells = S_pad * k * W_pad  # padded = what actually allocates
+    if cells > _MAX_GRID_CELLS or cells > max(_MAX_EXPANSION * n, 1 << 20):
+        return None
+    w = seg % W
+    r = (rel - w * every_ns) // dt
+    if (r < 0).any() or (r >= k).any():
+        return None  # window grid misaligned with the stride grid
+    rid = np.cumsum(boundary) - 1
+    flat = (rid * k + r) * W_pad + w
+    run_gid = (seg[run_starts] // W).astype(np.int64)
+    order = np.argsort(run_gid, kind="stable")
+    sg = run_gid[order]
+    gb = np.empty(S, dtype=np.bool_)
+    gb[0] = True
+    gb[1:] = sg[1:] != sg[:-1]
+    starts = np.flatnonzero(gb)
+    return {
+        "k": k, "S": S, "W_pad": W_pad, "shape": (S_pad, k, W_pad),
+        "flat": flat, "n": n, "rel": rel, "mesh": mesh,
+        "run_starts": run_starts, "dt": dt,  # the decode-on-device plan's
+        "row_order": order,  # grid rows sorted by gid
+        "gid_starts": starts,  # reduceat starts in row_order
+        "gids_present": sg[starts],
+        "rows_per_gid": np.diff(np.append(starts, S)),
+    }
 
 
 def _stride_gcd(dd: np.ndarray, every_ns: int) -> int:

@@ -27,8 +27,9 @@ import functools
 
 import numpy as np
 
-from opengemini_tpu.models import launch, templates
+from opengemini_tpu.models import launch, layoutplan, templates
 from opengemini_tpu.utils import devobs, tracing
+from opengemini_tpu.utils.stats import GLOBAL as STATS
 
 _REL_LO_BITS = 30
 _REL_LO_MASK = (1 << _REL_LO_BITS) - 1
@@ -116,10 +117,13 @@ class IntExactBatch:
 class BucketedBatch:
     """Drop-in alternative to templates.AggBatch for dense-capable
     aggregates. add() accumulates ragged chunks; the first run() freezes
-    the batch into dense buckets."""
+    the batch into dense buckets.  `plans` is the statement's
+    layoutplan.Plans: the batches fed the same rows share one bucket
+    plan, and each scatters only its own values and mask."""
 
-    def __init__(self, dtype=None):
+    def __init__(self, dtype=None, plans=None):
         self.dtype = dtype or templates.compute_dtype()
+        self._plans = plans or layoutplan.Plans()
         self._vals: list[np.ndarray] = []
         self._rel: list[np.ndarray] = []
         self._seg: list[np.ndarray] = []
@@ -131,7 +135,9 @@ class BucketedBatch:
     def add(self, values, rel_ns, seg_ids, mask, times_ns, sids=None):
         self._vals.append(np.asarray(values, dtype=self.dtype))
         self._rel.append(np.asarray(rel_ns, dtype=np.int64))
-        self._seg.append(np.asarray(seg_ids, dtype=np.int64))
+        # as handed (the plan widens it, once for all the fields): a
+        # private int64 copy a field would hide that they share their rows
+        self._seg.append(np.asarray(seg_ids))
         self._mask.append(np.asarray(mask, dtype=np.bool_))
         self._times.append(np.asarray(times_ns, dtype=np.int64))
         self.n += len(values)
@@ -150,80 +156,23 @@ class BucketedBatch:
                 self._frozen = []
             else:
                 with tracing.span("layout_build", rows=self.n):
-                    self._frozen = self._build_buckets(num_segments)
+                    shared = self.build(num_segments)
+                if shared:
+                    STATS.incr("executor", "layout_plans_shared")
         return self._frozen
 
-    def _build_buckets(self, num_segments: int) -> list:
-        vals = np.concatenate(self._vals)
-        rel = np.concatenate(self._rel)
-        seg = np.concatenate(self._seg)
-        mask = np.concatenate(self._mask)
-        n = len(vals)
-        row_idx = np.arange(n, dtype=np.int32)
-
-        counts = np.bincount(seg, minlength=num_segments)
-
-        # within-segment arrival offsets via run analysis (no global sort)
-        run_starts = np.concatenate([[0], np.flatnonzero(seg[1:] != seg[:-1]) + 1])
-        run_segs = seg[run_starts]
-        run_lens = np.diff(np.concatenate([run_starts, [n]]))
-        order = np.argsort(run_segs, kind="stable")  # runs, not rows
-        cum = np.zeros(len(run_starts), dtype=np.int64)
-        lens_sorted = run_lens[order]
-        segs_sorted = run_segs[order]
-        csum = np.cumsum(lens_sorted) - lens_sorted
-        first_run_of_seg = np.searchsorted(segs_sorted, segs_sorted)
-        base_sorted = csum - csum[first_run_of_seg]
-        cum[order] = base_sorted
-        offsets = (
-            np.arange(n, dtype=np.int64)
-            - np.repeat(run_starts, run_lens)
-            + np.repeat(cum, run_lens)
-        )
-
-        buckets: list[_Bucket] = []
-        bucket_of = np.full(num_segments, -1, dtype=np.int8)
-        for bi, w in enumerate(WIDTHS):
-            lo = WIDTHS[bi - 1] if bi else 0
-            if w == WIDTHS[-1]:
-                here = counts > lo  # larger segments split into sub-rows
-            else:
-                here = (counts > lo) & (counts <= w)
-            segs_here = np.nonzero(here)[0]
-            if len(segs_here) == 0:
-                continue
-            bucket_of[segs_here] = len(buckets)
-            buckets.append(_Bucket(w, segs_here, counts[segs_here]))
-
-        for b in buckets:
-            w = b.width
-            # sub-row layout: segment k gets ceil(count/w) consecutive rows
-            n_sub = np.maximum((b.seg_counts + w - 1) // w, 1)
-            sub_base = np.cumsum(n_sub) - n_sub  # first sub-row per segment
-            g = int(n_sub.sum())
-            g_pad = _pow2_at_least(g, _MIN_G)
-            slot_of = np.zeros(num_segments, dtype=np.int64)
-            slot_of[b.segs] = sub_base
-            rows = np.nonzero(bucket_of[seg] == _index_of(buckets, b))[0]
-            off = offsets[rows]
-            flat = (slot_of[seg[rows]] + off // w) * w + off % w
-            vmat = np.zeros((g_pad, w), dtype=self.dtype)
-            mmat = np.zeros((g_pad, w), dtype=np.bool_)
-            hmat = np.zeros((g_pad, w), dtype=np.int32)
-            lmat = np.zeros((g_pad, w), dtype=np.int32)
-            imat = np.zeros((g_pad, w), dtype=np.int32)
-            vmat.reshape(-1)[flat] = vals[rows]
-            mmat.reshape(-1)[flat] = mask[rows]
-            r = rel[rows]
-            hmat.reshape(-1)[flat] = (r >> _REL_LO_BITS).astype(np.int32)
-            lmat.reshape(-1)[flat] = (r & _REL_LO_MASK).astype(np.int32)
-            imat.reshape(-1)[flat] = row_idx[rows]
-            b.arrays = (vmat, hmat, lmat, imat, mmat)
-            b.g = g
-            b.sub_base = sub_base
-            b.n_sub = n_sub
-            b.rel = rel  # for host combine of split selectors
-        return buckets
+    def build(self, num_segments: int) -> bool:
+        """The freeze itself, under the caller's span and count (a grid
+        that refused freezes its fallback inside its own): the bucket
+        plan of these rows — built here, or taken from the batch of the
+        statement that built it: True — and this field's fill."""
+        plan, shared = self._plans.get(
+            ("buckets", num_segments), self._rel + self._seg,
+            lambda: _plan_buckets(self._rel, self._seg, num_segments))
+        vals = layoutplan.cat(self._vals)
+        mask = layoutplan.cat(self._mask)
+        self._frozen = [_Bucket(bp, self.dtype, vals, mask) for bp in plan]
+        return shared
 
     # -- execution -------------------------------------------------------
 
@@ -267,47 +216,169 @@ class BucketedBatch:
         return out, (sel if (is_selector and need_sel) else None), counts
 
 
-class _Bucket:
-    def __init__(self, width: int, segs: np.ndarray, seg_counts: np.ndarray):
+def _plan_buckets(rel_parts, seg_parts, num_segments: int) -> list:
+    """The bucket plan of a row set: one _BucketPlan a width in use.
+    Nothing here reads a value or a mask."""
+    seg = layoutplan.cat(seg_parts, np.int64)
+    n = len(seg)
+    counts = np.bincount(seg, minlength=num_segments)
+
+    # within-segment arrival offsets via run analysis (no global sort)
+    run_starts = np.concatenate([[0], np.flatnonzero(seg[1:] != seg[:-1]) + 1])
+    run_segs = seg[run_starts]
+    run_lens = np.diff(np.concatenate([run_starts, [n]]))
+    order = np.argsort(run_segs, kind="stable")  # runs, not rows
+    cum = np.zeros(len(run_starts), dtype=np.int64)
+    lens_sorted = run_lens[order]
+    segs_sorted = run_segs[order]
+    csum = np.cumsum(lens_sorted) - lens_sorted
+    first_run_of_seg = np.searchsorted(segs_sorted, segs_sorted)
+    base_sorted = csum - csum[first_run_of_seg]
+    cum[order] = base_sorted
+    offsets = (
+        np.arange(n, dtype=np.int64)
+        - np.repeat(run_starts, run_lens)
+        + np.repeat(cum, run_lens)
+    )
+
+    segs_of = []  # per bucket, the segments it holds
+    bucket_of = np.full(num_segments, -1, dtype=np.int8)
+    for bi, w in enumerate(WIDTHS):
+        lo = WIDTHS[bi - 1] if bi else 0
+        if w == WIDTHS[-1]:
+            here = counts > lo  # larger segments split into sub-rows
+        else:
+            here = (counts > lo) & (counts <= w)
+        segs_here = np.nonzero(here)[0]
+        if len(segs_here) == 0:
+            continue
+        bucket_of[segs_here] = len(segs_of)
+        segs_of.append((w, segs_here))
+
+    # the rows' relative times as one array, made when a selector asks
+    # (the time matrices, the host combine of split selectors)
+    rel = functools.cache(lambda: layoutplan.cat(rel_parts))
+    plan = []
+    for bi, (w, segs) in enumerate(segs_of):
+        seg_counts = counts[segs]
+        # sub-row layout: segment k gets ceil(count/w) consecutive rows
+        n_sub = np.maximum((seg_counts + w - 1) // w, 1)
+        sub_base = np.cumsum(n_sub) - n_sub  # first sub-row per segment
+        slot_of = np.zeros(num_segments, dtype=np.int64)
+        slot_of[segs] = sub_base
+        if len(segs_of) == 1:
+            rows, seg_here, off = None, seg, offsets  # every row, in order
+        else:
+            rows = np.nonzero(bucket_of[seg] == bi)[0]
+            seg_here, off = seg[rows], offsets[rows]
+        flat = (slot_of[seg_here] + off // w) * w + off % w
+        plan.append(_BucketPlan(w, segs, n_sub, sub_base, rows, flat, rel))
+    return plan
+
+
+class _BucketPlan:
+    """One width's bucket of a row set: its segments, their sub-rows and
+    the slot of every row — the half of a bucket every field of the
+    statement shares."""
+
+    def __init__(self, width, segs, n_sub, sub_base, rows, flat, rel):
         self.width = width
         self.segs = segs
-        self.seg_counts = seg_counts
-        self.arrays = None
-        self.g = 0
-        self.sub_base = None
-        self.n_sub = None
-        self.rel = None
+        self.n_sub = n_sub
+        self.sub_base = sub_base
+        self.rows = rows  # None: every row of the batch, in arrival order
+        self.flat = flat
+        self.rel = rel
+        self.g = int(n_sub.sum())
+        self.shape = (_pow2_at_least(self.g, _MIN_G), width)
+        self._selector_mats = None
+
+    def _own(self, column: np.ndarray) -> np.ndarray:
+        return column if self.rows is None else column[self.rows]
+
+    def _mat(self, own: np.ndarray, dtype) -> np.ndarray:
+        mat = np.zeros(self.shape, dtype=dtype)
+        mat.reshape(-1)[self.flat] = own
+        return mat
+
+    def scatter(self, column: np.ndarray, dtype) -> np.ndarray:
+        """One per-row column of the batch as this bucket's padded
+        matrix."""
+        return self._mat(self._own(column), dtype)
+
+    def selector_mats(self) -> tuple:
+        """(hmat, lmat, imat): every slot's split relative time and row
+        index.  Only the selector kernels read them, so they are built
+        when one is about to be launched — never for a `mean` under
+        GROUP BY time() — and, depending on the rows alone, once for all
+        the fields."""
+        if self._selector_mats is None:
+            with tracing.span("layout_build", rows=len(self.flat)):
+                r = self._own(self.rel())
+                idx = np.arange(len(r)) if self.rows is None else self.rows
+                self._selector_mats = (
+                    self._mat(r >> _REL_LO_BITS, np.int32),
+                    self._mat(r & _REL_LO_MASK, np.int32),
+                    self._mat(idx, np.int32))
+        return self._selector_mats
+
+
+class _Bucket:
+    """One field's bucket: its values and mask scattered through the
+    shared plan, and the statistics the kernels give of them."""
+
+    def __init__(self, plan: _BucketPlan, dtype, vals, mask):
+        self.plan = plan
+        self.width = plan.width
+        self.segs = plan.segs
+        self.g = plan.g
+        self.sub_base = plan.sub_base
+        self.n_sub = plan.n_sub
+        self.values = plan.scatter(vals, dtype)
+        self.mask = plan.scatter(mask, np.bool_)
         self._raw: dict = {}
         self._items: dict = {}  # kernel family -> its launch.Item
         self._combined: dict = {}
-        self._mesh_arrays = None
+        self._mesh_vm = None  # values and mask, row-sharded
+        self._mesh_times = None  # the selector matrices, row-sharded
         self._mesh_epoch = None
         self._ledger = None
 
-    def _device_arrays(self, mesh):
-        """Matrices for the kernels: with a configured mesh, row-sharded
-        device arrays (bucket rows are independent — GSPMD partitions the
-        dense reduces with zero collectives, parallel/distributed.py
+    def _args(self, mesh, family: str) -> tuple:
+        """What the family's kernel is passed: `basic` the values and the
+        mask, the selectors the plan's three time and index matrices
+        between them.  With a configured mesh, row-sharded device arrays
+        (bucket rows are independent — GSPMD partitions the dense
+        reduces with zero collectives, parallel/distributed.py
         shard_leading_axis); otherwise the host matrices as-is. The
-        sharded copy is keyed by mesh EPOCH so a hot config reload
+        sharded copies are keyed by mesh EPOCH so a hot config reload
         (runtime.set_mesh) reshards instead of serving a dead mesh."""
-        if mesh is None or self.g < mesh.size:
-            return self.arrays
-        from opengemini_tpu.parallel import runtime as _prt
-
-        epoch = _prt.mesh_epoch()
-        if self._mesh_arrays is None or self._mesh_epoch != epoch:
+        vm = (self.values, self.mask)
+        times = () if family == "basic" else self.plan.selector_mats()
+        if mesh is not None and self.g >= mesh.size:
             from opengemini_tpu.parallel import distributed as _dist
+            from opengemini_tpu.parallel import runtime as _prt
 
-            devobs.LEDGER.drop(getattr(self, "_ledger", None))
-            self._mesh_arrays = _dist.shard_leading_axis(
-                mesh, *self.arrays, xfer_site="bucket-shard")
-            self._mesh_epoch = epoch
-            self._ledger = devobs.LEDGER.register(
-                "bucket_mesh",
-                sum(int(a.nbytes) for a in self._mesh_arrays),
-                mesh_epoch=epoch, label="bucket", anchor=self)
-        return self._mesh_arrays
+            epoch = _prt.mesh_epoch()
+            if self._mesh_epoch != epoch:
+                devobs.LEDGER.drop(self._ledger)
+                self._mesh_vm = self._mesh_times = self._ledger = None
+                self._mesh_epoch = epoch
+            if self._mesh_vm is None:
+                self._mesh_vm = _dist.shard_leading_axis(
+                    mesh, *vm, xfer_site="bucket-shard")
+                self._ledger = devobs.LEDGER.register(
+                    "bucket_mesh", sum(int(a.nbytes) for a in self._mesh_vm),
+                    mesh_epoch=epoch, label="bucket", anchor=self)
+            if times and self._mesh_times is None:
+                self._mesh_times = _dist.shard_leading_axis(
+                    mesh, *times, xfer_site="bucket-shard")
+                devobs.LEDGER.update(self._ledger, sum(
+                    int(a.nbytes)
+                    for a in self._mesh_vm + self._mesh_times))
+            vm = self._mesh_vm
+            times = self._mesh_times if times else ()
+        return (vm[0], *times, vm[1])
 
     def launch_items(self, need_selectors: bool) -> list:
         """Items for the kernel families whose statistics are neither
@@ -321,14 +392,13 @@ class _Bucket:
     def _item(self, family: str):
         from opengemini_tpu.parallel import runtime as _prt
 
-        arrays = self._device_arrays(_prt.get_mesh())
+        args = self._args(_prt.get_mesh(), family)
         kind = family
-        if family == "selectors" and arrays is not self.arrays:
+        if family == "selectors" and args[0] is not self.values:
             # force the XLA selector form only when the inputs really are
             # mesh-sharded (pallas_call does not auto-partition);
             # unsharded buckets keep the fused Pallas kernel on TPU
             kind = "selectors_xla"
-        args = (arrays[0], arrays[4]) if family == "basic" else arrays
         return launch.Item("bucket_" + kind, _stats_fn(kind), args,
                            self._take)
 
@@ -382,7 +452,7 @@ class _Bucket:
                 ssd=np.add.reduceat(raw["ssd"] + extra, starts),
             )
         if need_selectors and "sel_first" not in out:
-            rel = self.rel
+            rel = self.plan.rel()
             i64max = np.iinfo(np.int64).max
             i64min = np.iinfo(np.int64).min
             for name, latest in (("first", False), ("last", True)):
@@ -417,13 +487,6 @@ class _Bucket:
 
 def _families(need_selectors: bool) -> tuple:
     return ("basic", "selectors") if need_selectors else ("basic",)
-
-
-def _index_of(buckets: list, b) -> int:
-    for i, x in enumerate(buckets):
-        if x is b:
-            return i
-    raise ValueError
 
 
 def _pow2_at_least(n: int, floor: int) -> int:

@@ -26,7 +26,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from opengemini_tpu.models import launch, ragged, templates
+from opengemini_tpu.models import launch, layoutplan, ragged, templates
 from opengemini_tpu.ops import aggregates as aggmod
 from opengemini_tpu.parallel import cluster as pcluster
 from opengemini_tpu.ops import window as winmod
@@ -81,9 +81,12 @@ class ScanContext:
 
 
 
-def pick_batch(schema, agg_names, field: str, dtype, grid_ctx=None):
+def pick_batch(schema, agg_names, field: str, dtype, grid_ctx=None,
+               plans=None):
     """Batch implementation for one field given the aggregate names that
-    will run on it. With a GROUP BY time() context (`grid_ctx` =
+    will run on it; `plans` is the statement's layoutplan.Plans, through
+    which the dense batches that are fed the same rows build their
+    layout's plan once. With a GROUP BY time() context (`grid_ctx` =
     (W, every_ns)), dense-capable aggregates try the regular-grid
     windows-on-lanes batch first (models/grid.py — the fastest layout,
     with built-in fallback when the scanned data is not constant-stride);
@@ -116,9 +119,9 @@ def pick_batch(schema, agg_names, field: str, dtype, grid_ctx=None):
         and schema.get(field) in (FieldType.FLOAT, FieldType.INT)
         and all(n in _grid.GRID_AGGS for n in agg_names)
     ):
-        return _grid.GridBatch(dtype, grid_ctx[0], grid_ctx[1])
+        return _grid.GridBatch(dtype, grid_ctx[0], grid_ctx[1], plans)
     if all(n in _ragged.DENSE_AGGS for n in agg_names):
-        return _ragged.BucketedBatch(dtype)
+        return _ragged.BucketedBatch(dtype, plans)
     return _templates.AggBatch(dtype)
 
 
@@ -1362,8 +1365,10 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
         for _call, spec, _params, fname in aggs:
             per_field_aggs.setdefault(fname, []).append(spec.name)
         grid_ctx = (W, group_time.every_ns) if group_time else None
+        plans = layoutplan.Plans()  # this statement's, shared by its fields
         batches: dict[str, object] = {
-            f: pick_batch(schema, per_field_aggs[f], f, dtype, grid_ctx)
+            f: pick_batch(schema, per_field_aggs[f], f, dtype, grid_ctx,
+                          plans)
             for f in needed_fields
         }
 
@@ -1942,9 +1947,10 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
                       if max(lo, rlo) < min(hi, rhi)]
             if not ranges:
                 continue
+            plans = layoutplan.Plans()  # a slice's rows are its own
             sbatches = {
                 f: pick_batch(schema, per_field_aggs[f], f, dtype,
-                              (W_s, group_time.every_ns))
+                              (W_s, group_time.every_ns), plans)
                 for f in needed_fields
             }
             if device_token is not None:

@@ -86,7 +86,7 @@ def compute_partials(engine, router, req: dict) -> bytes:
     aligned, every_ns, offset_ns, W, group_tags, aggs {field: [names]},
     tag_expr / field_expr (astjson docs), live, rf.
     """
-    from opengemini_tpu.models import templates
+    from opengemini_tpu.models import layoutplan, templates
     from opengemini_tpu.ops import aggregates as aggmod
     from opengemini_tpu.ops import window as winmod
     from opengemini_tpu.query import condition as cond
@@ -139,8 +139,9 @@ def compute_partials(engine, router, req: dict) -> bytes:
     # windows-on-lanes fast path for stride-regular data (pick_batch's
     # "both sides pick identical numerics" contract)
     grid_ctx = (W, every) if every else None
+    plans = layoutplan.Plans()
     batches = {
-        f: pick_batch(schema, per_field[f], f, dtype, grid_ctx)
+        f: pick_batch(schema, per_field[f], f, dtype, grid_ctx, plans)
         for f in per_field
     }
 

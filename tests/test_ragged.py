@@ -66,7 +66,7 @@ def test_bucket_shapes_canonical(rng):
     buckets = b._freeze(50)
     assert all(bk.width in ragged.WIDTHS for bk in buckets)
     for bk in buckets:
-        g_pad = bk.arrays[0].shape[0]
+        g_pad = bk.values.shape[0]
         assert (g_pad & (g_pad - 1)) == 0  # pow2-padded row counts
     # every non-empty segment appears exactly once
     seen = np.concatenate([bk.segs for bk in buckets])

@@ -503,8 +503,7 @@ def test_h2d_bytes_count_the_host_arrays_of_a_launch():
     assert prt.get_mesh() is None
     batch = _bucketed()
     buckets = batch._freeze(40)
-    want = sum(b.arrays[0].nbytes + b.arrays[4].nbytes for b in buckets)
-    assert want < sum(a.nbytes for b in buckets for a in b.arrays)
+    want = sum(b.values.nbytes + b.mask.nbytes for b in buckets)
     h0 = _counters("device").get("h2d_bytes_total", 0)
     q0 = _counters("query_stages")
     out, _sel, counts = batch.run(aggmod.get("mean"), 40)
@@ -513,15 +512,17 @@ def test_h2d_bytes_count_the_host_arrays_of_a_launch():
     d = _delta("query_stages", q0)
     assert d["device_launch_count"] == d["device_fetch_count"] == \
         d["host_combine_count"] == len(buckets)
+    # ... and until a selector is asked for those three are not built
+    assert all(b.plan._selector_mats is None for b in buckets)
     # a selector reads all five matrices
     h1 = _counters("device")["h2d_bytes_total"]
     batch.run(aggmod.get("first"), 40)
-    assert _counters("device")["h2d_bytes_total"] - h1 == \
-        sum(a.nbytes for b in buckets for a in b.arrays)
+    assert _counters("device")["h2d_bytes_total"] - h1 == want + sum(
+        a.nbytes for b in buckets for a in b.plan.selector_mats())
     # the same launch over arrays that already live on the device
     again = _bucketed()
     for b in again._freeze(40):
-        b.arrays = tuple(jax.device_put(a) for a in b.arrays)
+        b.values, b.mask = jax.device_put(b.values), jax.device_put(b.mask)
     h1 = _counters("device")["h2d_bytes_total"]
     out2, _sel, _counts = again.run(aggmod.get("mean"), 40)
     assert _counters("device")["h2d_bytes_total"] == h1

@@ -268,11 +268,19 @@ def test_a_served_query_counts_one_launch_a_group(engine):
             url = (f"http://127.0.0.1:{svc.port}/query?"
                    + urllib.parse.urlencode({"db": "db", "q": q}))
             q0 = _stages()
+            e0 = STATS.counters("executor")
             with urllib.request.urlopen(url, timeout=60) as r:
                 doc = json.loads(r.read())
             assert len(doc["results"][0]["series"]) == 24
             assert _moved(q0, "device_launch_count") == groups
             assert _moved(q0, "device_fetch_count") == groups
+            # `layout_build` opens once a batch: the first's covers the
+            # statement's one plan and its own fill, the others' their
+            # fills of the plan they took from it (models/layoutplan.py)
             assert _moved(q0, "layout_build_count") == fields
+            e1 = STATS.counters("executor")
+            assert e1["grid_batches"] - e0.get("grid_batches", 0) == fields
+            assert e1.get("layout_plans_shared", 0) \
+                - e0.get("layout_plans_shared", 0) == fields - 1
     finally:
         svc.stop()
