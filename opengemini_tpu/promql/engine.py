@@ -371,7 +371,6 @@ class PromEngine:
 
             if SLOWLOG.enabled():
                 SLOWLOG.note(qid, text, db, dur_ns / 1e6, trace=trace,
-                             stages=TRACKER.stages_of(qid),
                              extra={"kind": "promql"})
             TRACKER.unregister(qid)
 

@@ -710,9 +710,7 @@ class RuleManager:
 
                 if SLOWLOG.enabled():
                     SLOWLOG.note(qid, f"rules {g.db}.{g.name}", g.db,
-                                 dur_ns / 1e6,
-                                 stages=TRACKER.stages_of(qid),
-                                 extra={"kind": "rules"})
+                                 dur_ns / 1e6, extra={"kind": "rules"})
                 TRACKER.unregister(qid)
         STATS.incr("rules", "ticks")
         return True

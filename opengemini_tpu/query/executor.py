@@ -498,10 +498,9 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
             from opengemini_tpu.utils.slowlog import GLOBAL as SLOWLOG
 
             if SLOWLOG.enabled():
-                # capture BEFORE unregister: the stage attribution map
-                # lives on the running-query entry
-                SLOWLOG.note(qid, text, db, dur_ns / 1e6, trace=trace,
-                             stages=TRACKER.stages_of(qid))
+                # capture BEFORE unregister: a statement with no root
+                # above it loses its account there
+                SLOWLOG.note(qid, text, db, dur_ns / 1e6, trace=trace)
             if qid is not None:
                 TRACKER.unregister(qid)
             token.release()

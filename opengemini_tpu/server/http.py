@@ -173,6 +173,7 @@ class HttpService:
 
     def start(self) -> None:
         tracing.watch_gc()  # runtime/gc_* from the first request on
+        tracing.watch_pulse()   # runtime/pulse_*, stall records
         self._thread = threading.Thread(target=self.httpd.serve_forever, daemon=True)
         self._thread.start()
 
@@ -247,6 +248,24 @@ def _make_handler(svc: HttpService):
 
         def log_message(self, fmt, *args):  # quiet; logging layer comes later
             pass
+
+        def handle_one_request(self):
+            # during a profiler capture the wait for this connection's
+            # next request line is `ogt:conn_idle`: an idle gap of the
+            # device then reads "nothing was asked", "the process stood
+            # still" (`ogt:pulse`) or a stage.  Annotation only
+            ann = tracing.annotated("conn_idle")
+            if ann is not None:
+                try:
+                    self.rfile.peek(1)
+                except TimeoutError:
+                    self.close_connection = True
+                    return
+                except OSError:
+                    pass            # the read below meets it again
+                finally:
+                    ann.__exit__(None, None, None)
+            super().handle_one_request()
 
         # -- plumbing -------------------------------------------------------
 
@@ -512,6 +531,10 @@ def _make_handler(svc: HttpService):
                     _t.perf_counter() - STATS.started_pc, 1),
                                    "version": __version__}}
                 snap.update(STATS.snapshot())
+                # not counters (/metrics and the monitor skip them): the
+                # slowest requests since the mark, and the late beats
+                snap["tail"] = tracing.tail_doc()
+                snap["stalls"] = tracing.stalls_doc()
                 self._send_json(200, snap)
             elif path == "/debug/queries":
                 from opengemini_tpu.utils.querytracker import (
@@ -536,7 +559,9 @@ def _make_handler(svc: HttpService):
             elif path == "/debug/slow":
                 from opengemini_tpu.utils.slowlog import GLOBAL as _SLOW
 
-                self._send_json(200, _SLOW.snapshot())
+                self._send_json(200, dict(
+                    _SLOW.snapshot(), tail=tracing.tail_doc(),
+                    stalls=tracing.stalls_doc()))
             else:
                 self._send_json(404, {"error": "not found"})
 
@@ -1436,6 +1461,8 @@ def _make_handler(svc: HttpService):
                 if params.get("clear", "") in ("1", "true"):
                     _SLOW.clear()
                     tracing.clear_recent()
+                if params.get("mark", "") in ("1", "true"):
+                    tracing.mark()      # the tails and the pulse's maximum
                 slow = _SLOW.snapshot()
                 self._send_json(200, {
                     "status": "ok",

@@ -47,9 +47,10 @@ armed or not):
       `device_launch` span (utils/tracing.py; always on) around the
       call, and the host arrays among its arguments — the implicit H2D
       of a single-chip launch — counted as h2d bytes.  fetch_np() and
-      fetch_tree() wrap the device->host materialization (np.asarray of
-      a jax array: the device wait plus the D2H) in a `device_fetch`
-      span, one a launch, and count its bytes.
+      fetch_tree() wrap the device->host materialization in a
+      `device_fetch` span, one a launch, with the wait for the program
+      (`device_wait`) and the copies (`device_copy`) as its children,
+      and count its bytes.
 
   device-memory ledger every RETAINED device buffer registers (owner,
       nbytes, mesh-epoch): the colcache device tier, grid `mesh_arrays`
@@ -313,6 +314,10 @@ def mark_warm() -> None:
     with _lock:
         _warm_marked = True
         _compiles_since_warm = 0
+    # the same epoch for the slowest requests and the pulse's maximum
+    from opengemini_tpu.utils import tracing
+
+    tracing.mark()
 
 
 def clear_warm() -> None:
@@ -417,33 +422,41 @@ def _fetch(x, site: str):
 
 
 def fetch_np(x, site: str = "result-fetch"):
-    """np.asarray with d2h accounting: a device array is fetched inside
-    a `device_fetch` span (the wait for the device, then the copy) and
-    its bytes counted; host arrays pass straight through."""
+    """np.asarray with d2h accounting: a device array is fetched as
+    `fetch_tree` fetches a result of one array, and its bytes counted;
+    host arrays pass straight through."""
     import jax
 
     if not isinstance(x, jax.Array):
         return _np.asarray(x)
-    from opengemini_tpu.utils import tracing
-
-    with tracing.span("device_fetch") as sp:
-        a = _fetch(x, site)
-        sp.add_field("bytes", a.nbytes)
-    return a
+    return fetch_tree(x, site)
 
 
 def fetch_tree(outs, site: str = "result-fetch"):
-    """fetch_np over the arrays of ONE launch's result (any pytree: a
-    launch group's packed pair, a result dict), as ONE `device_fetch`
-    span: the wait for the program, then one copy an array."""
+    """The arrays of ONE launch's result (any pytree: a launch group's
+    packed pair, a result dict) brought to the host as ONE `device_fetch`
+    span with two children, so that a slow fetch says which half it
+    waited in: `device_wait`, until the program has finished, then
+    `device_copy`, one np.asarray an array.  The copies are asked for
+    before the wait, so they queue behind the program and travel
+    together: the wait costs no round trip of its own (on a v5e a
+    panel's two arrays 0.83 ms so, 1.19 ms by np.asarray alone, 1.30 ms
+    waiting first and asking then: PERF.md, PR 39)."""
     import jax
 
     from opengemini_tpu.utils import tracing
 
     with tracing.span("device_fetch") as sp:
-        got = jax.tree_util.tree_map(lambda x: _fetch(x, site), outs)
-        sp.add_field("bytes", sum(
-            a.nbytes for a in jax.tree_util.tree_leaves(got)))
+        with tracing.span("device_wait"):
+            for x in jax.tree_util.tree_leaves(outs):
+                if isinstance(x, jax.Array):
+                    x.copy_to_host_async()
+            jax.block_until_ready(outs)
+        with tracing.span("device_copy"):
+            got = jax.tree_util.tree_map(lambda x: _fetch(x, site), outs)
+        nbytes = sum(a.nbytes for a in jax.tree_util.tree_leaves(got))
+        sp.add_field("bytes", nbytes)
+        tracing.note_d2h(nbytes)
     return got
 
 
@@ -693,7 +706,7 @@ def pallas_supported() -> tuple[bool, str]:
 
 _profile_lock = lockdep.Lock()
 _profile = {"active": False, "dir": None, "started_uptime_s": None,
-            "seconds": None, "last": None}
+            "started_perf_ns": None, "seconds": None, "last": None}
 
 
 def start_profile(seconds: float, logdir: str | None = None,
@@ -725,6 +738,9 @@ def start_profile(seconds: float, logdir: str | None = None,
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 1 if python else 0
     try:
+        # the capture's start on the clock of request and stall records
+        # (tracing: `t0_ns`, `t_ns`), to place one on it by subtraction
+        _profile["started_perf_ns"] = time.perf_counter_ns()
         jax.profiler.start_trace(logdir, profiler_options=options)
     except Exception as e:  # noqa: BLE001 — surface, don't wedge the guard
         with _profile_lock:
@@ -735,7 +751,8 @@ def start_profile(seconds: float, logdir: str | None = None,
 
     def _stop():
         time.sleep(seconds)
-        doc = {"dir": logdir, "seconds": seconds, "ok": True}
+        doc = {"dir": logdir, "seconds": seconds, "ok": True,
+               "started_perf_ns": _profile["started_perf_ns"]}
         t_stop = time.perf_counter()
         try:
             jax.profiler.stop_trace()

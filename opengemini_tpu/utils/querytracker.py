@@ -48,21 +48,32 @@ class QueryTracker:
         self._admission_provider = None
 
     def register(self, text: str, db: str) -> int:
+        # the statement's stages are its request's account
+        # (utils/tracing.py), the one stage map there is: a span's close
+        # adds to it with no lock and this registry only looks at it
+        from opengemini_tpu.utils import tracing
+
+        account, made = tracing.statement_account()
+        info = {"query": redact(text), "database": db,
+                "started": time.monotonic(), "account": account,
+                "account_made": made}
         with self._lock:
             qid = self._next
             self._next += 1
-            self._running[qid] = {
-                "query": redact(text), "database": db,
-                "started": time.monotonic(),
-            }
+            self._running[qid] = info
+        account.qids.append(qid)
         self._local.qid = qid
         return qid
 
     def unregister(self, qid: int) -> None:
         with self._lock:
-            self._running.pop(qid, None)
+            info = self._running.pop(qid, None)
             self._killed.discard(qid)
         self._local.qid = None
+        if info is not None and info["account_made"]:
+            from opengemini_tpu.utils import tracing
+
+            tracing.release_account(info["account"])
 
     def kill(self, qid: int) -> bool:
         with self._lock:
@@ -108,27 +119,13 @@ class QueryTracker:
             return info.get("trace") if info else None
 
     def stages_of(self, qid: int | None) -> dict:
-        """Copy of the per-stage ns attribution for one running query
-        (the slow-log grabs it just before unregister)."""
+        """Per-stage ns of one running query: a copy of its request's
+        account so far (pool threads' stages folded in)."""
         if qid is None:
             return {}
         with self._lock:
             info = self._running.get(qid)
-            return dict(info.get("stages", ())) if info else {}
-
-    def add_stage_ns(self, qid: int | None, name: str, ns: int) -> None:
-        """Attribute stage time (e.g. the decoded-column cache's lookup /
-        fill work, storage/colcache.py) to a running query so SHOW
-        QUERIES-style snapshots expose where a long query spends its
-        time.  No-op off-query or after the query unregistered; helper
-        threads (scan pool) bind the owning qid per task."""
-        if qid is None or ns <= 0:
-            return
-        with self._lock:
-            info = self._running.get(qid)
-            if info is not None:
-                stages = info.setdefault("stages", {})
-                stages[name] = stages.get(name, 0) + ns
+        return info["account"].stage_ns() if info else {}
 
     def note_route(self, qid: int | None, stage: str, route: str) -> None:
         """Record the offload planner's chosen route (host/device/mesh)
@@ -161,7 +158,7 @@ class QueryTracker:
                     # per-stage attribution (colcache etc.), ms
                     "stages": {
                         name: ns // 1_000_000
-                        for name, ns in info.get("stages", {}).items()
+                        for name, ns in info["account"].stage_ns().items()
                     },
                 }
                 routes = info.get("routes")

@@ -1,8 +1,13 @@
-"""Slow-query capture: a bounded ring of the most recent queries that
+"""Slow-query capture: a bounded ring of the most recent requests that
 crossed the OGT_SLOW_QUERY_MS threshold, each record carrying enough to
 answer "which node/stage ate the time" after the fact — the statement,
-database/tenant, per-stage timings, the stitched cross-node span tree
-(when tracing is armed), and the governor ledger at completion.
+database/tenant, the request record of utils/tracing.py (`request`:
+the root's account so far — stages, CPU and off-CPU time, collector
+and stalled time; `stages_ms` is its stage map in ms), the stitched
+cross-node span tree (when tracing is armed), and the governor ledger
+at completion.  A statement is noted where it ends (executor, PromQL
+engine, rules tick); a request no statement speaks for (/write) by its
+root span when it closes.
 
 Reference: the query-manager slow-log + lib/statisticsPusher slow-query
 statistics.  Served at /debug/slow, tuned via /debug/ctrl?mod=obs,
@@ -60,17 +65,20 @@ class SlowLog:
                     self._ring = deque(self._ring, maxlen=slow_max)
 
     def note(self, qid, text: str, db: str, duration_ms: float,
-             trace=None, stages: dict | None = None,
-             extra: dict | None = None) -> bool:
+             trace=None, extra: dict | None = None,
+             request: dict | None = None) -> bool:
         """Record one finished query if it crossed the threshold.
-        `trace` is the (finished) tracing.Trace or None; `stages` the
-        querytracker per-stage ns map (colcache/rollup/admission_wait
+        `trace` is the (finished) tracing.Trace or None; `request` the
+        request record, by default the calling thread's so far (stage
         attribution rides along even with span trees off)."""
         thresh = self.threshold_ms
         if thresh is None or duration_ms < thresh:
             return False
+        from opengemini_tpu.utils import tracing
         from opengemini_tpu.utils.querytracker import redact
 
+        if request is None:
+            request = tracing.current_record()
         rec = {
             "qid": qid,
             "time": time.strftime("%Y-%m-%dT%H:%M:%S", time.gmtime()),
@@ -79,9 +87,10 @@ class SlowLog:
             "database": db,
             "tenant": db,  # the governor's tenant identity is the db
             "stages_ms": {
-                name: round(ns / 1e6, 3)
-                for name, ns in (stages or {}).items()
+                name: round(m[0] / 1e6, 3)
+                for name, m in (request or {}).get("stages", {}).items()
             },
+            "request": request,
             "trace": trace.to_dict() if trace is not None else None,
         }
         try:

@@ -711,3 +711,77 @@ class TestSherlockEmbedsSlowLog:
         assert "== slow queries ==" in text
         assert "SELECT count(v) FROM m" in text
         eng.close()
+
+
+# -- one stage map a request (PR 39) ------------------------------------------
+
+
+class _CountingLock:
+    """A lock that counts how often it is taken."""
+
+    def __init__(self, lock):
+        self.lock, self.taken = lock, 0
+
+    def __enter__(self):
+        self.taken += 1
+        return self.lock.__enter__()
+
+    def __exit__(self, *exc):
+        return self.lock.__exit__(*exc)
+
+
+class TestOneStageMap:
+    def test_the_trackers_stages_are_a_view_of_the_roots_account(self):
+        from opengemini_tpu.utils.querytracker import GLOBAL as TRACKER
+
+        with tracing.request("t_onemap") as root:
+            qid = TRACKER.register("SELECT 1", "db")
+            try:
+                with tracing.span("t_viewed"):
+                    pass
+                tracing.record_stage("t_viewed", 5)
+                # the registry keeps the account itself, and no map beside it
+                info = TRACKER._running[qid]
+                assert info["account"] is root.acct
+                assert "stages" not in info
+                ns = root.acct.stages["t_viewed"][0]
+                assert TRACKER.stages_of(qid) == {"t_viewed": ns}
+                [snap] = [q for q in TRACKER.snapshot() if q["qid"] == qid]
+                assert snap["stages"] == {"t_viewed": ns // 1_000_000}
+                assert root.acct.qids == [qid]
+            finally:
+                TRACKER.unregister(qid)
+            # the root's account outlives the statement: `send` is still its
+            with tracing.span("t_after"):
+                pass
+            assert set(root.acct.stages) == {"t_viewed", "t_after"}
+
+    def test_a_spans_close_takes_the_registrys_lock_and_the_histograms_only(
+            self, monkeypatch):
+        from opengemini_tpu.utils.querytracker import GLOBAL as TRACKER
+
+        with tracing.span("t_locks"):       # its keys and histogram exist
+            pass
+        hist = stats.histogram("query_stage_seconds", stage="t_locks")
+        locks = {"tracker": _CountingLock(TRACKER._lock),
+                 "registry": _CountingLock(stats.GLOBAL._lock),
+                 "pool": _CountingLock(tracing._POOL_LOCK),
+                 "tail": _CountingLock(tracing._TAIL_LOCK),
+                 "histogram": _CountingLock(hist._lock)}
+        with tracing.request("t_lockroute"):
+            qid = TRACKER.register("SELECT 1", "db")
+            try:
+                monkeypatch.setattr(TRACKER, "_lock", locks["tracker"])
+                monkeypatch.setattr(stats.GLOBAL, "_lock", locks["registry"])
+                monkeypatch.setattr(tracing, "_POOL_LOCK", locks["pool"])
+                monkeypatch.setattr(tracing, "_TAIL_LOCK", locks["tail"])
+                monkeypatch.setattr(hist, "_lock", locks["histogram"])
+                for _ in range(10):
+                    with tracing.span("t_locks"):
+                        pass
+                monkeypatch.undo()
+            finally:
+                TRACKER.unregister(qid)
+        assert {k: v.taken for k, v in locks.items()} == {
+            "tracker": 0, "registry": 10, "pool": 0, "tail": 0,
+            "histogram": 10}

@@ -32,12 +32,13 @@ MISS_SPANS = {"decode", "pool_wait", "block_read", "codec", "colcache_fill",
               "scan_merge"}
 QUERY_SPANS = {"sql_parse", "select: cpu", "map_shards", "scan", *MISS_SPANS,
                "mem_read", "colcache", "device_compute", "layout_build",
-               "device_launch", "device_fetch", "host_combine", "inc_cache",
+               "device_launch", "device_fetch", "device_wait", "device_copy",
+               "host_combine", "inc_cache",
                "render", "format", "serialize", "send"}
 PROM_SPANS = {"prom_parse", "prom_collect", *MISS_SPANS, "mem_read",
               "prom_prepare",
-              "prom_kernel", "device_launch", "device_fetch", "prom_render",
-              "serialize", "send"}
+              "prom_kernel", "device_launch", "device_fetch", "device_wait",
+              "device_copy", "prom_render", "serialize", "send"}
 WRITE_SPANS = {"read_body", "lp_parse", "type_check", "write_hooks",
                "write_lock_wait", "index_route", "memtable_apply",
                "wal_append", "wal_commit", "flush_inline", "write_observers",
@@ -224,6 +225,12 @@ def test_a_query_leaves_a_tree(server):
     for name in ("layout_build", "device_launch", "device_fetch",
                  "host_combine"):
         assert all(p["name"] == "device_compute" for _, p in spans[name])
+    # a fetch's two halves, once each a fetch, in that order
+    for fetch, _ in spans["device_fetch"]:
+        assert [c["name"] for c in fetch["children"]
+                if c["name"] != "gc"] == ["device_wait", "device_copy"]
+        assert sum(c["elapsed_ns"] for c in fetch["children"]) \
+            <= fetch["elapsed_ns"]
     # the bulk reads missed the cache and decoded, one span each, from
     # the prefetch thread, under the scan that dispatched them
     assert len(spans["decode"]) == 2
@@ -287,6 +294,10 @@ def test_a_promql_query_leaves_a_tree(server):
         assert [p["name"] for _, p in spans[name]] == ["http_prom"], name
     # nothing was flushed: the collect read the memtable, under one span
     assert [p["name"] for _, p in spans["mem_read"]] == ["prom_collect"]
+    # where the answer came from the device, each fetch has its two halves
+    for name in ("device_wait", "device_copy"):
+        assert [p["name"] for _, p in spans.get(name, [])] \
+            == ["device_fetch"] * len(spans.get("device_fetch", [])), name
 
 
 def test_a_range_answer_counts_the_points_it_rendered(server):
@@ -429,8 +440,10 @@ def test_a_capture_holds_the_spans_on_its_own_clock(tmp_path, python):
     lines = _capture_events(logdir)
     by = {name: (start, end, line, stats) for line, evs in lines.items()
           for name, start, end, stats in evs if name.startswith("ogt:")}
-    assert set(by) == {"ogt:http_query", "ogt:t_stage", "ogt:t_leaf",
-                       "ogt:t_pool_stage"}
+    # where an earlier test's server started the pulse, its sleeps are
+    # annotated too (tests/test_request_records.py holds that)
+    assert set(by) - {"ogt:pulse"} == {"ogt:http_query", "ogt:t_stage",
+                                       "ogt:t_leaf", "ogt:t_pool_stage"}
     root, stage, leaf = by["ogt:http_query"], by["ogt:t_stage"], \
         by["ogt:t_leaf"]
     # nested as the spans were, on one thread's line
