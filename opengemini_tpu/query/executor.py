@@ -1374,7 +1374,7 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
         # incremental result cache (reference inc_agg_transform +
         # lib/resultcache): GROUP BY time() windows whose shards took no
         # writes since the last execution are served from cached
-        # (value, count) cells; only the stale hull is scanned/computed
+        # (value, count) columns; only the stale hull is scanned/computed
         cache_plan = None
         if (
             group_time is not None

@@ -3,11 +3,16 @@
 The reference serves repeated dashboard queries from cached partials with
 incremental append (engine/executor/inc_agg_transform.go,
 inc_hash_agg_transform.go, lib/resultcache/). Here the unit of caching is
-one (group, window) cell: with GROUP BY time() the renderer never needs
+one window's columns: with GROUP BY time() the renderer never needs
 selector row identities (output times are window starts), so a cached
-cell is just ``(value, count)`` per aggregate — losslessly re-renderable
-under any fill/limit/order, including fill(previous)/linear which the
-renderer applies over the merged window sequence.
+window is the tuple of group keys it was computed over and, per
+aggregate, one array of values in that aggregate's own dtype beside an
+int64 array of counts — losslessly re-renderable under any
+fill/limit/order, including fill(previous)/linear which the renderer
+applies over the merged window sequence. The key tuple is one object
+shared by every window a statement stores (and by the windows a re-asked
+panel stores later, while its groups stay the same), so neither storing
+nor reading a window builds a Python object a (group, window) cell.
 
 Validity is tracked per window by the (path, data_version) signature of
 every shard overlapping it (storage/shard.py data_version: bumped by
@@ -49,7 +54,13 @@ class IncrementalCache:
         self.max_windows = max_windows
 
     def lookup(self, fp: str) -> dict:
-        """-> {window_start: (sig, {group_key: [(value, count), ...]})}.
+        """-> {window_start: (sig, keys, idx, values, counts)}: `keys` the
+        tuple of group keys the window was computed over (one object
+        shared between windows), `idx` the positions in it of the groups
+        with data in this window (None: all of them), `values` one 1-D
+        array an aggregate in its own dtype and `counts` one
+        (aggregates, groups-with-data) int64 array, both aligned to
+        `idx`; nothing in an entry is ever written again.
         Returns a shallow COPY — update() mutates/evicts the live entry
         concurrently and a plan must keep seeing the windows it
         validated."""
@@ -202,9 +213,6 @@ class CachePlan:
                 runs.append([ws, we])
         return [tuple(r) for r in runs]
 
-    def _fresh_ws(self):
-        return sorted(self.stale)
-
     def merge(self, agg_results, aggs, group_keys):
         """Overwrite cached windows into the computed arrays (extending
         group_keys with cache-only groups), then persist the freshly
@@ -212,84 +220,85 @@ class CachePlan:
         counts, spec, fname, times_abs); with GROUP BY time the renderer
         consumes only (out, counts, spec, fname)."""
         W = self.W
-        gid_of = {k: i for i, k in enumerate(group_keys)}
         hull = self.stale
-        for w in range(W):
-            if w in hull:
-                continue
-            _sig, groups = self.cached[self.wstarts[w]]
-            for key in groups:
-                if key not in gid_of:
-                    gid_of[key] = len(group_keys)
-                    group_keys.append(key)
+        reused = [(w, self.cached[self.wstarts[w]])
+                  for w in range(W) if w not in hull]
+        # group key -> gid once a distinct key tuple (by identity: the
+        # windows of one statement share theirs), not once a window; a
+        # cache-only group extends group_keys where a reused window has
+        # data for it, in the order of the tuples met and of their keys
+        # (both renderers sort the groups, so the order shows nowhere)
+        keysets: dict[int, tuple] = {}
+        for _w, (_sig, keys, idx, _vals, _cnts) in reused:
+            ks = keysets.get(id(keys))
+            if ks is None:
+                ks = keysets[id(keys)] = (keys, np.zeros(len(keys), bool))
+            ks[1][slice(None) if idx is None else idx] = True
+        gid_of = {k: i for i, k in enumerate(group_keys)} if keysets else {}
+        gids_of: dict[int, np.ndarray] = {}
+        for keys, used in keysets.values():
+            gids = np.fromiter((gid_of.get(k, -1) for k in keys),
+                               np.int64, len(keys))
+            for p in np.flatnonzero(used & (gids < 0)).tolist():
+                gids[p] = gid_of[keys[p]] = len(group_keys)
+                group_keys.append(keys[p])
+            gids_of[id(keys)] = gids
         G = len(group_keys)
-        n_seg = G * W
 
-        merged = {}
-        for ai, (call, spec, params, fname) in enumerate(aggs):
-            out, sel, counts, spec_, fname_, times_abs = agg_results[id(call)]
+        outs2d, cnts2d = [], []
+        for call, _s, _p, _f in aggs:
+            out, _sel, counts, spec_, fname_, _times = agg_results[id(call)]
             out = np.asarray(out)
-            new_out = np.zeros(n_seg, dtype=out.dtype)
-            new_cnt = np.zeros(n_seg, dtype=np.int64)
+            new_out = np.zeros((G, W), dtype=out.dtype)
+            new_cnt = np.zeros((G, W), dtype=np.int64)
             old_G = len(out) // W if W else 0
             if len(out):
-                new_out.reshape(G, W)[:old_G] = out.reshape(old_G, W)
-                new_cnt.reshape(G, W)[:old_G] = np.asarray(counts).reshape(
-                    old_G, W)
-            merged[id(call)] = (new_out, new_cnt, spec_, fname_)
-        n_aggs = len(aggs)
-        for w in range(W):
-            if w in hull:
-                continue
-            _sig, groups = self.cached[self.wstarts[w]]
-            if not groups:
-                continue
-            # vectorized per (window, agg): one fancy-index assignment
-            # over all of the window's cached groups
-            gids = np.fromiter((gid_of[key] for key in groups),
-                               np.int64, len(groups))
-            cells = np.asarray(
-                [[c[1] for c in v] for v in groups.values()], np.int64)
-            segs = gids * W + w
-            for ai, (call, _s, _p, _f) in enumerate(aggs):
-                new_out, new_cnt, _sp, _fn = merged[id(call)]
-                if new_out.dtype.kind in "iu":
-                    # int-exact values stay python-int end-to-end: a
-                    # float64 staging array would corrupt sums > 2^53
-                    new_out[segs] = np.fromiter(
-                        (v[ai][0] for v in groups.values()),
-                        np.int64, len(groups))
-                else:
-                    new_out[segs] = np.fromiter(
-                        (v[ai][0] for v in groups.values()),
-                        np.float64, len(groups))
-                new_cnt[segs] = cells[:, ai]
+                new_out[:old_G] = out.reshape(old_G, W)
+                new_cnt[:old_G] = np.asarray(counts).reshape(old_G, W)
+            outs2d.append(new_out)
+            cnts2d.append(new_cnt)
+            agg_results[id(call)] = (new_out.reshape(-1), None,
+                                     new_cnt.reshape(-1), spec_, fname_, None)
+        # one fancy-index assignment a (window, aggregate), each column
+        # in the dtype it was computed in (an int-exact sum stays int64)
+        for w, (_sig, keys, idx, vals, cnts) in reused:
+            gids = gids_of[id(keys)]
+            if idx is not None:
+                gids = gids[idx]
+            for ai in range(len(aggs)):
+                outs2d[ai][gids, w] = vals[ai]
+                cnts2d[ai][gids, w] = cnts[ai]
 
         # persist the recomputed windows (never the partial edge windows;
         # only groups with data — zero cells rebuild as zeros on read, so
-        # sparse windows stay cheap at high group cardinality)
-        keys_by_gid = list(gid_of)  # insertion order == gid order
-        outs2d = [merged[id(call)][0].reshape(G, W) for call, *_ in aggs]
-        cnts2d = [merged[id(call)][1].reshape(G, W) for call, *_ in aggs]
+        # sparse windows stay cheap at high group cardinality): one `has`
+        # matrix a statement, then column copies a window
+        store = [w for w in sorted(hull) if w not in self.partial]
+        if not store:
+            return group_keys
+        # the statement's one key tuple, or the equal one its reused
+        # windows hold already (a panel re-asked over a moving range)
+        keys = tuple(group_keys)
+        held = next((k for k, _u in keysets.values() if k == keys), None)
+        if held is not None:
+            keys = held
+        cnt = np.stack([c[:, store].T for c in cnts2d], axis=1)  # (S, A, G)
+        has = (cnt > 0).any(axis=1)                               # (S, G)
+        whole = has.all(axis=1).tolist()
         fresh: dict[int, tuple] = {}
-        for w in self._fresh_ws():
-            if w in self.partial:
-                continue
-            col_cnt = np.stack([c[:, w] for c in cnts2d])  # (n_aggs, G)
-            col_out = np.stack([o[:, w] for o in outs2d])
-            has = np.flatnonzero((col_cnt > 0).any(axis=0))
-            groups = {
-                keys_by_gid[g]: [
-                    (col_out[ai, g].item(), int(col_cnt[ai, g]))
-                    for ai in range(n_aggs)
-                ]
-                for g in has
-            }
-            fresh[self.wstarts[w]] = (self.sigs[w], groups)
-        if fresh:
-            self.cache.update(self.fp, fresh)
-
-        for call, _s, _p, _f in aggs:
-            new_out, new_cnt, sp, fn = merged[id(call)]
-            agg_results[id(call)] = (new_out, None, new_cnt, sp, fn, None)
+        for j, w in enumerate(store):
+            if whole[j]:
+                idx = None
+                vals = tuple(o[:, w].copy() for o in outs2d)
+                cnts = cnt[j].copy()
+            else:
+                idx = np.flatnonzero(has[j])
+                vals = tuple(o[idx, w] for o in outs2d)
+                cnts = cnt[j][:, idx]
+            fresh[self.wstarts[w]] = (self.sigs[w], keys, idx, vals, cnts)
+        self.cache.update(self.fp, fresh)
+        # a window stored alone under a tuple built for it shares nothing
+        shared = len(fresh) if held is not None or len(fresh) > 1 else 0
+        STATS.add("executor", (("inc_cache_windows_stored", len(fresh)),
+                               ("inc_cache_keysets_shared", shared)))
         return group_keys
