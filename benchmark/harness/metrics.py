@@ -61,13 +61,15 @@ def device_field(ctx: dict, p: dict):
 
 
 def window_decisions(ctx: dict) -> list[dict]:
-    """The planner's decisions made inside the window, oldest first: ring
-    entries whose per-geometry use count is past the count at the start."""
-    before = {(m["kernel"], m["geometry"]): m["uses"]
-              for m in ctx["dev0"]["planner"]["model"]}
-    ring = reversed(ctx["dev1"]["planner"]["decisions"])     # newest first
-    return [d for d in ring
-            if d["uses"] > before.get((d["kernel"], d["geometry"]), 0)]
+    """The planner's decisions made inside the window, oldest first: as many
+    of the ring's newest entries as `offload/decisions_total` grew by (one
+    count an entry; a frozen planner goes on counting and writing its ring,
+    and no longer moves a geometry's `uses`)."""
+    p0, p1 = ctx["dev0"]["planner"], ctx["dev1"]["planner"]
+    made = int(p1["counters"].get("decisions_total", 0)
+               - p0["counters"].get("decisions_total", 0))
+    ring = p1["decisions"]                                   # newest first
+    return ring[:max(0, made)][::-1]
 
 
 def planner_ring(ctx: dict, p: dict):

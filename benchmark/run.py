@@ -8,11 +8,21 @@ This process stays off JAX.  It builds the native libraries, starts ONE
 server through its CLI entry point with the server's defaults, exits
 non-zero unless the server reports platform `tpu` with as many chips as the
 cell asks for, makes the data from --seed, loads it, quiesces the server,
-warms up until no program is built and the planner stands still, measures
-for --seconds, verifies answers against the plain reference, stops the
-server and prints one JSON line.  Everything that belongs to one
+warms up until no program is built and the planner stands still, pins the
+planner's model there (`/debug/ctrl?mod=offload&freeze=1`: the route is
+the one the server chose; no OGT_* variable is set and nothing is forced),
+measures for --seconds, verifies answers against the plain reference, stops
+the server and prints one JSON line.  Everything that belongs to one
 configuration, one traffic mix or one per-layer metric is a file of its
-own under benchmark/, found by the name in BENCHMARK.json."""
+own under benchmark/, found by the name in BENCHMARK.json.
+
+A run ends in a result line that means what it says, or exits non-zero
+having printed why (`benchmark FAILED: ...`), and in both cases no process
+of its own outlives it (PR 41): a warm-up that did not stand still, a route
+the cell was not built to drive, a capture that holds no device operation
+are reasons, not results; SIGTERM, SIGHUP and SIGINT end the run through
+the same clean-up, and a SIGKILL takes the server with it
+(`harness/server.py`)."""
 
 from __future__ import annotations
 
@@ -25,6 +35,8 @@ import json                       # noqa: E402
 import os                         # noqa: E402
 import resource                   # noqa: E402
 import shutil                     # noqa: E402
+import signal                     # noqa: E402
+import statistics                 # noqa: E402
 import subprocess                 # noqa: E402
 import sys                        # noqa: E402
 
@@ -35,12 +47,32 @@ sys.path.insert(0, HERE)
 from harness import load_module, metrics, peaks, traffic  # noqa: E402
 from harness.metrics import counter                       # noqa: E402
 from harness.oracle import Mismatch, read_count            # noqa: E402
-from harness.server import (BenchFailure, Client, Server,  # noqa: E402
-                            build_native)
+from harness.server import (STOP_ENDED_S, STOP_S,          # noqa: E402
+                            BenchFailure, Client, Server,
+                            build_native, die_with_parent)
 
 WORK = os.path.join(ROOT, ".bench_work")
 MERGE_COUNTERS = ["compaction/leveled_merges", "compaction/out_of_order_merges",
                   "compaction/full_merges", "compact/offlock_merges"]
+ENDINGS = (signal.SIGTERM, signal.SIGHUP, signal.SIGINT)
+# A capture that `trace.requests` lengthens (readings: PERF.md section 6,
+# my chip runs, PR 41).  A request of 3-25 s took 0.98-1.09 times its
+# slowest steady warm repeat under the profiler, a phase's first the most:
+# TRACE_STRETCH leaves room.  CAPTURE_MAX_S is the longest capture a stored
+# cell has (the load cell's); what a longer one would cost is a run's 360 s,
+# not the reduction (42 s of 67 PromQL requests: 6.5 MB, reduced in 3.8 s).
+TRACE_STRETCH = 1.25
+CAPTURE_MAX_S = 60.0
+REDUCE_TIMEOUT_S = 240
+
+
+class Ended(BenchFailure):
+    """The run is being ended from outside."""
+
+    def __init__(self, signum: int):
+        super().__init__(f"ended from outside by {signal.Signals(signum).name}"
+                         ": no result; the server is stopped within seconds")
+        self.signum = signum
 
 
 def log(msg: str) -> None:
@@ -119,6 +151,9 @@ class Cell:
         self.trace_at = 0.0             # client clock, capture requested
         self.phase = None               # the traced phase's plan
         self.loaded = 0                 # rows the set-up load had acknowledged
+        self.steady_s: list[float] = []  # the warm repeats that built nothing
+        self.pinned: dict[str, dict] = {}  # planner kernel -> {geometry: route}
+        self.capture_s = float(self.traffic["trace"]["seconds"])
         self.ingest = traffic.Ingest(None, None)   # set_up's, with the data
         self.ctx: dict = {}             # what the metrics read, for the tools
 
@@ -133,10 +168,12 @@ class Cell:
         return mod.Reference(self.cfg, self.args.seed)
 
     def start(self) -> dict:
+        Server.refuse_beside_live(WORK)
         shutil.rmtree(WORK, ignore_errors=True)
         os.makedirs(WORK)
         log(f"native libraries ready in {build_native(ROOT):.1f}s")
-        self.srv = Server(ROOT, WORK, self.dry)
+        self.srv = Server(ROOT, WORK, self.dry, self.cfg.get("server"))
+        log(f"server pid {self.srv.proc.pid}, in a session of its own")
         log(f"server ready {self.srv.wait_ready():.1f}s after its start")
         devices = self.srv.device()["devices"]
         device = {"platform": devices[0]["platform"],
@@ -204,7 +241,8 @@ class Cell:
         statement shape until the last three repeats built no XLA program
         and each kernel's last five planner decisions name one route.  Then
         the cell's `ingest`, if it has one, begins and goes on to the end of
-        the run: the warm burst already overlaps it."""
+        the run: the warm burst already overlaps it.  Last, the planner's
+        model is pinned as it stands (`pin_planner`)."""
         srv, w = self.srv, self.traffic["warm"]
         client = Client(srv.port)
         kept = []
@@ -228,6 +266,7 @@ class Cell:
         for req in plan.warm_touch:
             log(f"warm touch: {ask(req).done - kept[-1][1].sent:.2f}s")
         built = [counter(srv.vars(), "device/xla_programs_total")]
+        moving: list = []
         for n, req in enumerate(plan.warm_repeat, 1):
             res = ask(req)
             built.append(counter(srv.vars(), "device/xla_programs_total"))
@@ -247,6 +286,7 @@ class Cell:
         else:
             log(f"warm-up reached its limit of {w['repeats_max']} repeats "
                 "without standing still; the guards will show it")
+        self.steady_s = [r.done - r.sent for _, r in kept[-3:]]
         client.close()
         if self.ingest.spec is not None:
             onto_one_core()             # the sender's thread with the others
@@ -261,7 +301,77 @@ class Cell:
             took = [1e3 * (r.done - r.due) for r in burst.results]
             log(f"warm burst of {w['burst_s']}s at the cell's rate: "
                 f"{len(took)} requests, slowest {max(took):.0f} ms")
+        self.pin_planner(moving)
         return kept
+
+    def pin_planner(self, moving: list) -> None:
+        """The warm-up stands still: the offload planner's model is pinned
+        as it stands, so that one stalled sample in the window or the
+        capture cannot move a kernel to another route for the rest of the
+        run (PERF.md section 7, "The planner and a stall").  A frozen
+        planner drops samples and stops exploring; it goes on writing its
+        decision ring and its counters, which `host_route_share` and
+        `route_flips_in_window` read.  The run stops here where a route was
+        still moving at the warm-up's limit, or where the kernel the
+        traffic file names (`device_work.planner_kernel`) is pinned to the
+        host: the cell would time another regime under its name."""
+        doc = self.srv.json("POST", "/debug/ctrl", mod="offload", freeze=1)
+        chosen: dict[tuple, str] = {}
+        for d in doc["decisions"]:                          # newest first
+            chosen.setdefault((d["kernel"], d["geometry"]), d["route"])
+        for m in doc["model"]:
+            # sampled without a decision: a stage on its static route
+            route = chosen.get((m["kernel"], m["geometry"]), "static")
+            self.pinned.setdefault(m["kernel"], {})[m["geometry"]] = route
+            log(f"planner pinned (frozen {doc['frozen']}): {m['kernel']} "
+                f"{m['geometry']} -> {route} (its last decision, or its "
+                "static route where it has made none); "
+                + ", ".join(f"{r} {s['count']} sample(s) ewma {s['ewma_ms']} ms"
+                            for r, s in m["routes"].items()))
+        if not doc["model"]:
+            log(f"planner pinned (frozen {doc['frozen']}): it has decided "
+                "nothing yet, and will keep to its static routes")
+        why = None
+        if moving:
+            why = (f"the warm-up reached its limit of "
+                   f"{self.traffic['warm']['repeats_max']} repeats with the "
+                   f"route of {moving} still moving")
+        kernel = self.traffic.get("device_work", {}).get("planner_kernel")
+        if kernel is not None and "host" in self.pinned.get(kernel, {}).values():
+            why = (f"the planner routes {kernel}, the kernel this cell was "
+                   "built to drive on the device, to the host "
+                   f"({self.pinned[kernel]}; the log has its samples)")
+        if why is not None:
+            self.stop_here(why + ": the cell would time another regime under "
+                           "its name")
+
+    def stop_here(self, why: str) -> None:
+        """The reason a run on the chip ends with.  The control-flow run,
+        whose routes and capture are the CPU's, says it and goes on."""
+        if not self.dry:
+            raise BenchFailure(why)
+        log(f"CPU DRY RUN: a run on the chip would stop here: {why}")
+
+    def capture_seconds(self) -> tuple[float, float]:
+        """(seconds the capture lasts, seconds the traced phase sends).
+        The traffic file's `trace.seconds` and `trace.send_s`; where it
+        carries `trace.requests`, the capture lasts at least that many times
+        the slowest steady warm repeat times TRACE_STRETCH, so that it holds
+        whole requests, and `send_s` follows it at the same distance."""
+        t = self.traffic["trace"]
+        seconds, send_s = float(t["seconds"]), float(t["send_s"])
+        if "requests" not in t or not self.steady_s:
+            return seconds, send_s
+        need = float(t["requests"]) * max(self.steady_s) * TRACE_STRETCH
+        if need > CAPTURE_MAX_S:
+            raise BenchFailure(
+                f"a capture of {t['requests']} whole requests needs "
+                f"{need:.1f}s (slowest steady warm repeat "
+                f"{max(self.steady_s):.2f}s x {TRACE_STRETCH}), over the "
+                f"{CAPTURE_MAX_S:.0f}s a capture may last: the request is "
+                "too long for this cell to be traced")
+        longer = max(seconds, need)
+        return longer, send_s + longer - seconds
 
     # -- the window -----------------------------------------------------------
 
@@ -285,15 +395,15 @@ class Cell:
         seconds the traffic file names, and wait for the capture to end.
         The write cell then reads its rows back inside the capture: its one
         device operation."""
-        t = self.traffic["trace"]
+        self.capture_s, send_s = self.capture_seconds()
         self.phase = traffic.rest(plan)
         t0 = time.perf_counter()
         self.srv.json("POST", "/debug/ctrl", mod="devobs", op="profile",
-                      seconds=float(t["seconds"]), dir=self.trace_dir)
-        log(f"capture of {t['seconds']}s began "
+                      seconds=self.capture_s, dir=self.trace_dir)
+        log(f"capture of {self.capture_s}s began "
             f"({time.perf_counter() - t0:.1f}s to start)")
         self.trace_at = t0
-        traffic.run(self.phase, self.srv.port, float(t["send_s"]))
+        traffic.run(self.phase, self.srv.port, send_s)
         if self.traffic["kind"] == "lp_stream":
             self.verify_writes(plan, ref)
         self.wait_trace()
@@ -369,20 +479,29 @@ class Cell:
                 raise BenchFailure(f"setup_q {q!r}: {res['error']}")
         if self.traffic["kind"] != "lp_stream":
             self.load(ref)
-        more = float(self.traffic["trace"]["send_s"]) if a.trace else 0.0
+        t = self.traffic["trace"]
+        more = 0.0
+        if a.trace:     # `trace.requests`: as long as a capture may get
+            more = float(t["send_s"]) + ("requests" in t) * max(
+                0.0, CAPTURE_MAX_S - float(t["seconds"]))
         plan = traffic.build(self.traffic, ref, a.seed, a.seconds + more)
         warm = self.warm_up(plan, ref)
         traffic.join_walk(plan, len(warm) - len(plan.warm_touch))
         onto_one_core()
         return device, ref, plan, warm
 
-    def stop(self) -> None:
-        """Both senders' ends: the ingest's thread, then the server."""
+    def stop(self, ended: bool = False) -> None:
+        """Both senders' ends: the ingest's thread, then the server.  A run
+        that is being `ended` from outside waits for neither: the sender is
+        a daemon thread, and the server gets seconds."""
         try:
-            self.ingest.stop()
+            if ended:
+                self.ingest.halt.set()
+            else:
+                self.ingest.stop()
         finally:
             if self.srv is not None:
-                self.srv.stop()
+                self.srv.stop(STOP_ENDED_S if ended else STOP_S)
 
     def run(self) -> dict:
         a = self.args
@@ -470,6 +589,7 @@ class Cell:
                "failed": failed, "metrics": {}, "device": device}
         if a.trace:
             ctx["trace"] = self.reduce_trace()
+            self.held_by_capture(ctx["trace"])
             ctx["traced"] = self.traced_work(ctx["trace"])
             ctx["peaks"] = None if self.dry else peaks.peaks_for(device["kind"])
             device["busy_s"] = ctx["trace"]["busy_s"]
@@ -504,22 +624,50 @@ class Cell:
         """The capture, reduced in a child process: reading it imports JAX,
         and this process stays off JAX."""
         env = dict(os.environ, JAX_PLATFORMS="cpu")
+        size = sum(os.path.getsize(os.path.join(d, f))
+                   for d, _, fs in os.walk(self.trace_dir) for f in fs)
+        t0 = time.monotonic()
         r = subprocess.run(
             [sys.executable, os.path.join(HERE, "harness", "trace_reduce.py"),
              self.trace_dir], capture_output=True, text=True, env=env,
-            timeout=240)
+            timeout=REDUCE_TIMEOUT_S, preexec_fn=die_with_parent)
         if r.returncode != 0:
             raise BenchFailure("trace reduction failed:\n" + r.stderr[-2000:])
         red = json.loads(r.stdout.strip().splitlines()[-1])
         log(f"trace: window {red['window_s']:.3f}s, device busy "
             f"{red['busy_s'] * 1e3:.3f}ms on {red['devices_busy']} of "
             f"{red['devices_traced']} device plane(s); launches "
-            f"{red['launches']}")
+            f"{red['launches']}; {size / 1e6:.1f} MB of capture reduced in "
+            f"{time.monotonic() - t0:.1f}s")
         if self.args.keep_trace:
             shutil.copytree(self.trace_dir, self.args.keep_trace,
                             dirs_exist_ok=True)
         shutil.rmtree(self.trace_dir, ignore_errors=True)
         return red
+
+    def held_by_capture(self, red: dict) -> None:
+        """A traced run shows the device busy, or says why it cannot: a
+        capture with no device operation is a reason, never a result line
+        whose `busy_s` reads 0."""
+        if red["busy_s"] > 0 and red["device_ops"]:
+            return
+        t0, t1 = self.trace_at, self.trace_at + red["window_s"]
+        sent = self.phase.results if self.phase else []
+        began = sum(t0 <= r.sent < t1 for r in sent)
+        whole = sum(t0 <= r.sent and r.done <= t1 for r in sent)
+        why = (f"the capture of {self.capture_s}s (traced {red['window_s']:.3f}s"
+               f", {red['devices_traced']} device plane(s)) holds no device "
+               f"operation: busy_s {red['busy_s']}; of the traced phase's "
+               f"{len(sent)} request(s) {began} began and {whole} both began "
+               "and ended inside it; median steady warm repeat "
+               f"{statistics.median(self.steady_s or [0.0]):.3f}s beside "
+               f"trace.seconds {self.traffic['trace']['seconds']}; the "
+               f"planner's pinned routes: {self.pinned or 'none'}; launches "
+               f"in the capture: {red['launches'] or 'none'}")
+        if whole == 0 and sent:
+            why += (".  No request lies whole inside the capture: give the "
+                    "traffic file `trace.requests`")
+        self.stop_here(why)
 
     def traced_work(self, red: dict) -> dict:
         """How much of the traffic's work the capture holds.  Where the
@@ -570,7 +718,15 @@ def main() -> int:
     ap.add_argument("--cpu-dry-run", action="store_true",
                     help="tiny sizes on the CPU: control flow only")
     args = ap.parse_args()
-    cell = None
+
+    def end(signum, frame):
+        for s in ENDINGS:       # the clean-up below is not ended a second time
+            signal.signal(s, signal.SIG_IGN)
+        raise Ended(signum)
+
+    for s in ENDINGS:
+        signal.signal(s, end)
+    cell, ended = None, False
     try:
         bench = load_json(ROOT, "BENCHMARK.json")
         if args.seconds is None:
@@ -581,10 +737,11 @@ def main() -> int:
             ValueError) as e:
         print(f"benchmark FAILED: {type(e).__name__}: {e}", file=sys.stderr,
               flush=True)
-        return 1
+        ended = isinstance(e, Ended)
+        return 128 + e.signum if ended else 1
     finally:
         if cell is not None:
-            cell.stop()
+            cell.stop(ended)
     print(json.dumps(out), flush=True)
     return 0
 

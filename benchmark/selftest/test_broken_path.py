@@ -5,10 +5,10 @@ Each test drives a whole run (`run.Cell.run`) at the tiny sizes of
 chip is what --cpu-dry-run skips — with the one function every timed
 request passes through (`traffic.send`) altered where the answer is
 produced: one value of one sampled answer moved by a thousandth; one /write
-reported acknowledged that the server never saw; and, in the draft of the
-cell that reads while it writes (`selftest/draft/`, PR 31), that /write of
-its ingest, or one sampled answer replaced by what its panel was answered a
-refresh earlier — a cached window served stale."""
+reported acknowledged that the server never saw; and, in the cell that
+reads while it writes (`tsbs_dash_refresh`), that /write of its ingest, or
+one sampled answer replaced by what its panel was answered a refresh
+earlier — a cached window served stale."""
 
 import argparse
 import json
@@ -28,11 +28,8 @@ def drive(workload: str, monkeypatch, break_send=None) -> dict:
         monkeypatch.setattr(traffic, "send", break_send(traffic.send))
     args = argparse.Namespace(workload=workload, seed=2147483659, seconds=2.0,
                               trace=0, cpu_dry_run=True, keep_trace=None)
-    if workload == draft.CELL:
-        cell = bench_run.Cell(args, draft.bench())
-    else:
-        with open(f"{ROOT}/BENCHMARK.json") as f:
-            cell = bench_run.Cell(args, json.load(f))
+    with open(f"{ROOT}/BENCHMARK.json") as f:
+        cell = bench_run.Cell(args, json.load(f))
     try:
         return cell.run()
     finally:

@@ -81,11 +81,19 @@ def test_planner_ring_counts_only_the_window():
         {"kernel": "k", "geometry": geo, "route": "device", "uses": 4},
         {"kernel": "k", "geometry": geo, "route": "host", "uses": 3},
     ]
-    ctx = {"dev0": {"planner": {"model": [
-        {"kernel": "k", "geometry": geo, "uses": 3}]}},
-        "dev1": {"planner": {"decisions": ring}}}
+    # the window's are as many of the newest as `decisions_total` grew by
+    ctx = {"dev0": {"planner": {"counters": {"decisions_total": 3}}},
+           "dev1": {"planner": {"decisions": ring,
+                                "counters": {"decisions_total": 6}}}}
     assert metrics.planner_ring(ctx, {"stat": "flips"}) == 1
     assert metrics.planner_ring(ctx, {"stat": "host_share"}) == \
         pytest.approx(100 / 3)
-    ctx["dev1"]["planner"]["decisions"] = ring[3:]
+    # a frozen planner counts and writes its ring, and moves no `uses`
+    for d in ring:
+        d["uses"] = 3
+    assert metrics.planner_ring(ctx, {"stat": "flips"}) == 1
+    ctx["dev1"]["planner"]["counters"]["decisions_total"] = 3
     assert metrics.planner_ring(ctx, {"stat": "flips"}) is None
+    # a program that has decided nothing has no counter yet
+    ctx["dev0"]["planner"]["counters"] = ctx["dev1"]["planner"]["counters"] = {}
+    assert metrics.planner_ring(ctx, {"stat": "host_share"}) is None

@@ -2,8 +2,8 @@
 `panels` asked again every `refresh_s`, a `range_end` that follows the
 newest tick acknowledged in full, and an `ingest` beside them, with an
 oracle that grows by the live ticks and answers windows the range cuts.
-`selftest/draft/` is the cell as data; a whole run of it is correct and is
-answered from the result cache (`test_broken_path.py` holds its controls)."""
+`traffic/dash_refresh.json` is the cell as data (PR 31's draft of it went in
+PR 41); a whole run of it is correct and is answered from the result cache (`test_broken_path.py` holds its controls)."""
 
 import json
 import os
@@ -21,8 +21,7 @@ from test_oracles import reference
 
 
 def mix(**over):
-    with open(os.path.join(BENCH, "selftest", "draft",
-                           "dash_refresh.json")) as f:
+    with open(os.path.join(BENCH, "traffic", "dash_refresh.json")) as f:
         t = json.load(f)
     t.update(t.pop("dry_run"))
     t.update(over)

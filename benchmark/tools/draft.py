@@ -1,21 +1,22 @@
 #!/usr/bin/env python3
-"""Drive the draft of a cell that is not in BENCHMARK.json yet.
+"""Drive whole runs of the cell that reads while it writes, its rates varied.
 
     python3 benchmark/tools/draft.py --seeds 1,2,3 --rows-per-s 400,4000 \
         --seconds 51 --trace 1
     python3 benchmark/tools/draft.py --seeds 1 --control stale
     python3 benchmark/tools/draft.py --seeds 7 --sweep 2.5,5,10,20 --seconds 12
 
-`selftest/draft/` holds a configuration file and a traffic file and no code
-(PR 31: `tsbs_dash_refresh`, panels re-asked over a trailing hour beside a
-paced write stream).  This tool puts them into an in-memory copy of
-BENCHMARK.json as one more cell, which takes the panels cell's metrics, and
-drives whole runs through `run.Cell`, one JSON line a run: the numbers the
-issue that adds the cell names its rates with.  `--control` breaks the
-timed path (`tools/control.py`: `drop` one acknowledged /write, a `stale`
-answer) and the run has to come out `correct: false`; `--sweep` is
-`tools/sweep.py` over the draft, its ingest on.  None of this is a run of
-the benchmark, and nothing it prints is a ledger metric."""
+PR 31 drafted `tsbs_dash_refresh` (panels re-asked over a trailing hour
+beside a paced write stream) with this tool, from two files under
+`selftest/draft/`; PR 32 made them the real cell's and PR 41 deleted the
+copies, so this drives the cell BENCHMARK.json has: whole runs through
+`run.Cell`, one JSON line a run, with the ingest's rate varied
+(`--rows-per-s`): the numbers an issue names a mixed cell's rates with.
+`--control` breaks the timed path (`tools/control.py`: `drop` one
+acknowledged /write, a `stale` answer) and the run has to come out
+`correct: false`; `--sweep` is `tools/sweep.py` over the cell, its ingest on.
+None of this is a run of the benchmark, and nothing it prints is a ledger
+metric."""
 
 from __future__ import annotations
 
@@ -32,27 +33,11 @@ import run as bench_run                      # noqa: E402
 from harness import metrics, traffic         # noqa: E402
 from tools import control, sweep             # noqa: E402
 
-CELL, LIKE = "tsbs_dash_refresh", "tsbs_host_panels"
-CONFIG, TRAFFIC = "tsbs-devops-cpu-4000-live", "dash_refresh"
+CELL = "tsbs_dash_refresh"
 # per million rows the ingest wrote, as the cell's own metric files will
 # divide (`client/rows_written`); the load cell's divide by its `units`
 PER_MROW = ("flush_inline", "write_lock_wait", "lp_parse", "memtable_apply",
             "write_observers")
-
-
-def bench() -> dict:
-    """BENCHMARK.json with the draft in it: its configuration, its cell,
-    and the cell's name on every metric the panels cell reports."""
-    doc = bench_run.load_json(bench_run.ROOT, "BENCHMARK.json")
-    doc["configs"].append({
-        "name": CONFIG, "file": f"benchmark/selftest/draft/{CONFIG}.json"})
-    # `run.Cell` looks under traffic/: the draft's file is two steps away
-    doc["workloads"].append({"name": CELL, "config": CONFIG, "chips": 1,
-                             "traffic": f"../selftest/draft/{TRAFFIC}"})
-    for m in doc["end_to_end"] + doc["per_layer"]:
-        if LIKE in m.get("workloads", []):
-            m["workloads"].append(CELL)
-    return doc
 
 
 def cell(seed: int, seconds: float, trace: int, dry: bool,
@@ -60,7 +45,8 @@ def cell(seed: int, seconds: float, trace: int, dry: bool,
     args = argparse.Namespace(workload=CELL, seed=seed, seconds=seconds,
                               trace=trace, cpu_dry_run=dry, keep_trace=None)
     bench_run.T_PROCESS = time.monotonic()   # a run's set-up counts from here
-    c = bench_run.Cell(args, bench())
+    c = bench_run.Cell(args, bench_run.load_json(bench_run.ROOT,
+                                                 "BENCHMARK.json"))
     if rows_per_s is not None:
         c.traffic["ingest"] = dict(c.traffic["ingest"], rows_per_s=rows_per_s)
     return c
