@@ -40,6 +40,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from opengemini_tpu.utils import tracing
+
 
 def prepare_matrix(series_samples: list[tuple[np.ndarray, np.ndarray]], dtype=np.float32):
     """[(times_ms int64, values f64)] -> padded matrices.
@@ -611,12 +613,21 @@ class TiledPrepared:
                 plan.window_s)
             self.plan = plan
             self.K = len(plan.a_idx)
-        total = int(lens.sum())
         # padded (S, N) matrices: the one flat-scatter fill shared with
         # the dense path (same +inf/zero padding and base_ms contract)
-        self.times, self.values, self.counts, self.base_ms = (
-            prepare_matrix_runs(t_ms_all, v_all, lens, dtype=self.dtype))
+        with tracing.span("prom_fill"):
+            self.times, self.values, self.counts, self.base_ms = (
+                prepare_matrix_runs(t_ms_all, v_all, lens, dtype=self.dtype))
+        with tracing.span("prom_tile_index"):
+            self._index_tiles(plan, t_ms_all, lens, max_gather_cols)
 
+    def _index_tiles(self, plan: TilePlan, t_ms_all, lens,
+                     max_gather_cols: int | None) -> None:
+        """The time structure every kernel answers from: per-(series,
+        tile) sample counts and their prefixes, each window's first and
+        last sample index, and the compact covered-tile gather layout."""
+        S, N = self.S, self.N
+        total = int(lens.sum())
         # -- integer-arithmetic tile bucketing (no searchsorted) --
         from opengemini_tpu.ops.window import tile_index
 
@@ -797,12 +808,17 @@ class TiledPrepared:
                         label="tiled-values-decoded", anchor=self)
                     cache[form] = dev
                     return dev
-            mat = self._narrowed(form) if narrow else self._host_values()
-            t0 = _time.perf_counter_ns()
-            dev = xp.asarray(mat)
-            devobs.note_transfer(
-                "h2d", "prom-values", int(mat.nbytes),
-                (_time.perf_counter_ns() - t0) / 1e9)
+            if narrow:
+                with tracing.span("prom_narrow", form=form):
+                    mat = self._narrowed(form)
+            else:
+                mat = self._host_values()
+            with tracing.span("prom_values_h2d", bytes=int(mat.nbytes)):
+                t0 = _time.perf_counter_ns()
+                dev = xp.asarray(mat)
+                devobs.note_transfer(
+                    "h2d", "prom-values", int(mat.nbytes),
+                    (_time.perf_counter_ns() - t0) / 1e9)
             devobs.LEDGER.register(
                 "prom_dev_values", int(mat.nbytes),
                 label="tiled-values", anchor=self)
@@ -827,7 +843,10 @@ class TiledPrepared:
         cache = self.__dict__.setdefault("_dev_levels", {})
         dev = cache.get(which)
         if dev is None:
-            dev = cache[which] = xp.asarray(self._narrowed_level(which))
+            with tracing.span("prom_narrow", level=which):
+                mat = self._narrowed_level(which)
+            with tracing.span("prom_values_h2d", bytes=int(mat.nbytes)):
+                dev = cache[which] = xp.asarray(mat)
         return dev
 
     def _vals(self, xp, values, value_shift, form: str = "rel"):

@@ -142,6 +142,20 @@ def _mesh_for_tiled():
     return prt.get_mesh()
 
 
+def _count_collected(lens: np.ndarray, parts: int, k: int) -> None:
+    """The one counter update a query, beside its `prom_collect` and
+    `prom_prepare` spans (group `prom`): the samples and series collected,
+    the (series, shard) pieces they were merged from, the cells of the
+    padded (S, N) matrices the prepare fills (`prepare_matrix_runs`: N is
+    the longest series) and the windows it indexes (S x steps)."""
+    s_dim = len(lens)
+    STATS.add("prom", (("collect_samples", int(lens.sum())),
+                       ("collect_series", s_dim),
+                       ("collect_parts", parts),
+                       ("prepare_cells", s_dim * max(1, int(lens.max()))),
+                       ("prepare_windows", s_dim * k)))
+
+
 def _anchor(pattern: str) -> str:
     return "^(?:" + pattern + ")$"
 
@@ -393,7 +407,8 @@ class PromEngine:
         raise PromError(f"unsupported expression {type(node).__name__}")
 
     def _collect_series(self, vs: pp.VectorSelector, t_min_ns: int,
-                        t_max_ns: int, db: str, want_encoded: bool = False):
+                        t_max_ns: int, db: str, want_encoded: bool = False,
+                        windows: int | None = None):
         """-> run-encoded (labels list, t_ms_all, v_all, lens[, enc]):
         one concatenated (times, values) pair with per-series lengths,
         ready for prepare_matrix_runs' flat scatter / the tiled prepare —
@@ -406,9 +421,21 @@ class PromEngine:
         5th return is (ftype, blocks, segments, slices) and v_all is None — the
         device decodes (ops/device_decode.decode_rows_matrix); any
         cross-shard merge, partial validity, or decoded column falls
-        back to returning the values eagerly, exactly as before."""
+        back to returning the values eagerly, exactly as before.
+
+        Three spans under the caller's `prom_collect`, each opened once a
+        shard (they sum by name): `prom_match` (the range's shards, then
+        each one's matching sids), `prom_read` (`mem_read`, `decode` and
+        `scan_merge` open inside it; on a read that only hits the column
+        cache its self time is the gather and the merge of the parts) and
+        `prom_assemble` (a shard's per-series slices, then the merge by
+        key and the concatenation).  ``windows``, the steps of the query
+        that collects, makes this the place of that query's one counter
+        update (`_count_collected`); the rule engine passes none."""
         metric = self._metric_of(vs)
-        shards = self.engine.shards_for_range(db, None, t_min_ns, t_max_ns)
+        with tracing.span("prom_match"):
+            shards = self.engine.shards_for_range(db, None, t_min_ns,
+                                                  t_max_ns)
         # series may span shards: merge by label key.
         # per_key: key -> (tags, [(times_ms, values)])
         per_key: dict[tuple, tuple] = {}
@@ -425,7 +452,8 @@ class PromEngine:
         bulk_min = _bulk_sids_min()
         for sh in shards:
             TRACKER.check()  # KILL QUERY cancellation point per shard
-            sids = _match_sids(sh, metric, vs.matchers)
+            with tracing.span("prom_match"):
+                sids = _match_sids(sh, metric, vs.matchers)
             if sids.size == 0:
                 continue
             if sids.size >= bulk_min and hasattr(sh, "read_series_bulk"):
@@ -435,100 +463,113 @@ class PromEngine:
                 # make the per-sid decode loop handle small matches.
                 # _match_sids already hands the sorted int64 array — no
                 # tags_of label materialization on the match path
-                sid_arr, rec = sh.read_series_bulk(
-                    metric, sids, t_min_ns, t_max_ns, fields=[vf])
-                col = rec.columns.get(vf)
-                if col is None or len(rec) == 0:
-                    continue
-                times_ms = rec.times // MS
-                # keep a still-encoded column encoded: per-series slices
-                # become (col, lo, hi) markers resolved at assembly; any
-                # partial-validity slice decodes the whole column (lazy
-                # .values — the bit-identical host path)
-                enc_col = (col if want_encoded
-                           and getattr(col, "is_decoded", True) is False
-                           else None)
-                vals64 = (None if enc_col is not None
-                          else col.values.astype(np.float64))
-                uniq, starts = np.unique(sid_arr, return_index=True)
-                ends = np.append(starts[1:], len(sid_arr))
-                if hasattr(sh.index, "entries_bulk"):
-                    entries = sh.index.entries_bulk(uniq)
-                else:
-                    entries = [(None, tuple(sh.index.tags_of(int(s)).items()))
-                               for s in uniq]
-                for (sid, lo, hi), entry in zip(
-                        zip(uniq, starts, ends), entries):
-                    if entry is None:
-                        continue
-                    m = col.valid[lo:hi]
-                    if not m.any():
-                        continue
-                    if enc_col is not None and m.all():
-                        add(dict(entry[1]), times_ms[lo:hi],
-                            _EncSlice(enc_col, int(lo), int(hi)))
-                        continue
-                    if vals64 is None:
-                        vals64 = col.values.astype(np.float64)
-                    add(dict(entry[1]), times_ms[lo:hi][m],
-                        vals64[lo:hi][m])
-            else:
-                for sid in sids.tolist():
-                    rec = sh.read_series(metric, sid, t_min_ns, t_max_ns,
-                                         fields=[vf])
+                with tracing.span("prom_read"):
+                    sid_arr, rec = sh.read_series_bulk(
+                        metric, sids, t_min_ns, t_max_ns, fields=[vf])
+                with tracing.span("prom_assemble"):
                     col = rec.columns.get(vf)
                     if col is None or len(rec) == 0:
                         continue
-                    valid = col.valid
-                    if not valid.any():
-                        continue
-                    add(sh.index.tags_of(sid),
-                        rec.times[valid] // MS,
-                        col.values[valid].astype(np.float64))
-        out_labels: list[dict] = []
-        t_parts: list[np.ndarray] = []
-        v_parts: list = []
-        lens: list[int] = []
-        for key in sorted(per_key):
-            tags, parts = per_key[key]
-            if len(parts) == 1:
-                t, v = parts[0]
+                    times_ms = rec.times // MS
+                    # keep a still-encoded column encoded: per-series slices
+                    # become (col, lo, hi) markers resolved at assembly; any
+                    # partial-validity slice decodes the whole column (lazy
+                    # .values — the bit-identical host path)
+                    enc_col = (col if want_encoded
+                               and getattr(col, "is_decoded", True) is False
+                               else None)
+                    vals64 = (None if enc_col is not None
+                              else col.values.astype(np.float64))
+                    uniq, starts = np.unique(sid_arr, return_index=True)
+                    ends = np.append(starts[1:], len(sid_arr))
+                    if hasattr(sh.index, "entries_bulk"):
+                        entries = sh.index.entries_bulk(uniq)
+                    else:
+                        entries = [
+                            (None, tuple(sh.index.tags_of(int(s)).items()))
+                            for s in uniq]
+                    for (sid, lo, hi), entry in zip(
+                            zip(uniq, starts, ends), entries):
+                        if entry is None:
+                            continue
+                        m = col.valid[lo:hi]
+                        if not m.any():
+                            continue
+                        if enc_col is not None and m.all():
+                            add(dict(entry[1]), times_ms[lo:hi],
+                                _EncSlice(enc_col, int(lo), int(hi)))
+                            continue
+                        if vals64 is None:
+                            vals64 = col.values.astype(np.float64)
+                        add(dict(entry[1]), times_ms[lo:hi][m],
+                            vals64[lo:hi][m])
             else:
-                t = np.concatenate([p[0] for p in parts])
-                v = np.concatenate([_materialize_slice(p[1])
-                                    for p in parts])
-                order = np.argsort(t, kind="stable")
-                t, v = t[order], v[order]
-            labels = dict(tags)
-            labels["__name__"] = metric
-            out_labels.append(labels)
-            t_parts.append(t)
-            v_parts.append(v)
-            lens.append(len(t))
-        t_ms_all = (np.concatenate(t_parts) if t_parts
-                    else np.empty(0, np.int64)).astype(np.int64, copy=False)
-        enc = None
-        if want_encoded and v_parts:
-            enc = _assemble_enc(v_parts)
-        if enc is not None:
-            v_all = None
-        else:
-            v_all = (np.concatenate(
-                [_materialize_slice(v) for v in v_parts]) if v_parts
-                else np.empty(0, np.float64))
+                # the per-sid loop reads and slices a series at a time: one
+                # span round it, not one a series
+                with tracing.span("prom_read"):
+                    for sid in sids.tolist():
+                        rec = sh.read_series(metric, sid, t_min_ns, t_max_ns,
+                                             fields=[vf])
+                        col = rec.columns.get(vf)
+                        if col is None or len(rec) == 0:
+                            continue
+                        valid = col.valid
+                        if not valid.any():
+                            continue
+                        add(sh.index.tags_of(sid),
+                            rec.times[valid] // MS,
+                            col.values[valid].astype(np.float64))
+        with tracing.span("prom_assemble"):
+            out_labels: list[dict] = []
+            t_parts: list[np.ndarray] = []
+            v_parts: list = []
+            lens: list[int] = []
+            for key in sorted(per_key):
+                tags, parts = per_key[key]
+                if len(parts) == 1:
+                    t, v = parts[0]
+                else:
+                    t = np.concatenate([p[0] for p in parts])
+                    v = np.concatenate([_materialize_slice(p[1])
+                                        for p in parts])
+                    order = np.argsort(t, kind="stable")
+                    t, v = t[order], v[order]
+                labels = dict(tags)
+                labels["__name__"] = metric
+                out_labels.append(labels)
+                t_parts.append(t)
+                v_parts.append(v)
+                lens.append(len(t))
+            t_ms_all = (np.concatenate(t_parts) if t_parts
+                        else np.empty(0, np.int64)).astype(
+                            np.int64, copy=False)
+            enc = None
+            if want_encoded and v_parts:
+                enc = _assemble_enc(v_parts)
+            if enc is not None:
+                v_all = None
+            else:
+                v_all = (np.concatenate(
+                    [_materialize_slice(v) for v in v_parts]) if v_parts
+                    else np.empty(0, np.float64))
+        lens = np.asarray(lens, np.int64)
+        if windows is not None and lens.size:
+            # parts before the merge by key: more than there are series
+            # where a series spans shards
+            _count_collected(
+                lens, sum(len(p) for _tags, p in per_key.values()), windows)
         if want_encoded:
-            return (out_labels, t_ms_all, v_all,
-                    np.asarray(lens, np.int64), enc)
-        return out_labels, t_ms_all, v_all, np.asarray(lens, np.int64)
+            return out_labels, t_ms_all, v_all, lens, enc
+        return out_labels, t_ms_all, v_all, lens
 
     def _eval_selector(self, vs, steps, db, window_s, instant):
         eval_times = steps - vs.offset_s
         t_max_ns = int(eval_times[-1] * 1e9) + 1
         t_min_ns = int((eval_times[0] - window_s) * 1e9)
+        k = len(steps)
         with tracing.span("prom_collect"):
             labels, t_ms_all, v_all, lens = self._collect_series(
-                vs, t_min_ns, t_max_ns, db)
-        k = len(steps)
+                vs, t_min_ns, t_max_ns, db, windows=k)
         if not labels:
             return Frame([], np.zeros((0, k)), np.zeros((0, k), bool))
         with tracing.span("prom_prepare"):
@@ -853,7 +894,7 @@ class PromEngine:
             with tracing.span("prom_collect"):
                 got = self._collect_series(
                     vs, t_min_ns, t_max_ns, db,
-                    want_encoded=_want_encoded())
+                    want_encoded=_want_encoded(), windows=len(steps))
                 labels, t_ms_all, v_all, lens = got[:4]
                 enc = got[4] if len(got) > 4 else None
         k = len(steps)

@@ -35,9 +35,11 @@ QUERY_SPANS = {"sql_parse", "select: cpu", "map_shards", "scan", *MISS_SPANS,
                "device_launch", "device_fetch", "device_wait", "device_copy",
                "host_combine", "inc_cache",
                "render", "format", "serialize", "send"}
-PROM_SPANS = {"prom_parse", "prom_collect", *MISS_SPANS, "mem_read",
-              "prom_prepare",
-              "prom_kernel", "device_launch", "device_fetch", "device_wait",
+PROM_SPANS = {"prom_parse", "prom_collect", "prom_match", "prom_read",
+              "prom_assemble", *MISS_SPANS, "mem_read",
+              "prom_prepare", "prom_fill", "prom_tile_index",
+              "prom_kernel", "device_launch", "prom_narrow",
+              "prom_values_h2d", "device_fetch", "device_wait",
               "device_copy", "prom_render", "serialize", "send"}
 WRITE_SPANS = {"read_body", "lp_parse", "type_check", "write_hooks",
                "write_lock_wait", "index_route", "memtable_apply",
@@ -292,8 +294,23 @@ def test_a_promql_query_leaves_a_tree(server):
     for name in ("prom_parse", "prom_collect", "prom_prepare", "prom_kernel",
                  "prom_render", "serialize", "send"):
         assert [p["name"] for _, p in spans[name]] == ["http_prom"], name
-    # nothing was flushed: the collect read the memtable, under one span
-    assert [p["name"] for _, p in spans["mem_read"]] == ["prom_collect"]
+    # a collect is a match, a read a shard and an assembly; a prepare the
+    # fill of the padded matrices and the tile index (PR 42)
+    # (one shard: the range's shards and its sids are two matches, its
+    # slices and the merge by key two assemblies)
+    for name in ("prom_match", "prom_read", "prom_assemble"):
+        assert {p["name"] for _, p in spans[name]} == {"prom_collect"}, name
+    assert [len(spans[name]) for name in
+            ("prom_match", "prom_read", "prom_assemble")] == [2, 1, 2]
+    for name in ("prom_fill", "prom_tile_index"):
+        assert [p["name"] for _, p in spans[name]] == ["prom_prepare"], name
+    # nothing was flushed: the read took the memtable's rows, under one span
+    assert [p["name"] for _, p in spans["mem_read"]] == ["prom_read"]
+    # where the device computed in a narrower float, the narrowing and the
+    # copy of its values are the launch's children
+    for name in ("prom_narrow", "prom_values_h2d"):
+        assert {p["name"] for _, p in spans.get(name, [])} \
+            <= {"device_launch"}, name
     # where the answer came from the device, each fetch has its two halves
     for name in ("device_wait", "device_copy"):
         assert [p["name"] for _, p in spans.get(name, [])] \
