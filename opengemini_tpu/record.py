@@ -237,27 +237,42 @@ class EncodedColumn(Column):
     def concat(self, other: "Column") -> "Column":
         if (isinstance(other, EncodedColumn)
                 and self.ftype == other.ftype):
-            segs = np.concatenate(
-                [self.abs_segments(),
-                 other.abs_segments() + self.n_full])
-            if len(segs) <= self._SEG_CAP:
-                out = EncodedColumn(
-                    self.ftype, self.blocks + other.blocks,
-                    np.concatenate([self.valid, other.valid]),
-                    self._decode, segments=segs,
-                    n_full=self.n_full + other.n_full)
-                s1, s2 = self._spans_or_self(), other._spans_or_self()
-                if s1 is not None and s2 is not None:
-                    out._spans = s1 + [(r, off + self.n_full)
-                                       for r, off in s2]
-                if self._values is not None and other._values is not None:
-                    # both sides already decoded: carry the memoized
-                    # views forward so no host consumer re-decodes;
-                    # mixed decode states stay lazy (bit-identical)
-                    out._values = np.concatenate(
-                        [self._values, other._values])
+            out = EncodedColumn.join([self, other])
+            if out is not None:
                 return out
         return super().concat(other)
+
+    @staticmethod
+    def join(cols: list["EncodedColumn"]) -> "EncodedColumn | None":
+        """The columns' views end to end as ONE still-encoded column,
+        every array built once however many parts there are: the blocks
+        in order, each view's runs shifted by the rows before it, the
+        root spans where every part knows its roots, and the memoized
+        values where every part is already decoded (mixed decode states
+        stay lazy, bit-identical).  None past `_SEG_CAP` runs: the
+        caller then joins decoded values."""
+        segs, offs, off = [], [], 0
+        for c in cols:
+            segs.append(c.abs_segments() + off)
+            offs.append(off)
+            off += c.n_full
+        segs = np.concatenate(segs)
+        if len(segs) > EncodedColumn._SEG_CAP:
+            return None
+        first = cols[0]
+        out = EncodedColumn(
+            first.ftype, [b for c in cols for b in c.blocks],
+            np.concatenate([c.valid for c in cols]),
+            first._decode, segments=segs, n_full=off)
+        spans = [c._spans_or_self() for c in cols]
+        if all(s is not None for s in spans):
+            out._spans = [(r, o + at) for s, at in zip(spans, offs)
+                          for r, o in s]
+        if all(c._values is not None for c in cols):
+            # every side already decoded: carry the memoized views
+            # forward so no host consumer re-decodes
+            out._values = np.concatenate([c._values for c in cols])
+        return out
 
 
 @dataclass
@@ -414,185 +429,209 @@ class FieldTypeConflict(Exception):
         self.got = got
 
 
-def _merge_bulk_sorted_fast(parts, lo_t: int, hi_t: int):
-    """Sort-free fast path for the common bulk-scan shape: every part is
-    a single-series chunk. Grouping parts by sid and checking the
-    concatenation for strictly-increasing (sid, time) replaces the
-    three-key lexsort (the profiled hot spot of at-spec scans) with one
-    vectorized monotonicity pass. Returns None when the shape does not
-    apply (multi-sid parts, overlapping chunks, duplicate timestamps) —
-    the caller's general merge handles those."""
-    # PRECONDITION: every part is internally time-sorted (TSF chunks are
-    # written sorted, memtable bulk parts sort on freeze) — searchsorted
-    # slicing below relies on it; the post-slice monotonicity check still
-    # rejects cross-part overlap/duplicates.
-    single = []
-    ftypes: dict[str, object] = {}
-    for s, r in parts:
-        # CONSTANT sid required — endpoints alone are not enough: a
-        # time-sorted memtable part can interleave sids and still have
-        # s[0] == s[-1]
+def _join_plain(ftype: FieldType, cols: list, lens: list[int]) -> Column:
+    """The parts' columns (None where a part lacks the column) end to
+    end as decoded values: the output allocated once, each part copied
+    into its place once.  Zero-init, not np.empty: a slot no part fills
+    stays invalid, but its value bytes still flow into flushed chunks
+    and content_digest — heap garbage there breaks the
+    replica-identical digest guarantee."""
+    total = sum(lens)
+    values = _zeroed(ftype, total)
+    valid = np.zeros(total, dtype=np.bool_)
+    at = 0
+    for col, m in zip(cols, lens):
+        if col is not None:
+            values[at:at + m] = col.values
+            valid[at:at + m] = col.valid
+        at += m
+    return Column(ftype, values, valid)
+
+
+def _join_column(ftype: FieldType, cols: list, lens: list[int]) -> Column:
+    """One output column over the parts, built in one pass.  Where every
+    part carries it as an EncodedColumn the join stays ENCODED
+    (EncodedColumn.join): still-encoded parts never materialize decoded
+    bytes on the host (the device-decode cold path,
+    ops/device_decode.py), already-decoded ones (colcache host-tier
+    hits) carry their memoized values forward with the raw blocks still
+    attached, so the offload planner (query/offload.py) keeps the device
+    route on every repeat.  Any absence or run-cap overflow joins
+    decoded values instead, bit-identically."""
+    if len(cols) == 1 and cols[0] is not None:
+        return cols[0]      # one part: its own arrays, no copy
+    if cols and all(
+            isinstance(c, EncodedColumn) and c.ftype == ftype for c in cols):
+        enc = EncodedColumn.join(cols)
+        if enc is not None:
+            return enc
+    return _join_plain(ftype, cols, lens)
+
+
+def _trim_part(s: np.ndarray, r: Record, lo_t: int, hi_t: int):
+    """The rows of one part inside [lo_t, hi_t).  A part wholly inside
+    is handed back as it is — two reductions, no copy: what a hot read,
+    a whole-range read and the memtable's unbounded calls pay.  A
+    straddling part is masked (packed parts are (sid, time)-sorted, not
+    time-sorted: a mask, not two searchsorted; a mask over a sorted part
+    leaves it sorted) and copied once, as views where the rows kept are
+    one run (a single-series chunk).  EncodedColumns trim through their
+    own take(), so they stay encoded up to `_SEG_CAP` runs."""
+    t = r.times
+    if t.min() >= lo_t and t.max() < hi_t:
+        return s, r
+    idx = np.flatnonzero((t >= lo_t) & (t < hi_t))
+    if len(idx) and idx[-1] - idx[0] + 1 == len(idx):
+        lo, hi = int(idx[0]), int(idx[-1]) + 1
+        return s[lo:hi], Record(t[lo:hi], {
+            k: (c.take(idx) if isinstance(c, EncodedColumn)
+                else Column(c.ftype, c.values[lo:hi], c.valid[lo:hi]))
+            for k, c in r.columns.items()})
+    return s[idx], r.take(idx)
+
+
+def _strictly_increasing(sid: np.ndarray, t: np.ndarray) -> bool:
+    """Are the rows strictly (sid, time)-sorted?  Comparisons of
+    neighbours only: no difference array is built."""
+    if not (sid[1:] >= sid[:-1]).all():
+        return False
+    ok = t[1:] > t[:-1]
+    ok |= sid[1:] != sid[:-1]
+    return bool(ok.all())
+
+
+def _stable_order(sid: np.ndarray, t: np.ndarray) -> np.ndarray:
+    """The stable (sid, time) sort order of the rows.  Where both keys
+    fit one int64 (sid span x time span under 2^63: every store this
+    side of a million series over centuries of nanoseconds) it is ONE
+    stable sort of that key, and numpy's stable sort of int64 is a
+    timsort, which merges the sorted runs it finds: parts that are each
+    (sid, time)-sorted and interleave at a few seams cost a pass, not
+    n log n.  Else a two-key lexsort, stable too."""
+    s0, t0 = int(sid.min()), int(t.min())
+    span = int(t.max()) - t0 + 1
+    if (int(sid.max()) - s0 + 1) * span >= 2**63:
+        return np.lexsort((t, sid))
+    key = sid - s0
+    key *= span
+    key += t
+    key -= t0
+    return np.argsort(key, kind="stable")
+
+
+def _by_single_sid(live) -> list | None:
+    """The parts regrouped by sid where every part is ONE series' rows
+    (a per-series chunk) and they do not lie in sid order yet, else
+    None.  CONSTANT sid required — endpoints alone are not enough: a
+    time-sorted memtable part can interleave sids and still have
+    s[0] == s[-1].  The sort is stable: parts of one series keep
+    oldest-first order."""
+    keys = []
+    for s, _r in live:
         if s[0] != s[-1] or not (s == s[0]).all():
             return None
-        # column set collects over ALL parts — a part fully trimmed by
-        # the time range must still contribute its (all-invalid) columns,
-        # like the general merge path does
-        for name, col in r.columns.items():
-            ftypes.setdefault(name, col.ftype)
-        # pre-slice each part to [lo_t, hi_t): parts are time-sorted, so
-        # two searchsorteds trim chunk-straddle rows as VIEWS before any
-        # copy — the former post-concat range mask was a second full pass
-        lo = int(np.searchsorted(r.times, lo_t, "left"))
-        hi = int(np.searchsorted(r.times, hi_t, "left"))
-        if hi <= lo:
-            continue
-        single.append((int(s[0]), lo, hi, r))
-    if not single:
-        return np.empty(0, np.int64), Record(np.empty(0, np.int64), {})
-    # stable by sid: parts of one series keep oldest-first order, which
-    # the monotonicity check below then validates
-    single.sort(key=lambda x: x[0])
-    t_all = np.concatenate([r.times[lo:hi] for _k, lo, hi, r in single])
-    sid_all = np.concatenate(
-        [np.full(hi - lo, k, np.int64) for k, lo, hi, _r in single])
-    ds = np.diff(sid_all)
-    if not ((ds > 0) | ((ds == 0) & (np.diff(t_all) > 0))).all():
-        return None  # overlap or duplicates: general merge required
-    cols = {}
-    total = len(t_all)
-    for name, ftype in ftypes.items():
-        enc = _concat_encoded(name, ftype, single, total)
-        if enc is not None:
-            cols[name] = enc
-            continue
-        values = _zeroed(ftype, total)
-        valid = np.zeros(total, dtype=np.bool_)
-        at = 0
-        for _k, lo, hi, r in single:
-            m = hi - lo
-            col = r.columns.get(name)
-            if col is not None:
-                values[at:at + m] = col.values[lo:hi]
-                valid[at:at + m] = col.valid[lo:hi]
-            at += m
-        cols[name] = Column(ftype, values, valid)
-    return sid_all, Record(t_all, cols)
-
-
-def _concat_encoded(name, ftype, single, total):
-    """Encoded-view concatenation for the sorted-fast merge: when every
-    part contributes this column as an EncodedColumn, the merged column
-    composes their (possibly time-trimmed) row views.  Still-encoded
-    parts never materialize decoded bytes on the host (the device-decode
-    cold path, ops/device_decode.py); already-decoded parts (colcache
-    host-tier hits on a warm repeat) compose too, carrying their
-    memoized values forward WITH the raw blocks still attached — so the
-    offload planner (query/offload.py) keeps the device route available
-    on every repeat.  Any absence or run-cap overflow falls back to the
-    copying path (bit-identical either way)."""
-    merged = None
-    for _k, lo, hi, r in single:
-        col = r.columns.get(name)
-        if not isinstance(col, EncodedColumn) or col.ftype != ftype:
-            return None
-        view = col if (lo == 0 and hi == len(col)) \
-            else col.take(np.arange(lo, hi))
-        if not isinstance(view, EncodedColumn):
-            return None  # run-cap overflow dropped the blocks
-        merged = view if merged is None else merged.concat(view)
-        if not isinstance(merged, EncodedColumn):
-            return None
-    if merged is None or len(merged) != total:
+        keys.append(int(s[0]))
+    at = sorted(range(len(live)), key=keys.__getitem__)
+    if at == list(range(len(live))):
         return None
-    return merged
+    return [live[i] for i in at]
 
 
 def merge_bulk_parts(
-    parts: list[tuple[np.ndarray, Record]], lo_t: int, hi_t: int
+    parts: list[tuple[np.ndarray, Record]], lo_t: int, hi_t: int,
+    told: dict | None = None,
 ) -> tuple[np.ndarray, Record]:
     """Vectorized multi-series merge: `parts` is [(sid_arr, record)] in
-    oldest-to-newest order; output rows sort by (sid, time), duplicate
-    (sid, time) pairs keep the newest ROW whole (matching
-    merge_sorted_records / dedup_last_wins row semantics exactly), done
-    in one numpy pass over every series at once."""
-    parts = [(s, r) for s, r in parts if len(r)]
-    if not parts:
-        return np.empty(0, np.int64), Record(np.empty(0, np.int64), {})
-    # parts whose in-order concatenation is ALREADY strictly
-    # (sid, time)-sorted need no merge at all: one part (the memtable
-    # consolidation, one packed colstore chunk), or several packed
-    # chunks written series-ascending (a big flush streams a chunk
-    # every PACK_ROWS rows, never splitting a series).  One
-    # monotonicity pass + a time mask instead of the three-key lexsort,
-    # and — the part that matters for the device-decode cold path —
-    # Record.concat/take keep still-encoded columns ENCODED, where the
-    # general merge below materializes them on the host.
-    s_cat = (parts[0][0] if len(parts) == 1
-             else np.concatenate([s for s, _r in parts]))
-    t_cat = (parts[0][1].times if len(parts) == 1
-             else np.concatenate([r.times for _s, r in parts]))
-    ds = np.diff(s_cat)
-    if not len(ds) or (
-            (ds > 0) | ((ds == 0) & (np.diff(t_cat) > 0))).all():
-        rec = parts[0][1]
-        for _s, r in parts[1:]:
-            rec = rec.concat(r)
-        m = (t_cat >= lo_t) & (t_cat < hi_t)
-        if m.all():
-            return s_cat, rec
-        idx = np.flatnonzero(m)
-        return s_cat[idx], rec.take(idx)
-    fast = _merge_bulk_sorted_fast(parts, lo_t, hi_t)
-    if fast is not None:
-        return fast
-    sid_all = np.concatenate([s for s, _r in parts])
-    t_all = np.concatenate([r.times for _s, r in parts])
-    rank_all = np.concatenate(
-        [np.full(len(r), i, np.int32) for i, (_s, r) in enumerate(parts)])
-    in_range = (t_all >= lo_t) & (t_all < hi_t)
+    oldest-to-newest order; output rows are those with lo_t <= time <
+    hi_t, sorted by (sid, time); duplicate (sid, time) pairs keep the
+    newest ROW whole (matching merge_sorted_records / dedup_last_wins
+    row semantics exactly).
 
-    ftypes: dict[str, object] = {}
-    for _s, r in parts:
+    The work is in proportion to the rows KEPT.  First every part is
+    trimmed to the range (`_trim_part`; a part wholly outside gives no
+    rows but still its columns' names and types, so a column only it
+    carries comes out all-invalid, zero-filled).  Then, over the trimmed
+    parts, in this order:
+
+    - `inorder`: their concatenation is already strictly
+      (sid, time)-sorted — one part (the memtable consolidation, one
+      packed chunk), packed chunks written series-ascending (a flush
+      streams a chunk every PACK_ROWS rows, never splitting a series),
+      or files that overlap in sids only outside the range asked.
+      Nothing is sorted;
+    - `single_sid`: every part is one series' rows and, grouped by sid,
+      they are strictly sorted: one monotonicity pass instead of a sort;
+    - `sorted`: the general merge, one stable sort of the rows kept
+      (`_stable_order`; equal (sid, time) keep part order, so the last
+      of a group is its newest row, and no rank array is needed).
+
+    The first two join the parts with every output column built once
+    (`_join_column`), keeping still-encoded columns ENCODED; the general
+    merge materializes them on the host.  `told`, where given, is filled
+    with `branch` (one of the three names) and `rows` (rows that entered
+    the concatenation or sort, after the trim)."""
+    ftypes: dict[str, FieldType] = {}
+    live = []
+    for s, r in parts:
+        if not len(r):
+            continue
         for name, col in r.columns.items():
             ftypes.setdefault(name, col.ftype)
+        s, r = _trim_part(s, r, lo_t, hi_t)
+        if len(r):
+            live.append((s, r))
+    branch = "inorder"
+    if not live:
+        sid_all = t_all = np.empty(0, np.int64)
+    elif len(live) == 1:
+        sid_all, t_all = live[0][0], live[0][1].times
+    else:
+        sid_all = np.concatenate([s for s, _r in live])
+        t_all = np.concatenate([r.times for _s, r in live])
+    if not _strictly_increasing(sid_all, t_all):
+        by_sid = _by_single_sid(live)
+        if by_sid is not None:
+            live = by_sid
+            sid_all = np.concatenate([s for s, _r in live])
+            t_all = np.concatenate([r.times for _s, r in live])
+        # still out of order: overlap or duplicates, the general merge
+        branch = ("single_sid" if by_sid is not None
+                  and _strictly_increasing(sid_all, t_all) else "sorted")
+    if told is not None:
+        told["branch"], told["rows"] = branch, len(t_all)
+    if branch == "sorted":
+        return _merge_sorted(live, ftypes, sid_all, t_all)
+    lens = [len(r) for _s, r in live]
+    return sid_all, Record(t_all, {
+        name: _join_column(
+            ftype, [r.columns.get(name) for _s, r in live], lens)
+        for name, ftype in ftypes.items()})
 
-    order = np.lexsort((rank_all, t_all, sid_all))
-    order = order[in_range[order]]
-    n = len(order)
-    if n == 0:
-        return np.empty(0, np.int64), Record(np.empty(0, np.int64), {})
+
+def _merge_sorted(live, ftypes, sid_all, t_all) -> tuple[np.ndarray, Record]:
+    """merge_bulk_parts' general merge over its trimmed, non-empty
+    parts and their concatenated sids and times: overlapping chunks,
+    duplicate (sid, time) pairs, parts in any row order."""
+    # the sort is stable and the parts lie oldest first, so rows of one
+    # (sid, time) keep part order: the group's last is its newest
+    order = _stable_order(sid_all, t_all)
     sid_s = sid_all[order]
     t_s = t_all[order]
-    new_grp = np.empty(n, np.bool_)
-    new_grp[0] = True
-    new_grp[1:] = (np.diff(sid_s) != 0) | (np.diff(t_s) != 0)
-    starts = np.flatnonzero(new_grp)
-    # newest row of each (sid, time) group wins whole (rank is the last
-    # lexsort key, so the group's final position is its newest part)
-    winners = np.append(starts[1:], n) - 1
-    out_sid = sid_s[starts]
-    out_t = t_s[starts]
-
+    last = np.empty(len(order), np.bool_)
+    last[-1] = True
+    np.not_equal(sid_s[1:], sid_s[:-1], out=last[:-1])
+    last[:-1] |= t_s[1:] != t_s[:-1]
+    if not last.all():
+        # newest row of each (sid, time) group wins whole
+        keep = np.flatnonzero(last)
+        order, sid_s, t_s = order[keep], sid_s[keep], t_s[keep]
+    lens = [len(r) for _s, r in live]
     cols = {}
     for name, ftype in ftypes.items():
-        total = len(sid_all)
-        # zero-init, not np.empty: rows where no part has the column stay
-        # invalid but their value bytes still flow into flushed chunks and
-        # content_digest — heap garbage there breaks the replica-identical
-        # digest guarantee
-        values = _zeroed(ftype, total)
-        valid = np.zeros(total, dtype=np.bool_)
-        at = 0
-        for _s, r in parts:
-            m = len(r)
-            col = r.columns.get(name)
-            if col is not None:
-                values[at:at + m] = col.values
-                valid[at:at + m] = col.valid
-            at += m
-        take = order[winners]
-        cols[name] = Column(ftype, values[take], valid[take])
-    return out_sid, Record(out_t, cols)
+        col = _join_plain(
+            ftype, [r.columns.get(name) for _s, r in live], lens)
+        cols[name] = Column(ftype, col.values[order], col.valid[order])
+    return sid_s, Record(t_s, cols)
 
 
 def merge_sorted_records(records: list[Record]) -> Record:

@@ -74,6 +74,18 @@ def _pack_entries(buffer: list) -> tuple[np.ndarray, Record]:
 _merge_bulk_parts = merge_bulk_parts
 
 
+def _merge_counted(parts, lo_t: int, hi_t: int):
+    """A bulk read's merge, saying what it did: `scan/merges`, the
+    branch it took (`merges_inorder`, `merges_single_sid`,
+    `merges_sorted`) and `rows_merged`, the rows that entered a
+    concatenation or sort once every part was trimmed to the range."""
+    told: dict = {}
+    out = merge_bulk_parts(parts, lo_t, hi_t, told)
+    _STATS.add("scan", (("merges", 1), ("merges_" + told["branch"], 1),
+                        ("rows_merged", told["rows"])))
+    return out
+
+
 def _sid_entries(rec: Record, uniq, starts, ends):
     """(sid, per-series Record) views over one (sid, time)-sorted bulk
     table — the flush path's bridge from memtable tables to chunk writes.
@@ -1726,11 +1738,13 @@ class Shard:
                     tracing.span("mem_read", series=len(sids)))
                 parts.extend(self._mem_rows(mems, measurement, sids, fields))
             if not jobs:    # every chunk came from the cache
-                return _merge_bulk_parts(parts, lo_t, hi_t)
+                return _merge_counted(parts, lo_t, hi_t)
         # a read that decoded says what became of it: a chunk decodes
-        # whole, the merge keeps the rows inside [tmin, tmax)
+        # whole, and the merge trims every part to [tmin, tmax) before
+        # it joins or sorts anything, so what it works on (`rows_merged`)
+        # is what the statement keeps, not what was decoded
         with tracing.span("scan_merge", parts=len(parts)):
-            sid_arr, rec = _merge_bulk_parts(parts, lo_t, hi_t)
+            sid_arr, rec = _merge_counted(parts, lo_t, hi_t)
         _STATS.add("scan", (("rows_decoded", rows_decoded),
                             ("rows_kept", len(rec))))
         return sid_arr, rec

@@ -19,6 +19,7 @@ import sys
 import urllib.parse
 import urllib.request
 
+import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -222,6 +223,99 @@ def test_a_roomy_cache_hits_once_it_is_full_and_the_hit_path_adds_nothing(
             "scan/rows_decoded", "scan/rows_kept", "scan/decoded_bytes",
             "tsf/read_bytes", "tsf/blocks_read", "scanpool/busy_ns"]:
         assert delta(seen, path, 2) == 0, path
+
+
+# -- the merge works on what the statement keeps -------------------------------
+
+
+BRANCHES = ("inorder", "single_sid", "sorted")
+
+
+def test_a_cold_merge_works_on_the_rows_it_keeps_not_on_those_decoded(
+        runs, statements):
+    """A chunk decodes whole, six hours to keep one; every part is trimmed
+    to the hour before anything is joined or sorted, so what the merge
+    works on is the sixth the statement keeps.  The files lie series after
+    series, so trimmed they are in order: nothing is sorted."""
+    _, seen = runs["evicting"]
+    for n in range(len(statements)):
+        kept = delta(seen, "scan/rows_kept", n, n + 1)
+        assert kept == HOSTS * TICKS_AN_HOUR
+        assert delta(seen, "scan/rows_merged", n, n + 1) == kept
+        assert delta(seen, "scan/rows_decoded", n, n + 1) == HOURS * kept
+        assert delta(seen, "scan/merges", n, n + 1) == 1
+        assert delta(seen, "scan/merges_inorder", n, n + 1) == 1
+
+
+@pytest.mark.parametrize("which", [*REGIMES, "time_ordered"])
+def test_every_bulk_read_counts_its_merge_and_names_its_branch(
+        runs, statements, which):
+    """Both call sites: the read that decoded (under `scan_merge`) and the
+    one every chunk of which came from the cache (no span of its own)."""
+    _, seen = runs[which]
+    n = len(statements)
+    assert delta(seen, "scan/merges") == n
+    assert sum(delta(seen, f"scan/merges_{b}") for b in BRANCHES) == n
+    assert delta(seen, "scan/rows_merged") == n * HOSTS * TICKS_AN_HOUR
+    if which == "roomy":    # rows_kept counts the reads that decoded
+        assert delta(seen, "scan/rows_kept", 2) == 0
+        assert delta(seen, "scan/rows_merged", 2) \
+            == (n - 2) * HOSTS * TICKS_AN_HOUR
+
+
+def test_a_whole_range_read_of_in_order_parts_copies_each_part_once(
+        stores, monkeypatch):
+    """Not a timing: every array the merge makes is counted.  Joining P
+    parts one after the other makes P - 1 ever longer copies a column;
+    here the arrays made are the answer's own — sids, times and, a field,
+    values and validity — and their bytes are the answer's bytes."""
+    from opengemini_tpu import record
+    from opengemini_tpu.storage import shard as shard_mod
+
+    sh, = stores["series_major"].engine.all_shards()
+    fields = ["usage_user", "usage_system", "usage_idle"]
+    made: list[np.ndarray] = []
+    merges = []
+    real_merge, real_cat, real_join = (
+        record.merge_bulk_parts, np.concatenate, record._join_plain)
+
+    def counted_cat(arrays, *a, **kw):
+        made.append(real_cat(arrays, *a, **kw))
+        return made[-1]
+
+    def counted_join(*a):
+        col = real_join(*a)
+        made.extend([col.values, col.valid])
+        return col
+
+    def watched_merge(parts, lo_t, hi_t, told=None):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(np, "concatenate", counted_cat)
+            mp.setattr(record, "_join_plain", counted_join)
+            out = real_merge(parts, lo_t, hi_t, told)
+        merges.append((parts, out))
+        return out
+
+    monkeypatch.setattr(shard_mod, "merge_bulk_parts", watched_merge)
+    colcache.GLOBAL.configure(budget_mb=REGIMES["off"])
+    colcache.GLOBAL.clear()
+    before = STATS.counters("scan")
+    sids = np.array(sorted(sh.index.series_ids("cpu")), dtype=np.int64)
+    sid_arr, rec = sh.read_series_bulk("cpu", sids, None, None, fields)
+    after = STATS.counters("scan")
+
+    d = {k: after[k] - before.get(k, 0) for k in after}
+    rows = HOSTS * HOURS * TICKS_AN_HOUR
+    assert len(rec) == rows and list(rec.columns) == fields
+    assert d["merges"] == d["merges_inorder"] == 1
+    assert d["rows_merged"] == d["rows_kept"] == d["rows_decoded"] == rows
+    (parts, out), = merges
+    assert len(parts) == FILES and out[1] is rec
+    answer = [sid_arr, rec.times] + [
+        a for c in rec.columns.values() for a in (c.values, c.valid)]
+    assert len(made) == len(answer) == 2 + 2 * len(fields)
+    assert {id(a) for a in made} == {id(a) for a in answer}
+    assert sum(a.nbytes for a in made) == rows * (8 + 8 + 9 * len(fields))
 
 
 # -- the miss path's spans and counters ---------------------------------------

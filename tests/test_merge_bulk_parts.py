@@ -1,0 +1,353 @@
+"""`record.merge_bulk_parts` against a plain reference kept here: put every
+row of every part in one Python list, sort it by (sid, time, part rank),
+keep the newest row of each (sid, time) whole, cut to the range.  The
+reference reads the parts' arrays and nothing of `record.py`'s helpers; the
+comparison is bit for bit — dtypes, the bytes under invalid slots and the
+column set included — and each case also names the branch the merge has to
+take over its TRIMMED parts and whether the answer stays encoded."""
+
+import numpy as np
+import pytest
+
+from opengemini_tpu.record import (
+    Column, EncodedColumn, FieldType, Record, merge_bulk_parts,
+)
+
+F, I, B, S = (FieldType.FLOAT, FieldType.INT, FieldType.BOOL,
+              FieldType.STRING)
+ALL = (-(2**63), 2**63 - 1)
+
+
+# -- the plain reference ------------------------------------------------------
+
+
+def reference(parts, lo, hi):
+    ftypes, rows = {}, []
+    for rank, (sids, rec) in enumerate(parts):
+        if not len(rec.times):
+            continue
+        for name, col in rec.columns.items():
+            ftypes.setdefault(name, col.ftype)
+        cells = {name: (col.values, col.valid)
+                 for name, col in rec.columns.items()}
+        for i in range(len(rec.times)):
+            rows.append((int(sids[i]), int(rec.times[i]), rank,
+                         {n: (v[i], bool(ok[i]))
+                          for n, (v, ok) in cells.items()}))
+    rows.sort(key=lambda row: row[:3])      # stable: a part keeps its order
+    newest = {}
+    for row in rows:                        # the last of a pair is its newest
+        newest[row[:2]] = row
+    kept = [row for row in newest.values() if lo <= row[1] < hi]
+    kept.sort(key=lambda row: row[:2])
+    out = {}
+    for name, ftype in ftypes.items():
+        values = (np.full(len(kept), None, dtype=object) if ftype == S
+                  else np.zeros(len(kept), dtype=ftype.np_dtype))
+        valid = np.zeros(len(kept), dtype=np.bool_)
+        for at, row in enumerate(kept):
+            if name in row[3]:
+                values[at], valid[at] = row[3][name]
+        out[name] = (ftype, values, valid)
+    return (np.array([row[0] for row in kept], dtype=np.int64),
+            np.array([row[1] for row in kept], dtype=np.int64), out)
+
+
+def same_bits(got: np.ndarray, want: np.ndarray):
+    assert got.dtype == want.dtype and got.shape == want.shape
+    if want.dtype == object:
+        assert got.tolist() == want.tolist()
+    else:       # NaN payloads and the sign of zero too
+        assert np.ascontiguousarray(got).tobytes() == want.tobytes()
+
+
+# -- the parts ----------------------------------------------------------------
+
+
+def column(rng, ftype, n, holes=True):
+    """Values with garbage under the invalid slots: what a part carries
+    there has to come out as it went in."""
+    if ftype == F:
+        values = rng.normal(size=n).round(3)
+        values[rng.random(n) < 0.05] = np.nan
+    elif ftype == I:
+        values = rng.integers(-2**40, 2**40, n)
+    elif ftype == B:
+        values = rng.random(n) < 0.5
+    else:
+        values = np.array([f"s{k}" for k in rng.integers(0, 9, n)],
+                          dtype=object)
+    valid = rng.random(n) < 0.8 if holes else np.ones(n, np.bool_)
+    return Column(ftype, np.asarray(values, dtype=ftype.np_dtype), valid)
+
+
+def part(rng, sids, times, fields):
+    sids = np.asarray(sids, dtype=np.int64)
+    times = np.asarray(times, dtype=np.int64)
+    return sids, Record(times, {name: column(rng, ftype, len(times))
+                                for name, ftype in fields.items()})
+
+
+def packed(rng, sid_lo, sid_hi, t_lo, t_hi, fields, step=10):
+    """A packed chunk: series sid_lo..sid_hi-1, each all of t_lo..t_hi."""
+    ticks = np.arange(t_lo, t_hi, step)
+    n = sid_hi - sid_lo
+    return part(rng, np.repeat(np.arange(sid_lo, sid_hi), len(ticks)),
+                np.tile(ticks, n), fields)
+
+
+def _decode(ftype, blocks):
+    return np.concatenate([np.frombuffer(b, dtype=ftype.np_dtype)
+                           for b in blocks])
+
+
+def encoded(col: Column, decoded: bool = False) -> EncodedColumn:
+    """The column as one still-encoded block (a codec of this file's own:
+    the bytes of the array), its `.values` touched or not."""
+    out = EncodedColumn(col.ftype, [col.values.tobytes()], col.valid, _decode)
+    if decoded:
+        assert out.values is not None and out.is_decoded
+    return out
+
+
+def encode_parts(parts, decoded=()):
+    """Every column of every part encoded; parts whose index is in
+    `decoded` already decoded (a column-cache hit)."""
+    return [(s, Record(r.times, {k: encoded(c, at in decoded)
+                                 for k, c in r.columns.items()}))
+            for at, (s, r) in enumerate(parts)]
+
+
+TWO = {"usage_user": F, "usage_system": F}
+MIX = {"f": F, "i": I, "b": B, "s": S}
+
+
+def _packed_files(rng, fields=TWO):
+    """Three files of packed chunks, series-major, every chunk all of
+    0..600: what a compacted store's bulk read hands the merge."""
+    return [packed(rng, lo, lo + 4, 0, 600, fields) for lo in (0, 4, 8)]
+
+
+def _seam(rng, fields=TWO):
+    """Two files that overlap in sids 3 and 4 at their seam: the first has
+    their rows before 300, the second those from 300 on."""
+    a = packed(rng, 0, 3, 0, 600, fields)
+    a_seam = packed(rng, 3, 5, 0, 300, fields)
+    b_seam = packed(rng, 3, 5, 300, 600, fields)
+    b = packed(rng, 5, 8, 0, 600, fields)
+
+    def join(x, y):     # one file's two chunks as one part
+        return (
+            np.concatenate([x[0], y[0]]),
+            Record(np.concatenate([x[1].times, y[1].times]),
+                   {k: Column(c.ftype,
+                              np.concatenate([c.values,
+                                              y[1].columns[k].values]),
+                              np.concatenate([c.valid,
+                                              y[1].columns[k].valid]))
+                    for k, c in x[1].columns.items()}))
+
+    return [join(a, a_seam), join(b_seam, b)]
+
+
+def _duplicates(rng):
+    """The same (sid, time) pairs in three parts with different column
+    sets: the newest row wins WHOLE, so a column only the older part
+    carries comes out invalid there."""
+    return [packed(rng, 0, 3, 0, 100, {"a": F, "b": I}),
+            packed(rng, 1, 4, 50, 150, {"b": I, "c": S}),
+            packed(rng, 0, 2, 90, 120, {"a": F, "d": B})]
+
+
+def _outside_carries_a_column(rng):
+    return [packed(rng, 0, 3, 0, 100, {"a": F}),
+            packed(rng, 0, 3, 500, 600, {"a": F, "only_here": I})]
+
+
+def _single_sid(rng, fields=TWO, overlap=False):
+    """Per-series chunks, two a series, the files not in sid order."""
+    out = []
+    for sid in (4, 1, 3):
+        out.append(part(rng, [sid] * 30, np.arange(0, 300, 10), fields))
+    for sid in (1, 4, 3):
+        lo = 250 if overlap else 300
+        out.append(part(rng, [sid] * 30, np.arange(lo, lo + 300, 10), fields))
+    return out
+
+
+def _memtable_slab(rng):
+    """One part in arrival order: sids interleaved, one pair twice."""
+    sids = [2, 1, 2, 1, 3, 2, 1]
+    times = [10, 10, 20, 20, 10, 10, 30]
+    return [part(rng, sids, times, MIX)]
+
+
+def _wide_keys(rng):
+    """sid span x time span past 2^63: no single int64 sort key."""
+    return [part(rng, [0, 0, 2**40, 2**40], [5, 2**41, 5, 7], TWO),
+            part(rng, [0, 1, 2**40], [-2**41, 3, 5], TWO)]
+
+
+CASES = {
+    # name: (parts, (lo, hi), branch, answer stays encoded)
+    "packed_parts_straddle_the_range":
+        (lambda r: _packed_files(r), (200, 300), "inorder", False),
+    "packed_parts_wholly_inside":
+        (lambda r: _packed_files(r), (0, 600), "inorder", False),
+    "packed_parts_every_type":
+        (lambda r: _packed_files(r, MIX), (100, 450), "inorder", False),
+    "seam_overlap_cut_away_by_the_range":
+        (lambda r: _seam(r), (300, 400), "inorder", False),
+    "seam_overlap_inside_the_range":
+        (lambda r: _seam(r, MIX), (250, 350), "sorted", False),
+    "seam_overlap_unbounded":
+        (lambda r: _seam(r), ALL, "sorted", False),
+    "duplicates_newest_row_wins_whole":
+        (lambda r: _duplicates(r), (0, 200), "sorted", False),
+    "duplicates_unbounded":
+        (lambda r: _duplicates(r), ALL, "sorted", False),
+    "duplicates_trimmed_away":
+        (lambda r: _duplicates(r), (0, 50), "inorder", False),
+    "a_part_outside_alone_carries_a_column":
+        (lambda r: _outside_carries_a_column(r), (0, 200), "inorder", False),
+    "single_sid_parts":
+        (lambda r: _single_sid(r), (100, 500), "single_sid", False),
+    "single_sid_parts_every_type":
+        (lambda r: _single_sid(r, MIX), ALL, "single_sid", False),
+    "single_sid_parts_overlapping":
+        (lambda r: _single_sid(r, overlap=True), ALL, "sorted", False),
+    "single_sid_parts_in_sid_order":
+        (lambda r: sorted(_single_sid(r), key=lambda p: int(p[0][0])),
+         (0, 1000), "inorder", False),
+    "one_part":
+        (lambda r: _packed_files(r)[:1], (100, 200), "inorder", False),
+    "one_part_unbounded":
+        (lambda r: _packed_files(r, MIX)[:1], ALL, "inorder", False),
+    "one_part_in_arrival_order":
+        (lambda r: _memtable_slab(r), ALL, "sorted", False),
+    "one_part_in_arrival_order_cut":
+        (lambda r: _memtable_slab(r), (10, 20), "sorted", False),
+    "no_parts":
+        (lambda r: [], ALL, "inorder", False),
+    "only_empty_parts":
+        (lambda r: [part(r, [], [], TWO)], ALL, "inorder", False),
+    "an_empty_part_among_others":
+        (lambda r: [_packed_files(r)[0], part(r, [], [], {"gone": I}),
+                    _packed_files(r)[1]], (0, 600), "inorder", False),
+    "nothing_in_the_range":
+        (lambda r: _seam(r), (1000, 2000), "inorder", False),
+    "keys_too_wide_for_one_sort_key":
+        (lambda r: _wide_keys(r), ALL, "sorted", False),
+    "negative_times":
+        (lambda r: [packed(r, 0, 3, -300, 300, TWO),
+                    packed(r, 2, 5, -100, 100, TWO)], (-200, 50), "sorted",
+         False),
+    "encoded_parts_stay_encoded":
+        (lambda r: encode_parts(_packed_files(r, {"f": F, "i": I})),
+         (0, 600), "inorder", True),
+    "encoded_parts_trimmed_stay_encoded":
+        (lambda r: encode_parts(_packed_files(r, {"f": F, "i": I})),
+         (200, 300), "inorder", True),
+    "encoded_parts_decoded_stay_encoded":
+        (lambda r: encode_parts(_packed_files(r, {"f": F}), decoded={0, 1, 2}),
+         (200, 300), "inorder", True),
+    "encoded_parts_some_decoded":
+        (lambda r: encode_parts(_packed_files(r, {"f": F}), decoded={1}),
+         (0, 600), "inorder", True),
+    "encoded_single_sid_parts_stay_encoded":
+        (lambda r: encode_parts(_single_sid(r, {"f": F, "i": I})),
+         (100, 500), "single_sid", True),
+    "encoded_one_part_trimmed":
+        (lambda r: encode_parts(_packed_files(r, {"i": I})[:1]),
+         (100, 200), "inorder", True),
+    "encoded_parts_that_need_the_sort_decode":
+        (lambda r: encode_parts(_seam(r, {"f": F, "i": I})), ALL, "sorted",
+         False),
+    "encoded_beside_plain_decodes":
+        (lambda r: encode_parts(_packed_files(r)[:2]) + _packed_files(r)[2:],
+         (0, 600), "inorder", False),
+    "encoded_where_a_part_lacks_the_column":
+        (lambda r: encode_parts([packed(r, 0, 2, 0, 100, {"f": F}),
+                                 packed(r, 2, 4, 0, 100, {"f": F, "g": F})]),
+         ALL, "inorder", None),
+}
+
+
+@pytest.mark.parametrize("name", CASES)
+def test_the_merge_is_the_references_bit_for_bit(name):
+    build, (lo, hi), branch, stays_encoded = CASES[name]
+    parts = build(np.random.default_rng(43))
+
+    def decoded():
+        return [isinstance(c, EncodedColumn) and c.is_decoded
+                for _s, r in parts for c in r.columns.values()]
+
+    decoded_before = decoded()
+    told = {}
+    sid, rec = merge_bulk_parts(parts, lo, hi, told)
+    # read before the reference, whose walk over the values decodes them
+    decoded_after = decoded()
+    out_decoded = {k: isinstance(c, EncodedColumn) and c.is_decoded
+                   for k, c in rec.columns.items()}
+    want_sid, want_t, want_cols = reference(parts, lo, hi)
+
+    same_bits(sid, want_sid)
+    same_bits(rec.times, want_t)
+    assert list(rec.columns) == list(want_cols)
+    assert told["branch"] == branch
+    # rows that entered the join or sort: those in range, pairs counted twice
+    assert told["rows"] == sum(
+        int(((r.times >= lo) & (r.times < hi)).sum()) for _s, r in parts)
+    for col_name, (ftype, values, valid) in want_cols.items():
+        col = rec.columns[col_name]
+        if stays_encoded is True:
+            # joining and trimming encoded parts decodes none of them
+            assert isinstance(col, EncodedColumn), col_name
+            assert out_decoded[col_name] == all(decoded_before)
+            assert decoded_after == decoded_before
+        elif stays_encoded is False:
+            assert not isinstance(col, EncodedColumn), col_name
+        assert col.ftype == ftype
+        same_bits(col.valid, valid)
+        same_bits(col.values, values)
+        assert len(col) == len(want_sid)
+
+
+def test_a_part_wholly_inside_is_handed_on_as_it_is():
+    """One comparison pass and no copy: what the hot cell's parts, a
+    whole-range read and the memtable's unbounded calls pay."""
+    (sids, rec), = _packed_files(np.random.default_rng(1), MIX)[:1]
+    for lo, hi in (ALL, (0, 600)):
+        out_sid, out = merge_bulk_parts([(sids, rec)], lo, hi)
+        assert out_sid is sids and out.times is rec.times
+        for name, col in rec.columns.items():
+            assert out.columns[name] is col
+
+
+def test_an_encoded_join_is_the_pairwise_joins():
+    """Blocks, runs, root spans and memoized values of the many-part join
+    are those `a.concat(b).concat(c)` gives."""
+    rng = np.random.default_rng(2)
+    roots = [encoded(column(rng, I, n, holes=False)) for n in (40, 30, 50)]
+    views = [roots[0].take(np.arange(5, 35)), roots[1],
+             roots[2].take(np.array([0, 1, 2, 10, 11, 40]))]
+    for touch in ((), (0, 1, 2), (1,)):
+        cols = [EncodedColumn(v.ftype, v.blocks, v.valid, v._decode,
+                              segments=v.segments, n_full=v.n_full)
+                for v in views]
+        for c, v in zip(cols, views):
+            c._spans = v._spans_or_self()
+        for at in touch:
+            assert cols[at].values is not None
+        pair = cols[0].concat(cols[1]).concat(cols[2])
+        many = EncodedColumn.join(cols)
+        assert many.blocks == pair.blocks and many.n_full == pair.n_full
+        assert many.segments.tolist() == pair.segments.tolist()
+        assert [(id(r), off) for r, off in many._spans] \
+            == [(id(r), off) for r, off in pair._spans]
+        assert many.is_decoded == pair.is_decoded == (len(touch) == 3)
+        same_bits(many.valid, pair.valid)
+        same_bits(many.values, pair.values)
+    # past the run cap neither stays encoded
+    many_runs = [roots[2].take(np.arange(0, 50, 2))] * 200
+    assert EncodedColumn.join(many_runs) is None
