@@ -763,6 +763,24 @@ class Smoke:
             check(c.get("mesh_shard_devices") == self.args.devices,
                   f"sharded arrays span {c.get('mesh_shard_devices')} "
                   f"device(s), want {self.args.devices}")
+            # "sharded" as the cell tsbs_fleet_groupby_mesh4 counts it
+            # (benchmark/metrics/mesh_*.json): rows put under the row
+            # sharding, those of them the padding added, and bucket
+            # kernels whose matrices were sharded and not kept on the host
+            put, pad = c.get("mesh_put_rows", 0), c.get("mesh_pad_rows", 0)
+            items = (c.get("mesh_items_sharded", 0),
+                     c.get("mesh_items_unsharded", 0))
+            log(f"mesh: {put} rows put, {pad} of them padding; bucket "
+                f"kernels sharded {items[0]}, kept on the host {items[1]}; "
+                f"mesh {doc['mesh'].get('axes')} over devices "
+                f"{doc['mesh'].get('device_ids')}")
+            check(0 <= pad < put, f"mesh_pad_rows {pad} of mesh_put_rows "
+                  f"{put}: nothing was put, or only padding")
+            check(items[0] > 0, "no bucket kernel ran on sharded matrices "
+                  f"(mesh_items_sharded {items[0]}, unsharded {items[1]})")
+            check(doc["mesh"].get("device_ids") is not None
+                  and len(doc["mesh"]["device_ids"]) == self.args.devices,
+                  f"/debug/device mesh spans {doc['mesh'].get('device_ids')}")
         if not on_cpu and not mesh:
             # models/ragged.py routes unsharded selectors to Pallas on a
             # TPU, and ops/pallas_segment interprets on the CPU only: the

@@ -392,9 +392,18 @@ class _Bucket:
     def _item(self, family: str):
         from opengemini_tpu.parallel import runtime as _prt
 
-        args = self._args(_prt.get_mesh(), family)
+        mesh = _prt.get_mesh()
+        args = self._args(mesh, family)
+        sharded = args[0] is not self.values
+        if mesh is not None:
+            # a bucket of fewer sub-rows than the mesh has devices keeps
+            # its host matrices and so launches in a group of its own
+            # (launch.Item.key holds the placement): counted, so that a
+            # statement's launches on a mesh can be told from counters
+            STATS.incr("device", "mesh_items_sharded" if sharded
+                       else "mesh_items_unsharded")
         kind = family
-        if family == "selectors" and args[0] is not self.values:
+        if family == "selectors" and sharded:
             # force the XLA selector form only when the inputs really are
             # mesh-sharded (pallas_call does not auto-partition);
             # unsharded buckets keep the fused Pallas kernel on TPU

@@ -301,10 +301,23 @@ def shard_leading_axis(mesh: Mesh, *arrays, xfer_site: str = "mesh-shard"):
 
     Rows are padded (zeros -> masked out by the kernels' mask plane or
     sliced off by the [:g] caller convention) to a multiple of mesh.size.
+
+    The pad and the puts are one `mesh_shard` span of the request that
+    asked for them (`query_stages/mesh_shard_ns`; in a capture the idle
+    gap it causes is named `ogt:mesh_shard`).  It times the pad
+    (np.concatenate) and the ENQUEUE of the puts: `device_put` returns
+    before the bytes have moved, as `prom_values_h2d` does, so the wait
+    for them stays where it is paid, in the fetch's `device_wait`.
+    Counters, one update a call: `device/mesh_dense_batches`,
+    `mesh_h2d_bytes`, and — a row is a slice of the leading axis, counted
+    once an array — `mesh_put_rows` (rows put, padding included) and
+    `mesh_pad_rows` (of them, rows the padding added), so that padding is
+    a share and not a guess; `mesh_shard_devices` is set to the devices
+    the newest batch landed on.
     """
     import time as _time
 
-    from opengemini_tpu.utils import devobs
+    from opengemini_tpu.utils import devobs, tracing
     from opengemini_tpu.utils.stats import GLOBAL as _STATS
 
     n_dev = mesh.size
@@ -312,24 +325,29 @@ def shard_leading_axis(mesh: Mesh, *arrays, xfer_site: str = "mesh-shard"):
     npad = (n + n_dev - 1) // n_dev * n_dev
     out = []
     nbytes = 0
-    t0 = _time.perf_counter_ns()
-    for a in arrays:
-        if npad != n:
-            pad = np.zeros((npad - n,) + a.shape[1:], dtype=a.dtype)
-            a = np.concatenate([a, pad])
-        out.append(jax.device_put(a, leading_axis_sharding(mesh, a.ndim)))
-        nbytes += int(a.nbytes)
-    _STATS.incr("device", "mesh_dense_batches")
+    with tracing.span("mesh_shard", arrays=len(arrays), rows=n,
+                      pad_rows=npad - n) as sp:
+        t0 = _time.perf_counter_ns()
+        for a in arrays:
+            if npad != n:
+                pad = np.zeros((npad - n,) + a.shape[1:], dtype=a.dtype)
+                a = np.concatenate([a, pad])
+            out.append(jax.device_put(a, leading_axis_sharding(mesh, a.ndim)))
+            nbytes += int(a.nbytes)
+        enqueue_s = (_time.perf_counter_ns() - t0) / 1e9
+        sp.add_field("bytes", nbytes)
+    # every byte here is a host->device transfer a warm mesh query should
+    # NOT repeat (the colcache device tier retains the sharded buffers);
+    # tests/test_multichip.py asserts mesh_h2d_bytes is flat across warm runs
+    _STATS.add("device", (("mesh_dense_batches", 1),
+                          ("mesh_h2d_bytes", nbytes),
+                          ("mesh_put_rows", npad * len(arrays)),
+                          ("mesh_pad_rows", (npad - n) * len(arrays))))
     # how many devices the newest batch really landed on: a mesh that
     # put every shard on the first chip would read 1 here
     _STATS.set("device", "mesh_shard_devices",
                len({s.device.id for s in out[0].addressable_shards}))
-    # every byte here is a host->device transfer a warm mesh query should
-    # NOT repeat (the colcache device tier retains the sharded buffers);
-    # tests/test_multichip.py asserts this counter is flat across warm runs
-    _STATS.incr("device", "mesh_h2d_bytes", nbytes)
-    devobs.note_transfer("h2d", xfer_site, nbytes,
-                         (_time.perf_counter_ns() - t0) / 1e9)
+    devobs.note_transfer("h2d", xfer_site, nbytes, enqueue_s)
     return tuple(out)
 
 

@@ -803,9 +803,15 @@ def debug_doc() -> dict:
         # kernel compile inline on a serving thread
         "capabilities": backend_capabilities(probe=False),
         "devices": device_table(),
+        # the shape by axis name and the ids of the devices the mesh
+        # spans, row-major, as `devices` above lists them
         "mesh": {"configured": mesh is not None,
                  "size": getattr(mesh, "size", None),
-                 "epoch": _prt.mesh_epoch()},
+                 "epoch": _prt.mesh_epoch(),
+                 "axes": None if mesh is None else
+                 {name: int(n) for name, n in mesh.shape.items()},
+                 "device_ids": None if mesh is None else
+                 [int(d.id) for d in mesh.devices.flat]},
         "counters": _STATS.counters("device"),
         "compile_wall_ms": wall_ms,
         "jit_cache": jit_inventory(),

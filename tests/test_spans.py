@@ -247,6 +247,49 @@ def test_a_query_leaves_a_tree(server):
     assert fields["status"] == 200 and fields["bytes_out"] == len(body)
 
 
+@pytest.mark.parametrize("devices", [4, 0], ids=["mesh_of_four", "no_mesh"])
+def test_mesh_shard_is_a_stage_of_a_request_only_on_a_mesh(server, devices):
+    """With `[device] mesh-axes` the pad and the sharded puts of a
+    statement's matrices are the span `mesh_shard`
+    (parallel/distributed.py shard_leading_axis): in the tree under
+    `device_compute`, in the request's one stage map under its root, in
+    `query_stages`.  With no mesh it never opens."""
+    from opengemini_tpu.parallel import distributed as dist
+
+    port = server.port
+    lines = "\n".join(f"cpu,host=h{h} v={h + k} {(BASE + k * 600) * NS}"
+                      for h in range(12) for k in range(6))
+    assert _http(port, "POST", "/write", lines.encode(), db="db")[0] == 204
+    tracing.set_trace_enabled(True)
+    tracing.mark()
+    q0 = _counters("query_stages")
+    prt.set_mesh(dist.make_mesh(devices) if devices else None)
+    try:
+        status, body = _http(
+            port, "GET", "/query", db="db",
+            q=f"SELECT mean(v) FROM cpu WHERE time >= {BASE * NS} AND "
+              f"time < {(BASE + 3600) * NS} GROUP BY time(10m), host")
+    finally:
+        prt.set_mesh(None)
+    assert status == 200 and "error" not in json.loads(body)["results"][0]
+    doc = _trace_of(port, "http_query")         # the root has closed
+    spans = _check_tree(doc, "http_query", QUERY_SPANS | {"mesh_shard"})
+    [rec] = tracing.tail_doc()["query"]
+    d = _delta("query_stages", q0)
+    if not devices:
+        assert "mesh_shard" not in spans and "mesh_shard" not in rec["stages"]
+        assert not [k for k in d if k.startswith("mesh_shard")]
+        return
+    assert all(p["name"] == "device_compute" for _, p in spans["mesh_shard"])
+    ns, self_ns, count = rec["stages"]["mesh_shard"]
+    assert (ns, self_ns, count) == (
+        d["mesh_shard_ns"], d["mesh_shard_self_ns"], d["mesh_shard_count"])
+    assert count == len(spans["mesh_shard"]) >= 1 and 0 < ns == self_ns
+    assert ns <= rec["stages"]["device_compute"][0]
+    put = dict(map(tuple, spans["mesh_shard"][0][0]["fields"]))
+    assert put["arrays"] == 2 and put["pad_rows"] == 0 and put["bytes"] > 0
+
+
 @pytest.mark.parametrize("series", [70, 8], ids=["bulk", "per_series"])
 def test_rows_not_yet_flushed_are_read_under_one_span_a_shard(server, series):
     """Two shards a week apart, one flushed and one not: the read of the
