@@ -37,6 +37,8 @@ kernels see float seconds relative to a base — callers produce them via
 
 from __future__ import annotations
 
+import functools as _functools
+
 import jax.numpy as jnp
 import numpy as np
 
@@ -81,20 +83,32 @@ def prepare_matrix_runs(t_ms_all, v_all, lens, dtype=np.float32):
     # the time/count structure is prepared; the value matrix fills
     # lazily (host fallback) or decodes on device (ops/device_decode)
     values = None if v_all is None else np.zeros((S, n_max), dtype=dtype)
-    total = int(lens.sum())
-    starts = np.cumsum(lens) - lens
-    base_ms = 0
-    if total:
-        # times are ascending per series, so the global min is the min of
-        # each non-empty series' first sample
-        base_ms = int(t_ms_all[starts[lens > 0]].min())
-        rows = np.repeat(np.arange(S, dtype=np.int64), lens)
-        cols = np.arange(total, dtype=np.int64) - np.repeat(starts, lens)
-        flat = rows * n_max + cols
+    base_ms = _runs_base_ms(t_ms_all, lens)
+    if int(lens.sum()):
+        flat = _scatter_index(lens, n_max)
         times.reshape(-1)[flat] = (np.asarray(t_ms_all) - base_ms) / 1000.0
         if values is not None:
             values.reshape(-1)[flat] = v_all
     return times, values, lens.astype(np.int32), base_ms
+
+
+def _runs_base_ms(t_ms_all, lens) -> int:
+    """What a prepare's seconds are relative to: the earliest sample.
+    Times are ascending per series, so the global min is the min of each
+    non-empty series' first sample; 0 where there is none."""
+    starts = np.cumsum(lens) - lens
+    first = starts[lens > 0]
+    return int(np.asarray(t_ms_all)[first].min()) if len(first) else 0
+
+
+def _scatter_index(lens, n_max: int):
+    """The flat cell of a padded (S, n_max) matrix each run-encoded sample
+    lands in: sample j of the concatenation is column j - starts[i] of its
+    series' row i, so the index is j plus one per-series offset."""
+    starts = np.cumsum(lens) - lens
+    return (np.arange(int(lens.sum()), dtype=np.int64)
+            + np.repeat(np.arange(len(lens), dtype=np.int64) * n_max - starts,
+                        lens))
 
 
 def window_bounds(times, counts, step_starts, step_ends):
@@ -613,19 +627,32 @@ class TiledPrepared:
                 plan.window_s)
             self.plan = plan
             self.K = len(plan.a_idx)
-        # padded (S, N) matrices: the one flat-scatter fill shared with
-        # the dense path (same +inf/zero padding and base_ms contract)
+        # what the lazily built structures are made from: references, not
+        # copies (the engine keeps both alive through the kernel anyway)
+        self._t_ms_all, self._lens = t_ms_all, lens
+        self.counts = lens.astype(np.int32)
+        self.base_ms = _runs_base_ms(t_ms_all, lens)
+        # the padded (S, N) value matrix: the dense path's flat scatter
+        # and zero padding (prepare_matrix_runs).  The times matrix that
+        # fill also makes is built on its first read, below
         with tracing.span("prom_fill"):
-            self.times, self.values, self.counts, self.base_ms = (
-                prepare_matrix_runs(t_ms_all, v_all, lens, dtype=self.dtype))
+            self.values = None if v_all is None else self._pad_values(v_all)
         with tracing.span("prom_tile_index"):
             self._index_tiles(plan, t_ms_all, lens, max_gather_cols)
+
+    def _pad_values(self, v_all) -> np.ndarray:
+        values = np.zeros((self.S, self.N), dtype=self.dtype)
+        if len(v_all):
+            values.reshape(-1)[_scatter_index(self._lens, self.N)] = v_all
+        return values
 
     def _index_tiles(self, plan: TilePlan, t_ms_all, lens,
                      max_gather_cols: int | None) -> None:
         """The time structure every kernel answers from: per-(series,
         tile) sample counts and their prefixes, each window's first and
-        last sample index, and the compact covered-tile gather layout."""
+        last sample index and the times of those samples, and the size of
+        the covered-tile gather layout — which decides tiled against
+        dense here, while the layout itself waits for its first reader."""
         S, N = self.S, self.N
         total = int(lens.sum())
         # -- integer-arithmetic tile bucketing (no searchsorted) --
@@ -635,12 +662,12 @@ class TiledPrepared:
         tid = np.clip(tile_index(t_ms_all, plan.anchor_ms, plan.g_ms),
                       0, T - 1)
         if total:
-            rows = np.repeat(np.arange(S, dtype=np.int64), lens)
             # int32 throughout: counts and prefixes are bounded by N <
             # 2^31, and these (S, T) arrays are the prepare path's
             # dominant allocation
-            cnt = np.bincount(rows * T + tid,
-                              minlength=S * T).reshape(S, T).astype(np.int32)
+            tid += np.repeat(np.arange(S, dtype=np.int64) * T, lens)
+            cnt = np.bincount(tid, minlength=S * T).reshape(S, T).astype(
+                np.int32)
         else:
             cnt = np.zeros((S, T), np.int32)
         tile_cum = np.zeros((S, T + 1), np.int32)
@@ -658,14 +685,11 @@ class TiledPrepared:
         self.safe_fm1 = np.clip(first_idx - 1, 0, lim).astype(np.int32)
         self.safe_lm1 = np.clip(last_idx - 1, 0, lim).astype(np.int32)
         self.fmask = first_idx >= 1  # the straddling boundary pair exists
-        self.t_first = np.take_along_axis(
-            self.times, self.safe_f, axis=1).astype(self.dtype)
-        self.t_last = np.take_along_axis(
-            self.times, self.safe_l, axis=1).astype(self.dtype)
-        self.t_lm1 = np.take_along_axis(
-            self.times, self.safe_lm1, axis=1).astype(self.dtype)
+        self.t_first = self._times_at(self.safe_f)
+        self.t_last = self._times_at(self.safe_l)
+        self.t_lm1 = self._times_at(self.safe_lm1)
 
-        # -- compact covered-tile gather layout --
+        # -- the compact covered-tile gather layout's size --
         cov = plan.cov
         C = len(cov)
         cnt_cov = cnt[:, cov]
@@ -675,33 +699,12 @@ class TiledPrepared:
         if C * (pmax + 1) > max(budget, 64):
             raise TileBudgetExceeded(
                 f"gather layout {C}x{pmax + 1} over budget {budget}")
-        # slot 0 = the sample BEFORE the tile's first (any tile — pair
-        # quantities need the previous sample wherever it lives); slots
-        # 1..pmax = the tile's own samples
-        tile_start = tile_cum[:, cov]  # (S, C) first sample ordinal in tile
-        gidx_local = tile_start[:, :, None] + np.arange(-1, pmax)[None, None, :]
-        own_valid = (np.arange(pmax)[None, None, :] < cnt_cov[:, :, None])
-        prev_valid = tile_start > 0
-        self.gmask = np.concatenate(
-            [prev_valid[:, :, None], own_valid], axis=2)
-        gidx_local = np.clip(gidx_local, 0, lim[:, :, None])
-        self.gidx = (np.arange(S, dtype=np.int64)[:, None, None] * N
-                     + gidx_local)
-        if S * N <= np.iinfo(np.int32).max:
-            # what a device without x64 indexes in: narrowed here, once
-            # and checked, not wherever jax would wrap it silently
-            self.gidx = self.gidx.astype(np.int32)
-        # row-LOCAL gather columns (gidx minus its row offset): the mesh
-        # path gathers per series row so GSPMD can shard the series axis
-        # without collectives; None until shard_tiled derives it
-        self.gidx_col = None
         self.C, self.pmax = C, pmax
+        self._tile_cum, self._cnt_cov = tile_cum, cnt_cov
         # (1, K): take_along_axis broadcasts the non-gather dim, so the
         # per-series copy would be S redundant rows of the same indices
         self.ca2 = plan.ca[None, :].astype(np.int32)
         self.cb2 = plan.cb[None, :].astype(np.int32)
-        self.pairmask = self.gmask[:, :, 1:] & self.gmask[:, :, :-1]
-        self.ownmask = self.gmask[:, :, 1:]
         # window edges, base-relative seconds, kernel dtype
         self.starts_rel = ((np.rint(np.asarray(plan.a_idx) * plan.g_ms
                                     + plan.anchor_ms) - self.base_ms)
@@ -709,6 +712,84 @@ class TiledPrepared:
         self.ends_rel = ((np.rint(np.asarray(plan.b_idx) * plan.g_ms
                                   + plan.anchor_ms) - self.base_ms)
                          / 1000.0).astype(self.dtype)
+
+    def _times_at(self, idx) -> np.ndarray:
+        """`take_along_axis(self.times, idx)` without the matrix: the same
+        samples gathered from the run-encoded times, then shifted and
+        scaled by the same float64 operations, so the same bits.  An empty
+        series reads +inf, as its all-padding row of the matrix does."""
+        lens, t_ms_all = self._lens, self._t_ms_all
+        if not len(t_ms_all):
+            return np.full(idx.shape, np.inf, dtype=self.dtype)
+        # idx stays inside its series (safe_* clip to its length); an empty
+        # series' start may be the end of the array, or a neighbour's
+        flat = np.minimum((np.cumsum(lens) - lens)[:, None] + idx,
+                          len(t_ms_all) - 1)
+        out = ((t_ms_all[flat] - self.base_ms) / 1000.0).astype(self.dtype)
+        out[lens == 0] = np.inf
+        return out
+
+    # -- built on first read ---------------------------------------------
+    #
+    # Two structures only some kernels read.  Which ones a query needs is
+    # known only once the planner has picked its route (rate() of a
+    # counter gathers tiles on the host and not where the device narrows),
+    # and that is after the prepare: so each is built by its first reader,
+    # once, under a span of its own.  _TiledShardView assigns the same
+    # names as plain attributes, which a cached_property lets it do.
+
+    @_functools.cached_property
+    def times(self) -> np.ndarray:
+        """The padded float64 (S, N) times matrix, base-relative seconds,
+        +inf padding (prepare_matrix_runs' contract): linear_regression
+        gathers it, ShardedTiled ships it."""
+        with tracing.span("prom_times_matrix"):
+            times = np.full((self.S, self.N), np.inf, dtype=np.float64)
+            if len(self._t_ms_all):
+                times.reshape(-1)[_scatter_index(self._lens, self.N)] = (
+                    (self._t_ms_all - self.base_ms) / 1000.0)
+            return times
+
+    @_functools.cached_property
+    def _gather_layout(self) -> tuple:
+        """(gidx, gmask, pairmask, ownmask): the compact covered-tile
+        gather layout, (S, C, pmax+1).  Slot 0 = the sample BEFORE the
+        tile's first (any tile — pair quantities need the previous sample
+        wherever it lives); slots 1..pmax = the tile's own samples."""
+        with tracing.span("prom_gather_layout"):
+            S, N, pmax = self.S, self.N, self.pmax
+            cnt_cov = self._cnt_cov
+            lim = np.maximum(self._lens, 1)[:, None] - 1
+            # (S, C) first sample ordinal in tile
+            tile_start = self._tile_cum[:, self.plan.cov]
+            gidx_local = (tile_start[:, :, None]
+                          + np.arange(-1, pmax)[None, None, :])
+            own_valid = (np.arange(pmax)[None, None, :] < cnt_cov[:, :, None])
+            prev_valid = tile_start > 0
+            gmask = np.concatenate([prev_valid[:, :, None], own_valid], axis=2)
+            gidx_local = np.clip(gidx_local, 0, lim[:, :, None])
+            gidx = np.arange(S, dtype=np.int64)[:, None, None] * N + gidx_local
+            if S * N <= np.iinfo(np.int32).max:
+                # what a device without x64 indexes in: narrowed here, once
+                # and checked, not wherever jax would wrap it silently
+                gidx = gidx.astype(np.int32)
+            return (gidx, gmask, gmask[:, :, 1:] & gmask[:, :, :-1],
+                    gmask[:, :, 1:])
+
+    gidx = _functools.cached_property(lambda self: self._gather_layout[0])
+    gmask = _functools.cached_property(lambda self: self._gather_layout[1])
+    pairmask = _functools.cached_property(lambda self: self._gather_layout[2])
+    ownmask = _functools.cached_property(lambda self: self._gather_layout[3])
+    # row-LOCAL gather columns (gidx minus its row offset): the mesh path
+    # gathers per series row so GSPMD can shard the series axis without
+    # collectives; ShardedTiled derives and ships it, the view reads it
+    gidx_col = None
+
+    def unbuilt(self) -> tuple:
+        """Which of ("layout", "times") no reader has asked for."""
+        return tuple(name for name, attr in (("layout", "_gather_layout"),
+                                             ("times", "times"))
+                     if attr not in self.__dict__)
 
     # -- kernel building blocks ------------------------------------------
 
@@ -719,15 +800,8 @@ class TiledPrepared:
         if self.values is None:
             from opengemini_tpu.ops import device_decode
 
-            v_all = device_decode.materialize_enc(self._enc)
-            values = np.zeros((self.S, self.N), dtype=self.dtype)
-            lens = np.asarray(self.counts, np.int64)
-            starts = np.cumsum(lens) - lens
-            rows = np.repeat(np.arange(self.S, dtype=np.int64), lens)
-            cols = np.arange(int(lens.sum()), dtype=np.int64) \
-                - np.repeat(starts, lens)
-            values.reshape(-1)[rows * self.N + cols] = v_all
-            self.values = values
+            self.values = self._pad_values(
+                device_decode.materialize_enc(self._enc))
         return self.values
 
     def _ftype(self, xp) -> np.dtype:
@@ -1127,9 +1201,6 @@ class _PlanView:
     def __init__(self, win_tiles: int, window_s: float):
         self.win_tiles = win_tiles
         self.window_s = window_s
-
-
-import functools as _functools  # noqa: E402  (kernel-cache only)
 
 
 @_functools.lru_cache(maxsize=128)

@@ -920,9 +920,12 @@ class PromEngine:
         cells = _tile_cells_mult()
         max_tiles = min(max(cells * n_max + 64, 1024),
                         max((1 << 28) // max(s_dim, 1), 64))
-        plan = promops.plan_tiles(
-            eval_times - w, eval_times, int(t_ms_all.min()),
-            int(t_ms_all.max()), max_tiles)
+        # the lattice of the window edges: no sample enters it but the
+        # earliest and the latest, which are a pass over the times each
+        with tracing.span("prom_tile_plan"):
+            plan = promops.plan_tiles(
+                eval_times - w, eval_times, int(t_ms_all.min()),
+                int(t_ms_all.max()), max_tiles)
         if plan is None:
             return None
         host = _host_kernels()
@@ -1056,6 +1059,9 @@ class PromEngine:
                     spec, kind, prep, host=(route == "host"))
             offload.GLOBAL.observe("prom_" + kind, geo, route,
                                    _time.perf_counter() - t_route)
+            # what the routed kernel never read, the prepare never built
+            for name in prep.unbuilt():
+                STATS.incr("prom", f"tiled_{name}_skipped")
             return out, valid
         # dense fallback (searchsorted window bounds)
         STATS.incr("prom", "dense_kernels")

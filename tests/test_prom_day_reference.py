@@ -14,7 +14,9 @@ values narrowed on the host after the float64 arithmetic float32 cannot do
 (`ops/prom.py` `TiledPrepared._narrowed`).  And what PR 42 added to the
 program: the spans under `prom_collect`, `prom_prepare` and `device_launch`
 and the counters of group `prom`, read as the benchmark's metric files read
-them."""
+them.  And what PR 45 changed: the covered-tile gather layout and the
+(S, N) times matrix are built by the first kernel that reads them, under
+spans of their own, and `rate()` of a counter on the device reads neither."""
 
 import contextlib
 import glob
@@ -53,9 +55,11 @@ NEW = ("prom_collect_ns_per_sample", "prom_prepare_ns_per_sample",
        "prom_match_ms_per_q", "prom_read_ms_per_q", "prom_assemble_ms_per_q",
        "prom_fill_ms_per_q", "prom_tile_index_ms_per_q",
        "prom_narrow_ms_per_q", "prom_values_h2d_enqueue_ms_per_q",
-       "prom_cells_per_sample", "prom_samples_per_q")
+       "prom_cells_per_sample", "prom_samples_per_q",
+       "prom_layout_skipped_share")
+LATE = ("prom_gather_layout", "prom_times_matrix")
 CHILDREN = {"prom_collect": ("prom_match", "prom_read", "prom_assemble"),
-            "prom_prepare": ("prom_fill", "prom_tile_index"),
+            "prom_prepare": ("prom_tile_plan", "prom_fill", "prom_tile_index"),
             "device_launch": ("prom_narrow", "prom_values_h2d")}
 
 
@@ -307,9 +311,11 @@ def test_raw_float32_counters_would_fail(day, monkeypatch):
 # -- spans and counters -------------------------------------------------------
 
 
-def _tree(port: int) -> dict:
+def _tree(port: int, without: str | None = None) -> dict:
     """The newest retained http_prom tree.  A root closes after its
-    response is sent: wait for it."""
+    response is sent: wait for it — and, since the root of the ask before
+    may close after the `clear_recent` that followed it, for one with no
+    span `without` where the ask differs from that one by it."""
     def get(**params):
         url = f"http://127.0.0.1:{port}/debug/trace?" \
             + urllib.parse.urlencode(params)
@@ -320,7 +326,9 @@ def _tree(port: int) -> dict:
     while time.monotonic() < deadline:
         hit = [d for d in get()["recent"] if d["name"] == "http_prom"]
         if hit:
-            return get(trace_id=hit[0]["trace_id"])["trace"]["root"]
+            root = get(trace_id=hit[0]["trace_id"])["trace"]["root"]
+            if without not in _spans(root):
+                return root
         time.sleep(0.01)
     raise AssertionError("no http_prom tree was retained")
 
@@ -350,10 +358,11 @@ def test_the_new_spans_account_for_their_parents(which, shards, traced,
     served.ask("device")                  # programs built, cache filled
     # spans sum by name, one a shard: a match of the range's shards and one
     # of each shard's sids, a read a shard, an assembly a shard and the
-    # merge by key; one fill, one tile index; the narrowing and the copy
-    # twice, of the value matrix and of the windows' first samples
+    # merge by key; one plan of the tiles, one fill, one tile index; the
+    # narrowing and the copy twice, of the value matrix and of the windows'
+    # first samples
     times = {"prom_match": 1 + shards, "prom_read": shards,
-             "prom_assemble": shards + 1, "prom_fill": 1,
+             "prom_assemble": shards + 1, "prom_tile_plan": 1, "prom_fill": 1,
              "prom_tile_index": 1, "prom_narrow": 2, "prom_values_h2d": 2}
     shares = []
     for _ in range(6):
@@ -371,13 +380,23 @@ def test_the_new_spans_account_for_their_parents(which, shards, traced,
             share[parent] = inside / node["elapsed_ns"]
         # a read that only hits opens none of the miss path's spans
         assert not {"decode", "scan_merge", "mem_read"} & spans.keys()
+        # rate() of a counter where the device narrows reads neither the
+        # gather layout nor the times matrix: nothing built them
+        assert not set(LATE) & spans.keys()
         shares.append(share)
-        # at 40 series a prepare is 12 ms and the plan of the tiles, which
-        # no sample enters, 0.5 ms of it: the least disturbed of six asks
+        # at 40 series a prepare is 7 ms: the least disturbed of six asks
         if min(share["prom_collect"], share["prom_prepare"]) >= 0.95:
             break
     else:
         raise AssertionError(f"under 95 % of a parent in six asks: {shares}")
+    # the host route corrects the resets from the gathered tiles: its
+    # kernel builds the layout, once, and is where that time shows
+    tracing.clear_recent()
+    served.ask("host")
+    spans = _spans(_tree(served.svc.port, without="device_launch"))
+    assert [p["name"] for _, p in spans["prom_gather_layout"]] \
+        == ["prom_kernel"]
+    assert "prom_times_matrix" not in spans
     # ISSUE 42 asked the same of `device_launch`, and it does NOT hold
     # (PERF.md section 3): the launch does work of its own beside these two,
     # the dispatch of the eager chain with the implicit copies of the (S, K)
@@ -402,6 +421,8 @@ def test_the_counters_are_the_numbers_of_the_query(day, on):
     assert got["prom_samples_per_q"] == samples
     assert abs(samples - day.req.units) == SERIES       # within one scrape
     assert got["prom_cells_per_sample"] == 1.0
+    assert got["prom_layout_skipped_share"] == (100.0 if on == "device"
+                                                else 0.0)
 
     def stage(name):
         return (vars1["query_stages"][name + "_ns"]
@@ -422,6 +443,9 @@ def test_the_counters_are_the_numbers_of_the_query(day, on):
     assert moved["prepare_cells"] == SERIES * TICKS
     assert moved["prepare_windows"] == SERIES * STEPS
     assert moved["tiled_kernels"] == 1 and not moved.get("dense_kernels")
+    # rate() never reads the times matrix; the layout only off the device
+    assert moved["tiled_times_skipped"] == 1
+    assert moved.get("tiled_layout_skipped", 0) == (on == "device")
     # a program without the spans and counters (the parent): a number or
     # nothing, never an exception
     for vars1 in ({}, {"client": {"completed": 1}}):

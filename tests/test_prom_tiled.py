@@ -359,6 +359,143 @@ class TestDeviceWithoutX64:
         self._assert_close(got, want, prep, resets)
 
 
+# -- what only some kernels read is built by its first reader ----------------
+
+_COUNTER = {"kind": "rate", "is_counter": True}
+LAZY_KINDS = {
+    "rate": dict(_COUNTER, is_rate=True),
+    "increase": dict(_COUNTER, is_rate=False),
+    "rate_gauge": {"kind": "rate", "is_counter": False, "is_rate": True},
+    "delta": {"kind": "rate", "is_counter": False, "is_rate": False},
+    "irate": {"kind": "instant_rate", "per_second": True},
+    "idelta": {"kind": "instant_rate", "per_second": False},
+    "changes": {"kind": "changes_resets", "which": "changes"},
+    "resets": {"kind": "changes_resets", "which": "resets"},
+    "deriv": {"kind": "deriv"},
+    "predict_linear": {"kind": "predict", "dur": 600.0},
+    **{f + "_over_time": {"kind": "over_time", "func": f}
+       for f in sorted(PromEngine._TILED_OVER_TIME)},
+}
+# the kernels that gather covered tiles, and the one that gathers times
+_READS_LAYOUT = {"changes", "resets", "deriv", "predict_linear",
+                 *(f + "_over_time" for f in
+                   ("sum", "avg", "stddev", "stdvar", "min", "max"))}
+_READS_TIMES = {"deriv", "predict_linear"}
+
+
+def lazy_case():
+    """Ragged series on an irregular grid, resets inside: an empty series
+    in the middle and one at the end, a series of a single sample."""
+    rng = np.random.default_rng(45)
+    t_parts, v_parts, lens = [], [], [37, 1, 90, 0, 64, 12, 0]
+    for n in lens:
+        t = np.sort(rng.choice(np.arange(0, 3_600_000, 500), size=n,
+                               replace=False))
+        v = np.cumsum(rng.random(n) * 50)
+        if n > 1:
+            v[n // 2:] -= v[n // 2] * 0.75      # a counter reset
+        t_parts.append(BASE_MS + t.astype(np.int64))
+        v_parts.append(v)
+    ends = BASE + 120.0 + np.arange(40) * 60.0
+    return (np.concatenate(t_parts), np.concatenate(v_parts),
+            np.asarray(lens, np.int64), ends - 300.0, ends)
+
+
+class TestBuiltOnFirstRead:
+    """The covered-tile gather layout and the (S, N) times matrix are not
+    the prepare's to build: a kernel that reads one gets it, bit for bit
+    what a prepare that built both at construction answers from, and one
+    that reads neither never pays for it (ISSUE 45)."""
+
+    @staticmethod
+    def _answer(spec, prep, on):
+        import jax
+        import jax.numpy as jnp
+
+        if on == "host":
+            out, valid = PromEngine._tiled_dispatch(spec, spec["kind"],
+                                                    prep, np)
+        else:
+            with jax.enable_x64(False):   # a server: the device narrows
+                assert prep._narrows(jnp, None)
+                out, valid = PromEngine._tiled_dispatch(
+                    spec, spec["kind"], prep, jnp)
+        return np.asarray(out), np.asarray(valid)
+
+    @pytest.mark.parametrize("on", ["host", "device"])
+    @pytest.mark.parametrize("name", sorted(LAZY_KINDS))
+    def test_a_kernel_builds_what_it_reads_and_answers_the_same(self, name,
+                                                                on):
+        spec = LAZY_KINDS[name]
+        forced = make_prep(*lazy_case(), lane_quantum=8)
+        assert forced.unbuilt() == ("layout", "times")
+        assert forced.times.shape == (forced.S, forced.N)
+        assert forced.gidx.shape == forced.gmask.shape
+        assert forced.unbuilt() == ()
+        lazy = make_prep(*lazy_case(), lane_quantum=8)
+        with np.errstate(all="ignore"):
+            want = self._answer(spec, forced, on)
+            got = self._answer(spec, lazy, on)
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            assert g.tobytes() == w.tobytes()
+        # rate() of a counter corrects its resets from the gathered tiles
+        # only where the device has not had them folded in on the host
+        reads_layout = name in _READS_LAYOUT or (
+            name in ("rate", "increase") and on == "host")
+        left = tuple(n for n, read in (("layout", reads_layout),
+                                       ("times", name in _READS_TIMES))
+                     if not read)
+        assert lazy.unbuilt() == left
+
+    def test_the_edge_times_are_the_matrix_s(self):
+        """t_first, t_last and t_lm1 are gathered from the run-encoded
+        times; the matrix, once built, holds the same bits there — and
+        +inf in an empty series' row."""
+        prep = make_prep(*lazy_case())
+        for got, idx in ((prep.t_first, prep.safe_f),
+                         (prep.t_last, prep.safe_l),
+                         (prep.t_lm1, prep.safe_lm1)):
+            want = np.take_along_axis(prep.times, idx, axis=1)
+            assert got.tobytes() == want.astype(prep.dtype).tobytes()
+        empty = np.flatnonzero(np.asarray(prep.counts) == 0)
+        assert list(empty) == [3, 6]
+        assert np.isinf(prep.t_first[empty]).all()
+        assert not prep.has1[empty].any()
+        # what the dense path prepares from the same samples
+        times, values, counts, base_ms = promops.prepare_matrix_runs(
+            *lazy_case()[:3], dtype=np.float64)
+        assert base_ms == prep.base_ms
+        assert times.tobytes() == prep.times.tobytes()
+        assert values.tobytes() == prep.values.tobytes()
+        assert counts.tobytes() == prep.counts.tobytes()
+
+    def test_the_budget_is_decided_by_the_constructor_with_no_layout(
+            self, monkeypatch):
+        """Tiled against dense is decided before the route is: the
+        constructor raises from the layout's size alone."""
+        def built(self):
+            raise AssertionError("the gather layout was built")
+
+        monkeypatch.setattr(promops.TiledPrepared, "_gather_layout",
+                            property(built))
+        t_all = BASE_MS + np.arange(200, dtype=np.int64)
+        v_all = np.arange(200, dtype=np.float64)
+        lens = np.asarray([200], np.int64)
+        plan = promops.plan_tiles(np.asarray([BASE - 60.0]),
+                                  np.asarray([BASE + 60.0]),
+                                  int(t_all.min()), int(t_all.max()), 10_000)
+        with pytest.raises(promops.TileBudgetExceeded):
+            promops.TiledPrepared(plan, t_all, v_all, lens,
+                                  max_gather_cols=8)
+        assert promops.prepare_tiled(plan, t_all, v_all, lens,
+                                     max_gather_cols=8) is None
+        # inside the budget the same constructor builds no layout either
+        prep = promops.TiledPrepared(plan, t_all, v_all, lens)
+        assert (prep.C, prep.pmax) == (1, 200)
+        assert prep.unbuilt() == ("layout", "times")
+
+
 class TestBoundaries:
     """Left-open/right-closed edges, empty and 1-sample windows."""
 

@@ -37,7 +37,8 @@ QUERY_SPANS = {"sql_parse", "select: cpu", "map_shards", "scan", *MISS_SPANS,
                "render", "format", "serialize", "send"}
 PROM_SPANS = {"prom_parse", "prom_collect", "prom_match", "prom_read",
               "prom_assemble", *MISS_SPANS, "mem_read",
-              "prom_prepare", "prom_fill", "prom_tile_index",
+              "prom_prepare", "prom_tile_plan", "prom_fill", "prom_tile_index",
+              "prom_gather_layout", "prom_times_matrix",
               "prom_kernel", "device_launch", "prom_narrow",
               "prom_values_h2d", "device_fetch", "device_wait",
               "device_copy", "prom_render", "serialize", "send"}
@@ -345,8 +346,13 @@ def test_a_promql_query_leaves_a_tree(server):
         assert {p["name"] for _, p in spans[name]} == {"prom_collect"}, name
     assert [len(spans[name]) for name in
             ("prom_match", "prom_read", "prom_assemble")] == [2, 1, 2]
-    for name in ("prom_fill", "prom_tile_index"):
+    for name in ("prom_tile_plan", "prom_fill", "prom_tile_index"):
         assert [p["name"] for _, p in spans[name]] == ["prom_prepare"], name
+    # what only some kernels read is built by its first reader (PR 45): the
+    # gather layout under the kernel that gathers tiles, never the prepare
+    for name in ("prom_gather_layout", "prom_times_matrix"):
+        assert {p["name"] for _, p in spans.get(name, [])} \
+            <= {"prom_kernel", "device_launch"}, name
     # nothing was flushed: the read took the memtable's rows, under one span
     assert [p["name"] for _, p in spans["mem_read"]] == ["prom_read"]
     # where the device computed in a narrower float, the narrowing and the
