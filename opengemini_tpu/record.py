@@ -429,21 +429,24 @@ class FieldTypeConflict(Exception):
         self.got = got
 
 
-def _join_plain(ftype: FieldType, cols: list, lens: list[int]) -> Column:
+def _join_plain(ftype: FieldType, cols: list, lens: list[int],
+                dest: np.ndarray | None = None) -> Column:
     """The parts' columns (None where a part lacks the column) end to
-    end as decoded values: the output allocated once, each part copied
-    into its place once.  Zero-init, not np.empty: a slot no part fills
-    stays invalid, but its value bytes still flow into flushed chunks
-    and content_digest — heap garbage there breaks the
-    replica-identical digest guarantee."""
+    end as decoded values — or, given `dest`, every row of that
+    concatenation where `dest` puts it (`_interleave`): the output
+    allocated once, each part copied into its place once.  Zero-init,
+    not np.empty: a slot no part fills stays invalid, but its value
+    bytes still flow into flushed chunks and content_digest — heap
+    garbage there breaks the replica-identical digest guarantee."""
     total = sum(lens)
     values = _zeroed(ftype, total)
     valid = np.zeros(total, dtype=np.bool_)
     at = 0
     for col, m in zip(cols, lens):
         if col is not None:
-            values[at:at + m] = col.values
-            valid[at:at + m] = col.valid
+            to = slice(at, at + m) if dest is None else dest[at:at + m]
+            values[to] = col.values
+            valid[to] = col.valid
         at += m
     return Column(ftype, values, valid)
 
@@ -471,11 +474,16 @@ def _join_column(ftype: FieldType, cols: list, lens: list[int]) -> Column:
 def _trim_part(s: np.ndarray, r: Record, lo_t: int, hi_t: int):
     """The rows of one part inside [lo_t, hi_t).  A part wholly inside
     is handed back as it is — two reductions, no copy: what a hot read,
-    a whole-range read and the memtable's unbounded calls pay.  A
-    straddling part is masked (packed parts are (sid, time)-sorted, not
+    a whole-range read, the memtable's unbounded calls and, of a file
+    cut into time segments, every segment the range covers pay.  A
+    straddling part (a whole-range chunk of short series or of a file
+    written before the cut; the one or two segments a range's ends fall
+    in) is masked (packed parts are (sid, time)-sorted, not
     time-sorted: a mask, not two searchsorted; a mask over a sorted part
     leaves it sorted) and copied once, as views where the rows kept are
-    one run (a single-series chunk).  EncodedColumns trim through their
+    one run (a single-series chunk).  Parts wholly outside never come
+    this far where the reader's time pruning could tell: a chunk is
+    skipped by its own tmin/tmax.  EncodedColumns trim through their
     own take(), so they stay encoded up to `_SEG_CAP` runs."""
     t = r.times
     if t.min() >= lo_t and t.max() < hi_t:
@@ -537,6 +545,46 @@ def _by_single_sid(live) -> list | None:
     return [live[i] for i in at]
 
 
+def _interleave(lens, sid_all, t_all):
+    """Where every part lies sid-ascending: (dest, sids, times), the
+    rows laid sid after sid with the parts' runs of one sid one after
+    the other in part order — `dest[i]` is where row i of the parts'
+    concatenation goes.  That is the (sid, time) order whenever a sid's
+    rows lie in part order in ascending, disjoint times: the segments a
+    file's long series are cut into (`[span A, seg 0][span A, seg 1]
+    [span B, seg 0]...`), and files that meet at a seam in sids.  It is
+    checked in one pass over the rows laid out; None where it does not
+    hold (overlap, duplicates) or a part is not sid-ascending (a
+    memtable slab in arrival order): the general merge's cases.  The
+    work is on the runs (a part's rows of one sid), a few thousand, and
+    three passes over the rows; nothing is sorted but the runs' sids."""
+    n = len(sid_all)
+    first = np.empty(n, np.bool_)       # the first row of each run
+    first[0] = True
+    np.not_equal(sid_all[1:], sid_all[:-1], out=first[1:])
+    part_at = np.cumsum(lens[:-1], dtype=np.int64)
+    first[part_at] = True
+    starts = np.flatnonzero(first)
+    run_sid = sid_all[starts]
+    falls = run_sid[1:] < run_sid[:-1]  # allowed where a part begins
+    falls[np.searchsorted(starts, part_at) - 1] = False
+    if falls.any():
+        return None
+    run_len = np.diff(starts, append=n)
+    order = np.argsort(run_sid, kind="stable")  # equal sids: part order
+    off = np.empty(len(starts), np.int64)       # where each run goes
+    len_o = run_len[order]
+    off[order] = np.cumsum(len_o) - len_o
+    dest = np.repeat(off - starts, run_len)
+    dest += np.arange(n)
+    sids = np.repeat(run_sid[order], len_o)
+    times = np.empty(n, np.int64)
+    times[dest] = t_all
+    ok = times[1:] > times[:-1]
+    ok |= sids[1:] != sids[:-1]
+    return (dest, sids, times) if ok.all() else None
+
+
 def merge_bulk_parts(
     parts: list[tuple[np.ndarray, Record]], lo_t: int, hi_t: int,
     told: dict | None = None,
@@ -555,21 +603,30 @@ def merge_bulk_parts(
 
     - `inorder`: their concatenation is already strictly
       (sid, time)-sorted — one part (the memtable consolidation, one
-      packed chunk), packed chunks written series-ascending (a flush
-      streams a chunk every PACK_ROWS rows, never splitting a series),
-      or files that overlap in sids only outside the range asked.
-      Nothing is sorted;
+      packed chunk), packed chunks written series-ascending (short
+      series: a flush streams a chunk every PACK_ROWS rows, a whole
+      series at a time; long ones cut along time, of which the range
+      meets one segment a sid span), or files that overlap in sids only
+      outside the range asked.  Nothing is sorted;
     - `single_sid`: every part is one series' rows and, grouped by sid,
       they are strictly sorted: one monotonicity pass instead of a sort;
+    - `interleaved`: every part is sid-ascending and a sid's rows lie in
+      part order in ascending, disjoint times — the time segments of one
+      sid span (a range that crosses a segment boundary, every
+      whole-range read of long series) and files that meet at a seam.
+      The rows need interleaving, not sorting: their places are computed
+      from the parts' runs (`_interleave`), checked in one pass;
     - `sorted`: the general merge, one stable sort of the rows kept
       (`_stable_order`; equal (sid, time) keep part order, so the last
       of a group is its newest row, and no rank array is needed).
 
-    The first two join the parts with every output column built once
-    (`_join_column`), keeping still-encoded columns ENCODED; the general
-    merge materializes them on the host.  `told`, where given, is filled
-    with `branch` (one of the three names) and `rows` (rows that entered
-    the concatenation or sort, after the trim)."""
+    All but the last build every output column once: the first two join
+    the parts (`_join_column`), keeping still-encoded columns ENCODED;
+    `interleaved` writes each part to its places (`_join_plain` with
+    `dest`) and, like the general merge, materializes encoded columns on
+    the host.  `told`, where given, is filled with `branch` (one of the four
+    names) and `rows` (rows that entered the concatenation or sort,
+    after the trim)."""
     ftypes: dict[str, FieldType] = {}
     live = []
     for s, r in parts:
@@ -594,14 +651,24 @@ def merge_bulk_parts(
             live = by_sid
             sid_all = np.concatenate([s for s, _r in live])
             t_all = np.concatenate([r.times for _s, r in live])
-        # still out of order: overlap or duplicates, the general merge
         branch = ("single_sid" if by_sid is not None
                   and _strictly_increasing(sid_all, t_all) else "sorted")
+    lens = [len(r) for _s, r in live]
+    if branch == "sorted":
+        laid = _interleave(lens, sid_all, t_all)
+        if laid is not None:
+            branch = "interleaved"
     if told is not None:
         told["branch"], told["rows"] = branch, len(t_all)
+    if branch == "interleaved":
+        dest, sid_out, t_out = laid
+        return sid_out, Record(t_out, {
+            name: _join_plain(
+                ftype, [r.columns.get(name) for _s, r in live], lens, dest)
+            for name, ftype in ftypes.items()})
     if branch == "sorted":
+        # overlap, duplicates, a part in arrival order: the general merge
         return _merge_sorted(live, ftypes, sid_all, t_all)
-    lens = [len(r) for _s, r in live]
     return sid_all, Record(t_all, {
         name: _join_column(
             ftype, [r.columns.get(name) for _s, r in live], lens)

@@ -22,11 +22,9 @@ from opengemini_tpu.record import (
     Column, FieldTypeConflict, Record, merge_bulk_parts,
     merge_sorted_records, _zeroed as _rec_zeroed,
 )
-from opengemini_tpu.storage import colcache, scanpool
+from opengemini_tpu.storage import colcache, scanpool, tsf
 from opengemini_tpu.storage.memtable import MemTable, _series_slice
-from opengemini_tpu.storage.tsf import (
-    PACK_MIN_SERIES, PACK_ROWS, CorruptFile, TSFReader, TSFWriter,
-)
+from opengemini_tpu.storage.tsf import CorruptFile, TSFReader, TSFWriter
 from opengemini_tpu.storage.wal import WAL, WALCorruption
 from opengemini_tpu.utils.failpoint import inject as _fp
 from opengemini_tpu.utils.querytracker import GLOBAL as _TRACKER
@@ -77,8 +75,9 @@ _merge_bulk_parts = merge_bulk_parts
 def _merge_counted(parts, lo_t: int, hi_t: int):
     """A bulk read's merge, saying what it did: `scan/merges`, the
     branch it took (`merges_inorder`, `merges_single_sid`,
-    `merges_sorted`) and `rows_merged`, the rows that entered a
-    concatenation or sort once every part was trimmed to the range."""
+    `merges_interleaved`, `merges_sorted`) and `rows_merged`, the rows
+    that entered a concatenation or sort once every part was trimmed to
+    the range."""
     told: dict = {}
     out = merge_bulk_parts(parts, lo_t, hi_t, told)
     _STATS.add("scan", (("merges", 1), ("merges_" + told["branch"], 1),
@@ -95,20 +94,56 @@ def _sid_entries(rec: Record, uniq, starts, ends):
         yield int(sid), _series_slice(rec, lo, hi)
 
 
+def _write_packed(w: TSFWriter, mst: str, buffer: list) -> None:
+    """One buffer of (sid, rec) entries as packed chunks.  Short series
+    (`packed_segments` 1): one chunk, as ever.  Long ones: the packed
+    block is cut along time, at the times that split its rows into equal
+    shares, and each share is written as a packed chunk of its own —
+    (sid, time)-sorted as the block is, with its own narrow tmin/tmax,
+    smin/smax, sparse index and pre-aggregates — so that the reader's
+    time pruning reaches the stretch a statement asks for.  A buffer too
+    small for its segments (the tail of a measurement) is cut into fewer,
+    none under a quarter of a PACK_ROWS."""
+    sids, packed = _pack_entries(buffer)
+    rows = len(sids)
+    n_seg = min(tsf.packed_segments(rows, len(buffer)),
+                rows // (tsf.PACK_ROWS // 4))
+    if n_seg <= 1:
+        w.add_packed_chunk(mst, sids, packed)
+        return
+    at = [rows * j // n_seg for j in range(1, n_seg)]
+    bounds = np.unique(np.partition(packed.times, at)[at])
+    seg = np.searchsorted(bounds, packed.times, "right")
+    written = 0
+    for j in range(len(bounds) + 1):
+        idx = np.flatnonzero(seg == j)  # ascending: stays (sid, time)-sorted
+        if len(idx):
+            w.add_packed_chunk(mst, sids[idx], packed.take(idx))
+            written += 1
+    _STATS.add("tsf", (("packed_buffers_cut", 1),
+                       ("packed_segments_written", written)))
+
+
 def _write_measurement_chunks(w: TSFWriter, tidx, mst: str, entries,
                               n_series: int | None = None) -> int:
     """Write one measurement's series records: per-sid chunks at low
     cardinality, PK-sorted packed chunks (reference: colstore) once a
     flush carries >= PACK_MIN_SERIES series.  `entries` iterates
     (sid, rec) in ascending sid order; records stream out every
-    PACK_ROWS rows so compaction never holds a whole measurement.
-    Returns rows submitted to the writer — the flush path feeds this
-    into the durability ledger's tsf_rows counter."""
+    PACK_ROWS rows so compaction never holds a whole measurement.  Where
+    the series runs buffered are long (`tsf.packed_segments` > 1) the
+    buffer grows to a PACK_ROWS a segment (SEGMENT_BUFFER at most) and
+    is then cut along time (`_write_packed`): a series' rows then lie in
+    several packed chunks of the file, ascending and disjoint in time.
+    Flush, compact(), compact_level() and compact_out_of_order() all
+    write through here.  Returns rows submitted to the writer — the
+    flush path feeds this into the durability ledger's tsf_rows
+    counter."""
     rows = 0
     if n_series is None:
         entries = list(entries)
         n_series = len(entries)
-    if n_series < PACK_MIN_SERIES:
+    if n_series < tsf.PACK_MIN_SERIES:
         for sid, rec in entries:
             w.add_chunk(mst, sid, rec)
             tidx.add(mst, sid, rec)
@@ -119,17 +154,17 @@ def _write_measurement_chunks(w: TSFWriter, tidx, mst: str, entries,
     for sid, rec in entries:
         if len(rec) == 0:
             continue
-        tidx.add(mst, sid, rec)
+        tidx.add(mst, sid, rec)     # once a series a file, however cut
         buffer.append((sid, rec))
         buffered += len(rec)
         rows += len(rec)
-        if buffered >= PACK_ROWS:
-            sids, packed = _pack_entries(buffer)
-            w.add_packed_chunk(mst, sids, packed)
+        if buffered >= tsf.PACK_ROWS * min(
+                tsf.packed_segments(buffered, len(buffer)),
+                tsf.SEGMENT_BUFFER):
+            _write_packed(w, mst, buffer)
             buffer, buffered = [], 0
     if buffer:
-        sids, packed = _pack_entries(buffer)
-        w.add_packed_chunk(mst, sids, packed)
+        _write_packed(w, mst, buffer)
     return rows
 
 
@@ -1692,9 +1727,11 @@ class Shard:
         slots: list = []
         miss_at = []
         rows_decoded = 0
+        packed_met = 0
         for r in files:
             for c in r.chunks(measurement, None, tmin, tmax):
                 if c.packed:
+                    packed_met += 1
                     if c.smax < sids[0] or c.smin > sids[-1]:
                         continue
                     _TRACKER.check()  # warm-path kill point (see read_series)
@@ -1728,6 +1765,10 @@ class Shard:
                 except CorruptFile as e:
                     self.note_corrupt(e)  # see read_series
         parts.extend(p for p in slots if p is not None)
+        # what the time pruning of `chunks` spared this read: a long
+        # series' file is cut into time segments, each a packed chunk
+        _STATS.incr("scan", "packed_skipped_by_time", sum(
+            r.packed_count(measurement) for r in files) - packed_met)
         mems = [m for m in mems if m.holds(measurement)]
         with contextlib.ExitStack() as stack:
             if mems:

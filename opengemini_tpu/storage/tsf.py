@@ -21,7 +21,13 @@ codec).  Block locs cover the sealed length; `_read` strips the seal.
 
 Chunks are either one series' rows for one flush (time + field columns,
 validity masks, numeric pre-aggregation) or PK-sorted packed
-multi-series blocks (colstore layout, see add_packed_chunk).
+multi-series blocks (colstore layout, see add_packed_chunk).  A packed
+chunk covers a sid span and a stretch of time: where the series of a
+file are long, the writer cuts them along time (`packed_segments`), so
+a series' rows may lie in several packed chunks of one file, in
+ascending, disjoint time ranges — each an ordinary packed chunk with its
+own tmin/tmax, sparse index and pre-aggregates, which the time pruning
+of `TSFReader.chunks` skips like any other.
 """
 
 from __future__ import annotations
@@ -131,6 +137,26 @@ class ChunkMeta:
 PACK_MIN_SERIES = 64
 PACK_ROWS = 131072
 SPARSE_K = 1024
+# about how many rows of ONE series a packed chunk holds at most: longer
+# series runs are cut along time into segments of about this length
+# (reference: a TSSP chunk's row segments, each with its own time range
+# and pre-aggregates, on the order of 1,000 rows; here a quarter of that,
+# so that an hour of 10-15 s samples, 240-360 rows, meets two segments
+# and a read of it decodes under twice what it keeps)
+SEGMENT_ROWS = 256
+# a buffer that will be cut grows to a PACK_ROWS a segment first, so the
+# segments come out chunk-sized, not slivers — up to this many PACK_ROWS
+# (the buffer is copied once when it is packed: 2 M rows bound that)
+SEGMENT_BUFFER = 16
+
+
+def packed_segments(rows: int, series: int) -> int:
+    """Into how many time segments the chunk writer wants a buffer of
+    `rows` rows of `series` series cut: rows a series over SEGMENT_ROWS,
+    rounded to the nearest, so a run under one and a half segments (an
+    hour at a 10 s step) stays whole.  Reads only what the writer sees."""
+    return max(1, (rows // max(series, 1) + SEGMENT_ROWS // 2)
+               // SEGMENT_ROWS)
 
 
 def _col_nbytes(col: Column) -> int:
@@ -434,6 +460,10 @@ class TSFReader:
     def schema(self, measurement: str) -> dict[str, FieldType]:
         entry = self.meta.get(measurement)
         return entry[0] if entry else {}
+
+    def packed_count(self, measurement: str) -> int:
+        """How many packed chunks the file holds of the measurement."""
+        return len(self._packed_chunks.get(measurement, ()))
 
     def chunks(
         self,

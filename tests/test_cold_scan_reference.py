@@ -5,13 +5,19 @@ path: 512 hosts, six "hours" of 600 s, the cell's own statements from the
 benchmark's generator, data and expected answers from the plain reference
 `benchmark/configs/tsbs_cpu_only.py` on a seed.
 
-What the cell is defined around is held here: in the series-major layout
-(what compaction leaves) every chunk spans the whole range, so a statement
-over one hour decodes all six and a cache smaller than one statement's
-decode never hits; the same rows loaded in time order decode under twice
-what they keep; and the answer is the reference's, bit for bit the same,
-whatever the cache does.  The miss path's spans and counters (PR 27) are
-read as the benchmark's metric files read them."""
+What the cell is defined around is held here.  The store is loaded series
+after series (what compaction leaves), and its series are long: the chunk
+writer cuts them along time (PR 46), so a statement over one hour decodes
+the two or three segments the hour meets, under twice what it keeps, and a
+cache smaller than one statement's decode hits only in the segment two
+neighbouring hours share.  The writer's constants are scaled down with the
+data (360 rows a series here, 2,160 in the cell), so the rule engages as it
+does there.  The same files written before PR 46 (`uncut`: every chunk all
+six hours long) decode six hours to keep one; the same rows loaded in time
+order decode under twice what they keep; a compacted shard keeps the
+segments; and the answer is the reference's, bit for bit the same,
+whatever the layout and whatever the cache does.  The miss path's spans and
+counters (PR 27) are read as the benchmark's metric files read them."""
 
 import json
 import os
@@ -32,19 +38,26 @@ from harness.metrics import counter  # noqa: E402
 from harness.oracle import TOL  # noqa: E402
 
 from opengemini_tpu.server.http import HttpService  # noqa: E402
-from opengemini_tpu.storage import colcache, scanpool  # noqa: E402
+from opengemini_tpu.storage import colcache, scanpool, tsf  # noqa: E402
 from opengemini_tpu.storage.engine import Engine  # noqa: E402
 from opengemini_tpu.utils.stats import GLOBAL as STATS  # noqa: E402
 
 HOSTS, HOUR, HOURS, TICKS_AN_HOUR = 512, 600, 6, 60
-FILES = HOSTS // 64             # of the series-major load, a chunk each
+FILES = HOSTS // 64             # of the series-major load
 SEED = 27
-# One statement decodes 512 hosts x 360 rows x (5 fields x 9 B + times +
-# sids) = 11 MB in 8 chunks of 7 columns.  A 1 MB cache holds five such
-# columns, less than one chunk: with two workers going through the chunks
-# in order, what a statement leaves behind (of its last chunks) is long
-# evicted when the next one comes to look for it, as in the cell, where
-# 256 MB hold the last 35 of 70 chunks.  64 MB hold everything.
+# The writer's constants as the series-major store is written, in the
+# proportion of the data to the cell's (360 rows a series for 2,160): a
+# file of 64 hosts x 360 rows is one buffer, cut into round(360 / 32) = 11
+# segments of 32 or 33 rows a series, and an hour's 60 rows meet 2 or 3.
+CUT = {"SEGMENT_ROWS": 32, "PACK_ROWS": 8192}
+SEGMENTS = 11
+LAYOUTS = ("series_major", "uncut", "time_ordered", "compacted")
+# One statement decodes 512 hosts x 65-98 rows x (5 fields x 9 B + times +
+# sids) = 2-3 MB in 16-24 chunks of 7 columns (11 MB in 8 chunks where the
+# files are uncut).  A 1 MB cache holds a third of that: with two workers
+# going through the chunks in order, what a statement leaves behind (of its
+# last chunks) is mostly evicted when the next one comes to look for it, as
+# in the cell.  64 MB hold everything.
 REGIMES = {"off": 0, "evicting": 1, "roomy": 64}
 MISS_SPANS = ("decode", "pool_wait", "block_read", "codec", "colcache_fill",
               "scan_merge")
@@ -80,18 +93,28 @@ def statements(ref):
 
 
 class Served:
-    """One server over one store; `layout` is the order of the load."""
+    """One server over one store; `layout` is the order of the load and
+    what wrote the files."""
 
     def __init__(self, path, ref, layout: str):
         self.engine = Engine(str(path))
         self.engine.create_database(ref.db)
         self.svc = HttpService(self.engine, "127.0.0.1", 0)
         self.svc.start()
-        bodies = (ref.load_requests() if layout == "series_major" else
-                  ref.stream_requests(HOSTS * TICKS_AN_HOUR))
-        for body, _rows in bodies:     # a file a request, as a flush leaves
-            assert self.http("POST", "/write", body, db=ref.db)[0] == 204
-            self.http("POST", "/debug/ctrl", mod="flush")
+        bodies = (ref.stream_requests(HOSTS * TICKS_AN_HOUR)
+                  if layout == "time_ordered" else ref.load_requests())
+        with pytest.MonkeyPatch.context() as mp:
+            if layout == "uncut":       # a writer that never cuts: PR 45's
+                mp.setattr(tsf, "SEGMENT_ROWS", 10 ** 9)
+            elif layout != "time_ordered":
+                for name, value in CUT.items():
+                    mp.setattr(tsf, name, value)
+            for body, _rows in bodies:  # a file a request, as a flush leaves
+                assert self.http("POST", "/write", body, db=ref.db)[0] == 204
+                self.http("POST", "/debug/ctrl", mod="flush")
+            if layout == "compacted":
+                for sh in self.engine.all_shards():
+                    assert sh.compact() and sh.file_count() == 1
 
     def http(self, method, path, body=None, **params):
         url = f"http://127.0.0.1:{self.svc.port}{path}"
@@ -109,6 +132,10 @@ class Served:
     def vars(self) -> dict:
         return json.loads(self.http("GET", "/debug/vars")[1])
 
+    def chunks(self) -> list:
+        return [(r, c) for sh in self.engine.all_shards()
+                for r in sh._files for c in r.chunks("cpu")]
+
     def close(self):
         self.svc.stop()
         self.engine.close()
@@ -116,8 +143,10 @@ class Served:
 
 @pytest.fixture(scope="module")
 def stores(tmp_path_factory, ref):
-    """The same rows twice: series-major (8 files of 64 hosts, every packed
-    chunk all six hours long) and in time order (6 files, an hour each)."""
+    """The same rows four times: series-major (8 files of 64 hosts, each
+    cut into 11 time segments), the same load as PR 45 wrote it (`uncut`:
+    every packed chunk all six hours long), in time order (6 files, an
+    hour each) and series-major, then compacted into one file."""
     with pytest.MonkeyPatch.context() as mp:
         # a scan pool of two workers, whatever the machine's cores and
         # whatever pool an earlier test of this process left behind
@@ -125,7 +154,7 @@ def stores(tmp_path_factory, ref):
         mp.setattr(scanpool, "_pool", None)
         before = colcache.GLOBAL.config()
         made = {layout: Served(tmp_path_factory.mktemp(layout), ref, layout)
-                for layout in ("series_major", "time_ordered")}
+                for layout in LAYOUTS}
         yield made
         for s in made.values():
             s.close()
@@ -154,8 +183,8 @@ def run(srv: Served, statements, budget_mb: int):
 def runs(stores, statements):
     out = {name: run(stores["series_major"], statements, mb)
            for name, mb in REGIMES.items()}
-    out["time_ordered"] = run(stores["time_ordered"], statements,
-                              REGIMES["evicting"])
+    for layout in LAYOUTS[1:]:
+        out[layout] = run(stores[layout], statements, REGIMES["evicting"])
     return out
 
 
@@ -166,7 +195,7 @@ def delta(seen, path: str, lo: int = 0, hi: int = -1) -> float:
 # -- the answers --------------------------------------------------------------
 
 
-@pytest.mark.parametrize("which", [*REGIMES, "time_ordered"])
+@pytest.mark.parametrize("which", [*REGIMES, *LAYOUTS[1:]])
 def test_every_answer_is_the_references(ref, statements, runs, which):
     """Window times and group sets exact (`parse` raises otherwise), means
     within the configuration's limit, over both rounds of the hours."""
@@ -180,21 +209,117 @@ def test_every_answer_is_the_references(ref, statements, runs, which):
 
 
 def test_answers_do_not_depend_on_the_cache_or_the_layout(runs):
+    """Files cut into time segments, files written before the cut, the
+    rows in time order, a compacted shard: the bytes of the response."""
     want = runs["off"][0]
-    for which in ("evicting", "roomy", "time_ordered"):
-        assert runs[which][0] == want, which        # bytes of the response
+    for which in ("evicting", "roomy", *LAYOUTS[1:]):
+        assert runs[which][0] == want, which
+
+
+# -- the layout the writer leaves ---------------------------------------------
+
+
+def met(srv: Served, req) -> list:
+    """The chunks whose time range the statement's hour meets: what the
+    reader's pruning leaves of the store's."""
+    lo, hi = req.stmt["t0"] * 10**9, req.stmt["t1"] * 10**9
+    return [(r, c) for r, c in srv.chunks() if c.tmax >= lo and c.tmin < hi]
+
+
+def test_long_series_are_cut_along_time_into_ordinary_packed_chunks(
+        stores, ref):
+    """Each file of the series-major load: one sid span, SEGMENTS packed
+    chunks in ascending, disjoint time ranges that share the file's rows
+    evenly, every series in every one of them; the writer counted it."""
+    srv = stores["series_major"]
+    chunks = srv.chunks()
+    assert len(chunks) == FILES * SEGMENTS and all(c.packed
+                                                   for _r, c in chunks)
+    for sh in srv.engine.all_shards():
+        assert len(sh._files) == FILES
+        for r in sh._files:
+            segs = r.chunks("cpu")
+            assert len(segs) == SEGMENTS == r.packed_count("cpu")
+            assert len({(c.smin, c.smax) for c in segs}) == 1
+            assert segs[0].smax - segs[0].smin + 1 == 64
+            assert sum(c.rows for c in segs) == 64 * HOURS * TICKS_AN_HOUR
+            assert {c.rows // 64 for c in segs} == {32, 33}
+            for a, b in zip(segs, segs[1:]):
+                assert a.tmax < b.tmin
+            assert (segs[0].tmin, segs[-1].tmax) == (r.tmin, r.tmax)
+            for c in segs:
+                sids = r.read_packed_sids(c, cache=False)
+                assert (np.unique(sids, return_counts=True)[1]
+                        == c.rows // 64).all()
+                assert [list(e) for e in c.sparse] == [
+                    [int(sids[i]), i] for i in range(0, c.rows, 1024)]
+    assert counter(srv.vars(), "tsf/packed_buffers_cut") >= FILES
+    assert counter(srv.vars(), "tsf/packed_segments_written") \
+        >= FILES * SEGMENTS
+
+
+def test_a_compacted_shard_keeps_the_time_segments(stores):
+    """compact() writes through the same chunk writer: the one file it
+    leaves holds each sid span (a buffer of a PACK_ROWS a segment) as
+    SEGMENTS chunks, span after span; the few series of the tail are too
+    few rows to cut."""
+    chunks = [c for _r, c in stores["compacted"].chunks()]
+    assert sum(c.rows for c in chunks) == HOSTS * HOURS * TICKS_AN_HOUR
+    spans = {}
+    for c in chunks:
+        spans.setdefault((c.smin, c.smax), []).append(c)
+    assert list(spans) == sorted(spans)         # span after span
+    *full, last = spans.values()
+    assert len(full) >= 2 and all(len(v) == SEGMENTS for v in full)
+    for segs in full:
+        assert all(a.tmax < b.tmin for a, b in zip(segs, segs[1:]))
+        assert all(c.rows >= CUT["PACK_ROWS"] // 4 for c in segs)
+    assert len(last) == 1 and last[0].rows < 2 * (CUT["PACK_ROWS"] // 4)
+
+
+def test_files_written_before_the_cut_and_short_series_hold_whole_chunks(
+        stores):
+    for layout, files in (("uncut", FILES), ("time_ordered", HOURS)):
+        chunks = stores[layout].chunks()
+        assert len(chunks) == files and all(c.packed for _r, c in chunks)
+        assert all((c.tmin, c.tmax) == (r.tmin, r.tmax) for r, c in chunks)
 
 
 # -- the regime the cell is defined around ------------------------------------
 
 
-def test_an_evicting_cache_never_hits_and_decodes_six_hours_to_keep_one(
+@pytest.mark.parametrize("which", ["evicting", "compacted"])
+def test_an_evicting_cache_hardly_hits_and_a_statement_decodes_under_twice_what_it_keeps(
+        runs, stores, statements, which):
+    """The pruning reaches the hour: of each file's (each sid span's)
+    segments a statement decodes the two or three its hour meets and
+    skips the rest by their time range."""
+    _, seen = runs[which]
+    srv = stores["series_major" if which == "evicting" else which]
+    lookups = delta(seen, "colcache/hits") + delta(seen, "colcache/misses")
+    assert delta(seen, "colcache/misses") > 0
+    assert delta(seen, "colcache/evictions") > 0
+    assert delta(seen, "colcache/hits") < 0.05 * lookups
+    for n, req in enumerate(statements):        # of every statement alone
+        kept = delta(seen, "scan/rows_kept", n, n + 1)
+        assert kept == HOSTS * TICKS_AN_HOUR
+        decoded = delta(seen, "scan/rows_decoded", n, n + 1)
+        assert decoded == sum(c.rows for _r, c in met(srv, req))
+        assert kept <= decoded < 2 * kept
+        assert delta(seen, "scan/packed_skipped_by_time", n, n + 1) \
+            == len(srv.chunks()) - len(met(srv, req)) > 0
+
+
+def test_files_written_before_the_cut_decode_six_hours_to_keep_one(
         runs, statements):
-    _, seen = runs["evicting"]
+    """What PR 45 wrote is read as PR 45 read it: every chunk spans the
+    six hours, nothing is skipped, and the cache never hits."""
+    _, seen = runs["uncut"]
     assert delta(seen, "colcache/hits") == 0
     assert delta(seen, "colcache/misses") > 0
     assert delta(seen, "colcache/evictions") > 0
-    for n in range(len(statements)):            # of every statement alone
+    assert delta(seen, "scan/packed_skipped_by_time") == 0
+    for n in range(len(statements)):
         kept = delta(seen, "scan/rows_kept", n, n + 1)
         assert kept == HOSTS * TICKS_AN_HOUR
         assert delta(seen, "scan/rows_decoded", n, n + 1) == HOURS * kept
@@ -208,46 +333,67 @@ def test_the_same_rows_in_time_order_decode_under_twice_what_they_keep(runs):
     assert 1.0 <= delta(seen, "scan/rows_decoded") / kept < 2.0
 
 
-def test_a_roomy_cache_hits_once_it_is_full_and_the_hit_path_adds_nothing(
-        runs):
-    """Every chunk spans all six hours, so the two touches (fields 0-4,
-    then 5-9) decode the whole store and every later statement hits; a
-    read that only hits opens no span of the miss path and moves none of
-    its counters."""
+def test_a_roomy_cache_holds_the_segments_an_hour_met_and_the_hit_path_adds_nothing(
+        runs, stores, statements):
+    """A segment holds a stretch of time, so a statement fills the cache
+    with what its hour met and no more: the second round of the hours
+    finds the fields the first one asked and decodes the others.  A
+    statement asked again finds everything; a read that only hits opens
+    no span of the miss path and moves none of its counters."""
     _, seen = runs["roomy"]
-    assert delta(seen, "query_stages/decode_count", 0, 2) == 2
-    assert delta(seen, "colcache/hits", 2) > 0
-    assert delta(seen, "colcache/misses", 2) == 0
     assert delta(seen, "colcache/evictions") == 0
+    assert delta(seen, "colcache/hits", 0, 1) == 0
+    assert 0 < delta(seen, "colcache/misses", HOURS) \
+        < delta(seen, "colcache/misses", 0, HOURS)
+    assert delta(seen, "colcache/hits", HOURS) \
+        > delta(seen, "colcache/hits", 0, HOURS) > 0    # the shared segment
+    srv = stores["series_major"]
+    _, again = run(srv, statements[1:2], REGIMES["roomy"])
+    srv.svc.executor._inc_cache.clear()     # scan again, not a stored answer
+    srv.ask(statements[1])
+    again.append(srv.vars())
+    assert delta(again, "colcache/misses", 0, 1) > 0
+    assert delta(again, "colcache/hits", 1) \
+        == delta(again, "colcache/misses", 0, 1)
+    assert delta(again, "colcache/misses", 1) == 0
     for path in [f"query_stages/{s}_count" for s in MISS_SPANS] + [
             "scan/rows_decoded", "scan/rows_kept", "scan/decoded_bytes",
             "tsf/read_bytes", "tsf/blocks_read", "scanpool/busy_ns"]:
-        assert delta(seen, path, 2) == 0, path
+        assert delta(again, path, 0, 1) > 0, path
+        assert delta(again, path, 1) == 0, path
+    assert delta(again, "scan/merges", 1) == 1
+    assert delta(again, "scan/packed_skipped_by_time", 1) \
+        == delta(again, "scan/packed_skipped_by_time", 0, 1) > 0
 
 
 # -- the merge works on what the statement keeps -------------------------------
 
 
-BRANCHES = ("inorder", "single_sid", "sorted")
+BRANCHES = ("inorder", "single_sid", "interleaved", "sorted")
 
 
+@pytest.mark.parametrize("which, branch", [
+    ("evicting", "interleaved"), ("compacted", "interleaved"),
+    ("uncut", "inorder"), ("time_ordered", "inorder")])
 def test_a_cold_merge_works_on_the_rows_it_keeps_not_on_those_decoded(
-        runs, statements):
-    """A chunk decodes whole, six hours to keep one; every part is trimmed
-    to the hour before anything is joined or sorted, so what the merge
-    works on is the sixth the statement keeps.  The files lie series after
-    series, so trimmed they are in order: nothing is sorted."""
-    _, seen = runs["evicting"]
+        runs, statements, which, branch):
+    """A chunk decodes whole; every part is trimmed to the hour before
+    anything is joined or sorted, so what the merge works on is what the
+    statement keeps.  An hour crosses a segment boundary, so the parts of
+    one sid span repeat its sids, later in time: their rows are
+    interleaved, not sorted.  Whole chunks lie series after series, so
+    trimmed they are in order."""
+    _, seen = runs[which]
     for n in range(len(statements)):
         kept = delta(seen, "scan/rows_kept", n, n + 1)
         assert kept == HOSTS * TICKS_AN_HOUR
         assert delta(seen, "scan/rows_merged", n, n + 1) == kept
-        assert delta(seen, "scan/rows_decoded", n, n + 1) == HOURS * kept
         assert delta(seen, "scan/merges", n, n + 1) == 1
-        assert delta(seen, "scan/merges_inorder", n, n + 1) == 1
+        assert delta(seen, f"scan/merges_{branch}", n, n + 1) == 1
+    assert delta(seen, "scan/merges_sorted") == 0
 
 
-@pytest.mark.parametrize("which", [*REGIMES, "time_ordered"])
+@pytest.mark.parametrize("which", [*REGIMES, *LAYOUTS[1:]])
 def test_every_bulk_read_counts_its_merge_and_names_its_branch(
         runs, statements, which):
     """Both call sites: the read that decoded (under `scan_merge`) and the
@@ -257,10 +403,20 @@ def test_every_bulk_read_counts_its_merge_and_names_its_branch(
     assert delta(seen, "scan/merges") == n
     assert sum(delta(seen, f"scan/merges_{b}") for b in BRANCHES) == n
     assert delta(seen, "scan/rows_merged") == n * HOSTS * TICKS_AN_HOUR
-    if which == "roomy":    # rows_kept counts the reads that decoded
-        assert delta(seen, "scan/rows_kept", 2) == 0
-        assert delta(seen, "scan/rows_merged", 2) \
-            == (n - 2) * HOSTS * TICKS_AN_HOUR
+    # rows_kept counts the reads that decoded
+    assert delta(seen, "scan/rows_kept") == HOSTS * TICKS_AN_HOUR * delta(
+        seen, "query_stages/decode_count")
+
+
+def _whole_range_read(srv: Served, fields):
+    sh, = srv.engine.all_shards()
+    colcache.GLOBAL.configure(budget_mb=REGIMES["off"])
+    colcache.GLOBAL.clear()
+    before = STATS.counters("scan")
+    sids = np.array(sorted(sh.index.series_ids("cpu")), dtype=np.int64)
+    sid_arr, rec = sh.read_series_bulk("cpu", sids, None, None, fields)
+    after = STATS.counters("scan")
+    return sid_arr, rec, {k: after[k] - before.get(k, 0) for k in after}
 
 
 def test_a_whole_range_read_of_in_order_parts_copies_each_part_once(
@@ -272,7 +428,6 @@ def test_a_whole_range_read_of_in_order_parts_copies_each_part_once(
     from opengemini_tpu import record
     from opengemini_tpu.storage import shard as shard_mod
 
-    sh, = stores["series_major"].engine.all_shards()
     fields = ["usage_user", "usage_system", "usage_idle"]
     made: list[np.ndarray] = []
     merges = []
@@ -297,14 +452,8 @@ def test_a_whole_range_read_of_in_order_parts_copies_each_part_once(
         return out
 
     monkeypatch.setattr(shard_mod, "merge_bulk_parts", watched_merge)
-    colcache.GLOBAL.configure(budget_mb=REGIMES["off"])
-    colcache.GLOBAL.clear()
-    before = STATS.counters("scan")
-    sids = np.array(sorted(sh.index.series_ids("cpu")), dtype=np.int64)
-    sid_arr, rec = sh.read_series_bulk("cpu", sids, None, None, fields)
-    after = STATS.counters("scan")
+    sid_arr, rec, d = _whole_range_read(stores["uncut"], fields)
 
-    d = {k: after[k] - before.get(k, 0) for k in after}
     rows = HOSTS * HOURS * TICKS_AN_HOUR
     assert len(rec) == rows and list(rec.columns) == fields
     assert d["merges"] == d["merges_inorder"] == 1
@@ -318,34 +467,61 @@ def test_a_whole_range_read_of_in_order_parts_copies_each_part_once(
     assert sum(a.nbytes for a in made) == rows * (8 + 8 + 9 * len(fields))
 
 
+@pytest.mark.parametrize("layout", ["series_major", "compacted"])
+def test_a_whole_range_read_of_time_segments_is_interleaved_not_sorted(
+        stores, layout):
+    """Every segment of every sid span is a part: the spans repeat their
+    sids, later in time.  The rows are those of the uncut files, bit for
+    bit, and nothing was skipped or sorted."""
+    fields = ["usage_user", "usage_idle"]
+    want_sid, want, d0 = _whole_range_read(stores["uncut"], fields)
+    sid_arr, rec, d = _whole_range_read(stores[layout], fields)
+    assert d0["merges_inorder"] == 1 and d["merges_interleaved"] == 1
+    assert d["merges"] == 1 and d.get("merges_sorted", 0) == 0
+    assert d["packed_skipped_by_time"] == 0
+    assert d["rows_merged"] == d["rows_decoded"] == len(want)
+    assert sid_arr.tobytes() == want_sid.tobytes()
+    assert rec.times.tobytes() == want.times.tobytes()
+    assert list(rec.columns) == fields
+    for name in fields:
+        assert rec.columns[name].values.tobytes() \
+            == want.columns[name].values.tobytes()
+        assert rec.columns[name].valid.tobytes() \
+            == want.columns[name].valid.tobytes()
+
+
 # -- the miss path's spans and counters ---------------------------------------
 
 
+@pytest.mark.parametrize("which", ["evicting", "uncut"])
 def test_the_miss_path_reports_its_stages_and_its_bytes(
-        stores, runs, statements):
-    _, seen = runs["evicting"]
+        stores, runs, statements, which):
+    _, seen = runs[which]
+    srv = stores["series_major" if which == "evicting" else which]
     n = len(statements)
-    chunks = [(r, c) for sh in stores["series_major"].engine.all_shards()
-              for r in sh._files for c in r.chunks("cpu")]
-    assert len(chunks) == FILES and all(c.packed for _r, c in chunks)
+    chunks = [met(srv, req) for req in statements]
+    assert {len(c) for c in chunks} == (
+        {2 * FILES, 3 * FILES} if which == "evicting" else {FILES})
     for name in ("decode", "scan_merge"):
         assert delta(seen, f"query_stages/{name}_count") == n
         assert delta(seen, f"query_stages/{name}_ns") > 0
     for name in ("pool_wait", "block_read", "codec", "colcache_fill"):
-        assert delta(seen, f"query_stages/{name}_count") == n * len(chunks)
+        assert delta(seen, f"query_stages/{name}_count") \
+            == sum(len(c) for c in chunks)
         assert delta(seen, f"query_stages/{name}_ns") > 0
     assert delta(seen, "scanpool/busy_ns") > 0
     # of one statement: the blocks of times, sids and its five fields in
-    # every chunk, seals included; and what the codecs made of them
+    # every chunk its hour meets, seals included; and what the codecs
+    # made of them
     for k, req in enumerate(statements):
-        locs = [loc for _r, c in chunks
+        locs = [loc for _r, c in chunks[k]
                 for loc in [c.time_loc, c.sid_loc]
                 + [c.cols[f][part] for f in req.stmt["fields"]
                    for part in "vm"] if loc]
         assert delta(seen, "tsf/blocks_read", k, k + 1) == len(locs)
         assert delta(seen, "tsf/read_bytes", k, k + 1) \
             == sum(loc[1] for loc in locs)
-        rows = sum(c.rows for _r, c in chunks)
+        rows = sum(c.rows for _r, c in chunks[k])
         assert delta(seen, "scan/decoded_bytes", k, k + 1) \
             == rows * (5 * 9 + 8 + 8)
 
@@ -370,7 +546,7 @@ def test_decode_self_time_is_never_negative(stores, statements, pool,
         after = STATS.counters("query_stages")
         d = {k: after[k] - before.get(k, 0) for k in after}
         assert d["decode_count"] == 1
-        assert d["pool_wait_count"] == (FILES if pool else 0)
+        assert d["pool_wait_count"] == (len(met(srv, req)) if pool else 0)
         stages = d["block_read_ns"] + d["codec_ns"] + d["colcache_fill_ns"]
         if pool:    # exact: the frame holds what its children recorded
             assert d["decode_self_ns"] == d["decode_ns"] - d["pool_wait_ns"]

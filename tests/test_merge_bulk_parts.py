@@ -150,6 +150,30 @@ def _seam(rng, fields=TWO):
     return [join(a, a_seam), join(b_seam, b)]
 
 
+def _segmented(rng, n_seg, fields=TWO, spans=((0, 4), (4, 8)), t0=0):
+    """One file whose long series the writer cut along time: each sid
+    span as `n_seg` packed chunks in ascending, disjoint times, span
+    after span — [A, seg 0][A, seg 1]...[B, seg 0]...  Sid 0 has rows in
+    its first segment only (a series that stopped reporting)."""
+    edge = [t0 + 600 * j // n_seg // 10 * 10 for j in range(n_seg + 1)]
+    out = []
+    for lo, hi in spans:
+        for j, (a, b) in enumerate(zip(edge, edge[1:])):
+            out.append(packed(rng, lo + (j > 0 and lo == 0), hi, a, b,
+                              fields))
+    return out
+
+
+def _memtable_on_top(rng, fields=TWO, again=()):
+    """A segmented file and, newest, the memtable's consolidated part:
+    (sid, time)-sorted rows of sids 2..5 after the file's, and those of
+    `again`, (sid, time) pairs the file holds too."""
+    rows = sorted([(sid, t) for sid in range(2, 6)
+                   for t in range(600, 650, 10)] + list(again))
+    return _segmented(rng, 3, fields) + [
+        part(rng, [r[0] for r in rows], [r[1] for r in rows], fields)]
+
+
 def _duplicates(rng):
     """The same (sid, time) pairs in three parts with different column
     sets: the newest row wins WHOLE, so a column only the older part
@@ -199,9 +223,9 @@ CASES = {
     "seam_overlap_cut_away_by_the_range":
         (lambda r: _seam(r), (300, 400), "inorder", False),
     "seam_overlap_inside_the_range":
-        (lambda r: _seam(r, MIX), (250, 350), "sorted", False),
+        (lambda r: _seam(r, MIX), (250, 350), "interleaved", False),
     "seam_overlap_unbounded":
-        (lambda r: _seam(r), ALL, "sorted", False),
+        (lambda r: _seam(r), ALL, "interleaved", False),
     "duplicates_newest_row_wins_whole":
         (lambda r: _duplicates(r), (0, 200), "sorted", False),
     "duplicates_unbounded":
@@ -260,12 +284,57 @@ CASES = {
     "encoded_one_part_trimmed":
         (lambda r: encode_parts(_packed_files(r, {"i": I})[:1]),
          (100, 200), "inorder", True),
+    "encoded_parts_that_need_interleaving_decode":
+        (lambda r: encode_parts(_seam(r, {"f": F, "i": I})), ALL,
+         "interleaved", False),
     "encoded_parts_that_need_the_sort_decode":
-        (lambda r: encode_parts(_seam(r, {"f": F, "i": I})), ALL, "sorted",
-         False),
+        (lambda r: encode_parts([packed(r, 0, 3, 0, 100, {"f": F, "i": I}),
+                                 packed(r, 1, 4, 50, 150, {"f": F, "i": I})]),
+         ALL, "sorted", False),
     "encoded_beside_plain_decodes":
         (lambda r: encode_parts(_packed_files(r)[:2]) + _packed_files(r)[2:],
          (0, 600), "inorder", False),
+    # a file's long series cut into time segments (and files like it)
+    **{f"segments_{n}_whole_range":
+       (lambda r, n=n: _segmented(r, n), ALL, "interleaved", False)
+       for n in (2, 3, 8)},
+    "segments_every_type_whole_range":
+        (lambda r: _segmented(r, 3, MIX), (0, 600), "interleaved", False),
+    "segments_range_inside_one_segment":
+        (lambda r: _segmented(r, 3), (210, 390), "inorder", False),
+    "segments_range_crosses_a_boundary":
+        (lambda r: _segmented(r, 3, MIX), (150, 250), "interleaved", False),
+    "segments_range_crosses_two_boundaries":
+        (lambda r: _segmented(r, 8), (100, 300), "interleaved", False),
+    "segments_of_two_files_one_after_the_other":
+        (lambda r: _segmented(r, 3) + _segmented(r, 2, t0=600), ALL,
+         "interleaved", False),
+    "segments_of_two_files_duplicates_across_files":
+        (lambda r: _segmented(r, 3) + _segmented(r, 2), ALL, "sorted",
+         False),
+    "segments_of_two_files_duplicates_cut_away":
+        (lambda r: _segmented(r, 3) + _segmented(r, 3, t0=200), (0, 200),
+         "inorder", False),
+    "segments_where_a_span_lacks_a_column":
+        (lambda r: _segmented(r, 3, {"f": F}, spans=((0, 4),))
+         + _segmented(r, 3, {"f": F, "g": I}, spans=((4, 8),)), ALL,
+         "interleaved", False),
+    "segments_with_a_memtable_part_on_top":
+        (lambda r: _memtable_on_top(r, MIX), ALL, "interleaved", False),
+    "segments_with_a_memtable_part_that_rewrites_a_row":
+        (lambda r: _memtable_on_top(r, again=[(3, 590), (4, 0)]), ALL,
+         "sorted", False),
+    "segments_with_a_memtable_part_range_in_the_file":
+        (lambda r: _memtable_on_top(r), (0, 150), "inorder", False),
+    "encoded_segments_decode_to_interleave":
+        (lambda r: encode_parts(_segmented(r, 3, {"f": F, "i": I})), ALL,
+         "interleaved", False),
+    "encoded_segments_some_decoded":
+        (lambda r: encode_parts(_segmented(r, 3, {"f": F}), decoded={0, 4}),
+         (150, 450), "interleaved", False),
+    "encoded_segments_inside_one_segment_stay_encoded":
+        (lambda r: encode_parts(_segmented(r, 3, {"f": F, "i": I})),
+         (210, 390), "inorder", True),
     "encoded_where_a_part_lacks_the_column":
         (lambda r: encode_parts([packed(r, 0, 2, 0, 100, {"f": F}),
                                  packed(r, 2, 4, 0, 100, {"f": F, "g": F})]),
@@ -311,6 +380,54 @@ def test_the_merge_is_the_references_bit_for_bit(name):
         same_bits(col.valid, valid)
         same_bits(col.values, values)
         assert len(col) == len(want_sid)
+
+
+@pytest.mark.parametrize(
+    "name", [n for n, case in CASES.items() if case[2] == "interleaved"])
+def test_interleaving_gives_what_the_general_merge_gives(name, monkeypatch):
+    """The same parts through `sorted` (the branch they took before there
+    was `interleaved`): the same bits, dtypes and column order."""
+    from opengemini_tpu import record
+
+    build, (lo, hi), _branch, _enc = CASES[name]
+    told = {}
+    sid, rec = merge_bulk_parts(build(np.random.default_rng(43)), lo, hi,
+                                told)
+    assert told["branch"] == "interleaved"
+    monkeypatch.setattr(record, "_interleave", lambda *a: None)
+    want_sid, want = merge_bulk_parts(build(np.random.default_rng(43)),
+                                      lo, hi, told)
+    assert told["branch"] == "sorted"
+    same_bits(sid, want_sid)
+    same_bits(rec.times, want.times)
+    assert list(rec.columns) == list(want.columns)
+    for col_name, col in want.columns.items():
+        assert type(rec.columns[col_name]) is type(col) is Column
+        assert rec.columns[col_name].ftype == col.ftype
+        same_bits(rec.columns[col_name].values, col.values)
+        same_bits(rec.columns[col_name].valid, col.valid)
+
+
+def test_interleaving_builds_every_output_column_once(monkeypatch):
+    """Not a timing: the arrays of the answer are the only row-long
+    arrays of a column's type that the branch makes — no concatenation
+    of the parts that a gather then reads."""
+    from opengemini_tpu import record
+
+    parts = _segmented(np.random.default_rng(5), 8, {"f": F, "g": F})
+    made = []
+    real = record._zeroed
+
+    def counted(ftype, n):
+        made.append(real(ftype, n))
+        return made[-1]
+
+    monkeypatch.setattr(record, "_zeroed", counted)
+    told = {}
+    _sid, rec = merge_bulk_parts(parts, *ALL, told)
+    assert told["branch"] == "interleaved"
+    assert [id(a) for a in made] == [id(c.values)
+                                     for c in rec.columns.values()]
 
 
 def test_a_part_wholly_inside_is_handed_on_as_it_is():

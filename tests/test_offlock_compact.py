@@ -257,3 +257,132 @@ def test_compaction_runs_clean_under_armed_lockdep(tmp_path, monkeypatch):
     sh.compact()  # may be a no-op if the set already collapsed to one
     assert len(lockdep.violations()) == v0
     sh.close()
+
+
+# -- compaction of time-segmented files (PR 46) --------------------------------
+#
+# Long series are written as packed chunks cut along time.  Every kind of
+# compaction writes through the same chunk writer, so what it leaves is
+# cut the same way, and what it reads may be: a series' rows then come
+# from several chunks of one input.  The writer's constants are scaled to
+# the data (360 rows a series for the 2,160 of tsbs-devops-cpu-4000-6h).
+
+SEG_SERIES, SEG_ROWS, SEG_CUT = 96, 360, {"SEGMENT_ROWS": 32,
+                                          "PACK_ROWS": 4096}
+
+
+def _seg_write(sh, series, rows, salt=0):
+    import numpy as np
+
+    from opengemini_tpu.ingest import native_lp
+
+    out = []
+    for s in series:
+        v = np.random.default_rng(s * 7919 + salt).normal(
+            size=(max(rows) + 1, 2)).round(2)
+        out.extend(f"cpu,host=h{s:03d} a={v[r, 0]},b={v[r, 1]} "
+                   f"{BASE + r * 10 * NS}" for r in rows)
+    body = "\n".join(out).encode()
+    sh.write_columnar(native_lp.parse_columnar(body, "ns", 0), None, body,
+                      "ns", 0)
+    sh.flush()
+
+
+def _seg_layout(sh):
+    """[(file, smin, smax, [(rows, tmin, tmax)])] of the packed chunks
+    (a file of under 64 series holds a chunk a series)."""
+    out = []
+    for at, r in enumerate(sh._files):
+        spans = {}
+        for c in r.chunks("cpu"):
+            if c.packed:
+                spans.setdefault((c.smin, c.smax), []).append(
+                    (c.rows, c.tmin, c.tmax))
+        out.extend((at, lo, hi, segs) for (lo, hi), segs in spans.items())
+    return out
+
+
+def _seg_read(sh, lo, hi):
+    import numpy as np
+
+    sids = np.array(sorted(sh.index.series_ids("cpu")), dtype=np.int64)
+    before = STATS.counters("scan")
+    sid_arr, rec = sh.read_series_bulk("cpu", sids, BASE + lo * 10 * NS,
+                                       BASE + hi * 10 * NS)
+    after = STATS.counters("scan")
+    return sid_arr, rec, {k: after[k] - before.get(k, 0) for k in after}
+
+
+@pytest.mark.parametrize("how", ["compact", "compact_level",
+                                 "compact_out_of_order"])
+def test_compaction_of_segmented_files_keeps_rows_digest_and_layout(
+        tmp_path, monkeypatch, how):
+    from opengemini_tpu.storage import colcache, tsf
+
+    for name, value in SEG_CUT.items():
+        monkeypatch.setattr(tsf, name, value)
+    before_cc = colcache.GLOBAL.config()
+    colcache.GLOBAL.configure(budget_mb=0)
+    sh = Shard(str(tmp_path / "s"), BASE - NS, BASE + 10_000_000 * NS)
+    series = range(SEG_SERIES)
+    # two segmented files, one after the other in time ...
+    _seg_write(sh, series, range(SEG_ROWS))
+    _seg_write(sh, series, range(SEG_ROWS, 2 * SEG_ROWS))
+    if how == "compact_out_of_order":
+        # ... and a late one that rewrites a stretch of the first
+        _seg_write(sh, range(10, 30), range(100, 160), salt=1)
+    else:
+        _seg_write(sh, series, range(2 * SEG_ROWS, 2 * SEG_ROWS + 40))
+        _seg_write(sh, series, range(2 * SEG_ROWS + 40, 2 * SEG_ROWS + 80))
+    for _at, _lo, _hi, segs in _seg_layout(sh)[:2]:
+        assert len(segs) == 11 and sum(s[0] for s in segs) \
+            == SEG_SERIES * SEG_ROWS
+    digest = sh.content_digest()
+    rows = digest["cpu"][0]
+    assert rows == SEG_SERIES * (2 * SEG_ROWS + (
+        0 if how == "compact_out_of_order" else 80))
+    want = _seg_read(sh, 90, 150)
+    one = sh.read_series("cpu", sorted(sh.index.series_ids("cpu"))[12])
+
+    cut0 = STATS.counters("tsf").get("packed_buffers_cut", 0)
+    if how == "compact":
+        assert sh.compact() and sh.file_count() == 1
+    elif how == "compact_level":
+        assert sh.compact_level(fanout=4) and sh.file_count() == 1
+    else:
+        assert sh.has_time_overlap()
+        assert sh.compact_out_of_order(max_files=4)
+        assert sh.file_count() == 1 and not sh.has_time_overlap()
+    assert STATS.counters("tsf")["packed_buffers_cut"] > cut0
+
+    assert sh.content_digest() == digest            # the rows, bit for bit
+    assert sum(s[0] for _f, _l, _h, segs in _seg_layout(sh)
+               for s in segs) == rows
+    wanted = tsf.packed_segments(rows, SEG_SERIES)
+    assert wanted == (rows // SEG_SERIES + 16) // 32 > 11
+    spans = _seg_layout(sh)                         # and the layout
+    assert [s[1] for s in spans] == sorted(s[1] for s in spans)
+    assert len(spans[0][3]) == wanted               # a full buffer
+    for _at, _lo, _hi, segs in spans:
+        assert len(segs) == min(wanted, sum(s[0] for s in segs)
+                                // (SEG_CUT["PACK_ROWS"] // 4))
+        assert all(a[2] < b[1] for a, b in zip(segs, segs[1:]))
+    got = _seg_read(sh, 90, 150)
+    assert got[0].tobytes() == want[0].tobytes()
+    assert got[1].times.tobytes() == want[1].times.tobytes()
+    for name, col in want[1].columns.items():
+        assert got[1].columns[name].values.tobytes() == col.values.tobytes()
+    assert got[2]["rows_kept"] == SEG_SERIES * 60
+    assert got[2]["rows_decoded"] < 2 * got[2]["rows_kept"]
+    assert got[2]["packed_skipped_by_time"] > 0
+    assert got[2].get("merges_sorted", 0) == 0
+    again = sh.read_series("cpu", sorted(sh.index.series_ids("cpu"))[12])
+    assert again.times.tobytes() == one.times.tobytes()
+    assert again.columns["a"].values.tobytes() \
+        == one.columns["a"].values.tobytes()
+    sh.close()
+    # the merged file reopens with its segments
+    sh2 = Shard(str(tmp_path / "s"), BASE - NS, BASE + 10_000_000 * NS)
+    assert sh2.content_digest() == digest
+    sh2.close()
+    colcache.GLOBAL.configure(**before_cc)
