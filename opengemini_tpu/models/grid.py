@@ -28,7 +28,6 @@ row order (selector timestamp resolution is unchanged).
 from __future__ import annotations
 
 import functools
-import time
 
 import numpy as np
 
@@ -49,28 +48,6 @@ _MAX_EXPANSION = 8
 # samples-per-window above this would make (S, k, W) degenerate (one
 # giant sublane axis); bucketed split rows handle it better
 _MAX_K = 8192
-
-
-class _EncodedVals:
-    """Array-like holder of one add_encoded() value column that is still
-    in its on-disk encoded blocks (record.EncodedColumn): the grid
-    freeze ships the raw payloads to the device decoder
-    (ops/device_decode.py); any host consumer — the bucketed fallback,
-    a scatter rebuild — decodes via __array__, the same numbers by
-    construction."""
-
-    __slots__ = ("col",)
-
-    def __init__(self, col):
-        self.col = col
-
-    def __len__(self):
-        return len(self.col)
-
-    def __array__(self, dtype=None, copy=None):
-        v = self.col.values
-        return np.asarray(v, dtype=dtype) if dtype is not None \
-            else np.asarray(v)
 
 
 class GridBatch:
@@ -111,22 +88,7 @@ class GridBatch:
         is independent, so a stager that concatenates records from
         different shards must keep equal sid values from fusing into one
         stride run."""
-        self._push(np.asarray(values, dtype=self.dtype), rel_ns, seg_ids,
-                   mask, times_ns, sids, boundaries)
-
-    def add_encoded(self, col, rel_ns, seg_ids, mask, times_ns, sids=None,
-                    boundaries=None):
-        """add() variant taking a still-encoded value column
-        (record.EncodedColumn): when EVERY add of the batch arrives
-        encoded, the freeze ships the raw block payloads to the device
-        and one jit program decodes, scatters, and reduces
-        (ops/device_decode.py); every fallback path decodes on the host
-        through the column's lazy .values — bit-identical either way."""
-        self._push(_EncodedVals(col), rel_ns, seg_ids, mask, times_ns,
-                   sids, boundaries)
-
-    def _push(self, vals, rel_ns, seg_ids, mask, times_ns, sids,
-              boundaries):
+        vals = np.asarray(values, dtype=self.dtype)
         self._vals.append(vals)
         self._rel.append(np.asarray(rel_ns, dtype=np.int64))
         # segment and series ids stay as handed (the plan widens and
@@ -204,8 +166,8 @@ class GridBatch:
 
     def _fill(self, plan: dict) -> dict:
         """This field's half of a freeze, as the state dict: its grids
-        from the device tier, as a fused decode-on-device plan, or
-        scattered here through the plan's index."""
+        from the device tier, or scattered here through the plan's
+        index."""
         shape, flat, mesh = plan["shape"], plan["flat"], plan["mesh"]
         # device tier consult: an identically-signed earlier scan already
         # holds the padded grid on device — skip the host scatter AND the
@@ -219,26 +181,11 @@ class GridBatch:
             dev_entry = colcache.GLOBAL.device_get(
                 self.device_cache_token,
                 shape=shape, dtype=str(self.dtype), mesh=mesh)
-        enc_plan = None
-        host_s = None
-        arrays = None
-        if dev_entry is None:
-            enc_plan = self._encoded_plan(shape, flat, mesh, plan["rel"],
-                                          plan["run_starts"], plan["dt"])
-            if enc_plan is None:
-                # host route: the decode (through _EncodedVals.__array__)
-                # + scatter wall; each launch adds its own dispatch wall
-                # so the planner's host samples cover the same span the
-                # fused device sample does — including the selector
-                # group's second full-grid transfer, which the device
-                # route avoids by keeping the grid resident
-                t0 = time.perf_counter()
-                arrays = self._scatter_grid(shape, flat)
-                host_s = time.perf_counter() - t0
         return {
             **plan,
-            "arrays": arrays, "device_entry": dev_entry,
-            "encoded_plan": enc_plan, "host_route_s": host_s,
+            "arrays": (self._scatter_grid(shape, flat)
+                       if dev_entry is None else None),
+            "device_entry": dev_entry,
             # imat (sample-index grid for the selector kernels) builds
             # lazily from `flat` — count/sum/mean scans never pay for it
             "imat": None,
@@ -335,83 +282,6 @@ class GridBatch:
             out2d[gids] = vals2d
         return out, sel, counts
 
-    def _encoded_plan(self, shape, flat, mesh, rel, starts, dt):
-        """Fused device-decode plan for a fully-encoded cold scan
-        (ops/device_decode.py), or None: every add must still carry its
-        encoded blocks and the decoder must accept every block.  Under a
-        configured mesh the plan is partitioned by output row shard
-        (rows are already padded to a mesh multiple) so each device
-        decodes only its own shard's bytes.  None means the freeze
-        scatters on the host exactly as it always has."""
-        if not self._vals:
-            return None
-        views = []
-        any_decoded = False
-        for v in self._vals:
-            col = getattr(v, "col", None)
-            if col is None:
-                return None
-            if col.is_decoded:
-                # the colcache host tier already decoded this column —
-                # but the encoded blocks are still attached, so the
-                # DEVICE route stays available: a warm planner can
-                # route the repeat back to the accelerator where the
-                # decoded grid goes RESIDENT (colcache device tier)
-                # and every later repeat skips decode AND transfer
-                any_decoded = True
-            views.append((col.blocks, col.abs_segments(), col.n_full))
-        from opengemini_tpu.ops import device_decode
-        from opengemini_tpu.query import offload
-
-        # THE route decision for the encoded cold scan (query/offload.py):
-        # static prior = today's behavior (attempt the device build on
-        # cold encoded columns — the byte gate stays live as the
-        # planner's zero-sample prior; scatter on the host once the
-        # columns are already decoded), so a cold or disabled planner is
-        # bit-identical to the pre-planner dispatch.  "host" skips the
-        # build — the freeze scatters on the host exactly as it always
-        # has, without counting it as a decode fallback (it is a
-        # routing choice, not a failure)
-        dev_route = "mesh" if mesh is not None else "device"
-        static = "host" if any_decoded else dev_route
-        geo = (tuple(shape), str(self.dtype))
-        route = offload.GLOBAL.decide(
-            "grid_decode", geo, ("host", dev_route), static,
-            stage="grid_decode")
-        if route == "host" and not offload.wants_prewarm(
-                "grid_decode", geo):
-            return None
-        mask = layoutplan.cat(self._mask)
-        if mesh is not None:
-            plan = device_decode.build_mesh_grid_plan(
-                views, flat, mask, shape, self.dtype, mesh,
-                rel=rel, starts=starts, every_ns=self.every_ns, dt=dt)
-        else:
-            plan = device_decode.build_grid_plan(
-                views, flat, mask, shape, self.dtype,
-                rel=rel, starts=starts, every_ns=self.every_ns, dt=dt)
-        if route == "host":
-            # flip-justified by the planner but not yet compiled: hand
-            # the fused program to the BACKGROUND pre-warmer (the plan
-            # build above is host-side only) — this query still
-            # scatters on the host, and the geometry flips to the
-            # device once the compile lands
-            if plan is not None:
-                if mesh is not None:
-                    geoms = tuple(p.geom for p in plan.shards)
-                    offload.register_builder(
-                        "grid_decode", geo,
-                        lambda gs=geoms: [device_decode._grid_program(g)
-                                          for g in gs])
-                else:
-                    offload.register_builder(
-                        "grid_decode", geo,
-                        lambda g=plan.geom: device_decode._grid_program(g))
-            return None
-        if plan is None:
-            STATS.incr("executor", "grid_decode_fallbacks")
-        return plan
-
     def _scatter_grid(self, shape, flat):
         """Scatter the raw rows into the padded (S_pad, k, W_pad) grid:
         the ONE scatter shared by freeze and the entry-lost rebuild, so
@@ -501,18 +371,9 @@ class GridBatch:
                     from opengemini_tpu.storage import colcache
 
                     ent_mesh = ent.get("mesh")
-                    flat_dev = st.get("flat_dev")
-                    if flat_dev is not None and ent_mesh is None:
-                        # fused-decode entries keep their scatter slots
-                        # on device: build the selector grid there
-                        from opengemini_tpu.ops import device_decode
-
-                        imat_d = device_decode.imat_from_flat(
-                            flat_dev, st["shape"])
-                    else:
-                        (imat_d,) = self._device_put(
-                            ent_mesh, self._build_imat_np(),
-                            xfer_site="colcache-fill")
+                    (imat_d,) = self._device_put(
+                        ent_mesh, self._build_imat_np(),
+                        xfer_site="colcache-fill")
                     imat = colcache.GLOBAL.device_add_imat(
                         self.device_cache_token, ent, imat_d,
                         mesh=ent_mesh)
@@ -533,9 +394,6 @@ class GridBatch:
                     "grid device entry lost after prefetch dropped the "
                     "host rows (device mesh changed mid-query?)")
             st["arrays"] = self._scatter_grid(st["shape"], st["flat"])
-            # a pending fused-decode plan is superseded by the host
-            # scatter (encoded adds decode through _EncodedVals.__array__)
-            st["encoded_plan"] = None
         vt, mt = st["arrays"]
         imat = None
         if with_imat:
@@ -582,75 +440,15 @@ class GridBatch:
         shape (JAX dispatch is async — the host is free to keep decoding
         while the device reduces)."""
         st = self._state
-        if (st["arrays"] is None and st.get("device_entry") is None
-                and st.get("encoded_plan") is None):
+        if st["arrays"] is None and st.get("device_entry") is None:
             raise RuntimeError(
                 f"grid kernel {kind!r} needed after prefetch dropped the "
                 "host arrays")
-        sink = functools.partial(self._take, st["S"])
-        plan = st.get("encoded_plan")
-        if plan is not None and kind == "basic":
-            # fused cold path: compressed bytes -> device -> decode ->
-            # scatter -> basic reduce in ONE jit program of this field's
-            # own (its launch is the item's flight already); the decoded
-            # grid buffers come back for retention so ssd/selector
-            # kernels (and identically-signed future scans through the
-            # colcache device tier) reuse them without any transfer
-            from opengemini_tpu.ops import device_decode
-            from opengemini_tpu.query import offload
-
-            plan_mesh = getattr(plan, "mesh", None)
-            t0 = time.perf_counter()
-            if plan_mesh is not None:
-                stats, vt, mt, flat_d = \
-                    device_decode.run_mesh_grid_plan(plan)
-            else:
-                stats, vt, mt, flat_d = device_decode.run_grid_plan(plan)
-            offload.GLOBAL.observe(
-                "grid_decode", (st["shape"], str(self.dtype)),
-                "mesh" if plan_mesh is not None else "device",
-                time.perf_counter() - t0)
-            st["encoded_plan"] = None
-            ent = None
-            if self.device_cache_token is not None:
-                from opengemini_tpu.storage import colcache
-
-                ent = colcache.GLOBAL.device_put_grid(
-                    self.device_cache_token, vt, mt,
-                    shape=st["shape"], dtype=str(self.dtype),
-                    mesh=plan_mesh)
-            if ent is None:
-                ent = {"vt": vt, "mt": mt, "imat": None,
-                       "shape": st["shape"], "dtype": str(self.dtype),
-                       "mesh": plan_mesh}
-            # device-resident scatter slots, QUERY-scoped (on st, not
-            # the retained cache entry — the cache's budget/ledger
-            # accounting must not carry unaccounted buffers): this
-            # query's selector imat builds from them on device
-            # (device_decode.imat_from_flat) with no host grid
-            # transfer; warm repeats reuse the retained imat instead
-            st["flat_dev"] = flat_d
-            st["device_entry"] = ent
-            STATS.incr("executor", "grid_decode_fused")
-            return launch.Item("grid_decode_fused", None, (), sink,
-                               flight=launch.Flight(stats, sink))
         vt, mt, imat = self._device_arrays(with_imat=(kind == "selectors"))
-        item = launch.Item(
+        return launch.Item(
             "grid_" + kind, _KERNELS[kind],
-            (vt, mt, imat) if kind == "selectors" else (vt, mt), sink)
-        if st.get("arrays") is not None or st.get("host_route_s") is not None:
-            # host-route planner sample, one a launch: the first launch
-            # carries the decode+scatter wall (freeze) of the grids that
-            # ride in it, every launch adds its own H2D-and-reduce
-            # dispatch, as the mean a grid — the same span the fused
-            # device route's single sample covers for its one grid
-            from opengemini_tpu.query import offload
-
-            item.cost_s = st.pop("host_route_s", None) or 0.0
-            item.observe = functools.partial(
-                offload.GLOBAL.observe, "grid_decode",
-                (st["shape"], str(self.dtype)), "host")
-        return item
+            (vt, mt, imat) if kind == "selectors" else (vt, mt),
+            functools.partial(self._take, st["S"]))
 
     def _take(self, S: int, stats: dict) -> None:
         self._raw.update({k: a[:S, : self.W] for k, a in stats.items()})
@@ -827,7 +625,6 @@ def _plan_grid(rel_parts, seg_parts, sid_parts, bnd_parts, W: int,
     return {
         "k": k, "S": S, "W_pad": W_pad, "shape": (S_pad, k, W_pad),
         "flat": flat, "n": n, "rel": rel, "mesh": mesh,
-        "run_starts": run_starts, "dt": dt,  # the decode-on-device plan's
         "row_order": order,  # grid rows sorted by gid
         "gid_starts": starts,  # reduceat starts in row_order
         "gids_present": sg[starts],

@@ -33,7 +33,6 @@ first of its batches needs a number, or at once under run().
 from __future__ import annotations
 
 import functools
-import time
 
 import numpy as np
 
@@ -47,22 +46,16 @@ class Item:
     `bucket_selectors`); `kernel(*args)` is the traceable per-field
     function returning {statistic: array}; `args` is only what it reads,
     host or device arrays, let go of once dispatched; `sink(stats)`
-    takes the statistics as host arrays.  `observe(seconds)`, if set, is
-    the offload planner's ear for the route these arguments took and
-    `cost_s` the host seconds they cost to prepare: a launch gives ONE
-    sample, the mean an item of those seconds plus its dispatch wall."""
+    takes the statistics as host arrays."""
 
-    __slots__ = ("program", "kernel", "args", "sink", "cost_s", "observe",
-                 "flight")
+    __slots__ = ("program", "kernel", "args", "sink", "flight")
 
-    def __init__(self, program, kernel, args, sink, flight=None):
+    def __init__(self, program, kernel, args, sink):
         self.program = program
         self.kernel = kernel
         self.args = tuple(args)
         self.sink = sink
-        self.cost_s = 0.0
-        self.observe = None
-        self.flight = flight  # set once dispatched
+        self.flight = None  # set once dispatched
 
     def key(self):
         """What makes two items one launch: the same kernel over
@@ -92,8 +85,7 @@ class Flight:
 
 def dispatch(items) -> None:
     """Group `items` and call one program a group; every item leaves with
-    its `flight`.  Items already flying (the fused decode-on-device plan
-    launches its own field) are left alone."""
+    its `flight`.  Items already flying are left alone."""
     groups: dict[tuple, list[Item]] = {}
     for it in items:
         if it.flight is None:
@@ -102,15 +94,9 @@ def dispatch(items) -> None:
         TRACKER.check()  # KILL QUERY cancellation point, once a launch
         fn, fnames, inames = _program(program, kernel, len(group), sig)
         devobs.note_use(program, (len(group), sig))
-        t0 = time.perf_counter()
         out = devobs.launch(fn, (tuple(it.args for it in group),),
                             program=program,
                             xfer_site=program.partition("_")[0] + "-launch")
-        wall = time.perf_counter() - t0
-        heard = [it for it in group if it.observe is not None]
-        if heard:
-            heard[0].observe(
-                (sum(it.cost_s for it in heard) + wall) / len(group))
         flight = Flight(out, functools.partial(
             _deliver, group, fnames, inames))
         for it in group:

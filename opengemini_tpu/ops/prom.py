@@ -79,16 +79,12 @@ def prepare_matrix_runs(t_ms_all, v_all, lens, dtype=np.float32):
     S = len(lens)
     n_max = max(1, int(lens.max()) if S else 1)
     times = np.full((S, n_max), np.inf, dtype=np.float64)
-    # v_all None = still-encoded values (TiledPrepared enc mode): only
-    # the time/count structure is prepared; the value matrix fills
-    # lazily (host fallback) or decodes on device (ops/device_decode)
-    values = None if v_all is None else np.zeros((S, n_max), dtype=dtype)
+    values = np.zeros((S, n_max), dtype=dtype)
     base_ms = _runs_base_ms(t_ms_all, lens)
     if int(lens.sum()):
         flat = _scatter_index(lens, n_max)
         times.reshape(-1)[flat] = (np.asarray(t_ms_all) - base_ms) / 1000.0
-        if values is not None:
-            values.reshape(-1)[flat] = v_all
+        values.reshape(-1)[flat] = v_all
     return times, values, lens.astype(np.int32), base_ms
 
 
@@ -595,16 +591,10 @@ class TiledPrepared:
 
     def __init__(self, plan: TilePlan, t_ms_all, v_all, lens,
                  dtype=np.float64, max_gather_cols: int | None = None,
-                 lane_quantum: int = 1, enc=None):
+                 lane_quantum: int = 1):
         lens = np.asarray(lens, np.int64)
         t_ms_all = np.asarray(t_ms_all, np.int64)
         self.plan = plan
-        # enc = (ftype, blocks, segments, slices): the value column is
-        # on-disk encoded blocks (device-decode cold path) — v_all may
-        # then be None and the (S, N) value matrix decodes on the DEVICE
-        # (_values_for -> ops/device_decode.decode_rows_matrix) or
-        # materializes lazily on the host (_host_values, bit-identical)
-        self._enc = enc if v_all is None else None
         self.dtype = np.dtype(dtype)
         S = len(lens)
         N = max(1, int(lens.max()) if S else 1)
@@ -636,7 +626,7 @@ class TiledPrepared:
         # and zero padding (prepare_matrix_runs).  The times matrix that
         # fill also makes is built on its first read, below
         with tracing.span("prom_fill"):
-            self.values = None if v_all is None else self._pad_values(v_all)
+            self.values = self._pad_values(v_all)
         with tracing.span("prom_tile_index"):
             self._index_tiles(plan, t_ms_all, lens, max_gather_cols)
 
@@ -793,17 +783,6 @@ class TiledPrepared:
 
     # -- kernel building blocks ------------------------------------------
 
-    def _host_values(self):
-        """The (S, N) value matrix on the host, materializing a
-        still-encoded column lazily (decode + the same flat scatter
-        prepare_matrix_runs does — bit-identical to the eager path)."""
-        if self.values is None:
-            from opengemini_tpu.ops import device_decode
-
-            self.values = self._pad_values(
-                device_decode.materialize_enc(self._enc))
-        return self.values
-
     def _ftype(self, xp) -> np.dtype:
         """The float dtype the kernels compute in: the prepared dtype on
         the host, what x64 allows on the device (float32 without it) —
@@ -832,7 +811,7 @@ class TiledPrepared:
                 monotone counter Prometheus defines.  rate()/irate()
                 difference it directly, so no reset is left for float32
                 to cancel against a 1e9 correction."""
-        raw = self._host_values()
+        raw = self.values
         if form == "abs":
             return raw.astype(self._ftype(jnp))
         out = raw - raw[:, :1]
@@ -856,11 +835,9 @@ class TiledPrepared:
         """The prepared value matrix in xp's array type (one cached device
         copy per form for the traced path, so gathers run on device; a
         device that does not narrow has the one exact copy for every
-        form).  A still-encoded column decodes ON the device for the
-        traced path — the H2D carries the raw block payloads instead of
-        the padded f64 matrix."""
+        form)."""
         if xp is np:
-            return self._host_values()
+            return self.values
         narrow = self._narrows(xp, None)
         if not narrow:
             form = "abs"
@@ -871,22 +848,11 @@ class TiledPrepared:
 
             from opengemini_tpu.utils import devobs
 
-            if self.values is None:
-                from opengemini_tpu.ops import device_decode
-
-                dev = device_decode.decode_rows_matrix(
-                    self._enc, (self.S, self.N), self.dtype)
-                if dev is not None:
-                    devobs.LEDGER.register(
-                        "prom_dev_values", int(dev.nbytes),
-                        label="tiled-values-decoded", anchor=self)
-                    cache[form] = dev
-                    return dev
             if narrow:
                 with tracing.span("prom_narrow", form=form):
                     mat = self._narrowed(form)
             else:
-                mat = self._host_values()
+                mat = self.values
             with tracing.span("prom_values_h2d", bytes=int(mat.nbytes)):
                 t0 = _time.perf_counter_ns()
                 dev = xp.asarray(mat)
@@ -906,7 +872,7 @@ class TiledPrepared:
         and mono values cannot give it back; "base" (S, 1), what rel
         values are relative to — a sum, a mean and the regression's
         intercept return a level."""
-        raw = self._host_values()
+        raw = self.values
         out = (np.take_along_axis(raw, self.safe_f, axis=1)
                if which == "first" else raw[:, :1])
         return out.astype(self._ftype(jnp))
@@ -1290,7 +1256,7 @@ class ShardedTiled:
         dev = self._values.get(form)
         if dev is None:
             host = (self.prep._narrowed(form) if self.narrow
-                    else self.prep._host_values())
+                    else self.prep.values)
             (dev,) = dist.shard_leading_axis(self.mesh, host,
                                              xfer_site="prom-shard")
             devobs.LEDGER.register(
@@ -1339,13 +1305,12 @@ class TileBudgetExceeded(ValueError):
 
 
 def prepare_tiled(plan: TilePlan, t_ms_all, v_all, lens, dtype=np.float64,
-                  max_gather_cols: int | None = None, lane_quantum: int = 1,
-                  enc=None):
+                  max_gather_cols: int | None = None, lane_quantum: int = 1):
     """TiledPrepared or None (budget exceeded -> dense fallback)."""
     try:
         return TiledPrepared(plan, t_ms_all, v_all, lens, dtype=dtype,
                              max_gather_cols=max_gather_cols,
-                             lane_quantum=lane_quantum, enc=enc)
+                             lane_quantum=lane_quantum)
     except TileBudgetExceeded:
         return None
 

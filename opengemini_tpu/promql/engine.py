@@ -52,59 +52,6 @@ def _tile_cells_mult() -> int:
     return max(1, _env_int("OGT_PROM_TILE_CELLS", 8))
 
 
-class _EncSlice:
-    """One series' untrimmed all-valid slice of a still-encoded bulk
-    value column (record.EncodedColumn) — resolved at assembly into
-    either a (ftype, blocks, segments, slices) device-decode
-    descriptor or, on any
-    fallback, the decoded values."""
-
-    __slots__ = ("col", "lo", "hi")
-
-    def __init__(self, col, lo: int, hi: int):
-        self.col = col
-        self.lo = lo
-        self.hi = hi
-
-
-def _materialize_slice(v):
-    if isinstance(v, _EncSlice):
-        # slice BEFORE the astype: converting the whole column per
-        # slice would be O(series x column) copies on fallback
-        return v.col.values[v.lo:v.hi].astype(np.float64)
-    return v
-
-
-def _assemble_enc(v_parts):
-    """(ftype, blocks, segments, slices) when every per-series part
-    is a slice of
-    ONE still-encoded column, else None (values materialize eagerly)."""
-    col = None
-    slices = []
-    for v in v_parts:
-        if not isinstance(v, _EncSlice):
-            return None
-        if col is None:
-            col = v.col
-        elif v.col is not col:
-            return None  # cross-shard/cross-column: host merge path
-        slices.append((v.lo, v.hi))
-    if col is None or col.is_decoded:
-        return None
-    return (col.ftype, tuple(col.blocks), col.segments, tuple(slices))
-
-
-def _want_encoded() -> bool:
-    """Collect still-encoded value columns only when the traced kernel
-    path will run (device decode is pointless under host kernels) and
-    the device decoder is usable."""
-    if _host_kernels():
-        return False
-    from opengemini_tpu.ops import device_decode
-
-    return device_decode.active()
-
-
 @functools.lru_cache(maxsize=1)
 def _backend_is_cpu() -> bool:
     import jax
@@ -407,21 +354,12 @@ class PromEngine:
         raise PromError(f"unsupported expression {type(node).__name__}")
 
     def _collect_series(self, vs: pp.VectorSelector, t_min_ns: int,
-                        t_max_ns: int, db: str, want_encoded: bool = False,
+                        t_max_ns: int, db: str,
                         windows: int | None = None):
-        """-> run-encoded (labels list, t_ms_all, v_all, lens[, enc]):
+        """-> run-encoded (labels list, t_ms_all, v_all, lens):
         one concatenated (times, values) pair with per-series lengths,
         ready for prepare_matrix_runs' flat scatter / the tiled prepare —
         no per-series matrix fill loop downstream.
-
-        ``want_encoded=True`` (the traced tiled path with device decode
-        active) additionally tries to keep the value column in its
-        on-disk encoded blocks: when the whole match resolves to
-        untrimmed all-valid slices of ONE still-encoded bulk column, the
-        5th return is (ftype, blocks, segments, slices) and v_all is None — the
-        device decodes (ops/device_decode.decode_rows_matrix); any
-        cross-shard merge, partial validity, or decoded column falls
-        back to returning the values eagerly, exactly as before.
 
         Three spans under the caller's `prom_collect`, each opened once a
         shard (they sum by name): `prom_match` (the range's shards, then
@@ -471,15 +409,7 @@ class PromEngine:
                     if col is None or len(rec) == 0:
                         continue
                     times_ms = rec.times // MS
-                    # keep a still-encoded column encoded: per-series slices
-                    # become (col, lo, hi) markers resolved at assembly; any
-                    # partial-validity slice decodes the whole column (lazy
-                    # .values — the bit-identical host path)
-                    enc_col = (col if want_encoded
-                               and getattr(col, "is_decoded", True) is False
-                               else None)
-                    vals64 = (None if enc_col is not None
-                              else col.values.astype(np.float64))
+                    vals64 = col.values.astype(np.float64)
                     uniq, starts = np.unique(sid_arr, return_index=True)
                     ends = np.append(starts[1:], len(sid_arr))
                     if hasattr(sh.index, "entries_bulk"):
@@ -495,12 +425,6 @@ class PromEngine:
                         m = col.valid[lo:hi]
                         if not m.any():
                             continue
-                        if enc_col is not None and m.all():
-                            add(dict(entry[1]), times_ms[lo:hi],
-                                _EncSlice(enc_col, int(lo), int(hi)))
-                            continue
-                        if vals64 is None:
-                            vals64 = col.values.astype(np.float64)
                         add(dict(entry[1]), times_ms[lo:hi][m],
                             vals64[lo:hi][m])
             else:
@@ -522,7 +446,7 @@ class PromEngine:
         with tracing.span("prom_assemble"):
             out_labels: list[dict] = []
             t_parts: list[np.ndarray] = []
-            v_parts: list = []
+            v_parts: list[np.ndarray] = []
             lens: list[int] = []
             for key in sorted(per_key):
                 tags, parts = per_key[key]
@@ -530,8 +454,7 @@ class PromEngine:
                     t, v = parts[0]
                 else:
                     t = np.concatenate([p[0] for p in parts])
-                    v = np.concatenate([_materialize_slice(p[1])
-                                        for p in parts])
+                    v = np.concatenate([p[1] for p in parts])
                     order = np.argsort(t, kind="stable")
                     t, v = t[order], v[order]
                 labels = dict(tags)
@@ -543,23 +466,14 @@ class PromEngine:
             t_ms_all = (np.concatenate(t_parts) if t_parts
                         else np.empty(0, np.int64)).astype(
                             np.int64, copy=False)
-            enc = None
-            if want_encoded and v_parts:
-                enc = _assemble_enc(v_parts)
-            if enc is not None:
-                v_all = None
-            else:
-                v_all = (np.concatenate(
-                    [_materialize_slice(v) for v in v_parts]) if v_parts
-                    else np.empty(0, np.float64))
+            v_all = (np.concatenate(v_parts) if v_parts
+                     else np.empty(0, np.float64))
         lens = np.asarray(lens, np.int64)
         if windows is not None and lens.size:
             # parts before the merge by key: more than there are series
             # where a series spans shards
             _count_collected(
                 lens, sum(len(p) for _tags, p in per_key.values()), windows)
-        if want_encoded:
-            return out_labels, t_ms_all, v_all, lens, enc
         return out_labels, t_ms_all, v_all, lens
 
     def _eval_selector(self, vs, steps, db, window_s, instant):
@@ -884,7 +798,6 @@ class PromEngine:
             eval_times = steps - ms_sel.offset_s
             labels, t_ms_all, v_all, lens = self._subquery_samples(
                 ms_sel, steps, db)
-            enc = None
         else:
             vs = ms_sel.vector
             w = ms_sel.range_s
@@ -892,21 +805,17 @@ class PromEngine:
             t_max_ns = int(eval_times[-1] * 1e9) + 1
             t_min_ns = int((eval_times[0] - w) * 1e9)
             with tracing.span("prom_collect"):
-                got = self._collect_series(
-                    vs, t_min_ns, t_max_ns, db,
-                    want_encoded=_want_encoded(), windows=len(steps))
-                labels, t_ms_all, v_all, lens = got[:4]
-                enc = got[4] if len(got) > 4 else None
+                labels, t_ms_all, v_all, lens = self._collect_series(
+                    vs, t_min_ns, t_max_ns, db, windows=len(steps))
         k = len(steps)
         if not labels:
             return Frame([], np.zeros((0, k)), np.zeros((0, k), bool))
         out, valid = self._run_range_kernel(
-            spec, t_ms_all, v_all, lens, eval_times, float(w), enc=enc)
+            spec, t_ms_all, v_all, lens, eval_times, float(w))
         labels = [_drop_name(l) for l in labels]
         return Frame(labels, out, valid)
 
-    def _tiled_prep(self, spec, t_ms_all, v_all, lens, eval_times, w,
-                    enc=None):
+    def _tiled_prep(self, spec, t_ms_all, v_all, lens, eval_times, w):
         """TiledPrepared for this (samples, window grid) pair, or None
         when the spec or the grid is ineligible (dense fallback)."""
         kind = spec["kind"]
@@ -936,8 +845,7 @@ class PromEngine:
             lane_q = lane_quantum()
         return promops.prepare_tiled(
             plan, t_ms_all, v_all, lens, dtype=np.float64,
-            max_gather_cols=cells * n_max + 64, lane_quantum=lane_q,
-            enc=enc)
+            max_gather_cols=cells * n_max + 64, lane_quantum=lane_q)
 
     def _run_mesh_kernel(self, spec, kind, prep, mesh):
         """Multi-chip tiled kernels: series axis sharded over the mesh,
@@ -1014,21 +922,14 @@ class PromEngine:
             return icept + slope * spec["dur"], valid
         return prep.over_time(xp, func=spec["func"])
 
-    def _run_range_kernel(self, spec, t_ms_all, v_all, lens, eval_times,
-                          w, enc=None):
+    def _run_range_kernel(self, spec, t_ms_all, v_all, lens, eval_times, w):
         """Dispatch one range-vector spec: tiled interval reductions when
         the window grid fits the ms tile lattice, dense kernels otherwise.
         Returns host numpy (out, valid)."""
         kind = spec["kind"]
         with tracing.span("prom_prepare"):
             prep = self._tiled_prep(spec, t_ms_all, v_all, lens,
-                                    eval_times, w, enc=enc)
-        if prep is None and v_all is None:
-            # dense fallback needs host values: materialize the encoded
-            # descriptor (bit-identical host decode)
-            from opengemini_tpu.ops import device_decode
-
-            v_all = device_decode.materialize_enc(enc)
+                                    eval_times, w)
         mesh = _mesh_for_tiled() if prep is not None else None
         if prep is not None:
             # route through the offload planner (query/offload.py): the

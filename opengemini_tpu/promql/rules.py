@@ -827,9 +827,8 @@ class RuleManager:
         """(labels, t_ms, v, lens) for the selector over (lo_ms, hi_ms]
         — the engine's run-encoded collection (bulk decode + label-tier
         matcher probes)."""
-        got = self.prom._collect_series(
+        return self.prom._collect_series(
             sel.vs, lo_ms * MS_NS + 1, hi_ms * MS_NS + 1, db)
-        return got[:4]
 
     def _fold_tiles(self, g: RuleGroup, sel: _SelState,
                     tiles: set[int]) -> None:

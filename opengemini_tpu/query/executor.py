@@ -33,8 +33,7 @@ from opengemini_tpu.ops import window as winmod
 from opengemini_tpu.query import condition as cond
 from opengemini_tpu.query import functions as fnmod
 from opengemini_tpu.query import render as qrender
-from opengemini_tpu.record import (EncodedColumn, FieldType,
-                                   FieldTypeConflict)
+from opengemini_tpu.record import FieldType, FieldTypeConflict
 from opengemini_tpu.sql import ast
 from opengemini_tpu.storage import colcache as colcache_mod
 from opengemini_tpu.storage import scanpool
@@ -261,13 +260,6 @@ class _ScanStager:
                 vals = col.values  # int64 end-to-end, no float cast
             elif col.ftype == FieldType.STRING:
                 vals = None  # count-only payload: zeros at flush
-            elif (isinstance(col, EncodedColumn)
-                    and hasattr(batch, "add_encoded")):
-                # still-attached raw blocks (record.EncodedColumn, decoded
-                # or not): keep the view — flush composes one encoded
-                # column per field so the grid freeze's offload planner
-                # (query/offload.py) decides host-vs-device per query
-                vals = col
             else:
                 vals = col.values  # cast once per flush, not per record
             self._per_field[fname].append((ri, vals, m))
@@ -300,30 +292,12 @@ class _ScanStager:
             else:
                 times, seg, sids, rel, bounds = self._gather(rec_idx)
             mask = np.concatenate([e[2] for e in entries])
-            if all(isinstance(v, EncodedColumn) for _ri, v, _m in entries):
-                # every record kept its raw blocks: compose ONE encoded
-                # row-run view for the whole flush and hand it to the
-                # batch's encoded path — the freeze's offload planner
-                # routes it, and any host fallback decodes through the
-                # shared roots (bit-identical).  A composition overflow
-                # (run cap) drops to the copying path below.
-                merged = entries[0][1]
-                for _ri, v, _m in entries[1:]:
-                    merged = merged.concat(v)
-                    if not isinstance(merged, EncodedColumn):
-                        break
-                if isinstance(merged, EncodedColumn):
-                    batch.add_encoded(merged, rel, seg, mask, times,
-                                      sids=sids, boundaries=bounds)
-                    self._per_field[fname] = []
-                    continue
             # value payloads dispatch PER RECORD, exactly like the serial
             # _add_record_to_batches: a field may be numeric in one shard
             # and string (None marker -> zero payload) in another
             parts = [
                 np.zeros(len(self._recs[ri][0]), dtype=self.dtype)
-                if v is None
-                else (v.values if isinstance(v, EncodedColumn) else v)
+                if v is None else v
                 for ri, v, _m in entries
             ]
             vals = parts[0] if len(parts) == 1 else np.concatenate(parts)

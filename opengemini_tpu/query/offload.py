@@ -2,13 +2,12 @@
 per-stage cost model.
 
 Every host-vs-device choice in the query path used to be a hand-tuned
-static gate: the device-decode transfer gates (ops/device_decode.py),
-the `OGT_PROM_HOST_KERNELS` env read, the CPU host-numpy shortcut, the
-mesh-overrides.  The GPU-augmented OLAP literature (arXiv:2601.19911)
-makes offload a PLANNER decision fed by measured kernel and transfer
-costs; TiLT (arXiv:2301.12030) amortizes compile cost over observed
-query-shape recurrence.  PR 13's devobs tier already measures
-everything the model needs — compile wall per (kernel, geometry),
+static gate: the `OGT_PROM_HOST_KERNELS` env read, the CPU host-numpy
+shortcut, the mesh-overrides.  The GPU-augmented OLAP literature
+(arXiv:2601.19911) makes offload a PLANNER decision fed by measured
+kernel and transfer costs; TiLT (arXiv:2301.12030) amortizes compile
+cost over observed query-shape recurrence.  PR 13's devobs tier already
+measures everything the model needs — compile wall per (kernel, geometry),
 per-site transfer throughput histograms, warm exec walls, recurrence
 hit counts — so this module closes the loop:
 
@@ -16,8 +15,8 @@ hit counts — so this module closes the loop:
       candidate route (host / device / mesh): sample count, the cold
       first-run wall (carries the compile), and a warm EWMA.  Routes
       without measurements estimate from priors where the call site can
-      supply them — byte volumes at the measured `device-decode` H2D
-      throughput (falling back to a fixed default, which reduces the
+      supply them — byte volumes at the measured H2D throughput
+      (falling back to a fixed default, which reduces the
       comparison to the exact pre-planner byte inequality) — and stay
       un-estimable otherwise.
 
@@ -105,7 +104,7 @@ _PROM_HOST_KERNELS = os.environ.get("OGT_PROM_HOST_KERNELS", "")
 
 # forced route for A/B work (forced-all-host vs
 # forced-all-device): decide() answers this route whenever it is a
-# candidate, and gate_prior() stands aside for it
+# candidate
 _FORCE = os.environ.get("OGT_OFFLOAD_FORCE", "") or None
 
 # model-state bound: past this many live (kernel, geometry) records the
@@ -325,7 +324,7 @@ class Planner:
         gate's choice and is returned verbatim whenever the planner is
         off, the model is cold, or the estimates tie — the bit-identity
         contract.  `bytes_hint` maps routes to their transfer byte
-        volume when the call site knows it (the decode gates), giving
+        volume when the call site knows it, giving
         unmeasured routes a throughput-based prior estimate."""
         if _FORCE is not None and _FORCE in candidates:
             _STATS.incr("offload", "forced_total")
@@ -469,29 +468,6 @@ class Planner:
 
         _TRACKER.note_route(_TRACKER.current_qid(), stage, route)
 
-    # -- the static decode gates, as zero-sample priors ------------------
-
-    def gate_prior(self, kernel: str, geometry, device_bytes: int,
-                   host_bytes: int, route: str = "device") -> bool:
-        """The device-decode cost gates, subsumed: with NO measured
-        samples for `route` on this (kernel, geometry) this is EXACTLY
-        the pre-planner byte inequality (ship encoded iff the encoded
-        transfer undercuts the decoded buffer it replaces).  Once the
-        route has real wall samples, decide() owns the choice and the
-        byte rule stops second-guessing it — one mechanism, not two."""
-        if _FORCE == route:
-            return True
-        if _ON:
-            with self._lock:
-                g = self._geo.get((kernel, geo_key(geometry)))
-                r = g["routes"].get(route) if g is not None else None
-                if r is not None and r.count >= 1:
-                    return True
-        ok = int(device_bytes) < int(host_bytes)
-        if not ok:
-            _STATS.incr("offload", "gate_vetoes_total")
-        return ok
-
     # -- introspection ---------------------------------------------------
 
     def decisions(self) -> list[dict]:
@@ -555,9 +531,8 @@ def _measured_throughput() -> float:
 def _compile_estimate_s(kernel: str) -> float:
     """Predicted first-compile wall for a kernel family, from the devobs
     inventory's measured walls (prefix match: the planner's
-    `grid_decode` label covers the `grid_decode_fused` /
-    `grid_decode_imat` compile sites).  0.0 with no data — recurrence
-    alone gates exploration then."""
+    `prom_rate` label covers the `prom_rate*` compile sites).  0.0 with
+    no data — recurrence alone gates exploration then."""
     if not kernel:
         return 0.0
     from opengemini_tpu.utils import devobs

@@ -141,19 +141,6 @@ def _add_record_to_batches(rec, seg, aligned, needed_fields, batches, dtype,
         m = col.valid
         if fmask is not None:
             m = m & fmask
-        if (getattr(col, "blocks", None) is not None
-                and hasattr(batch, "add_encoded")):
-            # record.EncodedColumn into a device-decode-capable batch:
-            # keep the raw block payloads attached — the grid freeze can
-            # ship them to the accelerator and decode fused with the
-            # window reduce (ops/device_decode.py).  A column that is
-            # ALREADY decoded (colcache host-tier hit, or a row filter
-            # touched it) still rides this path: the offload planner
-            # (query/offload.py) decides host-vs-device per repeat, and
-            # host consumers read the memoized values through
-            # _EncodedVals.__array__ — bit-identical either way.
-            batch.add_encoded(col, rel, seg, m, rec.times, sids=sids)
-            continue
         if isinstance(batch, ragged.IntExactBatch):
             vals = col.values  # int64 end-to-end, no float cast
         elif col.ftype == FieldType.STRING:

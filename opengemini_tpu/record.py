@@ -12,7 +12,6 @@ before transfer.
 
 from __future__ import annotations
 
-import bisect
 import enum
 from dataclasses import dataclass, field
 
@@ -92,189 +91,6 @@ class Column:
         )
 
 
-class EncodedColumn(Column):
-    """Column whose values are still in their on-disk encoded blocks
-    (storage/encoding.py device-profile raw envelopes).
-
-    `.values` decodes lazily on the host — bit-identical to an eager
-    decode and memoized, so every existing consumer works unchanged.
-    Device-decode-aware consumers (models/grid.py GridBatch via
-    ops/device_decode.py) take `.blocks` — the raw self-describing block
-    buffers — and ship the encoded payloads to the accelerator instead.
-    `valid` is always a real (eagerly decoded) array: masks are tiny.
-
-    The column VIEW may be a row subset of the blocks' decoded
-    concatenation: `segments` is a (k, 2) int64 array of absolute
-    [lo, hi) row runs (None = the whole concatenation of `n_full`
-    rows).  A strictly-increasing take() — every time-range trim, sid
-    filter, and dedup keep over sorted rows — stays ENCODED by
-    composing run lists; anything else decodes, bit-identically.  The
-    device decoder replays the same runs after decoding whole blocks.
-
-    The column is immutable by the read-path contract like any cached
-    decoded column; the lazy decode is idempotent, so concurrent first
-    touches converge on identical arrays."""
-
-    # past this many row runs the per-run bookkeeping stops paying for
-    # itself; take() then just decodes
-    _SEG_CAP = 4096
-
-    def __init__(self, ftype: FieldType, blocks, valid: np.ndarray, decode,
-                 segments: np.ndarray | None = None,
-                 n_full: int | None = None):
-        self.ftype = ftype
-        self.blocks = list(blocks)
-        self.valid = valid
-        self.segments = segments
-        self.n_full = len(valid) if n_full is None else int(n_full)
-        self._decode = decode  # (ftype, blocks) -> np.ndarray host decode
-        self._values: np.ndarray | None = None
-        # provenance of this view's block concatenation as
-        # [(root_column, abs_row_offset)] — the FULL-view columns
-        # (segments None, typically colcache-resident chunk columns)
-        # whose decodes concatenate to exactly this view's blocks.
-        # Host decodes route through each root's memoized .values, so N
-        # views/merges over one cached chunk column cost ONE block
-        # decode process-wide, not N.  None = decode own blocks directly.
-        self._spans: list | None = None
-
-    @property
-    def is_decoded(self) -> bool:
-        return self._values is not None
-
-    def _spans_or_self(self) -> list | None:
-        """This column as root spans, or None when it has no root
-        provenance (a standalone segmented view decodes its own
-        blocks)."""
-        if self._spans is not None:
-            return self._spans
-        if self.segments is None:
-            return [(self, 0)]
-        return None
-
-    @property
-    def values(self) -> np.ndarray:  # type: ignore[override]
-        v = self._values
-        if v is None:
-            spans = self._spans
-            if spans is not None:
-                # slice each [lo, hi) run out of its root's memoized
-                # full decode (runs merged across a root boundary by
-                # take() split back here) — one decode per root ever
-                offs = [off for _r, off in spans] + [self.n_full]
-                pieces = []
-                for a, b in self.abs_segments():
-                    j = bisect.bisect_right(offs, a) - 1
-                    while a < b:
-                        root, off = spans[j]
-                        hi = min(b, offs[j + 1])
-                        pieces.append(root.values[a - off:hi - off])
-                        a = hi
-                        j += 1
-                v = (np.concatenate(pieces) if pieces
-                     else np.empty(0, self.ftype.np_dtype))
-            else:
-                d = self._decode(self.ftype, self.blocks)
-                if self.segments is not None:
-                    d = (np.concatenate([d[a:b] for a, b in self.segments])
-                         if len(self.segments) else d[:0])
-                v = d
-            self._values = v
-        return v
-
-    def __len__(self) -> int:
-        return len(self.valid)
-
-    def accounted_nbytes(self) -> int:
-        """Cache-budget accounting WITHOUT firing the lazy decode:
-        decoded width (8 bytes/value — only numeric ftypes are ever
-        encoded) plus the retained encoded payload, since both stay
-        live once a host consumer memoizes `.values`.  The single rule
-        both column caches (storage/colcache.py, storage/tsf.py)
-        charge by."""
-        return (len(self) * 8 + int(self.valid.nbytes)
-                + sum(len(b) for b in self.blocks))
-
-    def abs_segments(self) -> np.ndarray:
-        """The view's absolute [lo, hi) runs over the decoded block
-        concatenation ((k, 2) int64; identity view = one full run)."""
-        if self.segments is not None:
-            return self.segments
-        return np.array([[0, self.n_full]], np.int64)
-
-    def _abs_index(self) -> np.ndarray:
-        """Absolute row index per view row."""
-        segs = self.abs_segments()
-        return (np.concatenate([np.arange(a, b) for a, b in segs])
-                if len(segs) else np.empty(0, np.int64))
-
-    def take(self, idx: np.ndarray) -> "Column":
-        idx = np.asarray(idx)
-        if len(idx) == 0:
-            return Column(self.ftype,
-                          np.empty(0, dtype=self.ftype.np_dtype),
-                          np.empty(0, dtype=np.bool_))
-        if len(idx) > 1 and (np.diff(idx) <= 0).any():
-            return super().take(idx)
-        abs_idx = self._abs_index()[idx]
-        brk = np.flatnonzero(np.diff(abs_idx) != 1)
-        if len(brk) + 1 > self._SEG_CAP:
-            return super().take(idx)
-        lo = np.concatenate([abs_idx[:1], abs_idx[brk + 1]])
-        hi = np.concatenate([abs_idx[brk], abs_idx[-1:]]) + 1
-        out = EncodedColumn(
-            self.ftype, self.blocks, self.valid[idx], self._decode,
-            segments=np.stack([lo, hi], axis=1), n_full=self.n_full)
-        out._spans = self._spans_or_self()
-        if self._values is not None:
-            # already decoded (e.g. a colcache host-tier hit): keep the
-            # blocks attached — the device route stays available for a
-            # warm repeat — and carry the row subset of the memoized
-            # view so no host consumer ever re-decodes
-            out._values = self._values[idx]
-        return out
-
-    def concat(self, other: "Column") -> "Column":
-        if (isinstance(other, EncodedColumn)
-                and self.ftype == other.ftype):
-            out = EncodedColumn.join([self, other])
-            if out is not None:
-                return out
-        return super().concat(other)
-
-    @staticmethod
-    def join(cols: list["EncodedColumn"]) -> "EncodedColumn | None":
-        """The columns' views end to end as ONE still-encoded column,
-        every array built once however many parts there are: the blocks
-        in order, each view's runs shifted by the rows before it, the
-        root spans where every part knows its roots, and the memoized
-        values where every part is already decoded (mixed decode states
-        stay lazy, bit-identical).  None past `_SEG_CAP` runs: the
-        caller then joins decoded values."""
-        segs, offs, off = [], [], 0
-        for c in cols:
-            segs.append(c.abs_segments() + off)
-            offs.append(off)
-            off += c.n_full
-        segs = np.concatenate(segs)
-        if len(segs) > EncodedColumn._SEG_CAP:
-            return None
-        first = cols[0]
-        out = EncodedColumn(
-            first.ftype, [b for c in cols for b in c.blocks],
-            np.concatenate([c.valid for c in cols]),
-            first._decode, segments=segs, n_full=off)
-        spans = [c._spans_or_self() for c in cols]
-        if all(s is not None for s in spans):
-            out._spans = [(r, o + at) for s, at in zip(spans, offs)
-                          for r, o in s]
-        if all(c._values is not None for c in cols):
-            # every side already decoded: carry the memoized views
-            # forward so no host consumer re-decodes
-            out._values = np.concatenate([c._values for c in cols])
-        return out
-
-
 @dataclass
 class Record:
     """A batch of rows for one series (or one measurement slice): a time
@@ -328,8 +144,7 @@ class Record:
                 len(self) <= 1 or not (self.times[1:] < self.times[:-1]).any()):
             # already ascending (every TSF chunk, most merged reads):
             # records are immutable on the read path, so the identity
-            # return is safe — and it keeps lazily-encoded columns
-            # (EncodedColumn) intact for the device-decode path
+            # return is safe
             return self
         order = np.argsort(self.times, kind="stable")
         if descending:
@@ -432,7 +247,7 @@ class FieldTypeConflict(Exception):
 def _join_plain(ftype: FieldType, cols: list, lens: list[int],
                 dest: np.ndarray | None = None) -> Column:
     """The parts' columns (None where a part lacks the column) end to
-    end as decoded values — or, given `dest`, every row of that
+    end — or, given `dest`, every row of that
     concatenation where `dest` puts it (`_interleave`): the output
     allocated once, each part copied into its place once.  Zero-init,
     not np.empty: a slot no part fills stays invalid, but its value
@@ -452,22 +267,9 @@ def _join_plain(ftype: FieldType, cols: list, lens: list[int],
 
 
 def _join_column(ftype: FieldType, cols: list, lens: list[int]) -> Column:
-    """One output column over the parts, built in one pass.  Where every
-    part carries it as an EncodedColumn the join stays ENCODED
-    (EncodedColumn.join): still-encoded parts never materialize decoded
-    bytes on the host (the device-decode cold path,
-    ops/device_decode.py), already-decoded ones (colcache host-tier
-    hits) carry their memoized values forward with the raw blocks still
-    attached, so the offload planner (query/offload.py) keeps the device
-    route on every repeat.  Any absence or run-cap overflow joins
-    decoded values instead, bit-identically."""
+    """One output column over the parts, built in one pass."""
     if len(cols) == 1 and cols[0] is not None:
         return cols[0]      # one part: its own arrays, no copy
-    if cols and all(
-            isinstance(c, EncodedColumn) and c.ftype == ftype for c in cols):
-        enc = EncodedColumn.join(cols)
-        if enc is not None:
-            return enc
     return _join_plain(ftype, cols, lens)
 
 
@@ -483,8 +285,7 @@ def _trim_part(s: np.ndarray, r: Record, lo_t: int, hi_t: int):
     leaves it sorted) and copied once, as views where the rows kept are
     one run (a single-series chunk).  Parts wholly outside never come
     this far where the reader's time pruning could tell: a chunk is
-    skipped by its own tmin/tmax.  EncodedColumns trim through their
-    own take(), so they stay encoded up to `_SEG_CAP` runs."""
+    skipped by its own tmin/tmax."""
     t = r.times
     if t.min() >= lo_t and t.max() < hi_t:
         return s, r
@@ -492,8 +293,7 @@ def _trim_part(s: np.ndarray, r: Record, lo_t: int, hi_t: int):
     if len(idx) and idx[-1] - idx[0] + 1 == len(idx):
         lo, hi = int(idx[0]), int(idx[-1]) + 1
         return s[lo:hi], Record(t[lo:hi], {
-            k: (c.take(idx) if isinstance(c, EncodedColumn)
-                else Column(c.ftype, c.values[lo:hi], c.valid[lo:hi]))
+            k: Column(c.ftype, c.values[lo:hi], c.valid[lo:hi])
             for k, c in r.columns.items()})
     return s[idx], r.take(idx)
 
@@ -621,12 +421,10 @@ def merge_bulk_parts(
       of a group is its newest row, and no rank array is needed).
 
     All but the last build every output column once: the first two join
-    the parts (`_join_column`), keeping still-encoded columns ENCODED;
-    `interleaved` writes each part to its places (`_join_plain` with
-    `dest`) and, like the general merge, materializes encoded columns on
-    the host.  `told`, where given, is filled with `branch` (one of the four
-    names) and `rows` (rows that entered the concatenation or sort,
-    after the trim)."""
+    the parts (`_join_column`); `interleaved` writes each part to its
+    places (`_join_plain` with `dest`).  `told`, where given, is filled
+    with `branch` (one of the four names) and `rows` (rows that entered
+    the concatenation or sort, after the trim)."""
     ftypes: dict[str, FieldType] = {}
     live = []
     for s, r in parts:

@@ -88,10 +88,6 @@ def _nbytes(val) -> int:
     """Decoded size of a cached value: a Record Column or a bare array.
     Mirrors TSFReader._val_nbytes so both caches account alike (object
     dtype — strings — estimates 64 bytes/element)."""
-    if getattr(val, "is_decoded", True) is False:
-        # still-encoded numeric column (record.EncodedColumn): one
-        # shared accounting rule, never firing the lazy decode
-        return val.accounted_nbytes()
     vals = getattr(val, "values", None)
     if vals is not None:  # Column
         if getattr(vals, "dtype", None) is not None and vals.dtype == object:

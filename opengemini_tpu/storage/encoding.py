@@ -17,8 +17,6 @@ import zlib
 
 import numpy as np
 
-import os
-
 from opengemini_tpu import native
 from opengemini_tpu.record import Column, FieldType
 
@@ -32,110 +30,16 @@ _T_GORILLA = 5  # float64 XOR-compressed (native C++ codec, py-decodable)
 _T_VARINT = 6  # int64 delta+zigzag varint (native C++ codec, py-decodable)
 _T_STRDICT = 7  # dictionary-coded strings: uniq table + min-width indices
 
-# device-profile flag bit on the tag byte: the payload is stored in its
-# RAW envelope (no zlib), so an accelerator kernel can decode the block
-# without a host round-trip (ops/device_decode.py).  Only _T_DELTA and
-# _T_RAW64 carry the flag (fixed-width FOR deltas and raw LE floats are
-# the device-decodable shapes); _T_CONST is device-decodable as-is (pure
-# header, an iota on device), and _T_GORILLA/_T_VARINT/_T_STRDICT are
-# device-decodable in their ordinary envelopes (the bit/byte streams ARE
-# the device payload; strdict additionally keeps its uniq table on the
-# host).  Written only under OGT_DEVICE_PROFILE=1; readers decode
-# flagged blocks unconditionally, so profile-written files stay readable
-# everywhere and legacy files are untouched.
+# flag bit on the tag byte of _T_DELTA and _T_RAW64: the payload is in
+# its raw envelope (no zlib).  No writer in the tree sets it; files
+# written under an earlier writer profile carry it, and the decoders read
+# flagged blocks unconditionally so that those files stay readable
+# (tests/test_golden_blocks.py holds their bytes).
 _DEV_FLAG = 0x80
 
 _ZLEVEL = 1
 
 _DELTA_HEAD = struct.calcsize("<BIqqB")
-
-
-def device_profile() -> bool:
-    """Writer-side device profile (OGT_DEVICE_PROFILE=1, README "Decode
-    on device"): int/float blocks stay in device-decodable envelopes so
-    cold scans can ship the encoded bytes straight to the accelerator.
-    Ints choose raw-envelope FOR vs native varint, floats gorilla vs raw
-    LE — all four shapes decode on device; the only codec the profile
-    forgoes is zlib (host-only)."""
-    return os.environ.get("OGT_DEVICE_PROFILE", "0") not in ("", "0")
-
-
-class DeviceBlock:
-    """Device-decodable view of one encoded block: the raw payload bytes
-    plus the scalar header the decode kernels need (ops/device_decode.py
-    builds its fused programs from these).  `kind` is one of:
-
-      const    int64 arithmetic run: first + step * iota(n); no payload
-      delta    int64 FOR deltas: out[0]=first, out[i]=first +
-               cumsum(widen(payload, width) + step); payload (n-1)*width
-      raw64    float64 raw LE values; payload n*8
-      gorilla  float64 XOR bit stream (the native codec's wire format);
-               `width` is the payload byte length (variable per block, so
-               the program signature carries it); decoded by a host
-               structural scan (control bits) + device bit-gather/XOR-scan
-      varint   int64 delta+zigzag LEB128 byte stream; `width` is the
-               payload byte length
-      strdict  dictionary-coded string indices: payload is the raw
-               min-width index array (width bytes each), `table` keeps
-               the uniq strings host-side for label work
-    """
-
-    __slots__ = ("kind", "n", "first", "step", "width", "payload", "table",
-                 "aux")
-
-    def __init__(self, kind, n, first=0, step=0, width=0, payload=b"",
-                 table=None, aux=None):
-        self.kind = kind
-        self.n = n
-        self.first = first
-        self.step = step
-        self.width = width
-        self.payload = payload
-        self.table = table
-        # Precomputed per-value structural scan for mid-stream slices of
-        # stateful codecs (gorilla control bits): (bitpos, mbits, shift)
-        # arrays rebased to this block's payload.  None for whole blocks.
-        self.aux = aux
-
-
-def device_block(buf: bytes) -> DeviceBlock | None:
-    """Classify one self-describing block: a DeviceBlock when its values
-    can be decoded on the accelerator, None when only the host decoders
-    apply (zlib/gorilla/varint/bool/string payloads)."""
-    tag = buf[0]
-    if tag == _T_CONST:
-        _, n, first, stride = struct.unpack_from("<BIqq", buf)
-        return DeviceBlock("const", n, first, stride)
-    if tag == (_T_DELTA | _DEV_FLAG):
-        (n,) = struct.unpack_from("<I", buf, 1)
-        if n == 0:
-            return DeviceBlock("const", 0)
-        first, dmin, width = struct.unpack_from("<qqB", buf, 5)
-        return DeviceBlock("delta", n, first, dmin, width,
-                           buf[_DELTA_HEAD:])
-    if tag == (_T_RAW64 | _DEV_FLAG):
-        (n,) = struct.unpack_from("<I", buf, 1)
-        return DeviceBlock("raw64", n, payload=buf[5:])
-    if tag == _T_GORILLA:
-        (n,) = struct.unpack_from("<I", buf, 1)
-        payload = buf[5:]
-        return DeviceBlock("gorilla", n, width=len(payload), payload=payload)
-    if tag == _T_VARINT:
-        (n,) = struct.unpack_from("<I", buf, 1)
-        payload = buf[5:]
-        return DeviceBlock("varint", n, width=len(payload), payload=payload)
-    if tag == _T_STRDICT:
-        n, k, width = struct.unpack_from("<IIB", buf, 1)
-        payload = zlib.decompress(buf[10:])
-        uoff = np.frombuffer(payload[: 4 * (k + 1)], dtype=np.uint32)
-        blob_end = 4 * (k + 1) + int(uoff[-1])
-        blob = payload[4 * (k + 1):blob_end]
-        table = tuple(
-            blob[uoff[i]:uoff[i + 1]].decode("utf-8") for i in range(k))
-        indices = payload[blob_end:blob_end + n * width]
-        return DeviceBlock("strdict", n, width=width, payload=indices,
-                           table=table)
-    return None
 
 
 def encode_ints(values: np.ndarray) -> bytes:
@@ -155,16 +59,6 @@ def encode_ints(values: np.ndarray) -> bytes:
     shifted = (deltas - dmin).astype(np.uint64)
     width = _min_width(int(shifted.max()))
     packed = shifted.astype({1: np.uint8, 2: np.uint16, 4: np.uint32, 8: np.uint64}[width])
-    if device_profile():
-        # device-decodable either way: raw-envelope FOR vs native varint
-        # (both ship encoded to the accelerator; keep the smaller block)
-        raw_block = struct.pack(
-            "<BIqqB", _T_DELTA | _DEV_FLAG, n,
-            int(values[0]), int(dmin), width) + packed.tobytes()
-        nv = native.varint_delta_encode(values)
-        if nv is not None and 5 + len(nv) < len(raw_block):
-            return struct.pack("<BI", _T_VARINT, n) + nv
-        return raw_block
     payload = zlib.compress(packed.tobytes(), _ZLEVEL)
     head = struct.pack("<BIqqB", _T_DELTA, n, int(values[0]), int(dmin), width)
     for_block = head + payload
@@ -206,14 +100,6 @@ def encode_floats(values: np.ndarray) -> bytes:
     """Adaptive: gorilla XOR (native) vs zlib — keep the smaller block
     (the reference's lib/encoding float.go also chooses per block)."""
     values = np.ascontiguousarray(values, dtype=np.float64)
-    if device_profile():
-        # device-decodable either way: gorilla XOR bit stream vs raw LE
-        # (both ship encoded to the accelerator; keep the smaller block)
-        g = native.gorilla_encode(values)
-        if g is not None and len(g) < 8 * len(values):
-            return struct.pack("<BI", _T_GORILLA, len(values)) + g
-        return struct.pack("<BI", _T_RAW64 | _DEV_FLAG, len(values)) \
-            + values.tobytes()
     z = zlib.compress(values.tobytes(), _ZLEVEL)
     g = native.gorilla_encode(values)
     if g is not None and len(g) < len(z):
@@ -301,18 +187,6 @@ def decode_strings(buf: bytes) -> np.ndarray:
     for i in range(n):
         out[i] = blob[offsets[i] : offsets[i + 1]].decode("utf-8")
     return out
-
-
-def decode_value_blocks(ftype: FieldType, blocks) -> np.ndarray:
-    """Host decode of one or more self-describing value blocks into a
-    single array — the lazy fallback behind record.EncodedColumn (and
-    the oracle the device decoder is bit-identical to)."""
-    dec = _DECODERS[ftype]
-    if len(blocks) == 1:
-        return dec(blocks[0])
-    if not blocks:
-        return np.empty(0, dtype=ftype.np_dtype)
-    return np.concatenate([dec(b) for b in blocks])
 
 
 def encode_mask(valid: np.ndarray) -> bytes:

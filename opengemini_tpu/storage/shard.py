@@ -1602,21 +1602,11 @@ class Shard:
         chunks = [(r, c) for r in files
                   for c in r.chunks(measurement, {sid}, tmin, tmax)]
         n_fields = len(fields) if fields is not None else None
-        # same deferred-decode contract as read_series_bulk: eligible
-        # value blocks come back as still-encoded EncodedColumns so the
-        # grid freeze's offload planner (query/offload.py) keeps the
-        # device route available; every host consumer decodes lazily,
-        # bit-identically
-        from opengemini_tpu.ops import device_decode as _devdec
-
-        encoded_ok = _devdec.active()
 
         def decode(r, c):
             if c.packed:
-                return r.read_packed_sid(measurement, c, sid, fields,
-                                         encoded_ok=encoded_ok)
-            return r.read_chunk(measurement, c, fields,
-                                encoded_ok=encoded_ok)
+                return r.read_packed_sid(measurement, c, sid, fields)
+            return r.read_chunk(measurement, c, fields)
 
         # decoded-column cache consult BEFORE pool dispatch
         # (storage/colcache.py): fully-cached chunks assemble inline and
@@ -1696,24 +1686,14 @@ class Shard:
         sid_set = set(int(s) for s in sids)
         files, mems = self._scan_state()
         n_fields = len(fields) if fields is not None else None
-        # device-decode bulk path (ops/device_decode.py): eligible value
-        # blocks come back as still-encoded EncodedColumns so the grid
-        # freeze can ship compressed payloads to the accelerator; any
-        # merge/filter/fallback that touches .values host-decodes them
-        # bit-identically
-        from opengemini_tpu.ops import device_decode as _devdec
-
-        encoded_ok = _devdec.active()
 
         def decode_packed(r, c):
             s_arr, rec = r.read_packed_bulk(
-                measurement, c, fields, sid_filter=sids,
-                encoded_ok=encoded_ok)
+                measurement, c, fields, sid_filter=sids)
             return (s_arr, rec) if len(rec) else None
 
         def decode_single(r, c):
-            rec = r.read_chunk(measurement, c, fields,
-                               encoded_ok=encoded_ok)
+            rec = r.read_chunk(measurement, c, fields)
             return (np.full(len(rec), c.sid, np.int64), rec)
 
         # chunk decodes fan out across the scan pool; map_ordered yields

@@ -45,7 +45,7 @@ from collections import OrderedDict
 
 import numpy as np
 
-from opengemini_tpu.record import Column, EncodedColumn, FieldType, Record
+from opengemini_tpu.record import Column, FieldType, Record
 from opengemini_tpu.storage import colcache, diskfault, encodepool, encoding
 from opengemini_tpu.utils import tracing
 from opengemini_tpu.utils.bloom import BloomFilter
@@ -545,10 +545,6 @@ class TSFReader:
 
     @staticmethod
     def _val_nbytes(val) -> int:
-        if getattr(val, "is_decoded", True) is False:
-            # still-encoded numeric column: one shared accounting rule
-            # (record.EncodedColumn), never firing the lazy decode
-            return val.accounted_nbytes()
         if isinstance(val, Column):
             return int(val.values.nbytes if hasattr(val.values, "nbytes")
                        else len(val.values) * 64) + int(val.valid.nbytes)
@@ -625,20 +621,9 @@ class TSFReader:
                     sum(self._val_nbytes(out[w[0]]) for w in todo))
         return out
 
-    @staticmethod
-    def _decode_field(ftype: FieldType, encoded_ok: bool,
-                      vbuf: bytes, mbuf: bytes):
-        if encoded_ok and ftype in (FieldType.FLOAT, FieldType.INT):
-            db = encoding.device_block(vbuf)
-            if db is not None:
-                return EncodedColumn(
-                    ftype, [vbuf], encoding.decode_mask(mbuf, db.n),
-                    encoding.decode_value_blocks)
-        return encoding.decode_column(ftype, vbuf, mbuf)
-
     def _chunk_columns(
         self, measurement: str, chunk: ChunkMeta, fields: list[str] | None,
-        cache: bool, encoded_ok: bool, with_sids: bool,
+        cache: bool, with_sids: bool,
     ) -> tuple[np.ndarray | None, Record]:
         """(sid column or None, record) of one chunk: one `_load_columns`
         for the times, the sids where asked and every field."""
@@ -650,7 +635,7 @@ class TSFReader:
             loc = chunk.cols.get(name)
             if loc is not None:
                 wanted.append((name, (loc["v"], loc["m"]), functools.partial(
-                    self._decode_field, schema[name], encoded_ok)))
+                    encoding.decode_column, schema[name])))
         cols = self._load_columns(chunk, wanted, cache)
         times = cols.pop(None)
         return cols.pop(_SIDS, None), Record(times, cols)
@@ -658,17 +643,9 @@ class TSFReader:
     def read_chunk(
         self, measurement: str, chunk: ChunkMeta,
         fields: list[str] | None = None, cache: bool = True,
-        encoded_ok: bool = False,
     ) -> Record:
-        """``encoded_ok=True`` (the device-decode bulk scan,
-        storage/shard.py read_series_bulk) returns numeric value columns
-        whose blocks are device-decodable as still-encoded
-        record.EncodedColumn — the CRC seal is verified here as always,
-        but the payload decode is deferred to the accelerator (or to the
-        column's lazy host fallback).  Times and masks always decode on
-        the host (they drive window/run planning)."""
         return self._chunk_columns(measurement, chunk, fields, cache,
-                                   encoded_ok, with_sids=False)[1]
+                                   with_sids=False)[1]
 
     def _chunk_from_cache(self, chunk: ChunkMeta,
                           fields: list[str] | None) -> Record | None:
@@ -733,41 +710,28 @@ class TSFReader:
 
     @staticmethod
     def _slice_rows(rec: Record, lo: int, hi: int) -> Record:
-        """Row window [lo, hi) of a chunk record.  Plain columns slice as
-        views; EncodedColumns compose an encoded row-run view instead —
-        keeping the raw blocks attached for the device-decode route while
-        any host consumer decodes ONCE through the shared root column
-        (record.EncodedColumn.take), bit-identically."""
-        cols = {}
-        for name, col in rec.columns.items():
-            if isinstance(col, EncodedColumn):
-                cols[name] = col.take(np.arange(lo, hi))
-            else:
-                cols[name] = Column(col.ftype, col.values[lo:hi],
-                                    col.valid[lo:hi])
+        """Row window [lo, hi) of a chunk record, as views."""
+        cols = {name: Column(col.ftype, col.values[lo:hi], col.valid[lo:hi])
+                for name, col in rec.columns.items()}
         return Record(rec.times[lo:hi], cols)
 
     def read_packed_sid(
         self, measurement: str, chunk: ChunkMeta, sid: int,
         fields: list[str] | None = None, cache: bool = True,
-        encoded_ok: bool = False,
     ) -> Record:
         """One series' rows out of a packed chunk: the sparse PK index
         bounds the candidate row window (and rejects out-of-span sids
         without touching data), then an exact binary search on the
         (cached) sid column finds the rows — the hybrid store reader
         (reference engine/immutable/colstore reader +
-        sparseindex/primary_index.go).  ``encoded_ok`` defers numeric
-        value decode exactly like read_chunk: the sid's rows come back as
-        an encoded row-run view over the chunk's blocks."""
+        sparseindex/primary_index.go)."""
         if sid < chunk.smin or sid > chunk.smax:
             return Record(np.empty(0, np.int64), {})
         sids = self.read_packed_sids(chunk, cache)
         lo, hi = self._sid_row_range(chunk, sids, sid)
         if lo == hi:
             return Record(np.empty(0, np.int64), {})
-        rec = self.read_chunk(measurement, chunk, fields, cache,
-                              encoded_ok=encoded_ok)
+        rec = self.read_chunk(measurement, chunk, fields, cache)
         return self._slice_rows(rec, lo, hi)
 
     def read_packed_sid_if_cached(
@@ -799,17 +763,13 @@ class TSFReader:
         self, measurement: str, chunk: ChunkMeta,
         fields: list[str] | None = None,
         sid_filter: np.ndarray | None = None, cache: bool = True,
-        encoded_ok: bool = False,
     ) -> tuple[np.ndarray, Record]:
         """(sids, record) of a packed chunk in ONE decode; when
         `sid_filter` (sorted int64 array) is given, rows are masked to
         those series — the batched multi-series scan that replaces
-        per-sid Python loops at high cardinality.  ``encoded_ok`` defers
-        numeric value decode exactly like read_chunk — a sid filter that
-        actually drops rows slices the columns, which host-decodes the
-        lazy ones (bit-identical fallback)."""
+        per-sid Python loops at high cardinality."""
         sids, rec = self._chunk_columns(measurement, chunk, fields, cache,
-                                        encoded_ok, with_sids=True)
+                                        with_sids=True)
         return self._packed_bulk_filter(sids, rec, sid_filter)
 
     @staticmethod

@@ -10,9 +10,7 @@ sharding, donate-resharding, result fetches), and retained device
 buffers (the colcache device tier, frozen-batch mesh arrays, the
 ShardedTiled caches).  Offload engines live or die by knowing exactly
 what transfer, compile, and residency cost each query pays (the
-GPU-offloading OLAP literature, arXiv:2601.19911); this is the
-instrumentation floor the decode-on-device roadmap item is judged
-against.
+GPU-offloading OLAP literature, arXiv:2601.19911).
 
 Four concerns, one arming model (the PR 8 idiom — `OGT_DEVOBS=1`, or
 `/debug/ctrl?mod=devobs&arm=1` at runtime; results are bit-identical

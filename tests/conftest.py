@@ -19,7 +19,11 @@ import jax  # noqa: E402
 # x64 on the CPU test mesh for exact float64/int64 parity with numpy oracles.
 # The package never turns x64 on: a server runs with it off (the device
 # computes in float32), which tests reach in subprocesses
-# (tests/test_bringup.py) or under jax.enable_x64(False).
+# (tests/test_bringup.py) or under jax.enable_x64(False)
+# (tests/test_served_contract.py holds the served numeric contract so).
+# No product path is chosen by the flag: it decides a dtype
+# (models/templates.py compute_dtype, jax's own canonicalization), never
+# which code reads a column.
 jax.config.update("jax_enable_x64", True)
 
 import numpy as np  # noqa: E402

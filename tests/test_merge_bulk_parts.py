@@ -4,13 +4,13 @@ keep the newest row of each (sid, time) whole, cut to the range.  The
 reference reads the parts' arrays and nothing of `record.py`'s helpers; the
 comparison is bit for bit — dtypes, the bytes under invalid slots and the
 column set included — and each case also names the branch the merge has to
-take over its TRIMMED parts and whether the answer stays encoded."""
+take over its TRIMMED parts."""
 
 import numpy as np
 import pytest
 
 from opengemini_tpu.record import (
-    Column, EncodedColumn, FieldType, Record, merge_bulk_parts,
+    Column, FieldType, Record, merge_bulk_parts,
 )
 
 F, I, B, S = (FieldType.FLOAT, FieldType.INT, FieldType.BOOL,
@@ -94,28 +94,6 @@ def packed(rng, sid_lo, sid_hi, t_lo, t_hi, fields, step=10):
     n = sid_hi - sid_lo
     return part(rng, np.repeat(np.arange(sid_lo, sid_hi), len(ticks)),
                 np.tile(ticks, n), fields)
-
-
-def _decode(ftype, blocks):
-    return np.concatenate([np.frombuffer(b, dtype=ftype.np_dtype)
-                           for b in blocks])
-
-
-def encoded(col: Column, decoded: bool = False) -> EncodedColumn:
-    """The column as one still-encoded block (a codec of this file's own:
-    the bytes of the array), its `.values` touched or not."""
-    out = EncodedColumn(col.ftype, [col.values.tobytes()], col.valid, _decode)
-    if decoded:
-        assert out.values is not None and out.is_decoded
-    return out
-
-
-def encode_parts(parts, decoded=()):
-    """Every column of every part encoded; parts whose index is in
-    `decoded` already decoded (a column-cache hit)."""
-    return [(s, Record(r.times, {k: encoded(c, at in decoded)
-                                 for k, c in r.columns.items()}))
-            for at, (s, r) in enumerate(parts)]
 
 
 TWO = {"usage_user": F, "usage_system": F}
@@ -213,151 +191,132 @@ def _wide_keys(rng):
 
 
 CASES = {
-    # name: (parts, (lo, hi), branch, answer stays encoded)
+    # name: (parts, (lo, hi), branch)
     "packed_parts_straddle_the_range":
-        (lambda r: _packed_files(r), (200, 300), "inorder", False),
+        (lambda r: _packed_files(r), (200, 300), "inorder"),
     "packed_parts_wholly_inside":
-        (lambda r: _packed_files(r), (0, 600), "inorder", False),
+        (lambda r: _packed_files(r), (0, 600), "inorder"),
     "packed_parts_every_type":
-        (lambda r: _packed_files(r, MIX), (100, 450), "inorder", False),
+        (lambda r: _packed_files(r, MIX), (100, 450), "inorder"),
     "seam_overlap_cut_away_by_the_range":
-        (lambda r: _seam(r), (300, 400), "inorder", False),
+        (lambda r: _seam(r), (300, 400), "inorder"),
     "seam_overlap_inside_the_range":
-        (lambda r: _seam(r, MIX), (250, 350), "interleaved", False),
+        (lambda r: _seam(r, MIX), (250, 350), "interleaved"),
     "seam_overlap_unbounded":
-        (lambda r: _seam(r), ALL, "interleaved", False),
+        (lambda r: _seam(r), ALL, "interleaved"),
     "duplicates_newest_row_wins_whole":
-        (lambda r: _duplicates(r), (0, 200), "sorted", False),
+        (lambda r: _duplicates(r), (0, 200), "sorted"),
     "duplicates_unbounded":
-        (lambda r: _duplicates(r), ALL, "sorted", False),
+        (lambda r: _duplicates(r), ALL, "sorted"),
     "duplicates_trimmed_away":
-        (lambda r: _duplicates(r), (0, 50), "inorder", False),
+        (lambda r: _duplicates(r), (0, 50), "inorder"),
     "a_part_outside_alone_carries_a_column":
-        (lambda r: _outside_carries_a_column(r), (0, 200), "inorder", False),
+        (lambda r: _outside_carries_a_column(r), (0, 200), "inorder"),
     "single_sid_parts":
-        (lambda r: _single_sid(r), (100, 500), "single_sid", False),
+        (lambda r: _single_sid(r), (100, 500), "single_sid"),
     "single_sid_parts_every_type":
-        (lambda r: _single_sid(r, MIX), ALL, "single_sid", False),
+        (lambda r: _single_sid(r, MIX), ALL, "single_sid"),
     "single_sid_parts_overlapping":
-        (lambda r: _single_sid(r, overlap=True), ALL, "sorted", False),
+        (lambda r: _single_sid(r, overlap=True), ALL, "sorted"),
     "single_sid_parts_in_sid_order":
         (lambda r: sorted(_single_sid(r), key=lambda p: int(p[0][0])),
-         (0, 1000), "inorder", False),
+         (0, 1000), "inorder"),
     "one_part":
-        (lambda r: _packed_files(r)[:1], (100, 200), "inorder", False),
+        (lambda r: _packed_files(r)[:1], (100, 200), "inorder"),
     "one_part_unbounded":
-        (lambda r: _packed_files(r, MIX)[:1], ALL, "inorder", False),
+        (lambda r: _packed_files(r, MIX)[:1], ALL, "inorder"),
     "one_part_in_arrival_order":
-        (lambda r: _memtable_slab(r), ALL, "sorted", False),
+        (lambda r: _memtable_slab(r), ALL, "sorted"),
     "one_part_in_arrival_order_cut":
-        (lambda r: _memtable_slab(r), (10, 20), "sorted", False),
+        (lambda r: _memtable_slab(r), (10, 20), "sorted"),
     "no_parts":
-        (lambda r: [], ALL, "inorder", False),
+        (lambda r: [], ALL, "inorder"),
     "only_empty_parts":
-        (lambda r: [part(r, [], [], TWO)], ALL, "inorder", False),
+        (lambda r: [part(r, [], [], TWO)], ALL, "inorder"),
     "an_empty_part_among_others":
         (lambda r: [_packed_files(r)[0], part(r, [], [], {"gone": I}),
-                    _packed_files(r)[1]], (0, 600), "inorder", False),
+                    _packed_files(r)[1]], (0, 600), "inorder"),
     "nothing_in_the_range":
-        (lambda r: _seam(r), (1000, 2000), "inorder", False),
+        (lambda r: _seam(r), (1000, 2000), "inorder"),
     "keys_too_wide_for_one_sort_key":
-        (lambda r: _wide_keys(r), ALL, "sorted", False),
+        (lambda r: _wide_keys(r), ALL, "sorted"),
     "negative_times":
         (lambda r: [packed(r, 0, 3, -300, 300, TWO),
-                    packed(r, 2, 5, -100, 100, TWO)], (-200, 50), "sorted",
-         False),
-    "encoded_parts_stay_encoded":
-        (lambda r: encode_parts(_packed_files(r, {"f": F, "i": I})),
-         (0, 600), "inorder", True),
-    "encoded_parts_trimmed_stay_encoded":
-        (lambda r: encode_parts(_packed_files(r, {"f": F, "i": I})),
-         (200, 300), "inorder", True),
-    "encoded_parts_decoded_stay_encoded":
-        (lambda r: encode_parts(_packed_files(r, {"f": F}), decoded={0, 1, 2}),
-         (200, 300), "inorder", True),
-    "encoded_parts_some_decoded":
-        (lambda r: encode_parts(_packed_files(r, {"f": F}), decoded={1}),
-         (0, 600), "inorder", True),
-    "encoded_single_sid_parts_stay_encoded":
-        (lambda r: encode_parts(_single_sid(r, {"f": F, "i": I})),
-         (100, 500), "single_sid", True),
-    "encoded_one_part_trimmed":
-        (lambda r: encode_parts(_packed_files(r, {"i": I})[:1]),
-         (100, 200), "inorder", True),
-    "encoded_parts_that_need_interleaving_decode":
-        (lambda r: encode_parts(_seam(r, {"f": F, "i": I})), ALL,
-         "interleaved", False),
-    "encoded_parts_that_need_the_sort_decode":
-        (lambda r: encode_parts([packed(r, 0, 3, 0, 100, {"f": F, "i": I}),
-                                 packed(r, 1, 4, 50, 150, {"f": F, "i": I})]),
-         ALL, "sorted", False),
-    "encoded_beside_plain_decodes":
-        (lambda r: encode_parts(_packed_files(r)[:2]) + _packed_files(r)[2:],
-         (0, 600), "inorder", False),
+                    packed(r, 2, 5, -100, 100, TWO)], (-200, 50), "sorted"),
+    "packed_parts_float_and_int_wholly_inside":
+        (lambda r: _packed_files(r, {"f": F, "i": I}), (0, 600), "inorder"),
+    "packed_parts_float_and_int_straddle":
+        (lambda r: _packed_files(r, {"f": F, "i": I}), (200, 300),
+         "inorder"),
+    "packed_parts_one_field_straddle":
+        (lambda r: _packed_files(r, {"f": F}), (200, 300), "inorder"),
+    "packed_parts_one_field_wholly_inside":
+        (lambda r: _packed_files(r, {"f": F}), (0, 600), "inorder"),
+    "single_sid_parts_float_and_int":
+        (lambda r: _single_sid(r, {"f": F, "i": I}), (100, 500),
+         "single_sid"),
+    "one_int_part_trimmed":
+        (lambda r: _packed_files(r, {"i": I})[:1], (100, 200), "inorder"),
+    "seam_overlap_float_and_int_unbounded":
+        (lambda r: _seam(r, {"f": F, "i": I}), ALL, "interleaved"),
+    "packed_parts_overlapping_in_sids_and_times":
+        (lambda r: [packed(r, 0, 3, 0, 100, {"f": F, "i": I}),
+                    packed(r, 1, 4, 50, 150, {"f": F, "i": I})],
+         ALL, "sorted"),
+    "packed_parts_of_two_draws":
+        (lambda r: _packed_files(r)[:2] + _packed_files(r)[2:],
+         (0, 600), "inorder"),
     # a file's long series cut into time segments (and files like it)
     **{f"segments_{n}_whole_range":
-       (lambda r, n=n: _segmented(r, n), ALL, "interleaved", False)
+       (lambda r, n=n: _segmented(r, n), ALL, "interleaved")
        for n in (2, 3, 8)},
     "segments_every_type_whole_range":
-        (lambda r: _segmented(r, 3, MIX), (0, 600), "interleaved", False),
+        (lambda r: _segmented(r, 3, MIX), (0, 600), "interleaved"),
     "segments_range_inside_one_segment":
-        (lambda r: _segmented(r, 3), (210, 390), "inorder", False),
+        (lambda r: _segmented(r, 3), (210, 390), "inorder"),
     "segments_range_crosses_a_boundary":
-        (lambda r: _segmented(r, 3, MIX), (150, 250), "interleaved", False),
+        (lambda r: _segmented(r, 3, MIX), (150, 250), "interleaved"),
     "segments_range_crosses_two_boundaries":
-        (lambda r: _segmented(r, 8), (100, 300), "interleaved", False),
+        (lambda r: _segmented(r, 8), (100, 300), "interleaved"),
     "segments_of_two_files_one_after_the_other":
         (lambda r: _segmented(r, 3) + _segmented(r, 2, t0=600), ALL,
-         "interleaved", False),
+         "interleaved"),
     "segments_of_two_files_duplicates_across_files":
-        (lambda r: _segmented(r, 3) + _segmented(r, 2), ALL, "sorted",
-         False),
+        (lambda r: _segmented(r, 3) + _segmented(r, 2), ALL, "sorted"),
     "segments_of_two_files_duplicates_cut_away":
         (lambda r: _segmented(r, 3) + _segmented(r, 3, t0=200), (0, 200),
-         "inorder", False),
+         "inorder"),
     "segments_where_a_span_lacks_a_column":
         (lambda r: _segmented(r, 3, {"f": F}, spans=((0, 4),))
          + _segmented(r, 3, {"f": F, "g": I}, spans=((4, 8),)), ALL,
-         "interleaved", False),
+         "interleaved"),
     "segments_with_a_memtable_part_on_top":
-        (lambda r: _memtable_on_top(r, MIX), ALL, "interleaved", False),
+        (lambda r: _memtable_on_top(r, MIX), ALL, "interleaved"),
     "segments_with_a_memtable_part_that_rewrites_a_row":
         (lambda r: _memtable_on_top(r, again=[(3, 590), (4, 0)]), ALL,
-         "sorted", False),
+         "sorted"),
     "segments_with_a_memtable_part_range_in_the_file":
-        (lambda r: _memtable_on_top(r), (0, 150), "inorder", False),
-    "encoded_segments_decode_to_interleave":
-        (lambda r: encode_parts(_segmented(r, 3, {"f": F, "i": I})), ALL,
-         "interleaved", False),
-    "encoded_segments_some_decoded":
-        (lambda r: encode_parts(_segmented(r, 3, {"f": F}), decoded={0, 4}),
-         (150, 450), "interleaved", False),
-    "encoded_segments_inside_one_segment_stay_encoded":
-        (lambda r: encode_parts(_segmented(r, 3, {"f": F, "i": I})),
-         (210, 390), "inorder", True),
-    "encoded_where_a_part_lacks_the_column":
-        (lambda r: encode_parts([packed(r, 0, 2, 0, 100, {"f": F}),
-                                 packed(r, 2, 4, 0, 100, {"f": F, "g": F})]),
-         ALL, "inorder", None),
+        (lambda r: _memtable_on_top(r), (0, 150), "inorder"),
+    "segments_float_and_int_whole_range":
+        (lambda r: _segmented(r, 3, {"f": F, "i": I}), ALL, "interleaved"),
+    "segments_one_field_range_crosses_two_boundaries":
+        (lambda r: _segmented(r, 3, {"f": F}), (150, 450), "interleaved"),
+    "segments_float_and_int_inside_one_segment":
+        (lambda r: _segmented(r, 3, {"f": F, "i": I}), (210, 390),
+         "inorder"),
+    "packed_parts_where_one_lacks_a_column":
+        (lambda r: [packed(r, 0, 2, 0, 100, {"f": F}),
+                    packed(r, 2, 4, 0, 100, {"f": F, "g": F})],
+         ALL, "inorder"),
 }
 
 
 @pytest.mark.parametrize("name", CASES)
 def test_the_merge_is_the_references_bit_for_bit(name):
-    build, (lo, hi), branch, stays_encoded = CASES[name]
+    build, (lo, hi), branch = CASES[name]
     parts = build(np.random.default_rng(43))
-
-    def decoded():
-        return [isinstance(c, EncodedColumn) and c.is_decoded
-                for _s, r in parts for c in r.columns.values()]
-
-    decoded_before = decoded()
     told = {}
     sid, rec = merge_bulk_parts(parts, lo, hi, told)
-    # read before the reference, whose walk over the values decodes them
-    decoded_after = decoded()
-    out_decoded = {k: isinstance(c, EncodedColumn) and c.is_decoded
-                   for k, c in rec.columns.items()}
     want_sid, want_t, want_cols = reference(parts, lo, hi)
 
     same_bits(sid, want_sid)
@@ -369,13 +328,7 @@ def test_the_merge_is_the_references_bit_for_bit(name):
         int(((r.times >= lo) & (r.times < hi)).sum()) for _s, r in parts)
     for col_name, (ftype, values, valid) in want_cols.items():
         col = rec.columns[col_name]
-        if stays_encoded is True:
-            # joining and trimming encoded parts decodes none of them
-            assert isinstance(col, EncodedColumn), col_name
-            assert out_decoded[col_name] == all(decoded_before)
-            assert decoded_after == decoded_before
-        elif stays_encoded is False:
-            assert not isinstance(col, EncodedColumn), col_name
+        assert type(col) is Column, col_name
         assert col.ftype == ftype
         same_bits(col.valid, valid)
         same_bits(col.values, values)
@@ -389,7 +342,7 @@ def test_interleaving_gives_what_the_general_merge_gives(name, monkeypatch):
     was `interleaved`): the same bits, dtypes and column order."""
     from opengemini_tpu import record
 
-    build, (lo, hi), _branch, _enc = CASES[name]
+    build, (lo, hi), _branch = CASES[name]
     told = {}
     sid, rec = merge_bulk_parts(build(np.random.default_rng(43)), lo, hi,
                                 told)
@@ -441,30 +394,134 @@ def test_a_part_wholly_inside_is_handed_on_as_it_is():
             assert out.columns[name] is col
 
 
-def test_an_encoded_join_is_the_pairwise_joins():
-    """Blocks, runs, root spans and memoized values of the many-part join
-    are those `a.concat(b).concat(c)` gives."""
-    rng = np.random.default_rng(2)
-    roots = [encoded(column(rng, I, n, holes=False)) for n in (40, 30, 50)]
-    views = [roots[0].take(np.arange(5, 35)), roots[1],
-             roots[2].take(np.array([0, 1, 2, 10, 11, 40]))]
-    for touch in ((), (0, 1, 2), (1,)):
-        cols = [EncodedColumn(v.ftype, v.blocks, v.valid, v._decode,
-                              segments=v.segments, n_full=v.n_full)
-                for v in views]
-        for c, v in zip(cols, views):
-            c._spans = v._spans_or_self()
-        for at in touch:
-            assert cols[at].values is not None
-        pair = cols[0].concat(cols[1]).concat(cols[2])
-        many = EncodedColumn.join(cols)
-        assert many.blocks == pair.blocks and many.n_full == pair.n_full
-        assert many.segments.tolist() == pair.segments.tolist()
-        assert [(id(r), off) for r, off in many._spans] \
-            == [(id(r), off) for r, off in pair._spans]
-        assert many.is_decoded == pair.is_decoded == (len(touch) == 3)
-        same_bits(many.valid, pair.valid)
-        same_bits(many.values, pair.values)
-    # past the run cap neither stays encoded
-    many_runs = [roots[2].take(np.arange(0, 50, 2))] * 200
-    assert EncodedColumn.join(many_runs) is None
+# -- parts as the reader hands them -------------------------------------------
+
+NS, T0, STEP_S = 10**9, 1_700_000_000, 10
+SERIES, TICKS = 64, 420
+
+
+def _lines(series, ticks, salt=0) -> str:
+    """Line protocol of every type, series after series; a tenth of the
+    rows leave `f` out, another tenth `s`."""
+    out = []
+    for s in series:
+        rng = np.random.default_rng(s * 7919 + salt)
+        f = rng.normal(size=TICKS).round(3).tolist()
+        i = rng.integers(-2**40, 2**40, TICKS).tolist()
+        gone = rng.integers(0, 10, TICKS).tolist()
+        for k in ticks:
+            fields = [f"i={i[k]}i", f"b={'true' if i[k] % 2 else 'false'}"]
+            if gone[k] != 0:
+                fields.append(f"f={f[k]!r}")
+            if gone[k] != 1:
+                fields.append(f's="s{i[k] % 9}"')
+            out.append(f"cpu,host=h{s:03d} {','.join(fields)} "
+                       f"{(T0 + k * STEP_S) * NS}")
+    return "\n".join(out)
+
+
+@pytest.fixture(scope="module")
+def written(tmp_path_factory):
+    """{"cut": a shard whose one file holds 64 series x 420 rows as three
+    sid spans of two time segments and a tail (the chunk writer's cut, PR
+    46, with `tsf.PACK_ROWS` lowered to this size), "rewritten": the same
+    and a second file, a chunk a series, that writes a stretch of every
+    third series anew}."""
+    from opengemini_tpu.storage import colcache, tsf
+    from opengemini_tpu.storage.engine import Engine
+
+    cache = colcache.GLOBAL.config()
+    colcache.GLOBAL.configure(budget_mb=0)
+    engines, shards = [], {}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(tsf, "PACK_ROWS", 4096)
+        for name in ("cut", "rewritten"):
+            e = Engine(str(tmp_path_factory.mktemp(name)))
+            e.create_database("db")
+            e.write_lines("db", _lines(range(SERIES), range(TICKS)))
+            e.flush_all()
+            if name == "rewritten":
+                e.write_lines("db", _lines(range(0, SERIES, 3),
+                                           range(150, 270), salt=1))
+                e.flush_all()
+            engines.append(e)
+            (shards[name],) = e.shards_for_range(
+                "db", None, T0 * NS, (T0 + TICKS * STEP_S) * NS)
+    yield shards
+    for e in engines:
+        e.close()
+    colcache.GLOBAL.configure(**cache)
+
+
+def reader_parts(sh, lo, hi, fields=None, every=1):
+    """[(sids, record)] as `Shard.read_series_bulk` gathers them: the
+    chunks the reader's pruning leaves, in file order, a packed one
+    through `read_packed_bulk`, a series' own through `read_chunk`."""
+    sids = np.array(sorted(sh.index.series_ids("cpu"))[::every], np.int64)
+    parts = []
+    for r in sh._files:
+        for c in r.chunks("cpu", set(sids.tolist()), lo, hi):
+            if c.packed:
+                s_arr, rec = r.read_packed_bulk(
+                    "cpu", c, fields, sid_filter=sids, cache=False)
+            else:
+                rec = r.read_chunk("cpu", c, fields, cache=False)
+                s_arr = np.full(len(rec), c.sid, np.int64)
+            if len(rec):
+                parts.append((s_arr, rec))
+    return sids, parts
+
+
+def _t(k: int) -> int:
+    return (T0 + k * STEP_S) * NS
+
+
+READER_CASES = {
+    # name: (store, (first tick, end tick) or None, fields, every nth
+    #        series, branch, parts)
+    "whole_range": ("cut", None, None, 1, "interleaved", 7),
+    "inside_one_segment": ("cut", (20, 150), None, 1, "inorder", 4),
+    "across_the_cut": ("cut", (100, 330), None, 1, "interleaved", 7),
+    "one_tick": ("cut", (209, 210), None, 1, "inorder", 4),
+    "every_third_series": ("cut", None, None, 3, "interleaved", 7),
+    "one_field_of_four": ("cut", (5, 415), ["i"], 1, "interleaved", 7),
+    "rewritten_rows_newest_wins": ("rewritten", None, None, 1, "sorted",
+                                   7 + 22),
+    "rewritten_rows_cut_away": ("rewritten", (270, 420), None, 1,
+                                "inorder", 4),
+}
+
+
+@pytest.mark.parametrize("name", READER_CASES)
+def test_parts_from_the_reader_merge_as_the_reference_merges(written, name):
+    """The parts are what `read_packed_bulk` decodes from a segment-cut
+    file, not columns built by hand; the merge of them is the row-by-row
+    reference's, bit for bit, and what `read_series_bulk` answers."""
+    store, ticks, fields, every, branch, n_parts = READER_CASES[name]
+    sh = written[store]
+    lo, hi = (None, None) if ticks is None else (_t(ticks[0]), _t(ticks[1]))
+    sids, parts = reader_parts(sh, lo, hi, fields, every)
+    assert len(parts) == n_parts
+    assert all(type(c) is Column for _s, r in parts
+               for c in r.columns.values())
+    lo_t, hi_t = ALL if ticks is None else (lo, hi)
+    told = {}
+    sid, rec = merge_bulk_parts(parts, lo_t, hi_t, told)
+    assert told["branch"] == branch
+    want_sid, want_t, want_cols = reference(parts, lo_t, hi_t)
+    rows = len(sids) * (TICKS if ticks is None else ticks[1] - ticks[0])
+    assert len(want_sid) == rows
+    same_bits(sid, want_sid)
+    same_bits(rec.times, want_t)
+    assert list(rec.columns) == list(want_cols)
+    for col_name, (ftype, values, valid) in want_cols.items():
+        col = rec.columns[col_name]
+        assert type(col) is Column and col.ftype == ftype
+        same_bits(col.valid, valid)
+        same_bits(col.values, values)
+    got_sid, got = sh.read_series_bulk("cpu", sids, lo, hi, fields)
+    same_bits(got_sid, sid)
+    same_bits(got.times, rec.times)
+    for col_name, col in rec.columns.items():
+        same_bits(got.columns[col_name].valid, col.valid)
+        same_bits(got.columns[col_name].values, col.values)

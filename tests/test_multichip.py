@@ -580,158 +580,3 @@ print("FORCED-MESH-OK")
                        text=True, timeout=180, cwd=root, env=env)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "FORCED-MESH-OK" in r.stdout
-
-
-class TestMeshShardedDecode:
-    """ISSUE 16 tentpole: the fused decode->scatter->window-reduce path
-    under a configured mesh — encoded bytes partitioned by output row
-    shard, per-shard programs with zero collectives, results landing in
-    the mesh-aware colcache device tier."""
-
-    NS = 10**9
-    BASE = 1_700_000_000
-
-    def _engine(self, tmp_path, monkeypatch, n_hosts, name="md"):
-        from opengemini_tpu.storage.engine import Engine
-
-        monkeypatch.setenv("OGT_DEVICE_PROFILE", "1")
-        rng = np.random.default_rng(n_hosts)
-        e = Engine(str(tmp_path / f"{name}{n_hosts}"))
-        e.create_database("db")
-        lines = []
-        for h in range(n_hosts):
-            for p in range(110):
-                lines.append(
-                    f"cpu,host=h{h} vi={int(rng.integers(0, 250))}i,"
-                    f"vf={float(rng.standard_normal()):.6f} "
-                    f"{(self.BASE + p * 10) * self.NS}")
-        e.write_lines("db", "\n".join(lines))
-        e.flush_all()
-        return e
-
-    # 64 hosts -> S a mesh multiple; 70/13 -> uneven (padded rows leave
-    # one shard partially — or entirely — masked off)
-    @pytest.mark.parametrize("n_hosts", [64, 70, 13])
-    def test_mesh_decode_bit_identical(self, tmp_path, monkeypatch, mesh,
-                                       n_hosts):
-        import json
-
-        from opengemini_tpu.query.executor import Executor
-        from opengemini_tpu.storage import colcache
-
-        e = self._engine(tmp_path, monkeypatch, n_hosts)
-        ex = Executor(e)
-        monkeypatch.setenv("OGT_DEVICE_DECODE", "1")
-        lo, hi = self.BASE * self.NS, (self.BASE + 2000) * self.NS
-        queries = [
-            f"SELECT count(vi), min(vi), max(vi) FROM cpu WHERE time >= "
-            f"{lo} AND time < {hi} GROUP BY time(1m)",
-            f"SELECT mean(vf), sum(vf), stddev(vf), first(vf), last(vf) "
-            f"FROM cpu WHERE time >= {lo} AND time < {hi} "
-            "GROUP BY time(90s), host",
-        ]
-
-        def run(q, m):
-            prt.set_mesh(m)
-            try:
-                colcache.GLOBAL.clear()
-                ex._inc_cache.clear()
-                return ex.execute(q, db="db")
-            finally:
-                prt.set_mesh(None)
-
-        try:
-            for q in queries:
-                solo = run(q, None)
-                meshed = run(q, mesh)
-                assert json.dumps(solo, sort_keys=True) == \
-                    json.dumps(meshed, sort_keys=True), q
-        finally:
-            e.close()
-
-    def test_mesh_decode_engages_and_warm_is_transfer_free(
-            self, tmp_path, monkeypatch, mesh):
-        import json
-
-        from opengemini_tpu.query.executor import Executor
-        from opengemini_tpu.storage import colcache
-        from opengemini_tpu.utils import devobs
-
-        e = self._engine(tmp_path, monkeypatch, 70, name="warm")
-        ex = Executor(e)
-        monkeypatch.setenv("OGT_DEVICE_DECODE", "1")
-        prior = colcache.GLOBAL.config()
-        colcache.GLOBAL.clear()
-        # pin budgets: a zero budget inherited from an earlier test would
-        # evict the device tier between the cold and warm runs
-        colcache.GLOBAL.configure(device=True, budget_mb=256,
-                                  device_budget_mb=256)
-        q = (f"SELECT count(vi), min(vi), max(vi) FROM cpu WHERE time >= "
-             f"{self.BASE * self.NS} AND time < "
-             f"{(self.BASE + 2000) * self.NS} GROUP BY time(1m)")
-
-        def counters():
-            c = STATS.snapshot()
-            return (c.get("device", {}).get("h2d_bytes_total", 0),
-                    c.get("device", {}).get("mesh_h2d_bytes", 0),
-                    c.get("executor", {}).get("grid_decode_fused", 0))
-
-        prt.set_mesh(mesh)
-        try:
-            h0, m0, f0 = counters()
-            cold = ex.execute(q, db="db")
-            h1, m1, f1 = counters()
-            ex._inc_cache.clear()  # drop result cache, keep device tier
-            warm = ex.execute(q, db="db")
-            h2, m2, f2 = counters()
-            # the device-tier hit has its own program: a second repeat
-            # builds none
-            devobs.mark_warm()
-            ex._inc_cache.clear()
-            again = ex.execute(q, db="db")
-            recompiles = devobs.compiles_since_warm()
-        finally:
-            devobs.clear_warm()
-            prt.set_mesh(None)
-            colcache.GLOBAL.configure(**prior)
-            e.close()
-        assert f1 - f0 >= 1, "mesh fused decode did not engage"
-        assert m1 - m0 > 0, "mesh-cold H2D not accounted as mesh bytes"
-        assert h2 - h1 == 0, "warm mesh repeat must transfer zero bytes"
-        assert recompiles == 0, "warm mesh repeat built a new program"
-        assert again == warm
-        assert json.dumps(cold, sort_keys=True) == \
-            json.dumps(warm, sort_keys=True)
-
-    def test_mesh_plan_shards_cover_rows(self, mesh, rng):
-        """build_mesh_grid_plan unit geometry: every shard's sub-plan
-        rows sum to the view, outputs land one shard per device."""
-        from opengemini_tpu.ops import device_decode as dd
-        from opengemini_tpu.storage import encoding as enc
-
-        os.environ["OGT_DEVICE_PROFILE"] = "1"
-        try:
-            S_pad, k, w_pad = 16, 1, 8
-            n = S_pad * 4
-            v = np.cumsum(rng.integers(0, 200, n)).astype(np.int64)
-            blocks = [enc.encode_ints(v)]
-            rows = np.repeat(np.arange(S_pad, dtype=np.int64), 4)
-            w = np.tile(np.arange(4, dtype=np.int64), S_pad)
-            flat = (rows * k) * w_pad + w
-            views = [(blocks, np.array([[0, n]], np.int64), n)]
-            mplan = dd.build_mesh_grid_plan(
-                views, flat, np.ones(n, bool), (S_pad, k, w_pad),
-                np.float64, mesh)
-            assert mplan is not None
-            assert len(mplan.shards) == mesh.size
-            assert sum(p.n for p in mplan.shards) == n
-            stats, vt, mt, _ = dd.run_mesh_grid_plan(mplan)
-            assert len({s.device for s in vt.addressable_shards}) \
-                == mesh.size
-            want = np.zeros((S_pad, k, w_pad))
-            want.reshape(-1)[flat] = v
-            np.testing.assert_array_equal(np.asarray(vt), want)
-            np.testing.assert_array_equal(
-                np.asarray(mt).reshape(-1)[flat], np.ones(n, bool))
-        finally:
-            os.environ.pop("OGT_DEVICE_PROFILE", None)

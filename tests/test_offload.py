@@ -83,11 +83,11 @@ class TestModelPrimitives:
 
     def test_compile_estimate_prefix_matches_inventory(self, monkeypatch):
         inv = {
-            "grid_decode_fused": {"geometries": [
+            "prom_rate": {"geometries": [
                 {"geometry": "a", "wall_ms": 800.0},
                 {"geometry": "b", "wall_ms": 1200.0},
             ]},
-            "grid_decode_imat": {"geometries": [
+            "prom_rate_sharded": {"geometries": [
                 {"geometry": "a", "wall_ms": 400.0},
             ]},
             "bucket_stats": {"geometries": [
@@ -95,8 +95,8 @@ class TestModelPrimitives:
             ]},
         }
         monkeypatch.setattr(devobs, "inventory", lambda: inv)
-        # "grid_decode" covers both fused and imat sites (prefix match)
-        est = offload._compile_estimate_s("grid_decode")
+        # "prom_rate" covers both compile sites (prefix match)
+        est = offload._compile_estimate_s("prom_rate")
         assert est == pytest.approx((800 + 1200 + 400) / 3 / 1e3)
         assert offload._compile_estimate_s("bucket_stats") == \
             pytest.approx(0.05)
@@ -384,25 +384,6 @@ class TestFreezeForceGate:
         with pytest.raises(ValueError):
             offload.set_force("gpu")
 
-    def test_gate_prior_is_byte_inequality_until_measured(self):
-        p = Planner()
-        # no samples: exactly the pre-planner byte rule
-        assert p.gate_prior("k", GEO, device_bytes=10, host_bytes=100)
-        assert not p.gate_prior("k", GEO, device_bytes=100,
-                                host_bytes=10)
-        # a measured device route owns the choice; the byte rule stops
-        # second-guessing it
-        p.observe("k", GEO, "device", 0.001)
-        assert p.gate_prior("k", GEO, device_bytes=100, host_bytes=10)
-        # ...but only for the measured geometry
-        assert not p.gate_prior("k", GEO2, device_bytes=100,
-                                host_bytes=10)
-
-    def test_gate_prior_forced_route_always_passes(self):
-        offload.set_force("device")
-        p = Planner()
-        assert p.gate_prior("k", GEO, device_bytes=100, host_bytes=10)
-
     def test_prom_host_kernels_mode_validation(self):
         offload.set_prom_host_kernels_mode("1")
         assert offload.prom_host_kernels_mode() == "1"
@@ -454,40 +435,6 @@ class TestBitIdentity:
             off = [run() for _ in range(3)]
             assert on_cold == off
             assert len(set(on_cold)) == 1
-        finally:
-            eng.close()
-            colcache.GLOBAL.clear()
-
-    def test_forced_routes_answer_alike(self, tmp_path, monkeypatch):
-        """OGT_OFFLOAD_FORCE=host|device over encoded (device-profile)
-        columns: the fused device decode and the host scatter give the
-        same bytes as the planner's own choice, and each forced route
-        is the one that ran."""
-        from opengemini_tpu.query.executor import Executor
-        from opengemini_tpu.utils.stats import GLOBAL as STATS
-
-        monkeypatch.setenv("OGT_DEVICE_PROFILE", "1")
-        monkeypatch.setenv("OGT_DEVICE_DECODE", "1")
-        eng = _mk_engine(tmp_path, hosts=70)  # >= 64: the bulk scan
-        try:
-            ex = Executor(eng)
-
-            def fused():
-                return STATS.counters("executor").get("grid_decode_fused", 0)
-
-            def run(force):
-                offload.set_force(force)
-                colcache.GLOBAL.clear()
-                ex._inc_cache.clear()
-                before = fused()
-                out = json.dumps(ex.execute(_Q, db="db"), sort_keys=True)
-                return out, fused() - before
-
-            adaptive, _ = run(None)
-            host, host_fused = run("host")
-            device, device_fused = run("device")
-            assert host == adaptive == device
-            assert host_fused == 0 and device_fused >= 1
         finally:
             eng.close()
             colcache.GLOBAL.clear()
@@ -584,7 +531,7 @@ class TestCtrlAndDebug:
     def test_debug_device_has_planner_section(self, server):
         offload.GLOBAL.observe("k", GEO, "host", 0.005)
         offload.GLOBAL.decide("k", GEO, ("host", "device"),
-                              static="host", stage="grid_decode")
+                              static="host", stage="prom_kernel")
         status, body = _get(server.port, "/debug/device")
         assert status == 200
         doc = json.loads(body)
@@ -596,7 +543,7 @@ class TestCtrlAndDebug:
         assert pl["model"][0]["kernel"] == "k"
         assert pl["model"][0]["routes"]["host"]["count"] == 1
         dec = pl["decisions"][0]
-        assert dec["stage"] == "grid_decode"
+        assert dec["stage"] == "prom_kernel"
         assert dec["route"] == "host" and dec["reason"] == "prior"
         assert "est_ms" in dec
         assert set(pl["prewarm"]) >= {"registered", "warm", "wanted",

@@ -301,7 +301,7 @@ def test_raw_float32_counters_would_fail(day, monkeypatch):
     on the way in, to the nearest 64.  The limit catches it."""
     monkeypatch.setattr(
         promops.TiledPrepared, "_narrowed",
-        lambda self, form: self._host_values().astype(np.float32))
+        lambda self, form: self.values.astype(np.float32))
     got = day.answer("device")
     quiet = np.arange(1, SERIES)          # series 0 restarts
     assert rel_err(got[quiet], day.ref.want(day.req.stmt)[quiet]) \

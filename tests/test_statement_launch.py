@@ -130,22 +130,6 @@ def test_items_are_grouped_by_geometry_not_by_count():
         assert not x.launch_items(SEGMENTS, ["max"], want_sel=False)
 
 
-def test_a_launch_gives_the_planner_one_sample(monkeypatch):
-    """offload.GLOBAL.observe: one sample a launch (the mean a grid of
-    the scatter wall plus the dispatch), not one a field."""
-    from opengemini_tpu.query import offload
-
-    heard = []
-    monkeypatch.setattr(
-        offload.GLOBAL, "observe",
-        lambda kernel, geo, route, s: heard.append((kernel, route, s)))
-    batches = _field_batches("grid", 5)
-    launch.run([it for b in batches
-                for it in b.launch_items(SEGMENTS, ["mean"], want_sel=False)])
-    assert len(heard) == 1
-    assert heard[0][:2] == ("grid_decode", "host") and heard[0][2] > 0
-
-
 # -- through the executor -----------------------------------------------------
 
 

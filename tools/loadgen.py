@@ -632,10 +632,8 @@ def run_mixed_shapes(host: str, port: int, clients: int = 6,
         if resp.status != 204:
             conn.close()
             raise RuntimeError(f"mixed_shapes seed write: {resp.status}")
-    # flush to TSF before the fleet: the offload routes under test are
-    # the ENCODED-column paths (device decode needs flushed blocks); a
-    # live memtable tail would pin every scan to the host for the wrong
-    # reason
+    # flush to TSF before the fleet: the scans under test read flushed
+    # blocks through the column cache, not a live memtable tail
     conn.request("POST", "/debug/ctrl?mod=flush")
     conn.getresponse().read()
     conn.close()
