@@ -248,7 +248,7 @@ class MergesetIndex:
             if not n:
                 return set()
             raw = ctypes.string_at(ptr, n * 8)
-            return set(map(int, np.frombuffer(raw, "<u8")))
+            return set(np.frombuffer(raw, "<u8").tolist())
         finally:
             self._lib.msi_free(ptr)
 

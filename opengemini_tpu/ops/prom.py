@@ -437,11 +437,39 @@ def instant_rate(times, values, counts, starts, ends, per_second: bool):
     return dv, valid
 
 
+# Rows of at most this many samples select by comparison, longer ones by
+# binary search and a row gather: the longest row at which the comparison
+# lost on neither backend measured (PERF.md section 6, PR 49).  Compiled for
+# a v5e, 1,000,000 series x 5 steps: the gather takes the compiler 84-186 s
+# at 8 to 256 samples a row and leaves 200 MB of code with 2.3 GB of
+# temporaries (84 s at 33), the comparison under 2 s with no temporaries at
+# 8 and 256 MB at 32.  On the CPU, 10,000 series x 240 steps, the
+# comparison is five to thirteen times the faster at 16 and 32 samples, and
+# at 64 XLA no longer fuses its (S, K, N) cells into the sum (1.2 GB of
+# them) and it is seven times the slower.
+INSTANT_COMPARE_MAX_SAMPLES = 32
+
+
 def instant_values(times, values, counts, eval_times, lookback_s: float = 300.0):
     """Instant vector selection: latest sample within [t - lookback, t].
     Returns (vals (S, K), valid (S, K)) — prom staleness semantics (without
     explicit staleness markers, which the influx data model doesn't carry).
+    `times` rows ascend and are padded with +inf (`prepare_matrix_runs`).
     """
+    if times.shape[1] <= INSTANT_COMPARE_MAX_SAMPLES:
+        # the newest sample at or before t is the one whose successor is
+        # after t: one cell a (series, step), picked without an index.  The
+        # successor is shifted in the (S, N) rows, so that the (S, K, N)
+        # cells are compared and summed and never stored
+        after = jnp.concatenate(
+            [times[:, 1:], jnp.full_like(times[:, :1], jnp.inf)], axis=1)
+        t = eval_times[None, :, None]
+        newest = (times[:, None, :] <= t) & (after[:, None, :] > t)
+        t_at = jnp.where(newest, times[:, None, :], 0).sum(axis=2)
+        v_at = jnp.where(newest, values[:, None, :], 0).sum(axis=2)
+        valid = (times[:, :1] <= eval_times[None, :]) & (
+            t_at >= eval_times[None, :] - lookback_s)
+        return v_at, valid
     idx = _vmap_searchsorted(times, eval_times, "right") - 1
     safe = jnp.clip(idx, 0, times.shape[1] - 1)
     t_at = _gather_rows(times, safe)
@@ -450,6 +478,51 @@ def instant_values(times, values, counts, eval_times, lookback_s: float = 300.0)
         idx < counts[:, None]
     )
     return v_at, valid
+
+
+def prom_instant(times, values, counts, eval_times, lookback_s):
+    """`instant_values` under the name a profiler capture lists it by
+    (`jit_prom_instant`): one program a query."""
+    return instant_values(times, values, counts, eval_times, lookback_s)
+
+
+@_functools.lru_cache(maxsize=64)
+def _instant_jit(geometry: tuple):
+    """One compiled instant selection a geometry (series, samples, steps,
+    dtype), counted where it is built."""
+    import jax
+
+    from opengemini_tpu.utils import devobs
+
+    devobs.note_compile("prom_instant", geometry)
+    return jax.jit(prom_instant)
+
+
+def instant_select(times, values, counts, eval_times, lookback_s: float):
+    """`instant_values` as ONE named device program, in the dtype the
+    device computes in by statement: the host narrows `times` (seconds
+    from the query's first sample: a day is exact in float32 to 8 ms, the
+    lookback behind a few steps to 30 us), `values` and the step times
+    HERE, explicitly — jax would narrow float64 numpy on the way in,
+    silently — and what comes back are those values, selected, never
+    recomputed.  Host numpy in and out: ((S, K) values, (S, K) valid);
+    the launch and the fetch are counted transfer sites."""
+    import jax
+
+    from opengemini_tpu.utils import devobs
+
+    dtype = np.dtype(jax.dtypes.canonicalize_dtype(np.float64))
+    # the comparison reads no counts: a program is passed what it reads,
+    # so that the bytes counted are the bytes that cross
+    by_compare = np.shape(times)[1] <= INSTANT_COMPARE_MAX_SAMPLES
+    args = (np.asarray(times, dtype), np.asarray(values, dtype),
+            None if by_compare else np.asarray(counts, np.int32),
+            np.asarray(eval_times, dtype), dtype.type(lookback_s))
+    geometry = (*args[0].shape, len(args[3]), str(dtype))
+    devobs.note_use("prom_instant", geometry)
+    out = devobs.launch(_instant_jit(geometry), args, program="prom_instant",
+                        xfer_site="prom-launch")
+    return devobs.fetch_tree(out, "prom-fetch")
 
 
 # ---------------------------------------------------------------------------

@@ -31,6 +31,9 @@ from opengemini_tpu.utils.stats import GLOBAL as STATS
 
 MS = 1_000_000  # ns per ms
 DEFAULT_LOOKBACK_S = 300.0
+# under this many matched series the lazy-label aggregation path hands a
+# statement to the eager one: its label dicts cost little there
+FAST_AGG_MIN_SERIES = 4096
 
 
 class PromError(ValueError):
@@ -491,9 +494,9 @@ class PromEngine:
                 t_ms_all, v_all, lens, dtype=np.float64)
         rel = eval_times - base_ms / 1000.0
         with tracing.span("prom_kernel"):
-            vals, valid = promops.instant_values(times, values, counts, rel,
+            vals, valid = promops.instant_select(times, values, counts, rel,
                                                  window_s)
-        return Frame(labels, np.asarray(vals), np.asarray(valid))
+        return Frame(labels, vals, valid)
 
     def _eval_function(self, node: pp.FunctionCall, steps, db) -> Frame:
         name = node.name
@@ -1017,39 +1020,52 @@ class PromEngine:
 
     def _collect_runs(self, vs, t_min_ns: int, t_max_ns: int, db: str):
         """Label-free bulk collection for the lazy aggregation fast path:
-        (shard, metric, uniq_sids, t_ms_all, v_all, lens) or None when
-        ineligible (multi-shard ranges must merge series by label, small
-        matches gain nothing)."""
+        (shard, metric, uniq_sids, t_ms_all, v_all, lens), or the reason
+        it is ineligible, a word (multi-shard ranges must merge series by
+        label, small matches gain nothing).  The three spans of
+        `_collect_series`, under the caller's `prom_collect`."""
         metric = self._metric_of(vs)
-        shards = self.engine.shards_for_range(db, None, t_min_ns, t_max_ns)
-        if (len(shards) != 1
-                or not hasattr(shards[0], "read_series_bulk")
-                or not hasattr(shards[0].index, "entries_bulk")):
-            return None  # dict-index fallback has no bulk label fetch
-        sh = shards[0]
-        sids = _match_sids(sh, metric, vs.matchers)
-        if sids.size < 4096:
-            return None  # eager path is fine at low cardinality
-        sid_arr, rec = sh.read_series_bulk(
-            metric, sids, t_min_ns, t_max_ns,
-            fields=[self.value_field])
-        col = rec.columns.get(self.value_field)
-        if col is None or len(rec) == 0:
-            return (sh, metric, np.empty(0, np.int64),
-                    np.empty(0, np.int64), np.empty(0, np.float64),
-                    np.empty(0, np.int64))
-        keep = col.valid
-        sid_k = sid_arr[keep]
-        uniq, lens = np.unique(sid_k, return_counts=True)
-        return (sh, metric, uniq, rec.times[keep] // MS,
-                col.values[keep].astype(np.float64), lens)
+        with tracing.span("prom_match"):
+            shards = self.engine.shards_for_range(db, None, t_min_ns,
+                                                  t_max_ns)
+            if len(shards) != 1:
+                return "shards"
+            sh = shards[0]
+            if not (hasattr(sh, "read_series_bulk")
+                    and hasattr(sh.index, "entries_bulk")):
+                return "dict_index"     # it has no bulk label fetch
+            sids = _match_sids(sh, metric, vs.matchers)
+            if sids.size < FAST_AGG_MIN_SERIES:
+                return "few_series"     # the eager path is fine there
+        with tracing.span("prom_read"):
+            sid_arr, rec = sh.read_series_bulk(
+                metric, sids, t_min_ns, t_max_ns,
+                fields=[self.value_field])
+        with tracing.span("prom_assemble"):
+            col = rec.columns.get(self.value_field)
+            if col is None or len(rec) == 0:
+                return (sh, metric, np.empty(0, np.int64),
+                        np.empty(0, np.int64), np.empty(0, np.float64),
+                        np.empty(0, np.int64))
+            keep = col.valid
+            sid_k = sid_arr[keep]
+            uniq, lens = np.unique(sid_k, return_counts=True)
+            return (sh, metric, uniq, rec.times[keep] // MS,
+                    col.values[keep].astype(np.float64), lens)
 
     def _eval_agg_fast(self, node: pp.Aggregation, steps, db):
         """topk/bottomk/count_values over a bare high-cardinality selector
         without materializing input labels: the winners' (or none of the)
         labels resolve AFTER selection. At 1M series (BASELINE.md config
         #5) the eager path builds a label dict per input series that the
-        result never uses. Returns None when inapplicable.
+        result never uses. Returns None when inapplicable: another shape
+        of statement, or a fallback counted with its reason
+        (`prom/fast_agg_fallbacks`, `prom/fast_agg_fallback_<reason>`).
+
+        Its stages are the eager path's by name (`prom_collect` with its
+        three, `prom_prepare`, `prom_kernel`) and two of its own:
+        `prom_select`, the choice on the host over what the device
+        returned, and `prom_labels`, the labels of what it chose.
 
         Exact-value ties at the topk/bottomk boundary may admit a
         different (equally-valid) subset than the eager path: this path
@@ -1064,19 +1080,26 @@ class PromEngine:
         eval_times = steps - vs.offset_s
         t_max_ns = int(eval_times[-1] * 1e9) + 1
         t_min_ns = int((eval_times[0] - window_s) * 1e9)
-        got = self._collect_runs(vs, t_min_ns, t_max_ns, db)
-        if got is None:
+        with tracing.span("prom_collect"):
+            got = self._collect_runs(vs, t_min_ns, t_max_ns, db)
+        if isinstance(got, str):
+            STATS.add("prom", (("fast_agg_fallbacks", 1),
+                               ("fast_agg_fallback_" + got, 1)))
             return None
         sh, metric, uniq, t_ms_all, v_all, lens = got
         k = len(steps)
+        STATS.add("prom", (("fast_agg_queries", 1),
+                           ("fast_agg_series", len(uniq))))
         if len(uniq) == 0:
             return Frame([], np.zeros((0, k)), np.zeros((0, k), bool))
-        times, values, counts, base_ms = promops.prepare_matrix_runs(
-            t_ms_all, v_all, lens, dtype=np.float64)
+        _count_collected(lens, len(lens), k)
+        with tracing.span("prom_prepare"):
+            times, values, counts, base_ms = promops.prepare_matrix_runs(
+                t_ms_all, v_all, lens, dtype=np.float64)
         rel = eval_times - base_ms / 1000.0
-        vals, valid = promops.instant_values(times, values, counts, rel,
-                                             window_s)
-        vals, valid = np.asarray(vals), np.asarray(valid)
+        with tracing.span("prom_kernel"):
+            vals, valid = promops.instant_select(times, values, counts, rel,
+                                                 window_s)
 
         def resolve(rows):
             entries = sh.index.entries_bulk(uniq[rows])
@@ -1094,20 +1117,24 @@ class PromEngine:
             n = int(nv)
             if n <= 0:
                 return Frame([], np.zeros((0, k)), np.zeros((0, k), bool))
-            keep = _topk_keep(vals, valid, min(n, len(uniq)),
-                              descending=(node.op == "topk"))
-            rows = np.flatnonzero(keep.any(axis=1))
-            labels = resolve(rows)
-            order = sorted(range(len(rows)),
-                           key=lambda i: tuple(sorted(labels[i].items())))
-            rows = rows[order]
-            return Frame([labels[i] for i in order], vals[rows], keep[rows])
+            with tracing.span("prom_select", series=len(uniq)):
+                keep = _topk_keep(vals, valid, min(n, len(uniq)),
+                                  descending=(node.op == "topk"))
+                rows = np.flatnonzero(keep.any(axis=1))
+            with tracing.span("prom_labels", series=len(rows)):
+                labels = resolve(rows)
+                order = sorted(range(len(rows)),
+                               key=lambda i: tuple(sorted(labels[i].items())))
+                rows = rows[order]
+                return Frame([labels[i] for i in order], vals[rows],
+                             keep[rows])
 
         # count_values: input labels are never consulted (no grouping)
         if not isinstance(node.param, pp.StringLit):
             raise PromError("count_values expects a label-name string")
-        out_labels, out_rows = _count_values_cells(
-            vals, valid, k, {}, node.param.val)
+        with tracing.span("prom_select", series=len(uniq)):
+            out_labels, out_rows = _count_values_cells(
+                vals, valid, k, {}, node.param.val)
         if not out_labels:
             return Frame([], np.zeros((0, k)), np.zeros((0, k), bool))
         out = np.vstack(out_rows)

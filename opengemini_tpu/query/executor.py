@@ -1220,7 +1220,9 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
                 sids = np.asarray(keep, np.int64)
             sids = _prune_text_sids(sh, mst, sids, match_terms)
             for sid in sids.tolist():
-                tags = sh.index.tags_of(sid)
+                # no GROUP BY tag: one group, and no series' tags are read
+                # (a count() over 1,000,000 series spent 28 s in tags_of)
+                tags = sh.index.tags_of(sid) if group_tags else {}
                 key = tuple(tags.get(k, "") for k in group_tags)
                 gid = gid_of.get(key)
                 if gid is None:
@@ -1800,60 +1802,66 @@ class Executor(ShowDdlMixin, SubqueryMixin, HostPathMixin):
         # many series are scanned (packed colstore chunks decode once
         # for all their series, with no per-sid Python loop: config #5
         # of BASELINE.md scans 1M series)
-        remaining_plan = scan_plan
         mem_kw: dict[int, dict] = {}    # read_series' `mem`, by shard
-        if not pre_eligible:
-            by_shard: dict[int, tuple] = {}
-            for sh, sid, gid in scan_plan:
-                by_shard.setdefault(id(sh), (sh, []))[1].append((sid, gid))
-            remaining_plan = []
-            units = []  # thunks: () -> (sh, sid_sorted, gid_sorted, sid_arr, rec)
-            for sh, pairs in by_shard.values():
-                if len(pairs) < 64 or not hasattr(sh, "read_series_bulk"):
-                    remaining_plan.extend(
-                        (sh, sid, gid) for sid, gid in pairs)
-                    # rows not yet flushed: taken once a shard for all its
-                    # series of the tail (span `mem_read`), not once a
-                    # series and a scan range; a proxy has no such view
-                    view = sh.mem_view(
-                        mst, [sid for sid, _gid in pairs], read_fields) \
-                        if hasattr(sh, "mem_view") else None
-                    if view is not None:
-                        mem_kw[id(sh)] = {"mem": view}
-                    continue
-                sid_list = np.asarray([p[0] for p in pairs], np.int64)
-                gid_list = np.asarray([p[1] for p in pairs], np.int64)
-                o = np.argsort(sid_list)
-                sid_sorted, gid_sorted = sid_list[o], gid_list[o]
-                for rlo, rhi in scan_ranges:
-                    units.append(
-                        lambda sh=sh, ss=sid_sorted, gs=gid_sorted,
-                        rlo=rlo, rhi=rhi:
-                        (sh, ss, gs) + sh.read_series_bulk(
-                            mst, ss, rlo, rhi, fields=read_fields))
-            for sh, sid_sorted, gid_sorted, sid_arr, rec in \
-                    scanpool.prefetch_ordered(units):
-                TRACKER.check()
-                if len(rec) == 0:
-                    continue
-                rows_scanned += len(rec)
-                fmask = (
-                    cond.eval_row_filter(sc, rec, sid_arr=sid_arr,
-                                         index=sh.index)
-                    if sc.has_row_filter
-                    else None
-                )
-                gid_rows = gid_sorted[
-                    np.searchsorted(sid_sorted, sid_arr)]
-                if group_time:
-                    widx, _ = winmod.window_index(
-                        rec.times, tmin, group_time.every_ns,
-                        group_time.offset_ns)
-                    seg = (gid_rows * W + widx.astype(np.int64)
-                           ).astype(np.int32)
-                else:
-                    seg = gid_rows.astype(np.int32)
-                _scan_record(rec, seg, sids=sid_arr)
+        by_shard: dict[int, tuple] = {}
+        for sh, sid, gid in scan_plan:
+            by_shard.setdefault(id(sh), (sh, []))[1].append((sid, gid))
+        remaining_plan = []
+        units = []  # thunks: () -> (sh, sid_sorted, gid_sorted, sid_arr, rec)
+        for sh, pairs in by_shard.values():
+            if pre_eligible:
+                # a packed chunk's stored sums are the chunk's, not a
+                # series': its series would each be refused by
+                # `_scan_preagg` and decoded one by one (150 s of a count()
+                # over 1,000,000 series), so they take the bulk decode
+                # here, and only the others ask for their chunks' sums
+                pairs, stored = _split_packed(sh, mst, pairs, tmin, tmax)
+                remaining_plan.extend((sh, sid, gid) for sid, gid in stored)
+            if len(pairs) < 64 or not hasattr(sh, "read_series_bulk"):
+                remaining_plan.extend(
+                    (sh, sid, gid) for sid, gid in pairs)
+                # rows not yet flushed: taken once a shard for all its
+                # series of the tail (span `mem_read`), not once a
+                # series and a scan range; a proxy has no such view
+                view = sh.mem_view(
+                    mst, [sid for sid, _gid in pairs], read_fields) \
+                    if hasattr(sh, "mem_view") and not pre_eligible else None
+                if view is not None:
+                    mem_kw[id(sh)] = {"mem": view}
+                continue
+            sid_list = np.asarray([p[0] for p in pairs], np.int64)
+            gid_list = np.asarray([p[1] for p in pairs], np.int64)
+            o = np.argsort(sid_list)
+            sid_sorted, gid_sorted = sid_list[o], gid_list[o]
+            for rlo, rhi in scan_ranges:
+                units.append(
+                    lambda sh=sh, ss=sid_sorted, gs=gid_sorted,
+                    rlo=rlo, rhi=rhi:
+                    (sh, ss, gs) + sh.read_series_bulk(
+                        mst, ss, rlo, rhi, fields=read_fields))
+        for sh, sid_sorted, gid_sorted, sid_arr, rec in \
+                scanpool.prefetch_ordered(units):
+            TRACKER.check()
+            if len(rec) == 0:
+                continue
+            rows_scanned += len(rec)
+            fmask = (
+                cond.eval_row_filter(sc, rec, sid_arr=sid_arr,
+                                     index=sh.index)
+                if sc.has_row_filter
+                else None
+            )
+            gid_rows = gid_sorted[
+                np.searchsorted(sid_sorted, sid_arr)]
+            if group_time:
+                widx, _ = winmod.window_index(
+                    rec.times, tmin, group_time.every_ns,
+                    group_time.offset_ns)
+                seg = (gid_rows * W + widx.astype(np.int64)
+                       ).astype(np.int32)
+            else:
+                seg = gid_rows.astype(np.int32)
+            _scan_record(rec, seg, sids=sid_arr)
         # per-series tail: stage rows and materialize ONE contiguous
         # array set per field at the end (per-chunk concatenation in this
         # loop was the executor-side hot spot at high cardinality)

@@ -124,6 +124,23 @@ def _series_needs_merged_decode(sh, mst, sid, tmin, tmax):
     return False, srcs
 
 
+def _split_packed(sh, mst, pairs, tmin, tmax):
+    """One shard's (sid, gid) pairs -> (those `_series_needs_merged_decode`
+    would refuse for a packed chunk in range, the others), all at once: a
+    sid is refused when some packed chunk's [smin, smax] holds it."""
+    spans = {(c.smin, c.smax) for _r, c in sh.file_chunks(mst, None, tmin, tmax)
+             if c.packed}
+    if not spans or not pairs:
+        return [], pairs
+    lo, hi = np.asarray(sorted(spans), np.int64).T
+    top = np.maximum.accumulate(hi)     # the widest reach of the spans so far
+    sids = np.fromiter((p[0] for p in pairs), np.int64, len(pairs))
+    at = np.searchsorted(lo, sids, side="right") - 1
+    inside = ((at >= 0) & (top[np.maximum(at, 0)] >= sids)).tolist()
+    return ([p for p, m in zip(pairs, inside) if m],
+            [p for p, m in zip(pairs, inside) if not m])
+
+
 
 def _add_record_to_batches(rec, seg, aligned, needed_fields, batches, dtype,
                            fmask, sids=None):
@@ -1109,6 +1126,7 @@ def estimate_scan_bytes(shards, mst: str, tmin: int, tmax: int,
 __all__ = [
     "_prune_text_sids",
     "_series_needs_merged_decode",
+    "_split_packed",
     "_add_record_to_batches",
     "_merge_multi_source",
     "_inner_source_name",

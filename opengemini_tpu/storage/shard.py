@@ -1675,7 +1675,7 @@ class Shard:
         sid, last-write-wins deduped.  Packed chunks decode ONCE for all
         their series, with no per-sid Python loop (BASELINE.md config #5
         reads 1M series)."""
-        sids = np.asarray(sorted(int(s) for s in sids), dtype=np.int64)
+        sids = np.sort(np.asarray(sids, dtype=np.int64))
         lo_t = tmin if tmin is not None else -(2**63)
         hi_t = tmax if tmax is not None else 2**63 - 1
         # parts MUST append in file order (oldest first): _merge_bulk_parts
@@ -1683,7 +1683,7 @@ class Shard:
         # packed and per-sid chunks out of file order would let stale
         # rows win
         parts: list[tuple[np.ndarray, Record]] = []
-        sid_set = set(int(s) for s in sids)
+        sid_set = set(sids.tolist())
         files, mems = self._scan_state()
         n_fields = len(fields) if fields is not None else None
 
